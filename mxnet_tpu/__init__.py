@@ -22,6 +22,15 @@ import jax as _jax
 # Creation defaults stay float32 (reference numpy-frontend default dtype).
 _jax.config.update("jax_enable_x64", True)
 
+# JAX's persistent compilation cache: where JAX_COMPILATION_CACHE_DIR is set
+# JAX reads it itself; otherwise a fixed directory inside the checkout
+# (git-ignored). The path is part of the cache key, so it must not move.
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _jax.config.update(
+        "jax_compilation_cache_dir",
+        _os.path.join(_os.path.dirname(_os.path.dirname(
+            _os.path.abspath(__file__))), ".jax_cache"))
+
 # Crash diagnostics: dump python stack traces on SIGSEGV/SIGABRT/fatal
 # signals (reference USE_SIGNAL_HANDLER stack traces, src/initialize.cc).
 # Honors the reference env-var name; default on like the release builds.

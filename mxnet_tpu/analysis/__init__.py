@@ -23,7 +23,7 @@ from . import guards
 from .guards import (AliasSentinel, GuardViolation, HostSyncError,
                      LockOrderError, LockOrderWitness, RecompileError,
                      WitnessLock, check_lock_order, debug_guards_enabled,
-                     disable_debug, dma_ledger_check, enable_debug,
+                     disable_debug, enable_debug,
                      make_lock, no_recompile, no_sync, reset_lock_witness,
                      witness)
 
@@ -57,7 +57,7 @@ __all__ = [
     "AliasSentinel", "GuardViolation", "HostSyncError", "LockOrderError",
     "LockOrderWitness", "RecompileError", "WitnessLock",
     "check_lock_order", "debug_guards_enabled", "disable_debug",
-    "dma_ledger_check", "enable_debug", "make_lock", "no_recompile",
+    "enable_debug", "make_lock", "no_recompile",
     "no_sync", "reset_lock_witness", "witness",
     "RULES", "Finding", "lint_file", "lint_paths", "lint_source",
     "find_cycles",

@@ -50,7 +50,6 @@ __all__ = [
     "LockOrderWitness", "WitnessLock", "make_lock", "witness",
     "check_lock_order", "reset_lock_witness",
     "debug_guards_enabled", "enable_debug", "disable_debug",
-    "dma_ledger_check",
 ]
 
 
@@ -531,43 +530,3 @@ def make_lock(name: str):
     if _DEBUG:
         return WitnessLock(name)
     return threading.Lock()
-
-
-# ---------------------------------------------------------------------------
-# DMA ledger parity
-# ---------------------------------------------------------------------------
-
-def dma_ledger_check(require_traffic: bool = False, action: str = "raise"
-                     ) -> Dict[str, Any]:
-    """Assert start/wait parity of the DMA-resident decode ledger.
-
-    The runtime face of mxlint MX101: the static analyzer proves every
-    ``make_async_copy`` start in the kernel source reaches a wait on all
-    paths; this checks the same invariant on the live counters —
-    ``mxnet_decode_dma_copies_total`` (starts) must equal
-    ``mxnet_decode_dma_waits_total`` (retired waits) after a paged-DMA
-    serve round. A skew means a launch-site ledger drifted from the
-    kernel's actual DMA program (copies recorded without their waits, or
-    vice versa). ``require_traffic=True`` additionally fails when the
-    ledger is empty — for callers that just ran a round which must have
-    recorded DMA traffic (``run_decode_check``).
-
-    Returns ``{"copies": c, "waits": w, "ok": bool}``; on a violation
-    raises :class:`GuardViolation` (``action="raise"``) or counts it on
-    ``mxnet_guard_violations_total{guard=dma_ledger}`` and returns
-    (``action="count"``).
-    """
-    from .. import metrics as _metrics
-    copies = _metrics.get_sample_value("mxnet_decode_dma_copies_total") or 0
-    waits = _metrics.get_sample_value("mxnet_decode_dma_waits_total") or 0
-    ok = copies == waits and not (require_traffic and copies == 0)
-    if not ok:
-        skew = int(abs(copies - waits))
-        _count_violation("dma_ledger", skew or 1)
-        msg = ("DMA ledger parity violated: "
-               f"{int(copies)} copies started vs {int(waits)} waits "
-               "retired" if copies != waits else
-               "DMA ledger empty after a round that must record traffic")
-        if action == "raise":
-            raise GuardViolation(msg)
-    return {"copies": int(copies), "waits": int(waits), "ok": ok}

@@ -1,8 +1,9 @@
 """mxkernlint: static verification of the Pallas kernel family.
 
-The hand-written Pallas kernels (fused block decode, its VMEM-paged and
-DMA-resident paged variants, the int4/int8 weight GEMVs, flash
-attention) carry three invariant classes that no CPU interpret-mode
+Hand-written Pallas kernels (today: the int4/int8 weight GEMVs, the
+fused LM-head sampler, flash attention; the rules were written for
+kernels that also issue their own DMAs and launch behind a ``fusable*``
+VMEM gate) carry three invariant classes that no CPU interpret-mode
 parity test can see: an async copy that is started but never waited
 corrupts VMEM on real hardware only; a double-buffer scratch slot
 re-started before its in-flight gather lands is a data race that
@@ -25,7 +26,7 @@ machine (no jax import — ``tools/mxlint.py`` loads it standalone):
   same loop, or the loop's trip count provably never exceeds the slot
   count (the warm-up pattern ``range(min(depth - 1, nt))``).
 - **MX102 memory-space discipline** — an HBM-resident ref
-  (``pl.BlockSpec(memory_space=pltpu.ANY)``) may only feed async copies
+  (``pl.BlockSpec(memory_space=pl.ANY)``) may only feed async copies
   (``ref.at[...]`` inside ``make_async_copy``) or be ``del``-ed; any
   direct load/store or compute use reads HBM from inside the kernel.
 - **MX103 static VMEM budget** — each kernel's VMEM-resident footprint
@@ -943,17 +944,6 @@ class _Machine:
             return ("ds", args[0], args[1] if len(args) > 1 else None)
         if last == "when":
             return _WhenV(args[0] if args else True)
-        if last == "load" and dotted and dotted.startswith("pl"):
-            if args and isinstance(args[0], _RefV):
-                self._access(args[0], args[1] if len(args) > 1 else None,
-                             "load", node.lineno)
-                return _atom(f"load({_canon(args[0])},"
-                             f"{_canon(args[1] if len(args) > 1 else None)})")
-        if last == "store" and dotted and dotted.startswith("pl"):
-            if args and isinstance(args[0], _RefV):
-                self._access(args[0], args[1] if len(args) > 1 else None,
-                             "store", node.lineno)
-            return None
         if last == "program_id":
             return _atom(f"pl.program_id({_canon(args[0]) if args else ''})")
         if last == "fori_loop":
@@ -1513,7 +1503,7 @@ def _mx102(site: _KernelSite) -> List[Dict[str, Any]]:
                 and n.id not in shadowed and id(n) not in allowed:
             findings.append({
                 "rule": "MX102", "line": n.lineno, "col": n.col_offset,
-                "message": (f"HBM-resident (pltpu.ANY) ref '{n.id}' used "
+                "message": (f"HBM-resident (pl.ANY) ref '{n.id}' used "
                             "outside an async copy — direct loads/stores "
                             "or compute on an ANY ref read HBM from "
                             "inside the kernel"),

@@ -39,7 +39,7 @@ Rules
 - **MX101/MX102/MX103 Pallas kernel family** — DMA lifecycle (every
   ``make_async_copy`` start reaches a wait on all paths, no scratch-slot
   reuse before its in-flight copy lands), memory-space discipline (an
-  HBM-resident ``pltpu.ANY`` ref only feeds async copies), and the
+  HBM-resident ``pl.ANY`` ref only feeds async copies), and the
   static VMEM budget cross-check against the runtime ``fusable_*``
   gates. Implemented in :mod:`analysis.kernels`; the rules only fire on
   files containing a ``pallas_call`` site.

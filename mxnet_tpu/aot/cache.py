@@ -428,6 +428,21 @@ class _AotExecutable:
             return self._jitted(*args)
 
 
+def _execution_devices(example_args):
+    """Devices, in assignment order, that a program lowered for
+    ``example_args`` runs on: the mesh of a NamedSharding argument, else
+    the one device the arguments sit on, else the default device."""
+    from jax.sharding import NamedSharding
+    single = None
+    for leaf in jax.tree.leaves(example_args):
+        sh = getattr(leaf, "sharding", None)
+        if isinstance(sh, NamedSharding):
+            return list(sh.mesh.devices.flat)
+        if sh is not None and single is None:
+            single = list(sh.device_set)
+    return single or [jax.devices()[0]]
+
+
 def compile_cached(jitted, example_args: Sequence, label: str,
                    extra: Any = None):
     """Compile ``jitted`` for ``example_args`` through the persistent
@@ -488,7 +503,11 @@ def compile_cached(jitted, example_args: Sequence, label: str,
             t0 = time.perf_counter()
             try:
                 triple = pickle.loads(payload)
-                compiled = _se.deserialize_and_load(*triple)
+                # load onto the devices this program's arguments live
+                # on; left to itself the loader takes every local device
+                compiled = _se.deserialize_and_load(
+                    *triple, execution_devices=_execution_devices(
+                        example_args))
                 _metrics.AOT_HITS.labels(block=label).inc()
                 _metrics.AOT_LOAD_SECONDS.observe(time.perf_counter() - t0)
                 _ledger(compiled)

@@ -454,8 +454,7 @@ def quantize_net(network, quantized_dtype: str = "auto",
                  calib_data=None, data_shapes=None,
                  calib_mode: str = "none", num_calib_batches: Optional[int] = None,
                  device=None, ctx=None, logger_=None,
-                 quantize_tied_head: Optional[bool] = None,
-                 fused_decode: bool = False, bits: int = 8):
+                 quantize_tied_head: Optional[bool] = None, bits: int = 8):
     """Quantize a (forward-run) HybridBlock in place and return it
     (reference contrib.quantization.quantize_net, quantization.py:92).
 
@@ -469,13 +468,6 @@ def quantize_net(network, quantized_dtype: str = "auto",
     excluded via ``exclude_layers``/``exclude_layers_match`` — an exclusion
     means 'keep this layer full precision', and the tied head reads the
     SAME table, so it must honor it; True/False force either way.
-
-    ``fused_decode``: after freezing, opt the model's transformer blocks
-    into the block-level fused decode kernel (ops/fused_block_gemv: one
-    Pallas launch per block instead of 4 GEMV launches) when the model
-    exposes ``enable_fused_decode`` (GPT family). Blocks whose layers
-    were excluded from quantization keep the unfused path (per-layer
-    opt-in with an XLA fallback).
 
     ``bits``: weight codec width for Dense layers and the tied head — 8
     (default) or 4. ``bits=4`` stores the kvstore/quant.py block-scaled
@@ -534,8 +526,6 @@ def quantize_net(network, quantized_dtype: str = "auto",
             for n in tied_names)
     if quantize_tied_head:
         _quantize_tied_lm_head(network, bits=bits)
-    if fused_decode and hasattr(network, "enable_fused_decode"):
-        network.enable_fused_decode()
     network.hybridize()
     return network
 

@@ -35,7 +35,7 @@ def _cpu_device():
     """CPU device for callback execution; None when CPU is already the
     default backend (no transfer needed)."""
     try:
-        if jax.default_backend() == "cpu":
+        if jax.devices()[0].platform == "cpu":
             return None
         return jax.local_devices(backend="cpu")[0]
     except Exception:
@@ -140,9 +140,9 @@ class ExtensionOp:
             out_specs = op._infer([(v.shape, v.dtype) for v in vals])
             result_shape = tuple(
                 jax.ShapeDtypeStruct(s, d) for s, d in out_specs)
-            # Route the callback through the CPU backend: accelerator
-            # plugins without host send/recv support (e.g. tunneled PJRT)
-            # can't bind callbacks on device-committed operands. Outside
+            # Route the callback through the CPU backend: a backend
+            # without host send/recv support can't bind callbacks on
+            # device-committed operands. Outside
             # an accelerator jit these are explicit transfers; inside one
             # they require the backend to support host callbacks.
             cpu = _cpu_device()

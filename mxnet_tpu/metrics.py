@@ -901,36 +901,14 @@ SERVE_ROUNDTRIPS = Counter(
 DECODE_LAUNCHES = Counter(
     "mxnet_decode_launches_total",
     "Decode kernel-launch SITES recorded at trace time (kind=gemv|"
-    "fused_block|fused_block_paged|fused_head|spec_verify): one "
-    "increment per launch the compiled step will issue per execution — "
-    "the static launches-per-step the fused-decode path collapses "
-    "(ops/int8_gemv.count_launches tallies one trace). fused_block_paged "
-    "is the paged engine's one-launch block step; fused_block_paged_dma "
-    "its DMA-resident variant for pools past the VMEM budget; an _int4 "
-    "suffix (and the gemv_int4 kind) marks the packed-nibble weight "
-    "lane; spec_verify marks a speculative verify executable's trace",
+    "gemv_int4|fused_head|reference|spec_verify): one increment per "
+    "launch the compiled step will issue per execution "
+    "(ops/int8_gemv.count_launches tallies one trace). A kernel's kind "
+    "is counted only where the Pallas kernel itself runs; reference "
+    "marks a site where the plain-XLA reference ran in its place "
+    "(off-TPU); spec_verify marks a speculative verify executable's "
+    "trace",
     labels=("kind",))
-DECODE_DMA_COPIES = Counter(
-    "mxnet_decode_dma_copies_total",
-    "Async K/V page copies the DMA-resident paged fused decode kernel "
-    "issues per execution (scatters of the new token row + per-page "
-    "gathers into the double buffer). Trace-time semantics like "
-    "mxnet_decode_launches_total: the STATIC per-step DMA program, not "
-    "runtime events")
-DECODE_DMA_BYTES = Counter(
-    "mxnet_decode_dma_bytes_total",
-    "Bytes those async copies move per execution of the DMA-resident "
-    "paged fused decode step (pool-dtype bytes; gathers dominate). "
-    "bytes/copies = mean transfer size — small means the page size is "
-    "fragmenting the stream")
-DECODE_DMA_WAITS = Counter(
-    "mxnet_decode_dma_waits_total",
-    "Semaphore waits the DMA-resident paged fused decode kernel retires "
-    "per execution. The lifecycle invariant is waits == copies (every "
-    "async copy started is waited exactly once — the static guarantee "
-    "mxlint MX101 proves on the kernel source); "
-    "analysis.guards.dma_ledger_check() asserts the parity at runtime "
-    "after a paged-DMA serve round")
 
 # --- self-speculative decoding (serve engine speculate=K) --------------------
 SPEC_DRAFTED = Counter(

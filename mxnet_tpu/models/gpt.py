@@ -65,26 +65,8 @@ class GPTBlock(HybridBlock):
         return x + self.dropout(self.mlp_proj(h))
 
     def forward_cached(self, x, pos, k_cache, v_cache):
-        """Incremental forward against the [B, H, L, hd] KV caches. When
-        the block is opted into fused decode (enable_fused_decode after
-        quantize_net) and this is a T=1 step, the whole step — 4 int8
-        GEMVs, LN, cached attention, GeLU, residuals — runs as ONE launch
-        (ops/fused_block_gemv; XLA fallback off-TPU is bitwise-identical
-        to this unfused path)."""
+        """Incremental forward against the [B, H, L, hd] KV caches."""
         from .llama import _cached_attention
-        pack = getattr(self, "_fused_pack", None)
-        if pack is not None and x.shape[1] == 1:
-            from ..ndarray import apply_multi
-            from ..ops.fused_block_gemv import fused_block_decode
-
-            def ffn(xv, posv, kc, vc):
-                # pack Parameters (ln/bias) resolve through the active
-                # trace scope inside fused_block_decode; w_q/scales are
-                # frozen constants (the QuantizedDense idiom)
-                return fused_block_decode(xv, posv, kc, vc, pack)
-
-            return apply_multi(ffn, [x, pos, k_cache, v_cache],
-                               name="gpt_block_fused")
         B, T, d = x.shape
         H = self._heads
         hd = d // H
@@ -106,27 +88,8 @@ class GPTBlock(HybridBlock):
 
     def forward_cached_paged(self, x, pos, block_table, k_pages, v_pages):
         """Incremental forward against the shared PAGED KV pool
-        (models/llama._paged_attention). When the block is opted into
-        fused decode and this is a T=1 step, the whole step runs as ONE
-        launch gathering/scattering KV through the block table in-kernel
-        (ops/fused_block_gemv.fused_block_decode_paged) — the paged
-        engine gets the same 49→13 launch collapse as the contiguous
-        one. The XLA fallback replays this unfused paged op sequence
-        bitwise off-TPU."""
+        (models/llama._paged_attention)."""
         from .llama import _paged_attention
-        pack = getattr(self, "_fused_pack", None)
-        if pack is not None and x.shape[1] == 1:
-            from ..ndarray import apply_multi
-            from ..ops.fused_block_gemv import fused_block_decode_paged
-
-            def ffn(xv, posv, bt, kp, vp):
-                # pack Parameters (ln/bias) resolve through the active
-                # trace scope inside fused_block_decode_paged; w_q/scales
-                # are frozen constants (the QuantizedDense idiom)
-                return fused_block_decode_paged(xv, posv, bt, kp, vp, pack)
-
-            return apply_multi(ffn, [x, pos, block_table, k_pages, v_pages],
-                               name="gpt_block_fused_paged")
         B, T, d = x.shape
         H = self._heads
         hd = d // H
@@ -250,32 +213,6 @@ class GPTModel(HybridBlock):
         """(int8 table [Vp, D], scales [Vp], vocab) for the fused LM-head
         sampling path, or None when the tied head is not int8-quantized."""
         return getattr(self, "_q_lm_head", None)
-
-    def enable_fused_decode(self):
-        """Opt quantized transformer blocks into the block-level fused
-        decode kernel (one launch per block — ops/fused_block_gemv).
-        Per-layer: blocks whose four Dense layers are not all frozen
-        QuantizedDense keep the unfused path. Returns the number of blocks
-        fused. Drops cached decode executables (they baked the unfused
-        trace)."""
-        from ..ops.fused_block_gemv import pack_gpt_block
-        n = 0
-        for blk in self.blocks:
-            pack = pack_gpt_block(blk, eps=self.cfg.layer_norm_eps)
-            if pack is not None:
-                blk._fused_pack = pack
-                n += 1
-        from . import generation as _generation
-        _generation.clear_cache()
-        return n
-
-    def disable_fused_decode(self):
-        """Revert every block to the unfused decode path."""
-        for blk in self.blocks:
-            if hasattr(blk, "_fused_pack"):
-                del blk._fused_pack
-        from . import generation as _generation
-        _generation.clear_cache()
 
     def _lm_head(self, x):
         """Tied LM head. When quantize_net stored a weight-only int8 table

@@ -308,9 +308,7 @@ def _paged_attention(qh, kh, vh, k_pages, v_pages, block_table, pos, rep):
     logits are bitwise what the sequential decode would compute —
     rejected drafts leave stale K/V rows past the accepted point that
     the causal mask hides until the rows are overwritten, exactly like
-    the multi-token loop's speculative rows. This is also the program
-    the fused paged block kernel replays bitwise as its XLA fallback
-    (ops/fused_block_gemv._reference_block_decode_paged)."""
+    the multi-token loop's speculative rows."""
     B, H, T, hd = qh.shape
     G, ps = k_pages.shape[1], k_pages.shape[2]
     maxp = block_table.shape[1]

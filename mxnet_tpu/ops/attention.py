@@ -34,6 +34,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..device import on_tpu
+
 __all__ = ["flash_attention", "flash_attention_bthd", "attention"]
 
 # Adaptive query/kv block candidates, largest first (v5e sweep: 512/512
@@ -277,7 +279,7 @@ def _xla_attention(q, k, v, causal: bool, scale: float,
 def _fallback(q, k, v, causal: bool, scale: float):
     T, S = q.shape[2], k.shape[2]
     if T * S <= _XLA_PATH_MAX_SCORE_ELEMS:
-        if jax.default_backend() == "tpu":
+        if on_tpu():
             return _xla_attention(q, k, v, causal, scale)
         return _jnp_reference(q, k, v, causal, scale)
     return _chunked_reference(q, k, v, causal, scale)
@@ -562,6 +564,17 @@ def _pallas_backward(q, k, v, o, lse, do, causal: bool, scale: float):
         + 2 * bk * Dp * k.dtype.itemsize
     use_fused = fused_vmem <= 6 * 1024 * 1024
     assert lse.shape == (B, H, Tp, 1), lse.shape
+    # The two-kernel sweep holds whole-sequence blocks, double-buffered:
+    # dkv keeps q, do, o (Tp x Dp each) and lse, whose (Tp, 1) f32 block
+    # pads to 128 lanes in VMEM; dq keeps k and v. That passes the
+    # compiler's default scoped-VMEM limit (16 MiB) already at T=16k, so
+    # these two calls ask for what they need.
+    from jax.experimental.pallas import tpu as pltpu
+    isz = q.dtype.itemsize
+    two_kernel_vmem = 2 * max(3 * Tp * Dp * isz + Tp * 128 * 4,
+                              2 * Sp * Dp * isz) + (8 << 20)
+    two_kernel_params = pltpu.CompilerParams(
+        vmem_limit_bytes=two_kernel_vmem)
 
     with jax.enable_x64(False):
         if use_fused:
@@ -602,6 +615,7 @@ def _pallas_backward(q, k, v, o, lse, do, causal: bool, scale: float):
                 ],
                 out_specs=pl.BlockSpec((1, 1, bq, Dp),
                                        lambda b, h, i: (b, h, i, 0)),
+                compiler_params=two_kernel_params,
             )(qp, kp, vp, dop, op, lser)
             dk, dv = pl.pallas_call(
                 dkv_kernel,
@@ -620,6 +634,7 @@ def _pallas_backward(q, k, v, o, lse, do, causal: bool, scale: float):
                                         lambda b, h, j: (b, h, j, 0)),
                            pl.BlockSpec((1, 1, bk, Dp),
                                         lambda b, h, j: (b, h, j, 0))],
+                compiler_params=two_kernel_params,
             )(qp, kp, vp, dop, op, lser)
     dq = dq[:, :, :T, :D]
     dk = dk[:, :, :S, :D]
@@ -633,7 +648,7 @@ def _use_pallas(q, k, causal: bool) -> bool:
     tiny T/S (dispatch-bound, e.g. single-token decode — chunked fallback is
     exact and O(T·S) is KBs), head dim > 256 (no MXU tiling), causal with
     more queries than keys (ill-posed rows), exotic dtypes."""
-    if jax.default_backend() != "tpu":
+    if not on_tpu():
         return False
     B, H, T, D = q.shape
     S = k.shape[2]
@@ -643,7 +658,28 @@ def _use_pallas(q, k, causal: bool) -> bool:
         return False
     if causal and T > S:
         return False
+    # the forward kernel keeps whole-sequence k and v blocks in VMEM,
+    # double-buffered, under the compiler's default 16 MiB scoped limit
+    if 4 * _choose_block(S)[1] * _pad_head_dim(D) * q.dtype.itemsize \
+            > 12 * 1024 * 1024:
+        return False
     return T >= _MIN_KERNEL_LEN and S >= _MIN_KERNEL_LEN
+
+
+def _over_mesh(kernel, *arrays):
+    """Run ``kernel`` (a Pallas forward or backward over (B, H, ., .)
+    arrays) on the current mesh. GSPMD cannot partition a Mosaic kernel
+    ("Mosaic kernels cannot be automatically partitioned"), so under a mesh
+    the call is mapped by hand: the batch over 'dp', every other axis
+    replicated. Attention is independent per (batch, head), so each shard
+    is the whole computation for its rows."""
+    from ..parallel.mesh import P, current_mesh, shard_map
+    mesh = current_mesh()
+    if mesh is None or dict(mesh.shape).get("dp", 1) == 1:
+        return kernel(*arrays)
+    spec = P("dp")
+    return shard_map(kernel, mesh, in_specs=(spec,) * len(arrays),
+                     out_specs=spec, check_vma=False)(*arrays)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -656,14 +692,17 @@ def flash_attention(q, k, v, causal: bool = False, scale: Optional[float] = None
     every path. GQA: call with kv heads already repeated (see models.llama)."""
     s = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
     if _use_pallas(q, k, causal):
-        return _pallas_forward(q, k, v, causal, s)
+        return _over_mesh(
+            lambda q, k, v: _pallas_forward(q, k, v, causal, s), q, k, v)
     return _fallback(q, k, v, causal, s)
 
 
 def _fwd(q, k, v, causal, scale):
     s = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
     if _use_pallas(q, k, causal):
-        o, lse = _pallas_forward(q, k, v, causal, s, with_lse=True)
+        o, lse = _over_mesh(
+            lambda q, k, v: _pallas_forward(q, k, v, causal, s,
+                                            with_lse=True), q, k, v)
         return o, (q, k, v, o, lse)
     return _fallback(q, k, v, causal, s), (q, k, v, None, None)
 
@@ -672,7 +711,9 @@ def _bwd(causal, scale, res, g):
     q, k, v, o, lse = res
     s = scale if scale is not None else 1.0 / (q.shape[-1] ** 0.5)
     if o is not None:
-        return _pallas_backward(q, k, v, o, lse, g, causal, s)
+        return _over_mesh(
+            lambda q, k, v, o, lse, g: _pallas_backward(
+                q, k, v, o, lse, g, causal, s), q, k, v, o, lse, g)
 
     def ref(q, k, v):
         return _fallback(q, k, v, causal, s)

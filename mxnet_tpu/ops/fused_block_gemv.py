@@ -1,55 +1,13 @@
-"""Block-level fused GEMV family for the overhead-bound decode regime.
+"""Tied LM-head GEMV fused with token selection, for the decode step.
 
-ROOFLINE.md's r6 ledger shows the int8 decode step pays ~49 *separate*
-Pallas GEMV launches per token (4 Dense per transformer block x 12 + the
-tied head) and runs at 14% of its weight-stream floor: the per-launch
-overhead, not bytes or FLOPs, decides throughput (Operator Fusion in XLA,
-arXiv:2301.13062). This module collapses one transformer block's whole
-decode step — LN1 -> qkv GEMV -> cached attention -> out GEMV -> residual
--> LN2 -> fc GEMV -> GeLU -> proj GEMV -> residual — into ONE Pallas
-launch that streams all four int8 weight matrices through VMEM with
-dequantization, bias and activation epilogues inline, and fuses the tied
-LM-head GEMV with sampling so the [B, V] logits never round-trip through
-a separate full-vocab kernel.
-
-Four public entry points:
-
-- :func:`pack_gpt_block` — extract one GPT block's frozen int8 weights
-  (``contrib.quantization.QuantizedDense`` wrappers) into the packed
-  layout the kernel streams: ``w1`` = [qkv | attn_out | fc] rows over a
-  shared K=D contraction, ``w2`` = proj over K=4D, each with per-output-
-  channel scales and biases. Returns None unless every one of the four
-  layers is quantized — models opt in PER LAYER, and unpacked blocks keep
-  the unfused path (the XLA fallback contract).
-- :func:`fused_block_decode` — one block's T=1 decode step. On TPU (and
-  when :func:`fusable` approves the shapes) this is a single
-  ``pallas_call``; everywhere else it runs :func:`_reference_block_decode`,
-  which replays EXACTLY the op sequence of the unfused
-  QuantizedDense/LayerNorm/attention path so fused-vs-unfused parity is
-  bitwise off-TPU (tier-1 tests assert it).
-- :func:`fused_block_decode_paged` — the same one-launch step over the
-  PAGED KV pool (serve/paging): pages are fixed-size, so the per-slot
-  block table is a cheap index transform on the same VMEM stream — the
-  kernel scatters the new K/V row through ``table[pos // ps]`` and
-  gathers the table's pages back into the logical [L, hd] view before
-  the identical attention math. This is what lets the production engine
-  (``paged=True``) serve the 13-launch step on the 4×-concurrency pool
-  instead of choosing between them (the PR-7 remnant). The XLA fallback
-  replays the unfused ``_paged_attention`` op sequence bitwise off-TPU.
-  Pools past the VMEM-resident gate (:func:`fusable_paged`) do NOT fall
-  back anymore: the DMA-resident variant
-  (:func:`_pallas_block_decode_paged_dma`) keeps the pools in HBM and
-  double-buffers per-(row, head) page gathers into VMEM scratch with
-  ``pltpu.make_async_copy`` — the pool size drops out of the VMEM
-  arithmetic entirely (:func:`fusable_paged_dma`), so the 13-launch
-  step survives production pool sizes.
-- :func:`fused_lm_head_sample` — tied-head GEMV + temperature/top-k/top-p
-  + token selection in one step. On TPU the greedy / pure-temperature
-  rows stream the int8 table once with a running (Gumbel-)argmax in the
-  reduction epilogue — no [B, V] materialization, no full-vocab sort;
-  rows with top-k/top-p filters take the XLA path under ``lax.cond``
-  (exact ``filter_logits`` semantics need the sorted tail). Off-TPU the
-  fallback matches ``models.generation.sample_tokens`` bitwise.
+:func:`fused_lm_head_sample` runs the tied-head GEMV, temperature /
+top-k / top-p and token selection in one step. On TPU the greedy and
+pure-temperature rows stream the int8 table once through a Pallas kernel
+with a running (Gumbel-)argmax in the reduction epilogue — no [B, V]
+materialization, no full-vocab sort; rows with top-k/top-p filters take
+the XLA path under ``lax.cond`` (exact ``filter_logits`` semantics need
+the sorted tail), and so does a packed-int4 table. Off-TPU the reference
+matches ``models.generation.sample_tokens`` bitwise.
 
 Vocab padding: ``contrib.quantization._quantize_tied_lm_head`` pads the
 int8 table's vocab dim to a 128-lane multiple (50257 -> 50304) so the
@@ -57,184 +15,34 @@ reduction tiles land on lane boundaries without a remainder branch; the
 pad lanes are masked to -inf before any sampling and sliced off before
 any logits consumer (the slice is free — XLA folds it into the layout).
 
-TPU-side determinism note: the fused sampling kernel draws its Gumbel
-noise from a stateless hash of (request fold_in key bits, absolute vocab
-lane), so sampled tokens are deterministic per (seed, counter) and
-independent of batch composition — but follow a different stream than
-host ``jax.random.categorical``; greedy rows are exactly identical.
-Off-TPU (where the parity tests run) sampled rows are bitwise identical
-too, because the fallback IS ``sample_tokens``.
+TPU-side determinism note: the kernel draws its Gumbel noise from a
+stateless hash of (request fold_in key bits, absolute vocab lane), so
+sampled tokens are deterministic per (seed, counter) and independent of
+batch composition — but follow a different stream than host
+``jax.random.categorical``; greedy rows are exactly identical.
 
-No reference counterpart: the reference framework predates LLM decode;
-this design is TPU-first (SNIPPETS.md block-fusion idiom).
+The block-level decode kernels that once lived here (one launch per
+transformer block, contiguous / VMEM-paged / DMA-paged) were refused by
+the v5e compiler and are gone; CHANGES.md (PR 23) records the messages.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from .int8_gemv import record_dma, record_launch
+from ..device import on_tpu
+from .int8_gemv import record_launch
 
-__all__ = ["pack_gpt_block", "fused_block_decode",
-           "fused_block_decode_paged", "fused_lm_head_sample",
-           "fusable", "fusable_paged", "fusable_paged_dma",
-           "VOCAB_LANE", "pad_vocab"]
+__all__ = ["fused_lm_head_sample", "VOCAB_LANE", "pad_vocab"]
 
-# lane width the vocab dim is padded to (satellite: 50257 -> 50304)
+# lane width the vocab dim is padded to (50257 -> 50304)
 VOCAB_LANE = 128
-# output-channel block candidates for the streamed weight phases; the
-# chosen block must divide D so the 3D/D/4D segments tile without a
-# remainder branch
-_BN_CANDIDATES = (512, 384, 256, 128)
-# VMEM budget the single-launch kernels may claim (caches + scratch +
-# one weight block). This constant is the DEFAULT of the tuned-config
-# layer's `fused_vmem_budget` knob — the gates consult _vmem_budget()
-# below, never this constant directly, so a measured budget (or
-# MXNET_TUNE_FUSED_VMEM_BUDGET) applies without editing it.
-_VMEM_BUDGET = 12 * 1024 * 1024
-
-
-def _vmem_budget() -> int:
-    """The fused-kernel VMEM budget: env override
-    (``MXNET_TUNE_FUSED_VMEM_BUDGET``) > tuned config > ``_VMEM_BUDGET``.
-    Resolved at trace time by the shape gates, so the python comparison
-    never reaches a compiled step."""
-    from ..tune import config as _tune
-    return _tune.get_knob("fused_vmem_budget")
-
-
-def _dma_depth() -> int:
-    """Double-buffer slots of the DMA-resident paged kernel
-    (``fused_dma_depth`` knob; 2 = classic double buffering)."""
-    from ..tune import config as _tune
-    return _tune.get_knob("fused_dma_depth")
 
 
 def pad_vocab(n: int) -> int:
     """Smallest multiple of VOCAB_LANE >= n."""
     return -(-int(n) // VOCAB_LANE) * VOCAB_LANE
 
-
-def _block_n(D: int):
-    # the tuned-config layer may pin the output-channel block
-    # (`fused_block_bn`; 0/absent = the hand-picked candidate scan);
-    # a tuned block that does not divide D cannot tile and is ignored
-    from ..tune import config as _tune
-    bn = _tune.get_knob("fused_block_bn")
-    if bn and D % bn == 0:
-        return bn
-    for cand in _BN_CANDIDATES:
-        if D % cand == 0:
-            return cand
-    return None
-
-
-def fusable(B: int, D: int, heads: int, L: int, cache_itemsize: int = 4):
-    """Shape gate for the single-launch TPU kernel: the 3D/D/4D weight
-    segments must tile a lane-aligned block exactly and the KV cache
-    slice plus scratch must fit the VMEM budget. Unfusable shapes keep
-    the (correct, slower) unfused XLA path."""
-    bn = _block_n(D)
-    if bn is None or D % heads:
-        return False
-    hd = D // heads
-    if hd % 8:
-        return False
-    # x4: K and V, each held as an input block AND an output block
-    cache_bytes = 4 * B * heads * L * hd * cache_itemsize
-    scratch_bytes = B * (9 * D) * 4 + bn * max(D, 4 * D)
-    return cache_bytes + scratch_bytes <= _vmem_budget()
-
-
-def fusable_paged(B: int, D: int, heads: int, pool_pages: int,
-                  page_size: int, max_pages: int, cache_itemsize: int = 4):
-    """Shape gate for the PAGED single-launch kernel. Same tiling rules
-    as :func:`fusable`, but the resident KV state is the whole shared
-    page pool (incl. the sink page) rather than a per-slot contiguous
-    region, plus the [L, hd] gather scratch the per-row table walk fills.
-    Pools too large for the VMEM budget fall through to the DMA-resident
-    variant (:func:`fusable_paged_dma`), which drops the pool size from
-    the arithmetic entirely."""
-    bn = _block_n(D)
-    if bn is None or D % heads:
-        return False
-    hd = D // heads
-    if hd % 8:
-        return False
-    # x4: K and V pools, each held as an input block AND an output block
-    cache_bytes = 4 * pool_pages * heads * page_size * hd * cache_itemsize
-    # per-(b, h) gather scratch: the logical [max_pages * ps, hd] K and V
-    # views the table walk assembles (f32)
-    gather_bytes = 2 * max_pages * page_size * hd * 4
-    scratch_bytes = B * (9 * D) * 4 + bn * max(D, 4 * D)
-    return cache_bytes + gather_bytes + scratch_bytes <= _vmem_budget()
-
-
-def fusable_paged_dma(B: int, D: int, heads: int, pool_pages: int,
-                      page_size: int, max_pages: int,
-                      cache_itemsize: int = 4, depth: int = None):
-    """Shape gate for the DMA-resident paged single-launch kernel. Same
-    tiling rules as :func:`fusable_paged`, but the K/V pools stay in HBM
-    (``pltpu.ANY``) and only the ``depth`` double-buffered [L, hd]
-    gather slots plus the one-row scatter stages are VMEM-resident —
-    ``pool_pages`` deliberately does NOT appear in the byte arithmetic,
-    which is exactly the cap this variant removes. Shapes that fail the
-    tiling rules (or a budget too small even for the scratch) keep the
-    (correct, slower) unfused paged path."""
-    bn = _block_n(D)
-    if bn is None or D % heads:
-        return False
-    hd = D // heads
-    if hd % 8:
-        return False
-    if depth is None:
-        depth = _dma_depth()
-    L = max_pages * page_size
-    # depth [L, hd] K and V gather slots + the one-row K/V scatter
-    # stages, all POOL dtype (a DMA moves bytes, it cannot convert);
-    # the pools themselves are HBM-resident
-    gather_bytes = 2 * depth * L * hd * cache_itemsize
-    stage_bytes = 2 * hd * cache_itemsize
-    scratch_bytes = B * (9 * D) * 4 + bn * max(D, 4 * D)
-    return gather_bytes + stage_bytes + scratch_bytes <= _vmem_budget()
-
-
-# ---------------------------------------------------------------------------
-# packing
-# ---------------------------------------------------------------------------
-
-def pack_gpt_block(block, eps: float):
-    """Extract one GPTBlock's fused-decode pack, or None if any of the
-    four Dense layers is not a frozen QuantizedDense (per-layer opt-in:
-    such blocks keep the unfused path)."""
-    layers = []
-    for name in ("attn_qkv", "attn_out", "mlp_fc", "mlp_proj"):
-        q = getattr(block, name, None)
-        if q is None or not hasattr(q, "_w_q"):
-            return None
-        layers.append(q)
-    if len({str(q._w_q.dtype) for q in layers}) > 1:
-        # mixed int4/int8 layers (an odd-K layer kept int8 under bits=4)
-        # cannot share one packed weight stream; keep the unfused path
-        return None
-    qkv, out, fc, proj = layers
-
-    def wsb(q):
-        bias = None if q.inner.bias is None else q.inner.bias
-        return q._w_q, q._w_scale, bias
-
-    pack = {
-        "qkv": wsb(qkv), "out": wsb(out), "fc": wsb(fc), "proj": wsb(proj),
-        "ln1": (block.ln_1.gamma, block.ln_1.beta),
-        "ln2": (block.ln_2.gamma, block.ln_2.beta),
-        "eps": float(eps), "heads": int(block._heads),
-    }
-    return pack
-
-
-# ---------------------------------------------------------------------------
-# reference path — bitwise-identical to the unfused QuantizedDense chain
-# ---------------------------------------------------------------------------
 
 def _deq_matmul(x2d, w_q, w_scale):
     """The exact off-TPU math of ops.int8_gemv.int8_weight_matmul /
@@ -256,944 +64,6 @@ def _deq_matmul(x2d, w_q, w_scale):
     return x2d.astype(jnp.float32) @ wf.T
 
 
-def _ln(xv, gamma, beta, eps):
-    """The exact op sequence of numpy_extension.layer_norm (axis=-1)."""
-    mean = jnp.mean(xv, axis=-1, keepdims=True, dtype=jnp.float32)
-    var = jnp.mean(jnp.square(xv.astype(jnp.float32)), axis=-1,
-                   keepdims=True) - jnp.square(mean)
-    var = jnp.maximum(var, 0.0)
-    inv = jax.lax.rsqrt(var + eps)
-    out = ((xv.astype(jnp.float32) - mean) * inv).astype(xv.dtype)
-    shape = [1] * xv.ndim
-    shape[-1] = xv.shape[-1]
-    out = out * gamma.astype(out.dtype).reshape(shape)
-    return out + beta.astype(out.dtype).reshape(shape)
-
-
-def _dense(xv, w_q, w_scale, bias):
-    B, T, _ = xv.shape
-    y = _deq_matmul(xv.reshape(B * T, xv.shape[-1]), w_q, w_scale)
-    y = y.reshape(B, T, w_q.shape[0])
-    return y if bias is None else y + bias
-
-
-def _reference_block_decode(xv, posv, kc, vc, consts, heads, eps):
-    """One block's decode step with the SAME jnp op sequence as the
-    unfused LayerNorm -> QuantizedDense -> _cached_attention chain (the
-    bitwise XLA-fallback contract, asserted by tier-1 parity tests)."""
-    from ..models.llama import _cached_attention
-    (qkv_w, qkv_s, qkv_b, out_w, out_s, out_b, fc_w, fc_s, fc_b,
-     proj_w, proj_s, proj_b, g1, b1, g2, b2) = consts
-    B, T, d = xv.shape
-    hd = d // heads
-    qkv = _dense(_ln(xv, g1, b1, eps), qkv_w, qkv_s, qkv_b)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    qh = q.reshape(B, T, heads, hd).transpose(0, 2, 1, 3)
-    kh = k.reshape(B, T, heads, hd).transpose(0, 2, 1, 3)
-    vh = v.reshape(B, T, heads, hd).transpose(0, 2, 1, 3)
-    o, kc, vc = _cached_attention(qh, kh, vh, kc, vc, posv, 1)
-    ctx = o.transpose(0, 2, 1, 3).reshape(B, T, d)
-    x = xv + _dense(ctx, out_w, out_s, out_b)
-    h = _dense(_ln(x, g2, b2, eps), fc_w, fc_s, fc_b)
-    h = jax.nn.gelu(h, approximate=True)
-    return x + _dense(h, proj_w, proj_s, proj_b), kc, vc
-
-
-def _reference_block_decode_paged(xv, posv, bt, kp, vp, consts, heads, eps):
-    """One block's PAGED decode step with the SAME jnp op sequence as the
-    unfused LayerNorm -> QuantizedDense -> _paged_attention chain (the
-    bitwise XLA-fallback contract for the paged engine: fused-vs-unfused
-    paged decode is tier-1-asserted token-identical off-TPU)."""
-    from ..models.llama import _paged_attention
-    (qkv_w, qkv_s, qkv_b, out_w, out_s, out_b, fc_w, fc_s, fc_b,
-     proj_w, proj_s, proj_b, g1, b1, g2, b2) = consts
-    B, T, d = xv.shape
-    hd = d // heads
-    qkv = _dense(_ln(xv, g1, b1, eps), qkv_w, qkv_s, qkv_b)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    qh = q.reshape(B, T, heads, hd).transpose(0, 2, 1, 3)
-    kh = k.reshape(B, T, heads, hd).transpose(0, 2, 1, 3)
-    vh = v.reshape(B, T, heads, hd).transpose(0, 2, 1, 3)
-    o, kp, vp = _paged_attention(qh, kh, vh, kp, vp, bt, posv, 1)
-    ctx = o.transpose(0, 2, 1, 3).reshape(B, T, d)
-    x = xv + _dense(ctx, out_w, out_s, out_b)
-    h = _dense(_ln(x, g2, b2, eps), fc_w, fc_s, fc_b)
-    h = jax.nn.gelu(h, approximate=True)
-    return x + _dense(h, proj_w, proj_s, proj_b), kp, vp
-
-
-# ---------------------------------------------------------------------------
-# the single-launch TPU kernel
-# ---------------------------------------------------------------------------
-
-def _pack_tpu(consts, D):
-    """Concatenate the K=D matrices (qkv, out, fc) into one [8D, D] int8
-    stream + per-channel scale/bias rows; proj ([D, 4D]) streams second.
-
-    int4 packs (uint8 nibble codes with 2-D block scales) concatenate
-    the same way: the three K=D matrices are [N, D/2] with [N, D/block]
-    scales, so the row concat yields one [8D, D/2] nibble stream whose
-    per-row scale blocks ride along a matching [8D, D/block] matrix."""
-    (qkv_w, qkv_s, qkv_b, out_w, out_s, out_b, fc_w, fc_s, fc_b,
-     proj_w, proj_s, proj_b, g1, b1, g2, b2) = consts
-    int4 = qkv_w.dtype == jnp.uint8
-
-    def b_or_zero(b, n):
-        return jnp.zeros((n,), jnp.float32) if b is None \
-            else b.astype(jnp.float32)
-
-    w1 = jnp.concatenate([qkv_w, out_w, fc_w], axis=0)  # [8D, D(/2)]
-    if int4:
-        s1 = jnp.concatenate([qkv_s, out_s, fc_s], axis=0)  # [8D, D/blk]
-        s2 = proj_s                                         # [D, 4D/blk]
-    else:
-        s1 = jnp.concatenate([qkv_s, out_s, fc_s]).reshape(1, -1)
-        s2 = proj_s.reshape(1, -1)
-    bias1 = jnp.concatenate([b_or_zero(qkv_b, 3 * D),
-                             b_or_zero(out_b, D),
-                             b_or_zero(fc_b, 4 * D)]).reshape(1, -1)
-    bias2 = b_or_zero(proj_b, D).reshape(1, -1)
-    lane = (1, D)
-    return (w1, s1, bias1, proj_w, s2, bias2,
-            g1.astype(jnp.float32).reshape(lane),
-            b1.astype(jnp.float32).reshape(lane),
-            g2.astype(jnp.float32).reshape(lane),
-            b2.astype(jnp.float32).reshape(lane))
-
-
-def _deq_dot_body(src, w_ref, s_ref, b_ref):
-    """Shared in-kernel dequant-dot: int8 rows scale per out-channel
-    AFTER the dot; uint8 (packed int4) rows unpack the nibble pairs and
-    block-scale BEFORE it — both emit f32 ``src @ wf.T + bias`` with the
-    same accumulation order as their reference lanes."""
-    w = w_ref[...]
-    if w.dtype == jnp.uint8:
-        bn_, K2 = w.shape
-        Kw = 2 * K2
-        nsb = s_ref.shape[1]
-        blk = Kw // nsb
-        w32 = w.astype(jnp.int32)
-        # unpack_codes semantics: lo nibble first, then hi, offset -8
-        codes = jnp.stack([(w32 & 0xF) - 8, (w32 >> 4) - 8],
-                          axis=-1).reshape(bn_, Kw)
-        wf = (codes.astype(jnp.float32).reshape(bn_, nsb, blk)
-              * s_ref[...][:, :, None]).reshape(bn_, Kw)
-    else:
-        wf = w.astype(jnp.float32) * s_ref[...].T
-    acc = jax.lax.dot_general(
-        src, wf, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    return acc + b_ref[...]
-
-
-def _weight_specs(pl, bn, D, int4, s1_nsb, s2_nsb, w1_index, w2_index,
-                  lane1_index, lane2_index):
-    """BlockSpecs for the streamed weight operands (w1, s1, bias1, w2,
-    s2, bias2), shared by the VMEM- and DMA-resident block kernels. int4
-    streams packed [*, K/2] nibble rows whose block scales tile by ROW
-    block (same index map as the weights); int8 scales are lane rows."""
-    if int4:
-        return [
-            pl.BlockSpec((bn, D // 2), w1_index),
-            pl.BlockSpec((bn, s1_nsb), w1_index),           # s1 blocks
-            pl.BlockSpec((1, bn), lane1_index),             # bias1
-            pl.BlockSpec((bn, 2 * D), w2_index),            # 4D/2 lanes
-            pl.BlockSpec((bn, s2_nsb), w2_index),           # s2 blocks
-            pl.BlockSpec((1, bn), lane2_index),             # bias2
-        ]
-    return [
-        pl.BlockSpec((bn, D), w1_index),
-        pl.BlockSpec((1, bn), lane1_index),                 # s1
-        pl.BlockSpec((1, bn), lane1_index),                 # bias1
-        pl.BlockSpec((bn, 4 * D), w2_index),
-        pl.BlockSpec((1, bn), lane2_index),                 # s2
-        pl.BlockSpec((1, bn), lane2_index),                 # bias2
-    ]
-
-
-def _kernel_ln(x, g, b, eps):
-    """In-kernel LayerNorm over the lane dim (f32 in, f32 out)."""
-    D = x.shape[-1]
-    mean = jnp.sum(x, axis=-1, keepdims=True) / D
-    var = jnp.sum(jnp.square(x), axis=-1, keepdims=True) / D \
-        - jnp.square(mean)
-    var = jnp.maximum(var, 0.0)
-    return (x - mean) * jax.lax.rsqrt(var + eps) * g + b
-
-
-def _pallas_block_decode(xv, posv, kc, vc, consts, heads, eps,
-                         interpret=False):
-    """One transformer block's whole decode step as ONE pallas_call.
-
-    Grid cell g streams one output-channel block of one weight matrix:
-    cells [0, 3D/bn) the qkv rows, then attention fires once, cells for
-    attn_out accumulate straight into the residual, an LN2 epilogue, fc
-    cells with the GeLU epilogue, and finally the proj cells (K=4D) emit
-    the output block = residual + projection. Weights touch HBM exactly
-    once; every intermediate lives in VMEM scratch."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, T, D = xv.shape
-    hd = D // heads
-    L = kc.shape[2]
-    bn = _block_n(D)
-    n_qkv, n_out, n_fc = 3 * D // bn, D // bn, 4 * D // bn
-    nb1 = n_qkv + n_out + n_fc
-    n_proj = D // bn
-    grid = nb1 + n_proj
-
-    (w1, s1, bias1, w2, s2, bias2, g1, b1, g2, b2) = _pack_tpu(consts, D)
-    int4 = w1.dtype == jnp.uint8
-    x2 = xv.reshape(B, D)
-    pos = jnp.broadcast_to(jnp.asarray(posv, jnp.int32), (B,))
-
-    def kernel(x_ref, pos_ref, w1_ref, s1_ref, b1_ref, w2_ref, s2_ref,
-               b2_ref, g1_ref, b1g_ref, g2_ref, b2g_ref, kc_in, vc_in,
-               o_ref, kc_out, vc_out,
-               res, act, qkv_buf, fc_buf):
-        g = pl.program_id(0)
-
-        def ds(start, size):
-            # every dynamic index int32 (interpret-mode discharge rejects
-            # mixed int widths in one index tuple)
-            return pl.ds(jnp.asarray(start, jnp.int32), size)
-
-        @pl.when(g == 0)
-        def _setup():
-            kc_out[...] = kc_in[...]
-            vc_out[...] = vc_in[...]
-            x = x_ref[...].astype(jnp.float32)
-            res[...] = x
-            act[...] = _kernel_ln(x, g1_ref[...], b1g_ref[...], eps)
-
-        deq_dot = _deq_dot_body
-
-        # ---- phase 1: qkv blocks -> qkv_buf ------------------------------
-        @pl.when(g < n_qkv)
-        def _qkv():
-            acc = deq_dot(act[...], w1_ref, s1_ref, b1_ref)
-            pl.store(qkv_buf, (ds(0, B), ds(g * bn, bn)), acc)
-
-        # ---- attention (once, after qkv is complete) ---------------------
-        @pl.when(g == n_qkv)
-        def _attention():
-            def head(i, _):
-                b = i // heads
-                h = i % heads
-                p = pos_ref[b]
-                q = pl.load(qkv_buf, (ds(b, 1), ds(h * hd, hd)))
-                k_new = pl.load(qkv_buf,
-                                (ds(b, 1), ds(D + h * hd, hd)))
-                v_new = pl.load(qkv_buf,
-                                (ds(b, 1), ds(2 * D + h * hd, hd)))
-                pl.store(kc_out, (ds(b, 1), ds(h, 1), ds(p, 1), ds(0, hd)),
-                         k_new.astype(kc_out.dtype).reshape(1, 1, 1, hd))
-                pl.store(vc_out, (ds(b, 1), ds(h, 1), ds(p, 1), ds(0, hd)),
-                         v_new.astype(vc_out.dtype).reshape(1, 1, 1, hd))
-                kmat = pl.load(
-                    kc_out, (ds(b, 1), ds(h, 1), ds(0, L), ds(0, hd))
-                ).reshape(L, hd)
-                vmat = pl.load(
-                    vc_out, (ds(b, 1), ds(h, 1), ds(0, L), ds(0, hd))
-                ).reshape(L, hd)
-                scores = jax.lax.dot_general(
-                    q, kmat.astype(jnp.float32), (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)        # [1, L]
-                scores = scores * (1.0 / (hd ** 0.5))
-                cols = jax.lax.broadcasted_iota(jnp.int32, (1, L), 1)
-                scores = jnp.where(cols <= p, scores, -jnp.inf)
-                m = jnp.max(scores, axis=-1, keepdims=True)
-                e = jnp.exp(scores - m)
-                probs = e / jnp.sum(e, axis=-1, keepdims=True)
-                ctx = jax.lax.dot_general(
-                    probs, vmat.astype(jnp.float32),
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)        # [1, hd]
-                pl.store(act, (ds(b, 1), ds(h * hd, hd)), ctx)
-                return 0
-            jax.lax.fori_loop(0, B * heads, head, 0)
-
-        # ---- phase 2: attn_out blocks -> residual add --------------------
-        @pl.when((g >= n_qkv) & (g < n_qkv + n_out))
-        def _out():
-            acc = deq_dot(act[...], w1_ref, s1_ref, b1_ref)
-            col = (g - n_qkv) * bn
-            cur = pl.load(res, (ds(0, B), ds(col, bn)))
-            pl.store(res, (ds(0, B), ds(col, bn)), cur + acc)
-
-        # ---- LN2 epilogue (once, after the residual is complete) ---------
-        @pl.when(g == n_qkv + n_out)
-        def _ln2():
-            act[...] = _kernel_ln(res[...], g2_ref[...], b2g_ref[...], eps)
-
-        # ---- phase 3: fc blocks + GeLU -> fc_buf -------------------------
-        @pl.when((g >= n_qkv + n_out) & (g < nb1))
-        def _fc():
-            acc = deq_dot(act[...], w1_ref, s1_ref, b1_ref)
-            col = (g - n_qkv - n_out) * bn
-            pl.store(fc_buf, (ds(0, B), ds(col, bn)),
-                     jax.nn.gelu(acc, approximate=True))
-
-        # ---- phase 4: proj blocks (K=4D) -> output = res + proj ----------
-        @pl.when(g >= nb1)
-        def _proj():
-            acc = deq_dot(fc_buf[...], w2_ref, s2_ref, b2_ref)
-            col = (g - nb1) * bn
-            cur = pl.load(res, (ds(0, B), ds(col, bn)))
-            o_ref[...] = cur + acc
-
-    def w1_index(j):
-        return (jnp.minimum(j, nb1 - 1), 0)
-
-    def w2_index(j):
-        return (jnp.maximum(j - nb1, 0), 0)
-
-    def lane1_index(j):
-        return (0, jnp.minimum(j, nb1 - 1))
-
-    def lane2_index(j):
-        return (0, jnp.maximum(j - nb1, 0))
-
-    pinned2 = lambda j: (0, 0)                                  # noqa: E731
-    pinned4 = lambda j: (0, 0, 0, 0)                            # noqa: E731
-    cshape = (B, heads, L, hd)
-    out_shapes = (
-        jax.ShapeDtypeStruct((B, D), jnp.float32),
-        jax.ShapeDtypeStruct(cshape, kc.dtype),
-        jax.ShapeDtypeStruct(cshape, vc.dtype),
-    )
-    o, kc2, vc2 = pl.pallas_call(
-        kernel,
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec((B, D), pinned2),
-            pl.BlockSpec(memory_space=pltpu.SMEM),              # pos
-        ] + _weight_specs(
-            pl, bn, D, int4,
-            s1.shape[1] if int4 else 0, s2.shape[1] if int4 else 0,
-            w1_index, w2_index, lane1_index, lane2_index,
-        ) + [
-            pl.BlockSpec((1, D), pinned2),                      # ln1 gamma
-            pl.BlockSpec((1, D), pinned2),                      # ln1 beta
-            pl.BlockSpec((1, D), pinned2),                      # ln2 gamma
-            pl.BlockSpec((1, D), pinned2),                      # ln2 beta
-            pl.BlockSpec(cshape, pinned4),                      # k cache
-            pl.BlockSpec(cshape, pinned4),                      # v cache
-        ],
-        out_specs=(
-            pl.BlockSpec((B, bn), lambda j: (0, jnp.maximum(j - nb1, 0))),
-            pl.BlockSpec(cshape, pinned4),
-            pl.BlockSpec(cshape, pinned4),
-        ),
-        out_shape=out_shapes,
-        scratch_shapes=[
-            pltpu.VMEM((B, D), jnp.float32),                    # res
-            pltpu.VMEM((B, D), jnp.float32),                    # act
-            pltpu.VMEM((B, 3 * D), jnp.float32),                # qkv_buf
-            pltpu.VMEM((B, 4 * D), jnp.float32),                # fc_buf
-        ],
-        interpret=interpret,
-    )(x2, pos, w1, s1, bias1, w2, s2, bias2, g1, b1, g2, b2, kc, vc)
-    return o.reshape(B, T, D), kc2, vc2
-
-
-def _pallas_block_decode_paged(xv, posv, bt, kp, vp, consts, heads, eps,
-                               interpret=False):
-    """One transformer block's whole PAGED decode step as ONE pallas_call.
-
-    Identical phase structure to :func:`_pallas_block_decode` — the qkv /
-    attn_out / fc / proj weight phases stream the same packed int8
-    matrices — but the KV state is the engine's shared page pool
-    ([pool_pages, H, ps, hd]; last page = the sink) addressed through the
-    per-row block table ([B, max_pages] int32, SMEM): the attention phase
-    scatters the new K/V row at physical ``table[pos // ps]`` row
-    ``pos % ps`` and walks the table to gather the logical [L, hd] view
-    into VMEM scratch before the same masked-softmax math. Pages are
-    fixed-size, so the table lookup is a pure index transform — no extra
-    HBM traffic, no extra launches."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, T, D = xv.shape
-    hd = D // heads
-    NP1, _, ps, _ = kp.shape            # pool pages incl. the sink
-    maxp = bt.shape[1]
-    L = maxp * ps
-    bn = _block_n(D)
-    n_qkv, n_out, n_fc = 3 * D // bn, D // bn, 4 * D // bn
-    nb1 = n_qkv + n_out + n_fc
-    n_proj = D // bn
-    grid = nb1 + n_proj
-
-    (w1, s1, bias1, w2, s2, bias2, g1, b1, g2, b2) = _pack_tpu(consts, D)
-    int4 = w1.dtype == jnp.uint8
-    x2 = xv.reshape(B, D)
-    pos = jnp.broadcast_to(jnp.asarray(posv, jnp.int32), (B,))
-    table = jnp.asarray(bt, jnp.int32)
-
-    def kernel(x_ref, pos_ref, bt_ref, w1_ref, s1_ref, b1_ref, w2_ref,
-               s2_ref, b2_ref, g1_ref, b1g_ref, g2_ref, b2g_ref, kp_in,
-               vp_in, o_ref, kp_out, vp_out,
-               res, act, qkv_buf, fc_buf, kbuf, vbuf):
-        g = pl.program_id(0)
-
-        def ds(start, size):
-            # every dynamic index int32 (interpret-mode discharge rejects
-            # mixed int widths in one index tuple)
-            return pl.ds(jnp.asarray(start, jnp.int32), size)
-
-        @pl.when(g == 0)
-        def _setup():
-            kp_out[...] = kp_in[...]
-            vp_out[...] = vp_in[...]
-            x = x_ref[...].astype(jnp.float32)
-            res[...] = x
-            act[...] = _kernel_ln(x, g1_ref[...], b1g_ref[...], eps)
-
-        deq_dot = _deq_dot_body
-
-        # ---- phase 1: qkv blocks -> qkv_buf ------------------------------
-        @pl.when(g < n_qkv)
-        def _qkv():
-            acc = deq_dot(act[...], w1_ref, s1_ref, b1_ref)
-            pl.store(qkv_buf, (ds(0, B), ds(g * bn, bn)), acc)
-
-        # ---- attention (once; scatter/gather through the block table) ----
-        @pl.when(g == n_qkv)
-        def _attention():
-            def head(i, _):
-                b = i // heads
-                h = i % heads
-                p = pos_ref[b]
-                lp = jnp.minimum(p // ps, maxp - 1)
-                # pad/overflow positions redirect to the sink (same
-                # explicit redirect as models/llama._paged_attention:
-                # clamping would alias the row's LAST real page)
-                phys = jnp.where(p < L, bt_ref[b, lp], NP1 - 1)
-                off = p - (p // ps) * ps
-                q = pl.load(qkv_buf, (ds(b, 1), ds(h * hd, hd)))
-                k_new = pl.load(qkv_buf,
-                                (ds(b, 1), ds(D + h * hd, hd)))
-                v_new = pl.load(qkv_buf,
-                                (ds(b, 1), ds(2 * D + h * hd, hd)))
-                pl.store(kp_out,
-                         (ds(phys, 1), ds(h, 1), ds(off, 1), ds(0, hd)),
-                         k_new.astype(kp_out.dtype).reshape(1, 1, 1, hd))
-                pl.store(vp_out,
-                         (ds(phys, 1), ds(h, 1), ds(off, 1), ds(0, hd)),
-                         v_new.astype(vp_out.dtype).reshape(1, 1, 1, hd))
-
-                # table walk: logical page j lands at rows [j*ps, (j+1)*ps)
-                # of the gather scratch — position p maps to row p exactly,
-                # the same logical view the unfused gather materializes
-                def gather(j, _):
-                    pg = bt_ref[b, j]
-                    kpage = pl.load(
-                        kp_out, (ds(pg, 1), ds(h, 1), ds(0, ps), ds(0, hd))
-                    ).reshape(ps, hd)
-                    vpage = pl.load(
-                        vp_out, (ds(pg, 1), ds(h, 1), ds(0, ps), ds(0, hd))
-                    ).reshape(ps, hd)
-                    pl.store(kbuf, (ds(j * ps, ps), ds(0, hd)),
-                             kpage.astype(jnp.float32))
-                    pl.store(vbuf, (ds(j * ps, ps), ds(0, hd)),
-                             vpage.astype(jnp.float32))
-                    return 0
-                jax.lax.fori_loop(0, maxp, gather, 0)
-                scores = jax.lax.dot_general(
-                    q, kbuf[...], (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)        # [1, L]
-                scores = scores * (1.0 / (hd ** 0.5))
-                cols = jax.lax.broadcasted_iota(jnp.int32, (1, L), 1)
-                # masked columns read whatever the pool holds (unleased /
-                # sink garbage) — exactly like the unfused path, the -inf
-                # mask turns them into exact zeros
-                scores = jnp.where(cols <= p, scores, -jnp.inf)
-                m = jnp.max(scores, axis=-1, keepdims=True)
-                e = jnp.exp(scores - m)
-                probs = e / jnp.sum(e, axis=-1, keepdims=True)
-                ctx = jax.lax.dot_general(
-                    probs, vbuf[...], (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)        # [1, hd]
-                pl.store(act, (ds(b, 1), ds(h * hd, hd)), ctx)
-                return 0
-            jax.lax.fori_loop(0, B * heads, head, 0)
-
-        # ---- phase 2: attn_out blocks -> residual add --------------------
-        @pl.when((g >= n_qkv) & (g < n_qkv + n_out))
-        def _out():
-            acc = deq_dot(act[...], w1_ref, s1_ref, b1_ref)
-            col = (g - n_qkv) * bn
-            cur = pl.load(res, (ds(0, B), ds(col, bn)))
-            pl.store(res, (ds(0, B), ds(col, bn)), cur + acc)
-
-        # ---- LN2 epilogue (once, after the residual is complete) ---------
-        @pl.when(g == n_qkv + n_out)
-        def _ln2():
-            act[...] = _kernel_ln(res[...], g2_ref[...], b2g_ref[...], eps)
-
-        # ---- phase 3: fc blocks + GeLU -> fc_buf -------------------------
-        @pl.when((g >= n_qkv + n_out) & (g < nb1))
-        def _fc():
-            acc = deq_dot(act[...], w1_ref, s1_ref, b1_ref)
-            col = (g - n_qkv - n_out) * bn
-            pl.store(fc_buf, (ds(0, B), ds(col, bn)),
-                     jax.nn.gelu(acc, approximate=True))
-
-        # ---- phase 4: proj blocks (K=4D) -> output = res + proj ----------
-        @pl.when(g >= nb1)
-        def _proj():
-            acc = deq_dot(fc_buf[...], w2_ref, s2_ref, b2_ref)
-            col = (g - nb1) * bn
-            cur = pl.load(res, (ds(0, B), ds(col, bn)))
-            o_ref[...] = cur + acc
-
-    def w1_index(j):
-        return (jnp.minimum(j, nb1 - 1), 0)
-
-    def w2_index(j):
-        return (jnp.maximum(j - nb1, 0), 0)
-
-    def lane1_index(j):
-        return (0, jnp.minimum(j, nb1 - 1))
-
-    def lane2_index(j):
-        return (0, jnp.maximum(j - nb1, 0))
-
-    pinned2 = lambda j: (0, 0)                                  # noqa: E731
-    pinned4 = lambda j: (0, 0, 0, 0)                            # noqa: E731
-    pshape = kp.shape
-    out_shapes = (
-        jax.ShapeDtypeStruct((B, D), jnp.float32),
-        jax.ShapeDtypeStruct(pshape, kp.dtype),
-        jax.ShapeDtypeStruct(pshape, vp.dtype),
-    )
-    o, kp2, vp2 = pl.pallas_call(
-        kernel,
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec((B, D), pinned2),
-            pl.BlockSpec(memory_space=pltpu.SMEM),              # pos
-            pl.BlockSpec(memory_space=pltpu.SMEM),              # block table
-        ] + _weight_specs(
-            pl, bn, D, int4,
-            s1.shape[1] if int4 else 0, s2.shape[1] if int4 else 0,
-            w1_index, w2_index, lane1_index, lane2_index,
-        ) + [
-            pl.BlockSpec((1, D), pinned2),                      # ln1 gamma
-            pl.BlockSpec((1, D), pinned2),                      # ln1 beta
-            pl.BlockSpec((1, D), pinned2),                      # ln2 gamma
-            pl.BlockSpec((1, D), pinned2),                      # ln2 beta
-            pl.BlockSpec(pshape, pinned4),                      # k pool
-            pl.BlockSpec(pshape, pinned4),                      # v pool
-        ],
-        out_specs=(
-            pl.BlockSpec((B, bn), lambda j: (0, jnp.maximum(j - nb1, 0))),
-            pl.BlockSpec(pshape, pinned4),
-            pl.BlockSpec(pshape, pinned4),
-        ),
-        out_shape=out_shapes,
-        scratch_shapes=[
-            pltpu.VMEM((B, D), jnp.float32),                    # res
-            pltpu.VMEM((B, D), jnp.float32),                    # act
-            pltpu.VMEM((B, 3 * D), jnp.float32),                # qkv_buf
-            pltpu.VMEM((B, 4 * D), jnp.float32),                # fc_buf
-            pltpu.VMEM((L, hd), jnp.float32),                   # kbuf
-            pltpu.VMEM((L, hd), jnp.float32),                   # vbuf
-        ],
-        interpret=interpret,
-    )(x2, pos, table, w1, s1, bias1, w2, s2, bias2, g1, b1, g2, b2, kp, vp)
-    return o.reshape(B, T, D), kp2, vp2
-
-
-def _pallas_block_decode_paged_dma(xv, posv, bt, kp, vp, consts, heads,
-                                   eps, interpret=False, depth=None):
-    """One transformer block's whole PAGED decode step as ONE pallas_call
-    with the K/V pools HBM-RESIDENT (``pltpu.ANY``): the DMA pipeline
-    that removes :func:`fusable_paged`'s pool-size cap.
-
-    Same phase structure as :func:`_pallas_block_decode_paged` — the qkv
-    / attn_out / fc / proj weight phases stream the same packed weight
-    matrices through VMEM blocks — but the attention phase never holds
-    the pool: it first DMAs every row's new K/V token through a one-row
-    VMEM stage into physical page ``table[pos // ps]`` (all rows before
-    any gather, matching ``_paged_attention``'s scatter-then-gather
-    order even for adversarially aliased tables), then walks the block
-    table issuing ``pltpu.make_async_copy`` page gathers into ``depth``
-    double-buffered [L, hd] VMEM slots — tile i's copies are started up
-    to ``depth - 1`` tiles ahead, while the previous tile's attention
-    GEMVs run, and waited only right before its own dots. The pools ride
-    through ``input_output_aliases`` (in-place update; no pool-sized
-    copy on either side), so VMEM holds O(depth * L * hd) regardless of
-    how many pages the engine leases."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, T, D = xv.shape
-    hd = D // heads
-    NP1, _, ps, _ = kp.shape            # pool pages incl. the sink
-    maxp = bt.shape[1]
-    L = maxp * ps
-    bn = _block_n(D)
-    n_qkv, n_out, n_fc = 3 * D // bn, D // bn, 4 * D // bn
-    nb1 = n_qkv + n_out + n_fc
-    n_proj = D // bn
-    grid = nb1 + n_proj
-    if depth is None:
-        depth = _dma_depth()
-    nt = B * heads                      # attention tiles
-
-    (w1, s1, bias1, w2, s2, bias2, g1, b1, g2, b2) = _pack_tpu(consts, D)
-    int4 = w1.dtype == jnp.uint8
-    x2 = xv.reshape(B, D)
-    pos = jnp.broadcast_to(jnp.asarray(posv, jnp.int32), (B,))
-    table = jnp.asarray(bt, jnp.int32)
-
-    def kernel(x_ref, pos_ref, bt_ref, w1_ref, s1_ref, b1_ref, w2_ref,
-               s2_ref, b2_ref, g1_ref, b1g_ref, g2_ref, b2g_ref, kp_in,
-               vp_in, o_ref, kp_hbm, vp_hbm,
-               res, act, qkv_buf, fc_buf, kbuf, vbuf, kstage, vstage,
-               ksem, vsem, ssem):
-        del kp_in, vp_in                # aliased: kp_hbm/vp_hbm IS the pool
-        g = pl.program_id(0)
-
-        def ds(start, size):
-            # every dynamic index int32 (interpret-mode discharge rejects
-            # mixed int widths in one index tuple)
-            return pl.ds(jnp.asarray(start, jnp.int32), size)
-
-        @pl.when(g == 0)
-        def _setup():
-            x = x_ref[...].astype(jnp.float32)
-            res[...] = x
-            act[...] = _kernel_ln(x, g1_ref[...], b1g_ref[...], eps)
-
-        deq_dot = _deq_dot_body
-
-        # ---- phase 1: qkv blocks -> qkv_buf ------------------------------
-        @pl.when(g < n_qkv)
-        def _qkv():
-            acc = deq_dot(act[...], w1_ref, s1_ref, b1_ref)
-            pl.store(qkv_buf, (ds(0, B), ds(g * bn, bn)), acc)
-
-        # ---- attention (once; DMA scatter + double-buffered gathers) -----
-        def _gather_copies(i, slot):
-            """The maxp K and V page copies of attention tile ``i`` into
-            double-buffer slot ``slot`` (same descriptors for start and
-            wait — a DMA wait must match the copy it decrements)."""
-            b = i // heads
-            h = i % heads
-
-            def per_page(j):
-                pg = bt_ref[b, j]
-                kc = pltpu.make_async_copy(
-                    kp_hbm.at[pg, h], kbuf.at[slot, ds(j * ps, ps)],
-                    ksem.at[slot])
-                vc = pltpu.make_async_copy(
-                    vp_hbm.at[pg, h], vbuf.at[slot, ds(j * ps, ps)],
-                    vsem.at[slot])
-                return kc, vc
-            return per_page
-
-        def start_gathers(i, slot):
-            per_page = _gather_copies(i, slot)
-
-            def go(j, _):
-                kc, vc = per_page(jnp.asarray(j, jnp.int32))
-                kc.start()
-                vc.start()
-                return 0
-            jax.lax.fori_loop(0, maxp, go, 0)
-
-        def wait_gathers(i, slot):
-            per_page = _gather_copies(i, slot)
-
-            def go(j, _):
-                kc, vc = per_page(jnp.asarray(j, jnp.int32))
-                kc.wait()
-                vc.wait()
-                return 0
-            jax.lax.fori_loop(0, maxp, go, 0)
-
-        @pl.when(g == n_qkv)
-        def _attention():
-            # scatter EVERY row's new K/V token first (through the pool-
-            # dtype stage; a DMA moves bytes, so the f32 -> pool-dtype
-            # cast happens in VMEM), then gather: the same order the
-            # unfused _paged_attention applies, so shared-page tables
-            # see identical pool state
-            def scatter(i, _):
-                i = jnp.asarray(i, jnp.int32)
-                b = i // heads
-                h = i % heads
-                p = pos_ref[b]
-                lp = jnp.minimum(p // ps, maxp - 1)
-                # pad/overflow positions redirect to the sink (same
-                # explicit redirect as models/llama._paged_attention)
-                phys = jnp.where(p < L, bt_ref[b, lp], NP1 - 1)
-                off = p - (p // ps) * ps
-                k_new = pl.load(qkv_buf, (ds(b, 1), ds(D + h * hd, hd)))
-                v_new = pl.load(qkv_buf,
-                                (ds(b, 1), ds(2 * D + h * hd, hd)))
-                pl.store(kstage, (ds(0, 1), ds(0, hd)),
-                         k_new.astype(kstage.dtype))
-                pl.store(vstage, (ds(0, 1), ds(0, hd)),
-                         v_new.astype(vstage.dtype))
-                kc = pltpu.make_async_copy(
-                    kstage.at[0], kp_hbm.at[phys, h, off], ssem)
-                vc = pltpu.make_async_copy(
-                    vstage.at[0], vp_hbm.at[phys, h, off], ssem)
-                kc.start()
-                vc.start()
-                kc.wait()               # stages are reused next tile
-                vc.wait()
-                return 0
-            jax.lax.fori_loop(0, nt, scatter, 0)
-
-            # warm the pipeline: the first depth-1 tiles' page gathers
-            # are in flight before any attention math runs
-            for w in range(min(depth - 1, nt)):
-                start_gathers(jnp.int32(w), jnp.int32(w % depth))
-
-            def head(i, _):
-                i = jnp.asarray(i, jnp.int32)
-                slot = jax.lax.rem(i, jnp.int32(depth))
-                nxt = i + (depth - 1)
-
-                @pl.when(nxt < nt)
-                def _prefetch():
-                    # tile nxt's pages stream while THIS tile's GEMVs
-                    # run; its slot was consumed depth-1 tiles ago
-                    start_gathers(nxt, jax.lax.rem(nxt, jnp.int32(depth)))
-
-                wait_gathers(i, slot)
-                b = i // heads
-                h = i % heads
-                p = pos_ref[b]
-                q = pl.load(qkv_buf, (ds(b, 1), ds(h * hd, hd)))
-                kmat = pl.load(
-                    kbuf, (ds(slot, 1), ds(0, L), ds(0, hd))
-                ).reshape(L, hd).astype(jnp.float32)
-                vmat = pl.load(
-                    vbuf, (ds(slot, 1), ds(0, L), ds(0, hd))
-                ).reshape(L, hd).astype(jnp.float32)
-                scores = jax.lax.dot_general(
-                    q, kmat, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)        # [1, L]
-                scores = scores * (1.0 / (hd ** 0.5))
-                cols = jax.lax.broadcasted_iota(jnp.int32, (1, L), 1)
-                # masked columns read whatever the pool holds (unleased /
-                # sink garbage) — exactly like the unfused path, the -inf
-                # mask turns them into exact zeros
-                scores = jnp.where(cols <= p, scores, -jnp.inf)
-                m = jnp.max(scores, axis=-1, keepdims=True)
-                e = jnp.exp(scores - m)
-                probs = e / jnp.sum(e, axis=-1, keepdims=True)
-                ctx = jax.lax.dot_general(
-                    probs, vmat, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)        # [1, hd]
-                pl.store(act, (ds(b, 1), ds(h * hd, hd)), ctx)
-                return 0
-            jax.lax.fori_loop(0, nt, head, 0)
-
-        # ---- phase 2: attn_out blocks -> residual add --------------------
-        @pl.when((g >= n_qkv) & (g < n_qkv + n_out))
-        def _out():
-            acc = deq_dot(act[...], w1_ref, s1_ref, b1_ref)
-            col = (g - n_qkv) * bn
-            cur = pl.load(res, (ds(0, B), ds(col, bn)))
-            pl.store(res, (ds(0, B), ds(col, bn)), cur + acc)
-
-        # ---- LN2 epilogue (once, after the residual is complete) ---------
-        @pl.when(g == n_qkv + n_out)
-        def _ln2():
-            act[...] = _kernel_ln(res[...], g2_ref[...], b2g_ref[...], eps)
-
-        # ---- phase 3: fc blocks + GeLU -> fc_buf -------------------------
-        @pl.when((g >= n_qkv + n_out) & (g < nb1))
-        def _fc():
-            acc = deq_dot(act[...], w1_ref, s1_ref, b1_ref)
-            col = (g - n_qkv - n_out) * bn
-            pl.store(fc_buf, (ds(0, B), ds(col, bn)),
-                     jax.nn.gelu(acc, approximate=True))
-
-        # ---- phase 4: proj blocks (K=4D) -> output = res + proj ----------
-        @pl.when(g >= nb1)
-        def _proj():
-            acc = deq_dot(fc_buf[...], w2_ref, s2_ref, b2_ref)
-            col = (g - nb1) * bn
-            cur = pl.load(res, (ds(0, B), ds(col, bn)))
-            o_ref[...] = cur + acc
-
-    def w1_index(j):
-        return (jnp.minimum(j, nb1 - 1), 0)
-
-    def w2_index(j):
-        return (jnp.maximum(j - nb1, 0), 0)
-
-    def lane1_index(j):
-        return (0, jnp.minimum(j, nb1 - 1))
-
-    def lane2_index(j):
-        return (0, jnp.maximum(j - nb1, 0))
-
-    pinned2 = lambda j: (0, 0)                                  # noqa: E731
-    pshape = kp.shape
-    out_shapes = (
-        jax.ShapeDtypeStruct((B, D), jnp.float32),
-        jax.ShapeDtypeStruct(pshape, kp.dtype),
-        jax.ShapeDtypeStruct(pshape, vp.dtype),
-    )
-    o, kp2, vp2 = pl.pallas_call(
-        kernel,
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec((B, D), pinned2),
-            pl.BlockSpec(memory_space=pltpu.SMEM),              # pos
-            pl.BlockSpec(memory_space=pltpu.SMEM),              # block table
-        ] + _weight_specs(
-            pl, bn, D, int4,
-            s1.shape[1] if int4 else 0, s2.shape[1] if int4 else 0,
-            w1_index, w2_index, lane1_index, lane2_index,
-        ) + [
-            pl.BlockSpec((1, D), pinned2),                      # ln1 gamma
-            pl.BlockSpec((1, D), pinned2),                      # ln1 beta
-            pl.BlockSpec((1, D), pinned2),                      # ln2 gamma
-            pl.BlockSpec((1, D), pinned2),                      # ln2 beta
-            pl.BlockSpec(memory_space=pltpu.ANY),               # k pool HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),               # v pool HBM
-        ],
-        out_specs=(
-            pl.BlockSpec((B, bn), lambda j: (0, jnp.maximum(j - nb1, 0))),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ),
-        out_shape=out_shapes,
-        scratch_shapes=[
-            pltpu.VMEM((B, D), jnp.float32),                    # res
-            pltpu.VMEM((B, D), jnp.float32),                    # act
-            pltpu.VMEM((B, 3 * D), jnp.float32),                # qkv_buf
-            pltpu.VMEM((B, 4 * D), jnp.float32),                # fc_buf
-            pltpu.VMEM((depth, L, hd), kp.dtype),               # kbuf
-            pltpu.VMEM((depth, L, hd), vp.dtype),               # vbuf
-            pltpu.VMEM((1, hd), kp.dtype),                      # kstage
-            pltpu.VMEM((1, hd), vp.dtype),                      # vstage
-            pltpu.SemaphoreType.DMA((depth,)),                  # ksem
-            pltpu.SemaphoreType.DMA((depth,)),                  # vsem
-            pltpu.SemaphoreType.DMA(()),                        # ssem
-        ],
-        input_output_aliases={13: 1, 14: 2},
-        interpret=interpret,
-    )(x2, pos, table, w1, s1, bias1, w2, s2, bias2, g1, b1, g2, b2, kp, vp)
-    return o.reshape(B, T, D), kp2, vp2
-
-
-def _consts(pack):
-    """Flatten a pack dict into the positional const tuple the kernels
-    take (Parameters resolved to their bound values at trace time)."""
-    def data(p):
-        return None if p is None else (p.data()._data
-                                       if hasattr(p, "data") else p)
-    qkv_w, qkv_s, qkv_b = pack["qkv"]
-    out_w, out_s, out_b = pack["out"]
-    fc_w, fc_s, fc_b = pack["fc"]
-    proj_w, proj_s, proj_b = pack["proj"]
-    g1, b1 = pack["ln1"]
-    g2, b2 = pack["ln2"]
-    return (qkv_w, qkv_s, data(qkv_b), out_w, out_s, data(out_b),
-            fc_w, fc_s, data(fc_b), proj_w, proj_s, data(proj_b),
-            data(g1), data(b1), data(g2), data(b2))
-
-
-def _kind_suffix(consts):
-    """Launch-kind suffix for the weight lane: int4 packs (uint8 nibble
-    streams) tally under their own ``*_int4`` kinds so the telemetry
-    separates the halved-weight-stream path from the int8 one."""
-    return "_int4" if consts[0].dtype == jnp.uint8 else ""
-
-
-def fused_block_decode(xv, posv, kc, vc, pack, interpret=False):
-    """One transformer block's whole T=1 decode step. ``pack`` is a
-    :func:`pack_gpt_block` result (Parameters resolve through the trace
-    scope at call time). Single Pallas launch on TPU for fusable shapes;
-    bitwise-reference XLA path elsewhere."""
-    heads, eps = pack["heads"], pack["eps"]
-    consts = _consts(pack)
-    B, T, D = xv.shape
-    use_kernel = (T == 1 and fusable(B, D, heads, kc.shape[2],
-                                     jnp.dtype(kc.dtype).itemsize))
-    if use_kernel:
-        # ONE launch replaces the 4 per-matrix GEMVs + LN/attention glue
-        record_launch("fused_block" + _kind_suffix(consts))
-    else:
-        # honest accounting: the fallback still dispatches 4 GEMV-shaped
-        # matmuls (XLA-fused with their epilogues, but separate launches)
-        for _ in range(4):
-            record_launch("gemv")
-    if use_kernel and (interpret or jax.default_backend() == "tpu"):
-        return _pallas_block_decode(xv, posv, kc, vc, consts, heads, eps,
-                                    interpret=interpret)
-    return _reference_block_decode(xv, posv, kc, vc, consts, heads, eps)
-
-
-def fused_block_decode_paged(xv, posv, bt, kp, vp, pack, interpret=False):
-    """One transformer block's whole T=1 decode step over the PAGED KV
-    pool: ``bt`` is the [B, max_pages] block table, ``kp``/``vp`` the
-    shared [pool_pages, H, ps, hd] pools (last page = sink). Single
-    Pallas launch on TPU for fusable shapes: pools inside the VMEM
-    budget take the VMEM-resident kernel (``fusable_paged``); larger
-    pools take the DMA-resident double-buffered pipeline
-    (``fusable_paged_dma`` — the pool size does not cap it), so the
-    one-launch step survives production pool sizes. Bitwise-reference
-    XLA path (the unfused ``_paged_attention`` op sequence) for shapes
-    neither gate accepts, and everywhere off-TPU."""
-    heads, eps = pack["heads"], pack["eps"]
-    consts = _consts(pack)
-    B, T, D = xv.shape
-    itemsize = jnp.dtype(kp.dtype).itemsize
-    gate_args = (B, D, heads, kp.shape[0], kp.shape[2], bt.shape[1],
-                 itemsize)
-    use_kernel = T == 1 and fusable_paged(*gate_args)
-    use_dma = (not use_kernel) and T == 1 and fusable_paged_dma(*gate_args)
-    sfx = _kind_suffix(consts)
-    if use_kernel:
-        # ONE launch replaces the 4 per-matrix GEMVs + LN/attention glue;
-        # its own kind so the paged collapse is visible next to the
-        # contiguous fused_block sites
-        record_launch("fused_block_paged" + sfx)
-    elif use_dma:
-        record_launch("fused_block_paged_dma" + sfx)
-        # static per-step DMA program of this launch: 2 one-row K/V
-        # scatters per (row, head) tile + 2 page gathers per (row, head,
-        # logical page) — recorded at trace time like the launch kinds
-        heads_i, maxp, ps = heads, bt.shape[1], kp.shape[2]
-        hd = D // heads
-        scat = 2 * B * heads_i
-        gath = 2 * B * heads_i * maxp
-        record_dma(scat + gath,
-                   scat * hd * itemsize + gath * ps * hd * itemsize,
-                   # every scatter is waited at its phase end, every
-                   # gather on buffer rotation or the final drain
-                   waits=scat + gath)
-    else:
-        # honest accounting: the fallback still dispatches 4 GEMV-shaped
-        # matmuls (XLA-fused with their epilogues, but separate launches)
-        for _ in range(4):
-            record_launch("gemv")
-    if interpret or jax.default_backend() == "tpu":
-        if use_kernel:
-            return _pallas_block_decode_paged(
-                xv, posv, bt, kp, vp, consts, heads, eps,
-                interpret=interpret)
-        if use_dma:
-            return _pallas_block_decode_paged_dma(
-                xv, posv, bt, kp, vp, consts, heads, eps,
-                interpret=interpret)
-    return _reference_block_decode_paged(xv, posv, bt, kp, vp, consts,
-                                         heads, eps)
-
-
 # ---------------------------------------------------------------------------
 # fused LM-head sampling
 # ---------------------------------------------------------------------------
@@ -1212,7 +82,8 @@ def _hash_uniform(keys_u32, lanes_i32):
     z = z * jnp.uint32(0x846CA68B)
     z = z ^ (z >> 16)
     # 24 mantissa-safe bits -> (0, 1); +0.5 keeps it strictly positive
-    return ((z >> 8).astype(jnp.float32) + 0.5) * (1.0 / (1 << 24))
+    return ((z >> 8).astype(jnp.int32).astype(jnp.float32) + 0.5) \
+        * (1.0 / (1 << 24))
 
 
 def _head_kernel(h, w_q, w_scale, vocab, temps, keybits, out_dtype=None,
@@ -1229,18 +100,12 @@ def _head_kernel(h, w_q, w_scale, vocab, temps, keybits, out_dtype=None,
     running Gumbel-argmax reduction, so constrained selection costs one
     extra where() per block — never a materialized [B, V] filter.
 
-    ``w_q`` may be the int8 table ([Vp, D] with per-row ``w_scale``
-    [Vp]) or the int4 pack ([Vp, D/2] uint8 nibbles with block scales
-    ``w_scale`` [Vp, D/block]) — the nibble stream unpacks per vocab
-    block, same codec semantics as :func:`int4_weight_matmul`."""
+    ``w_q`` is the int8 table ([Vp, D]) with per-row ``w_scale`` [Vp]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, D = h.shape
     Vp = w_q.shape[0]
-    int4 = w_q.dtype == jnp.uint8
-    nsb = w_scale.shape[1] if int4 else 0
-    block = D // nsb if int4 else 0
     # largest candidate dividing Vp: GPT-2's padded 50304 = 131 x 384
     # (the 128 floor always divides — pad_vocab guarantees it)
     bnv = next(c for c in (2048, 1024, 512, 384, 256, VOCAB_LANE)
@@ -1261,15 +126,7 @@ def _head_kernel(h, w_q, w_scale, vocab, temps, keybits, out_dtype=None,
             best_v[...] = jnp.full((B, 1), -jnp.inf, jnp.float32)
             best_i[...] = jnp.zeros((B, 1), jnp.int32)
 
-        if int4:
-            w32 = w_ref[...].astype(jnp.int32)       # (bnv, D/2) nibbles
-            lo = (w32 & 0xF) - 8
-            hi = (w32 >> 4) - 8
-            codes = jnp.stack([lo, hi], axis=-1).reshape(bnv, D)
-            wf = (codes.astype(jnp.float32).reshape(bnv, nsb, block)
-                  * s_ref[...][:, :, None]).reshape(bnv, D)
-        else:
-            wf = w_ref[...].astype(jnp.float32) * s_ref[...].T
+        wf = w_ref[...].astype(jnp.float32) * s_ref[...].T
         acc = jax.lax.dot_general(
             h_ref[...].astype(jnp.float32), wf, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)                 # [B, bnv]
@@ -1302,38 +159,33 @@ def _head_kernel(h, w_q, w_scale, vocab, temps, keybits, out_dtype=None,
         def _emit():
             o_ref[...] = best_i[...]
 
-    if int4:
-        w_spec = pl.BlockSpec((bnv, D // 2), lambda j: (j, 0))
-        s_spec = pl.BlockSpec((bnv, nsb), lambda j: (j, 0))
-        s_op = w_scale                                       # [Vp, nsb]
-    else:
-        w_spec = pl.BlockSpec((bnv, D), lambda j: (j, 0))
-        s_spec = pl.BlockSpec((1, bnv), lambda j: (0, j))
-        s_op = w_scale.reshape(1, Vp)
     in_specs = [
         pl.BlockSpec((B, D), lambda j: (0, 0)),
-        w_spec,
-        s_spec,
+        pl.BlockSpec((bnv, D), lambda j: (j, 0)),
+        pl.BlockSpec((1, bnv), lambda j: (0, j)),
         pl.BlockSpec((B, 1), lambda j: (0, 0)),                  # temps
         pl.BlockSpec((B, 1), lambda j: (0, 0)),                  # key bits
     ]
-    operands = [h, w_q, s_op, temps.reshape(B, 1),
+    operands = [h, w_q, w_scale.reshape(1, Vp), temps.reshape(B, 1),
                 keybits.reshape(B, 1)]
     if has_mask:
         in_specs.append(pl.BlockSpec((B, bnv), lambda j: (0, j)))
         operands.append(mask)
-    out = pl.pallas_call(
-        kernel,
-        grid=(nb,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((B, 1), lambda j: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.int32),
-        scratch_shapes=[
-            pltpu.VMEM((B, 1), jnp.float32),
-            pltpu.VMEM((B, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(*operands)
+    # 64-bit types off: the package enables x64, and Mosaic refuses the
+    # int64 a Python int then becomes in an index map
+    with jax.enable_x64(False):
+        out = pl.pallas_call(
+            kernel,
+            grid=(nb,),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((B, 1), lambda j: (0, 0)),
+            out_shape=jax.ShapeDtypeStruct((B, 1), jnp.int32),
+            scratch_shapes=[
+                pltpu.VMEM((B, 1), jnp.float32),
+                pltpu.VMEM((B, 1), jnp.int32),
+            ],
+            interpret=interpret,
+        )(*operands)
     return out.reshape(B)
 
 
@@ -1355,8 +207,10 @@ def fused_lm_head_sample(h, w_q, w_scale, vocab, keys, temps, topks, topps,
     reduction (pad lanes stay masked), the XLA path forwards it to
     ``sample_tokens`` — same legality contract on every backend."""
     from ..models.generation import sample_tokens
-    record_launch("fused_head"
-                  + ("_int4" if w_q.dtype == jnp.uint8 else ""))
+    # the streamed kernel takes the int8 table on a TPU; a packed-int4
+    # table and every off-TPU call take the XLA reference
+    use_kernel = on_tpu() and w_q.dtype == jnp.int8
+    record_launch("fused_head" if use_kernel else "reference")
     B = h.shape[0]
     temps = jnp.reshape(jnp.asarray(temps, jnp.float32), (-1,))
     temps = jnp.broadcast_to(temps, (B,))
@@ -1369,7 +223,7 @@ def fused_lm_head_sample(h, w_q, w_scale, vocab, keys, temps, topks, topps,
             logits = logits.astype(out_dtype)
         return sample_tokens(logits, keys, temps, topks, topps, mask=mask)
 
-    if jax.default_backend() != "tpu":
+    if not use_kernel:
         return xla_sample()
 
     topks_a = jnp.broadcast_to(

@@ -30,8 +30,10 @@ import threading
 import jax
 import jax.numpy as jnp
 
+from ..device import on_tpu
+
 __all__ = ["int8_weight_matmul", "int4_weight_matmul", "count_launches",
-           "record_launch", "record_dma", "gemv_max_m"]
+           "record_launch", "gemv_max_m"]
 
 _BN = 512          # output-channel block per grid cell
 # hand-picked row threshold: above this the int8 MXU path wins. This is
@@ -51,13 +53,14 @@ def gemv_max_m() -> int:
     return _tune.get_knob("gemv_max_m")
 
 # ---------------------------------------------------------------------------
-# Kernel-launch accounting. Decode is overhead-bound (ROOFLINE.md r6): the
-# unit of cost is the LAUNCH, so the decode kernels self-report their launch
-# sites. record_launch fires once per python call — under jit that is once
-# per TRACE, so a tally taken around a trace (count_launches) measures the
-# static launches-per-step of the compiled executable, the quantity the
-# fused-decode acceptance criterion bounds (~49 -> <=16). The cumulative
-# mxnet_decode_launches_total counter has the same trace-time semantics.
+# Kernel-launch accounting. Decode is overhead-bound: the unit of cost is
+# the LAUNCH, so the decode kernels self-report their launch sites, under
+# the kernel's kind where the kernel runs and under ``reference`` where the
+# plain-XLA reference runs in its place (off-TPU). record_launch fires once
+# per python call — under jit that is once per TRACE, so a tally taken
+# around a trace (count_launches) measures the static launches-per-step of
+# the compiled executable. The cumulative mxnet_decode_launches_total
+# counter has the same trace-time semantics.
 # ---------------------------------------------------------------------------
 _TALLY = threading.local()
 
@@ -85,21 +88,6 @@ def record_launch(kind: str):
         _metrics.DECODE_LAUNCHES.labels(kind=kind).inc()
 
 
-def record_dma(copies: int, nbytes: int, waits: int = None):
-    """Record the async-copy traffic one DMA-resident decode launch will
-    issue per execution (called at trace time, like :func:`record_launch`
-    — the counters measure the STATIC per-step DMA program of the
-    compiled executable, not runtime events). ``waits`` defaults to
-    ``copies``: the kernel's rotation/drain discipline retires every
-    started copy exactly once, so start/wait parity is the invariant
-    ``analysis.guards.dma_ledger_check`` asserts after a serve round."""
-    from .. import metrics as _metrics
-    if _metrics.ENABLED:
-        _metrics.DECODE_DMA_COPIES.inc(copies)
-        _metrics.DECODE_DMA_BYTES.inc(nbytes)
-        _metrics.DECODE_DMA_WAITS.inc(copies if waits is None else waits)
-
-
 def _pad_to(x, mult: int, axis: int):
     size = x.shape[axis]
     rem = size % mult
@@ -114,10 +102,11 @@ def int8_weight_matmul(x, w_q, w_scale):
     """x: (M, K) float; w_q: (N, K) int8; w_scale: (N,) f32 per-out-channel.
     Returns (M, N) f32 = x @ (w_q * w_scale).T with dequantization fused
     into the weight stream (Pallas on TPU, plain jnp elsewhere)."""
-    record_launch("gemv")
     M, K = x.shape
     N = w_q.shape[0]
-    if jax.default_backend() != "tpu":
+    use_kernel = on_tpu()
+    record_launch("gemv" if use_kernel else "reference")
+    if not use_kernel:
         wf = w_q.astype(jnp.float32) * w_scale[:, None]
         return (x.astype(jnp.float32) @ wf.T)
 
@@ -129,19 +118,7 @@ def int8_weight_matmul(x, w_q, w_scale):
         x = x.astype(jnp.bfloat16)
     xp, _ = _pad_to(x, 8, 0)
     Mp = xp.shape[0]
-    # favor a block that divides N exactly (transformer dims are 384- or
-    # 512-friendly) — padding 768 -> 1024 wasted a third of the stream.
-    # For big-N heads (vocab-sized), large blocks amortize per-grid-cell
-    # overhead; padding waste is then marginal (<2%).
-    if N > 4096:
-        bn = 2048
-    else:
-        for cand in (512, 384, 256, 128):
-            if N % cand == 0:
-                bn = cand
-                break
-        else:
-            bn = min(_BN, N)
+    bn = _gemv_bn(N)
     wp, _ = _pad_to(w_q, bn, 0)
     sp, _ = _pad_to(w_scale, bn, 0)
     Np = wp.shape[0]
@@ -156,7 +133,7 @@ def int8_weight_matmul(x, w_q, w_scale):
             preferred_element_type=jnp.float32)   # (Mp, bn)
         o_ref[...] = acc * sb
 
-    with jax.experimental.enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             kernel,
             out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
@@ -172,9 +149,11 @@ def int8_weight_matmul(x, w_q, w_scale):
 
 
 def _gemv_bn(N: int) -> int:
-    """The int8 kernel's output-channel block choice, shared by the int4
-    lane (same tiling trade-offs: divide N exactly where possible, go
-    wide for vocab-sized heads)."""
+    """Output-channel block of both GEMV kernels: one that divides N
+    exactly where possible (transformer dims are 384- or 512-friendly —
+    padding 768 -> 1024 wasted a third of the stream); for vocab-sized
+    heads a large block amortizes per-grid-cell overhead and the padding
+    waste is marginal (<2%)."""
     if N > 4096:
         return 2048
     for cand in (512, 384, 256, 128):
@@ -198,13 +177,14 @@ def int4_weight_matmul(x, w_p, w_scale, interpret: bool = False):
     — dequant-exactness vs kvstore/quant.py holds by construction, and
     it is the bitwise contract fused-vs-unfused parity tests run
     against; kernel-vs-fallback parity is to bf16 input rounding."""
-    record_launch("gemv_int4")
     M = x.shape[0]
     N, K2 = w_p.shape
     K = 2 * K2
     nsb = w_scale.shape[1]
     block = K // nsb
-    if not interpret and jax.default_backend() != "tpu":
+    use_kernel = interpret or on_tpu()
+    record_launch("gemv_int4" if use_kernel else "reference")
+    if not use_kernel:
         from ..kvstore.quant import dequantize_blocks, unpack_codes
         codes = unpack_codes(w_p.reshape(-1), 4)
         wf = dequantize_blocks(codes, w_scale.reshape(-1),
@@ -217,33 +197,46 @@ def int4_weight_matmul(x, w_p, w_scale, interpret: bool = False):
         x = x.astype(jnp.bfloat16)
     xp, _ = _pad_to(x, 8, 0)
     Mp = xp.shape[0]
+    # byte j holds codes 2j (lo nibble) and 2j+1 (hi): split the few
+    # activation rows into even and odd columns out here, so the kernel
+    # contracts each nibble plane as it lies and never interleaves lanes
+    # (Mosaic does not finish compiling the in-kernel interleave)
+    x_lo, x_hi = xp[:, 0::2], xp[:, 1::2]
     bn = _gemv_bn(N)
     wp, _ = _pad_to(w_p, bn, 0)
     sp, _ = _pad_to(w_scale, bn, 0)          # pad scales 0 -> exact zeros
     Np = wp.shape[0]
+    half = block // 2                        # byte columns per scale block
 
-    def kernel(x_ref, w_ref, s_ref, o_ref):
+    def kernel(xl_ref, xh_ref, w_ref, s_ref, o_ref):
         w32 = w_ref[...].astype(jnp.int32)   # (bn, K/2) nibble pairs
-        lo = (w32 & 0xF) - 8                 # unpack_codes semantics:
-        hi = (w32 >> 4) - 8                  # lo nibble first, then hi
-        codes = jnp.stack([lo, hi], axis=-1).reshape(bn, K)
-        wf = (codes.astype(jnp.float32).reshape(bn, nsb, block)
-              * s_ref[...][:, :, None]).reshape(bn, K)
-        xb = x_ref[...]
-        o_ref[...] = jax.lax.dot_general(
-            xb, wf.astype(xb.dtype), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        sb = s_ref[...]                      # (bn, nsb) block scales
+        col = jax.lax.broadcasted_iota(jnp.int32, (bn, K2), 1) // half
+        scale = jnp.zeros((bn, K2), jnp.float32)
+        for b in range(nsb):
+            scale = jnp.where(col == b, sb[:, b:b + 1], scale)
 
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
-        grid=(Np // bn,),
-        in_specs=[
-            pl.BlockSpec((Mp, K), lambda j: (0, 0)),
-            pl.BlockSpec((bn, K2), lambda j: (j, 0)),
-            pl.BlockSpec((bn, nsb), lambda j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((Mp, bn), lambda j: (0, j)),
-        interpret=interpret,
-    )(xp, wp, sp)
+        def plane(codes, x_ref):
+            wf = (codes - 8).astype(jnp.float32) * scale
+            xb = x_ref[...]
+            return jax.lax.dot_general(
+                xb, wf.astype(xb.dtype), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        o_ref[...] = plane(w32 & 0xF, xl_ref) + plane(w32 >> 4, xh_ref)
+
+    with jax.enable_x64(False):
+        out = pl.pallas_call(
+            kernel,
+            out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
+            grid=(Np // bn,),
+            in_specs=[
+                pl.BlockSpec((Mp, K2), lambda j: (0, 0)),
+                pl.BlockSpec((Mp, K2), lambda j: (0, 0)),
+                pl.BlockSpec((bn, K2), lambda j: (j, 0)),
+                pl.BlockSpec((bn, nsb), lambda j: (j, 0)),
+            ],
+            out_specs=pl.BlockSpec((Mp, bn), lambda j: (0, j)),
+            interpret=interpret,
+        )(x_lo, x_hi, wp, sp)
     return out[:M, :N]

@@ -6,6 +6,7 @@ with PartitionSpecs, let XLA insert collectives. Axis names are conventional:
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Dict, Optional, Sequence
 
 import numpy as onp
@@ -16,31 +17,18 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from ..base import MXNetError
 
 __all__ = ["P", "make_mesh", "local_mesh", "current_mesh", "set_default_mesh",
+           "use_mesh",
            "named_sharding", "replicated", "shard_map"]
 
 P = PartitionSpec
 
 
 def shard_map(f, mesh, in_specs, out_specs, **kwargs):
-    """Version-portable ``shard_map``: new jax exposes it as
-    ``jax.shard_map`` (kwarg ``check_vma``), older releases only under
-    ``jax.experimental.shard_map`` (kwarg ``check_rep``). Every manual
-    mapping in the package goes through here so one jax pin doesn't decide
-    whether the sp/pp axes work."""
-    impl = getattr(jax, "shard_map", None)
-    if impl is None:
-        from jax.experimental.shard_map import shard_map as impl
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        if "axis_names" in kwargs:
-            # new-jax 'manual over these axes' spells 'auto over the rest'
-            # in the experimental API
-            manual = set(kwargs.pop("axis_names"))
-            kwargs["auto"] = frozenset(set(mesh.axis_names) - manual)
-    elif "check_rep" in kwargs:
-        kwargs["check_vma"] = kwargs.pop("check_rep")
-    return impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                **kwargs)
+    """``jax.shard_map`` with the mesh positional — the one spelling every
+    manual mapping in the package uses."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
+
 
 _DEFAULT_MESH: Optional[Mesh] = None
 
@@ -77,6 +65,23 @@ def set_default_mesh(mesh: Optional[Mesh]):
 
 def current_mesh() -> Optional[Mesh]:
     return _DEFAULT_MESH
+
+
+@contextlib.contextmanager
+def use_mesh(mesh: Optional[Mesh]):
+    """Make ``mesh`` the current mesh while a sharded program is traced
+    (``None`` leaves the current one in place). Code that cannot be
+    partitioned by GSPMD — a Pallas kernel — asks :func:`current_mesh`
+    and maps itself over the mesh by hand."""
+    global _DEFAULT_MESH
+    if mesh is None:
+        yield
+        return
+    prev, _DEFAULT_MESH = _DEFAULT_MESH, mesh
+    try:
+        yield
+    finally:
+        _DEFAULT_MESH = prev
 
 
 def named_sharding(mesh: Mesh, spec: Optional[PartitionSpec]) -> NamedSharding:

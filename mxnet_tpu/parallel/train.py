@@ -36,6 +36,7 @@ from ..observability import health as _health
 from ..observability import perf as _perf
 from ..observability import trace as _trace
 from . import elastic as _elastic
+from . import mesh as _mesh_mod
 from .functional import FunctionalModel, functionalize
 
 __all__ = ["TrainStep"]
@@ -376,8 +377,11 @@ class TrainStep:
                 return jnp.mean(loss), aux
 
             diff_vals = [param_vals[i] for i in diff_slots]
-            (loss, aux), grads = jax.value_and_grad(
-                loss_of, has_aux=True)(diff_vals)
+            # forward and backward are traced inside this call: kernels
+            # GSPMD cannot partition find the mesh to map themselves over
+            with _mesh_mod.use_mesh(mesh):
+                (loss, aux), grads = jax.value_and_grad(
+                    loss_of, has_aux=True)(diff_vals)
 
             new_params = list(param_vals)
             new_states = list(opt_states)
@@ -800,9 +804,8 @@ class TrainStep:
 
     def run(self, inputs, labels=None, steps: int = 1):
         """Run ``steps`` updates on the same batch inside ONE executable
-        (lax.fori_loop over the fused step). Each dispatch through PJRT —
-        and especially a network tunnel — costs milliseconds; looping on
-        device amortizes that and keeps donated params/state resident in
+        (lax.fori_loop over the fused step). Each dispatch through PJRT
+        costs host time; looping on device amortizes that and keeps donated params/state resident in
         HBM across iterations. The per-iteration step counter still
         advances, so momentum/Adam bias correction match ``steps`` separate
         calls. Returns the last step's loss."""

@@ -6,6 +6,8 @@ from collections import OrderedDict
 
 import jax
 
+from .device import on_tpu
+
 __all__ = ["Feature", "Features", "feature_list"]
 
 
@@ -24,14 +26,14 @@ def _detect() -> "OrderedDict[str, Feature]":
     def add(name, enabled):
         feats[name] = Feature(name, bool(enabled))
 
-    backend = jax.default_backend()
-    add("TPU", backend == "tpu")
+    tpu = on_tpu()
+    add("TPU", tpu)
     add("CPU", True)
     add("CUDA", False)           # reference flag names kept for parity
     add("CUDNN", False)
     add("NCCL", False)
     add("XLA", True)
-    add("PALLAS", backend == "tpu")
+    add("PALLAS", tpu)
     add("BF16", True)
     add("INT64_TENSOR_SIZE", jax.config.jax_enable_x64)
     add("DIST", True)            # jax.distributed collectives available

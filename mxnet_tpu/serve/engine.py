@@ -56,15 +56,7 @@ Architecture (vLLM-style continuous batching, TPU-static shapes):
   slot; the stateless per-request sampling streams make the resume
   exact). The contiguous path is kept verbatim (``paged=False``, the
   off-TPU default) as the bitwise-parity reference: paged greedy decode
-  is token-identical to it (tests/test_serve_paging.py). Fused block
-  decode COMPOSES with paging: opted-in models run the one-launch-per-
-  block kernel gathering/scattering KV through the block table in-kernel
-  (ops/fused_block_gemv.fused_block_decode_paged), so the paged pool and
-  the 49→13 launch collapse are no longer an either/or. Pools too large
-  for VMEM take the DMA-resident variant of the same kernel (the pool
-  stays in HBM; the table walk issues double-buffered async page copies
-  into VMEM gather slots), so the 13-launch step survives arbitrary pool
-  sizes — the old pool-size cap only picks WHICH fused kernel runs.
+  is token-identical to it (tests/test_serve_paging.py).
 - **Self-speculative decoding** (``speculate=K``): decode proceeds in
   draft-verify rounds — K-1 tokens drafted from the request's own token
   history (n-gram prompt lookup, serve/speculate.py; no draft model),
@@ -106,6 +98,7 @@ import numpy as onp
 from .. import metrics as _metrics
 from ..analysis import guards as _guards
 from ..base import MXNetError
+from ..device import on_tpu
 from ..models import generation as _gen
 from ..observability import perf as _perf
 from ..observability import recorder as _recorder
@@ -304,9 +297,8 @@ class InferenceEngine:
         (retire/refill is delayed one step — see module docstring)
     multi_token : emit K tokens per decode dispatch via the on-device
         ``lax.while_loop`` (models/generation.decode_multi_tokens): the
-        per-token host round-trip becomes one round-trip per K tokens,
-        attacking the dispatch overhead ROOFLINE.md's r6 ledger blames
-        for the overhead-bound decode regime. EOS/deadline/refill are
+        per-token host round-trip becomes one round-trip per K tokens.
+        EOS/deadline/refill are
         detected by scanning the returned K-vector; speculative tokens
         past a row's EOS/budget are discarded, so output is
         token-for-token identical to ``multi_token=1`` — with one scoped
@@ -358,17 +350,6 @@ class InferenceEngine:
         verify width, ``speculate - 1``).
     spec_lookup : max n-gram length the prompt-lookup draft source
         matches (default 4).
-    fused : assert the model's fused-decode state: ``True`` requires
-        fused packs (quantize_net(..., fused_decode=True)), ``False``
-        requires their absence, ``None`` follows the model. Fused block
-        decode now composes with ``paged=True`` — the kernel gathers/
-        scatters KV through the block table in-kernel
-        (ops/fused_block_gemv.fused_block_decode_paged), so the paged
-        pool serves the same 13-launch step as the contiguous engine.
-        Pools that exceed the VMEM budget keep the 13-launch step via
-        the DMA-resident kernel variant (HBM pool + double-buffered
-        async page gathers); pool size no longer forces the unfused
-        path.
     grammar : enable grammar-constrained decoding (serve/grammar.py):
         ``submit(..., grammar=...)`` compiles a regex/JSON-schema into a
         token-mask automaton whose per-slot state advances as DATA, and
@@ -409,7 +390,6 @@ class InferenceEngine:
                  speculate: Optional[int] = None,
                  spec_draft: Optional[int] = None,
                  spec_lookup: Optional[int] = None,
-                 fused: Optional[bool] = None,
                  name: str = "default",
                  tier: Optional[str] = None,
                  prefix_advert: Optional[int] = None,
@@ -627,40 +607,18 @@ class InferenceEngine:
                     f"{s1} vs {s2}")
             self._baxes.append(diffs[0])
 
-        fused_blocks = any(
-            getattr(blk, "_fused_pack", None) is not None
-            for blk in getattr(model, "blocks", ()) or ())
-        if fused is True and not fused_blocks:
-            raise MXNetError(
-                "fused=True but the model has no fused decode packs — "
-                "quantize_net(..., fused_decode=True) (or "
-                "enable_fused_decode()) first")
-        if fused is False and fused_blocks:
-            # packs live on the SHARED model object and the trace bakes
-            # them in — a per-engine opt-out cannot exist without
-            # retracing machinery; refuse rather than silently fuse
-            raise MXNetError(
-                "fused=False but the model has fused decode enabled; "
-                "call model.disable_fused_decode() (packs are a model "
-                "property, shared by every engine over it)")
-        # packed int8 tables are baked into fused executables as trace
-        # constants — swap_weights refuses on such engines (see there)
-        self._fused_blocks = fused_blocks
         if paged is None:
             # auto: paged on TPU — but only when the model speaks the
             # paged protocol and max_len is a page multiple, so existing
             # contiguous-only configurations keep working unchanged
             # (explicit paged=True still raises with the specific
-            # reason). Fused block decode composes with paging since the
-            # kernel gathers/scatters through the block table in-kernel
-            # (fused_block_decode_paged) — fused models take the paged
-            # pool like everyone else.
-            paged = (jax.default_backend() == "tpu"
+            # reason)
+            paged = (on_tpu()
                      and hasattr(model, "cache_spec_paged")
                      and hasattr(model, "forward_cached_paged")
                      and self.L % int(page_size) == 0)
             if (not paged and page_tuned
-                    and jax.default_backend() == "tpu"
+                    and on_tpu()
                     and hasattr(model, "cache_spec_paged")
                     and hasattr(model, "forward_cached_paged")
                     and self.L % int(page_size) != 0):
@@ -1094,9 +1052,9 @@ class InferenceEngine:
         if version is None:
             version = self.weight_version + 1
         version = int(version)
-        if self._head_pack is not None or self._fused_blocks:
-            # fused decode bakes the packed int8 tables (block packs and
-            # the tied-head table) into the jitted executables as trace
+        if self._head_pack is not None:
+            # fused head sampling bakes the packed int8 tied-head table
+            # into the jitted executables as trace
             # constants, NOT as swappable arguments — a values-only swap
             # would silently sample through the OLD head. Refuse rather
             # than serve inconsistent generations.

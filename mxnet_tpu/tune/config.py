@@ -67,29 +67,6 @@ KNOBS: Dict[str, Dict[str, Any]] = {
         "valid": lambda v: v >= 2 and v % 2 == 0,
         "doc": "values per fp32 scale in the block-scaled collective "
                "codecs (kvstore/quant.DEFAULT_BLOCK)"},
-    "fused_block_bn": {
-        "site": GLOBAL_SITE, "default": 0, "tags": ("overhead",
-                                                    "bandwidth"),
-        "valid": lambda v: v == 0 or (v >= 128 and v % 128 == 0),
-        "doc": "output-channel block of the fused-GEMV Pallas kernels; "
-               "0 = the hand-picked candidate scan "
-               "(ops/fused_block_gemv._BN_CANDIDATES)"},
-    "fused_vmem_budget": {
-        "site": GLOBAL_SITE, "default": 12 * 1024 * 1024,
-        "tags": ("geometry",),
-        "valid": lambda v: v > 0,
-        "doc": "VMEM bytes the single-launch fused decode kernels may "
-               "claim (caches/gather scratch + one weight block); "
-               "non-positive values are rejected "
-               "(ops/fused_block_gemv._VMEM_BUDGET)"},
-    "fused_dma_depth": {
-        "site": GLOBAL_SITE, "default": 2, "tags": ("overhead",
-                                                    "bandwidth"),
-        "valid": lambda v: 2 <= v <= 8,
-        "doc": "double-buffer slots of the DMA-resident paged fused "
-               "decode kernel: per-(row, head) K/V page gathers issued "
-               "up to depth-1 tiles ahead of the attention math "
-               "(ops/fused_block_gemv._pallas_block_decode_paged_dma)"},
     "gemv_int4_block": {
         "site": GLOBAL_SITE, "default": 128, "tags": ("bandwidth",),
         "valid": lambda v: v >= 2 and v % 2 == 0,

@@ -654,7 +654,7 @@ def test_mx101_rotation_proof():
 def test_mx102_any_ref_use():
     rep = _kanalyze(_MX102_DIRECT_LOAD)
     assert [f["rule"] for f in rep.findings] == ["MX102"]
-    assert "pltpu.ANY" in rep.findings[0]["message"]
+    assert "pl.ANY" in rep.findings[0]["message"]
     # feeding copies only (the legal use) is clean — MISSING_WAIT's
     # fixed variant already covers an ANY ref used solely as a DMA source
 
@@ -670,20 +670,6 @@ def test_mx103_gate_mismatch_and_agreement():
     assert [(p.gate, p.agree) for p in rep2.pairs] == [("gate_ok", True)]
 
 
-def test_mx103_agrees_with_all_shipped_fusable_gates():
-    """The acceptance pin: the static VMEM estimator must agree with the
-    byte arithmetic of every shipped fusable_* runtime gate — drift in
-    either direction is an MX103 finding and fails this gate."""
-    from mxnet_tpu.analysis import kernels
-    rep = kernels.analyze_file(
-        os.path.join(REPO, "mxnet_tpu", "ops", "fused_block_gemv.py"))
-    assert rep.findings == [] and rep.notes == []
-    pairs = {p.gate: p for p in rep.pairs}
-    assert set(pairs) == {"fusable", "fusable_paged", "fusable_paged_dma"}
-    for name, p in pairs.items():
-        assert p.agree, f"{name} vs {p.wrapper}: {p.detail}"
-
-
 def test_kernel_corpus_clean():
     """Zero unsuppressed MX1xx findings (and zero analyzer notes) over
     the whole shipped kernel family."""
@@ -695,7 +681,7 @@ def test_kernel_corpus_clean():
         assert rep.findings == [], (fn, rep.findings)
         assert rep.notes == [], (fn, rep.notes)
         sites += len(rep.kernels)
-    assert sites >= 10   # the family: 4 fused-block + 4 attention + 2 gemv
+    assert sites == 7    # the family: fused head + 4 attention + 2 gemv
 
 
 def test_kernel_rules_flow_through_linter():
@@ -721,10 +707,8 @@ def test_mxlint_cli_kernels_selector():
     assert doc["ok"] is True
     reports = {r["path"]: r for r in doc["kernel_reports"]}
     gemv = reports["mxnet_tpu/ops/fused_block_gemv.py"]
-    assert len(gemv["kernels"]) == 4
-    assert sorted(p["gate"] for p in gemv["pairs"]) == [
-        "fusable", "fusable_paged", "fusable_paged_dma"]
-    assert all(p["agree"] for p in gemv["pairs"])
+    assert len(gemv["kernels"]) == 1          # the fused head
+    assert gemv["pairs"] == []                # no VMEM gate ships today
 
 
 def test_mxlint_cli_jax_free():
@@ -848,35 +832,6 @@ def fresh_metrics():
     metrics.reset()
     if not was:
         metrics.disable()
-
-
-def test_dma_ledger_parity_and_skew(fresh_metrics):
-    from mxnet_tpu.ops.int8_gemv import record_dma
-    # empty ledger: parity holds, but require_traffic demands a round
-    assert guards.dma_ledger_check() == {"copies": 0, "waits": 0,
-                                         "ok": True}
-    with pytest.raises(guards.GuardViolation):
-        guards.dma_ledger_check(require_traffic=True)
-    # the router's ledger records waits == copies by construction
-    record_dma(10, 4096)
-    out = guards.dma_ledger_check(require_traffic=True)
-    assert out == {"copies": 10, "waits": 10, "ok": True}
-    # a drifted launch-site ledger (starts without waits) trips it
-    metrics.DECODE_DMA_COPIES.inc(3)
-    with pytest.raises(guards.GuardViolation, match="13 copies.*10 waits"):
-        guards.dma_ledger_check()
-    out = guards.dma_ledger_check(action="count")
-    assert out["ok"] is False
-    assert metrics.get_sample_value("mxnet_guard_violations_total",
-                                    {"guard": "dma_ledger"}) >= 3
-
-
-def test_record_dma_explicit_waits(fresh_metrics):
-    from mxnet_tpu.ops.int8_gemv import record_dma
-    record_dma(4, 1024, waits=2)    # deliberately skewed ledger
-    assert metrics.get_sample_value("mxnet_decode_dma_waits_total") == 2
-    with pytest.raises(guards.GuardViolation):
-        guards.dma_ledger_check()
 
 
 # ========================================================= runtime guards

@@ -215,39 +215,6 @@ def test_paged_vs_contiguous_parity_gpt(gpt_model):
 
 
 @pytest.mark.slow
-def test_paged_fused_vs_unfused_bitwise_gpt():
-    """Fused × paged composition (the PR-7 remnant): a quantized GPT
-    with fused block decode enabled must serve BITWISE-identical tokens
-    through the paged engine as the unfused paged path, across
-    multi_token K∈{1,4} — off-TPU the fused route's XLA fallback replays
-    the unfused paged op sequence exactly (ops/fused_block_gemv.
-    _reference_block_decode_paged), which is the contract that makes the
-    TPU kernel swap-in safe."""
-    from mxnet_tpu.contrib.quantization import quantize_net
-    mx.random.seed(0)
-    net = GPTModel(GPTConfig(vocab_size=64, hidden_size=32, num_layers=2,
-                             num_heads=2, max_position_embeddings=128,
-                             dropout=0.0))
-    net.initialize()
-    net(np.array(onp.zeros((1, 4), "int32")))
-    quantize_net(net, calib_mode="none")
-    prompts = _prompts(4, vocab=60, seed=3)
-    try:
-        base = {K: _serve_all(net, prompts, 8, max_batch_size=2,
-                              max_len=32, paged=True, page_size=8,
-                              multi_token=K, fused=False)
-                for K in (1, 4)}
-        assert net.enable_fused_decode() == 2
-        for K in (1, 4):
-            fused = _serve_all(net, prompts, 8, max_batch_size=2,
-                               max_len=32, paged=True, page_size=8,
-                               multi_token=K, fused=True)
-            assert fused == base[K], f"multi_token={K}"
-    finally:
-        net.disable_fused_decode()
-
-
-@pytest.mark.slow
 def test_paged_fused_parity_llama():
     """The llama half of the paged-fused contract: a tie_embeddings
     llama with an int8-quantized tied head (quantize_net sets
@@ -305,43 +272,6 @@ def test_paged_fused_parity_llama_int4():
     paged = _serve_all(net, prompts, 6, max_batch_size=2, max_len=32,
                        paged=True, page_size=8, multi_token=4)
     assert paged == base
-
-
-@pytest.mark.slow
-def test_paged_dma_serve_parity(monkeypatch):
-    """End-to-end DMA-route serving: with the VMEM budget shrunk so the
-    VMEM-resident paged gate declines but the DMA gate passes, a paged
-    fused engine must serve token-identical to the contiguous engine —
-    the tentpole's 'pool size no longer forces the unfused path'
-    contract at the serving layer, not just the kernel layer."""
-    from mxnet_tpu.contrib.quantization import quantize_net
-    from mxnet_tpu.ops import fused_block_gemv as fb
-    mx.random.seed(0)
-    net = GPTModel(GPTConfig(vocab_size=64, hidden_size=128, num_layers=2,
-                             num_heads=4, max_position_embeddings=128,
-                             dropout=0.0))
-    net.initialize()
-    net(np.array(onp.zeros((1, 4), "int32")))
-    quantize_net(net, calib_mode="none")
-    monkeypatch.setenv("MXNET_TUNE_FUSED_VMEM_BUDGET", str(128 * 1024))
-    # pool = 2*32/8 + sink = 9 pages: the VMEM gate declines, DMA passes
-    assert not fb.fusable_paged(2, 128, 4, 9, 8, 4)
-    assert fb.fusable_paged_dma(2, 128, 4, 9, 8, 4)
-    prompts = _prompts(4, vocab=60, seed=11)
-    try:
-        # K=4 exercises the whole fused surface (DMA blocks + fused
-        # head + device loop); the kernel-level DMA parity tests cover
-        # the rest of the matrix without another engine build
-        base = _serve_all(net, prompts, 8, max_batch_size=2, max_len=32,
-                          paged=True, page_size=8, multi_token=4,
-                          fused=False)
-        assert net.enable_fused_decode() == 2
-        fused = _serve_all(net, prompts, 8, max_batch_size=2, max_len=32,
-                           paged=True, page_size=8, multi_token=4,
-                           fused=True)
-        assert fused == base
-    finally:
-        net.disable_fused_decode()
 
 
 @pytest.mark.slow

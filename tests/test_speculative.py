@@ -8,9 +8,9 @@ The tier-1 contracts:
   recomputes exactly the token the sequential path would emit (same
   bitwise logits by T-invariance, same stateless fold_in keys), so
   speculation can change latency, never content.
-- Composition: paging + COW prefix sharing + chunked prefill + fused
+- Composition: paging + COW prefix sharing + chunked prefill + int8
   block decode all serve speculative traffic unchanged; the router
-  serves paged+fused+speculative end-to-end with zero steady-state
+  serves paged+int8+speculative end-to-end with zero steady-state
   recompiles (no_recompile()-guarded).
 - The drafting source is deterministic and the tuned-config knobs
   (serve_speculate / serve_spec_draft / serve_spec_lookup) resolve per
@@ -180,11 +180,9 @@ def test_spec_composes_with_prefix_cache_and_chunked_prefill(gpt_model):
     assert st["pages"]["prefix_hits"] >= 1      # the composition is real
 
 
-def test_spec_with_fused_paged_decode():
-    """The whole stack at once: quantized fused-block model + paged pool
-    + speculation — token-exact vs the unfused non-speculative paged
-    engine (the verify step runs T>1 so blocks take their unfused
-    (bitwise) path; single-token rounds never happen under speculate)."""
+def test_spec_with_quantized_paged_decode():
+    """The whole stack at once: int8-quantized model + paged pool +
+    speculation — token-exact vs the non-speculative paged engine."""
     from mxnet_tpu.contrib.quantization import quantize_net
     mx.random.seed(0)
     net = GPTModel(GPTConfig(vocab_size=64, hidden_size=32, num_layers=2,
@@ -194,16 +192,12 @@ def test_spec_with_fused_paged_decode():
     net(np.array(onp.zeros((1, 4), "int32")))
     quantize_net(net, calib_mode="none")
     prompts = _prompts(4, seed=6)
-    try:
-        base, _ = _serve_all(net, prompts, 8, max_batch_size=2,
-                             max_len=48, paged=True, page_size=8)
-        net.enable_fused_decode()
-        spec, _ = _serve_all(net, prompts, 8, max_batch_size=2,
-                             max_len=48, paged=True, page_size=8,
-                             speculate=4, fused=True)
-        assert spec == base
-    finally:
-        net.disable_fused_decode()
+    base, _ = _serve_all(net, prompts, 8, max_batch_size=2,
+                         max_len=48, paged=True, page_size=8)
+    spec, _ = _serve_all(net, prompts, 8, max_batch_size=2,
+                         max_len=48, paged=True, page_size=8,
+                         speculate=4)
+    assert spec == base
 
 
 def test_spec_parity_llama(gpt_model):
@@ -224,8 +218,8 @@ def test_spec_parity_llama(gpt_model):
 
 
 # --------------------------------------------------------- router end-to-end
-def test_router_serves_paged_fused_speculative_no_recompiles():
-    """The acceptance smoke: a router fronting paged+fused+speculative
+def test_router_serves_paged_quantized_speculative_no_recompiles():
+    """The acceptance smoke: a router fronting paged+int8+speculative
     replicas serves mixed traffic end-to-end with ZERO steady-state
     recompiles (no_recompile()-guarded) and speculation visibly active."""
     from mxnet_tpu import metrics
@@ -239,9 +233,9 @@ def test_router_serves_paged_fused_speculative_no_recompiles():
                              dropout=0.0))
     net.initialize()
     net(np.array(onp.zeros((1, 4), "int32")))
-    quantize_net(net, calib_mode="none", fused_decode=True)
+    quantize_net(net, calib_mode="none")
     eng = InferenceEngine(net, max_batch_size=2, max_len=48, paged=True,
-                          page_size=8, speculate=4, fused=True).start()
+                          page_size=8, speculate=4).start()
     eng.warmup()
     rounds0 = metrics.get_sample_value("mxnet_spec_rounds_total") or 0
     prompts = _prompts(5, seed=8)
@@ -265,60 +259,6 @@ def test_router_serves_paged_fused_speculative_no_recompiles():
         assert rate is not None and 0.0 <= rate <= 1.0
     finally:
         eng.shutdown()
-        net.disable_fused_decode()
-        if not was:
-            metrics.disable()
-
-
-def test_router_serves_dma_paged_fused_speculative_no_recompiles(
-        monkeypatch):
-    """The tentpole's steady-state contract: when the pool overflows the
-    (shrunken) VMEM budget and paged fused decode routes through the
-    DMA-resident kernel variant, a router fronting paged + fused +
-    speculative replicas still serves mixed traffic with ZERO
-    steady-state recompiles — the DMA route must not perturb the traced
-    step shapes the no_recompile() guard pins."""
-    from mxnet_tpu import metrics
-    from mxnet_tpu.analysis import guards
-    from mxnet_tpu.contrib.quantization import quantize_net
-    from mxnet_tpu.ops import fused_block_gemv as fb
-    was = metrics.enabled()
-    metrics.enable()
-    mx.random.seed(0)
-    net = GPTModel(GPTConfig(vocab_size=64, hidden_size=128, num_layers=2,
-                             num_heads=4, max_position_embeddings=128,
-                             dropout=0.0))
-    net.initialize()
-    net(np.array(onp.zeros((1, 4), "int32")))
-    quantize_net(net, calib_mode="none", fused_decode=True)
-    monkeypatch.setenv("MXNET_TUNE_FUSED_VMEM_BUDGET", str(128 * 1024))
-    # pool = 2*48/8 + sink = 13 pages: VMEM gate declines, DMA passes
-    assert not fb.fusable_paged(2, 128, 4, 13, 8, 6)
-    assert fb.fusable_paged_dma(2, 128, 4, 13, 8, 6)
-    eng = InferenceEngine(net, max_batch_size=2, max_len=48, paged=True,
-                          page_size=8, speculate=4, fused=True).start()
-    eng.warmup()
-    rounds0 = metrics.get_sample_value("mxnet_spec_rounds_total") or 0
-    prompts = _prompts(5, seed=9)
-    try:
-        with HTTPFrontend(eng, port=0) as fe:
-            router = Router([fe.url], health_interval=0.2).start()
-            try:
-                with guards.no_recompile(block="serve"):
-                    for i, p in enumerate(prompts):
-                        doc = router.generate({
-                            "input_ids": [int(t) for t in p],
-                            "max_new_tokens": 6,
-                            "temperature": 0.7 * (i % 2), "seed": i})
-                        assert doc["status"] == "ok", doc
-                        assert len(doc["generated_ids"]) == 6
-            finally:
-                router.stop()
-        rounds = metrics.get_sample_value("mxnet_spec_rounds_total") or 0
-        assert rounds > rounds0           # speculation actually served
-    finally:
-        eng.shutdown()
-        net.disable_fused_decode()
         if not was:
             metrics.disable()
 
@@ -395,6 +335,3 @@ def test_tuned_spec_multitoken_conflict_degrades_not_crashes(gpt_model):
         tune.deactivate_all()
 
 
-def test_fused_flag_validation(gpt_model):
-    with pytest.raises(MXNetError, match="fused=True"):
-        InferenceEngine(gpt_model, max_len=32, fused=True)

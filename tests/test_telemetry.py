@@ -328,20 +328,14 @@ def test_pipeline_check_tool_inprocess(fresh_metrics):
 
 
 def test_decode_check_tool_inprocess(fresh_metrics):
-    """CI guard for the fused/multi-token decode metric families: launch
-    sites recorded at trace time (incl. the DMA-resident paged and int4
-    kind variants), the async-copy ledger, round-trips << decode
-    tokens."""
+    """CI guard for the multi-token decode metric families: launch sites
+    recorded at trace time (off-TPU all under kind=reference, none under
+    a kernel's kind), round-trips << decode tokens."""
     mc = _load_metrics_check()
     summary = mc.run_decode_check()
     assert summary["ok"]
-    assert summary["fused_block_sites"] >= 2
-    assert summary["fused_head_sites"] >= 1
-    assert summary["fused_block_paged_dma_sites"] >= 2
-    assert summary["fused_block_int4_sites"] >= 2
-    assert summary["fused_head_int4_sites"] >= 1
-    assert summary["dma_copies"] >= 1
-    assert summary["dma_bytes"] >= summary["dma_copies"]
+    assert summary["reference_sites"] >= 2 * (4 * 2 + 1)
+    assert not any(summary["kernel_sites"].values())
     assert summary["decode_roundtrips"] < summary["decode_tokens"]
 
 
@@ -380,9 +374,14 @@ def test_perf_check_tool_inprocess(fresh_metrics):
     bucket) lands in the ledger with XLA costs on the
     mxnet_executable_* gauges, the live mxnet_mfu gauge matches the
     offline flops/dt/peak arithmetic, steady-state steps stay silent
-    under no_recompile(), and a regime verdict exists for decode."""
+    under no_recompile(), and a regime verdict exists for decode.
+
+    The check is of arithmetic agreement (live gauge vs ledger FLOPs /
+    dt / peak), so the test names the chip whose peaks both sides divide
+    by: the CPU it runs on has none on record, and perf refuses to
+    invent them."""
     mc = _load_metrics_check()
-    summary = mc.run_perf_check()
+    summary = mc.run_perf_check(chip="TPU v5 lite")
     assert summary["ok"]
     assert summary["train_flops"] > 0
     assert summary["train_peak_bytes"] > 0

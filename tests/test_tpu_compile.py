@@ -1,0 +1,215 @@
+"""Ask the chip's compiler, without the chip: every ``pallas_call`` site left
+in ``mxnet_tpu/ops`` and the whole jitted ``TrainStep`` body compile for a
+DESCRIBED v5e at GPT-2-small shapes. Interpret mode cannot see what Mosaic
+refuses (unaligned slices, scoped-VMEM overflow, 64-bit index maps); this
+file can, at no chip time.
+
+Nothing here touches the TPU library while a module is imported: the topology
+is described inside a fixture, by the one xdist worker that is given this
+file. The kernels' own TPU gates ask ``device.on_tpu()``, which still sees the
+CPU here, so each test steers that predicate itself.
+"""
+import dataclasses
+import importlib
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+# GPT-2-small serving/training geometry (models/gpt.py GPT2_SMALL)
+D, H, HD, VOCAB, VP = 768, 12, 64, 50257, 50304
+B_SERVE, B_TRAIN, T_TRAIN = 8, 16, 1024
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo, _no_compile_cache):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def _no_compile_cache():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without a chip; keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """The kernel gates answer as on a TPU (they ask ``on_tpu()``, which
+    sees this process's CPU)."""
+    for mod in ("attention", "int8_gemv", "fused_block_gemv"):
+        monkeypatch.setattr(
+            importlib.import_module(f"mxnet_tpu.ops.{mod}"), "on_tpu",
+            lambda: True)
+
+
+def _compile(fn, *args):
+    """Compile for the described chip; returns the number of Pallas
+    kernels (``tpu_custom_call``) in the optimized program."""
+    compiled = jax.jit(fn).lower(*args).compile()
+    return compiled.as_text().count("tpu_custom_call")
+
+
+def _s(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+# ------------------------------------------------------------ flash attention
+@pytest.mark.parametrize("shape", [(B_TRAIN, H, T_TRAIN, HD),
+                                   (1, H, 300, HD)],
+                         ids=["train_16x12x1024x64", "prefill_T300"])
+def test_flash_forward_compiles(one_chip, shape):
+    att = importlib.import_module("mxnet_tpu.ops.attention")
+    q = _s(one_chip, shape, jnp.bfloat16)
+    n = _compile(lambda q, k, v: att._pallas_forward(q, k, v, True, 0.125),
+                 q, q, q)
+    assert n == 1
+
+
+@pytest.mark.parametrize("shape,kernels", [
+    ((B_TRAIN, H, T_TRAIN, HD), 1),      # the fused dq/dk/dv kernel
+    ((1, H, 300, HD), 1),
+    ((1, H, 16384, HD), 2),              # the two-kernel dq; dk+dv sweep
+], ids=["train_fused", "prefill_T300_fused", "T16384_two_kernel"])
+def test_flash_backward_compiles(one_chip, shape, kernels):
+    att = importlib.import_module("mxnet_tpu.ops.attention")
+    q = _s(one_chip, shape, jnp.bfloat16)
+    tp = att._choose_block(shape[2])[1]
+    lse = _s(one_chip, shape[:2] + (tp, 1), jnp.float32)
+    n = _compile(
+        lambda q, k, v, o, l, do: att._pallas_backward(
+            q, k, v, o, l, do, True, 0.125), q, q, q, q, lse, q)
+    assert n == kernels
+
+
+# ------------------------------------------------------------------ the GEMVs
+_GEMV_SHAPES = [(3 * D, D), (D, D), (4 * D, D), (D, 4 * D), (VP, D)]
+_GEMV_IDS = ["qkv", "attn_out", "fc", "proj", "lm_head"]
+
+
+@pytest.mark.parametrize("N,K", _GEMV_SHAPES, ids=_GEMV_IDS)
+def test_int8_gemv_compiles(one_chip, as_tpu, N, K):
+    gemv = importlib.import_module("mxnet_tpu.ops.int8_gemv")
+    n = _compile(gemv.int8_weight_matmul,
+                 _s(one_chip, (B_SERVE, K), jnp.bfloat16),
+                 _s(one_chip, (N, K), jnp.int8),
+                 _s(one_chip, (N,), jnp.float32))
+    assert n == 1
+
+
+@pytest.mark.parametrize("N,K", _GEMV_SHAPES, ids=_GEMV_IDS)
+def test_int4_gemv_compiles(one_chip, as_tpu, N, K):
+    gemv = importlib.import_module("mxnet_tpu.ops.int8_gemv")
+    n = _compile(lambda x, w, s: gemv.int4_weight_matmul(x, w, s),
+                 _s(one_chip, (B_SERVE, K), jnp.bfloat16),
+                 _s(one_chip, (N, K // 2), jnp.uint8),
+                 _s(one_chip, (N, K // 128), jnp.float32))
+    assert n == 1
+
+
+# ------------------------------------------------------------- the fused head
+@pytest.mark.parametrize("masked", [False, True], ids=["plain", "grammar"])
+def test_fused_head_compiles(one_chip, masked):
+    fb = importlib.import_module("mxnet_tpu.ops.fused_block_gemv")
+    args = [_s(one_chip, (B_SERVE, D), jnp.bfloat16),
+            _s(one_chip, (VP, D), jnp.int8),
+            _s(one_chip, (VP,), jnp.float32),
+            _s(one_chip, (B_SERVE,), jnp.float32),
+            _s(one_chip, (B_SERVE,), jnp.uint32)]
+    if masked:
+        args.append(_s(one_chip, (B_SERVE, VP), jnp.bool_))
+
+    def head(h, w, s, t, kb, mask=None):
+        return fb._head_kernel(h, w, s, VOCAB, t, kb,
+                               out_dtype=jnp.bfloat16, mask=mask)
+
+    assert _compile(head, *args) == 1
+
+
+# ------------------------------------------------------- the TrainStep body
+def _gpt2_small_step(layers):
+    """A GPT-2-small-width TrainStep (depth cut to ``layers``: the
+    per-layer program repeats, and a 12-layer compile is chip_smoke.py's
+    job) and the argument tuple of its jitted body."""
+    import numpy as onp
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import np, parallel
+    from mxnet_tpu.gluon.loss import SoftmaxCrossEntropyLoss
+    from mxnet_tpu.models.gpt import GPT2_SMALL, GPTModel
+
+    cfg = dataclasses.replace(GPT2_SMALL, num_layers=layers, dropout=0.0,
+                              dtype=jnp.bfloat16)
+    net = GPTModel(cfg)
+    net.initialize()
+    ids = np.array(onp.zeros((B_TRAIN, T_TRAIN), onp.int32))
+    step = parallel.TrainStep(net, SoftmaxCrossEntropyLoss(),
+                              mx.optimizer.Adam(learning_rate=1e-4),
+                              example_inputs=[ids], donate=False)
+    args = (tuple(step.model.values()), tuple(step._opt_states),
+            ((ids._data,), (ids._data,)), jnp.float32(1e-4), jnp.int32(1),
+            jnp.int32(1), jnp.float32(1.0))
+    return step, args
+
+
+def _shapes(tree, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x),
+                                       sharding=sharding), tree)
+
+
+def test_trainstep_body_compiles(one_chip, as_tpu):
+    """The whole fused step (forward, Pallas flash fwd+bwd per layer, Adam)
+    at GPT-2-small width and B=16, T=1024 fits one v5e and keeps its
+    kernels."""
+    layers = 2
+    step, args = _gpt2_small_step(layers)
+    compiled = step._jitted.lower(*_shapes(args, one_chip)).compile()
+    # one forward and one fused backward kernel per layer
+    assert compiled.as_text().count("tpu_custom_call") == 2 * layers
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes \
+        < 16 * 1024 ** 3
+
+
+def test_trainstep_body_compiles_data_parallel(topo, one_chip, as_tpu):
+    """The same step over a dp=4 mesh of the described chips. GSPMD cannot
+    partition a Mosaic kernel; the flash kernels map themselves over 'dp'
+    (ops/attention._over_mesh) — without that this compile raises "Mosaic
+    kernels cannot be automatically partitioned", as the first four-chip
+    run of PR 23 did."""
+    import numpy as onp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from mxnet_tpu.parallel.mesh import use_mesh
+
+    layers = 2
+    step, args = _gpt2_small_step(layers)
+    mesh = Mesh(onp.array(topo.devices).reshape(4), ("dp",))
+    repl, dp = NamedSharding(mesh, P()), NamedSharding(mesh, P("dp"))
+    shapes = (_shapes(args[0], repl), _shapes(args[1], repl),
+              _shapes(args[2], dp)) + _shapes(tuple(args[3:]), repl)
+    with use_mesh(mesh):
+        compiled = step._jitted.lower(*shapes).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 * layers
+    assert "all-reduce" in text          # the dp gradient reduction

@@ -153,26 +153,15 @@ def test_defaults_pin_the_hand_picked_constants(no_tune):
     assert tune.knob_default("serve_bucket_growth") == 2
     assert tune.knob_default("serve_page_size") == 16
     assert tune.knob_default("serve_multi_token") == 1
-    # the fused-decode kernel knobs (ISSUE 19): defaults pin the
-    # constants/hand-picked values the gates consulted before
-    from mxnet_tpu.ops.fused_block_gemv import _VMEM_BUDGET
-    assert tune.knob_default("fused_vmem_budget") == _VMEM_BUDGET \
-        == 12 * 1024 * 1024
-    assert tune.knob_default("fused_dma_depth") == 2
     assert tune.knob_default("gemv_int4_block") == 128
 
 
-def test_fused_kernel_knob_validators(no_tune, monkeypatch):
-    """Invalid env/stored values for the fused-decode knobs degrade to
-    the defaults instead of poisoning the shape gates: non-positive
-    budgets, out-of-range DMA depths and odd int4 blocks are rejected."""
-    from mxnet_tpu.ops.fused_block_gemv import _VMEM_BUDGET
+def test_int4_block_knob_validator(no_tune, monkeypatch):
+    """Invalid env/stored values for the int4 block knob degrade to the
+    default: non-positive and odd blocks are rejected."""
     for env, bad, good, default in (
-            ("MXNET_TUNE_FUSED_VMEM_BUDGET", ("0", "-1"), "65536",
-             _VMEM_BUDGET),
-            ("MXNET_TUNE_FUSED_DMA_DEPTH", ("0", "1", "9"), "4", 2),
             ("MXNET_TUNE_GEMV_INT4_BLOCK", ("0", "-128", "127"), "64",
-             128)):
+             128),):
         knob = env[len("MXNET_TUNE_"):].lower()
         for v in bad:
             monkeypatch.setenv(env, v)
@@ -450,7 +439,8 @@ def test_gemv_routing_consults_tuned_threshold(no_tune):
         with count_launches() as tally:
             net(np.array(onp.random.RandomState(1).rand(4, 8)
                          .astype("float32"))).wait_to_read()
-        return tally.get("gemv", 0)
+        # off-TPU the GEMV site runs its XLA reference and says so
+        return tally.get("reference", 0)
 
     net.hybridize(active=False)  # re-trace every call for the tally
     assert gemv_launches() == 1          # 4 rows <= default 64: GEMV path

@@ -189,8 +189,16 @@ def test_zero_multi_step_run_matches_loop():
         l_loop = s1(np.array(X), np.array(Y))
     l_run = s2.run(np.array(X), np.array(Y), steps=3)
     assert float(l_loop.item()) == float(l_run.item())
+    # Not bitwise: inside the while body XLA's SPMD partitioner (JAX 0.9)
+    # lowers the dp gradient reduction onto the shards differently from
+    # the standalone step (it warns of an "involuntary full
+    # rematerialization" there), so the cross-replica f32 sum associates
+    # in another order. zero=0, which has no sharded reduction, IS bitwise
+    # (checked when this bound was set); the bound below is a few f32 ulp
+    # of O(1) weights over three Adam steps (measured 2.6e-7).
     for a, b in zip(s1.model.values(), s2.model.values()):
-        assert (onp.asarray(a) == onp.asarray(b)).all()
+        onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b),
+                                    rtol=0, atol=2e-6)
 
 
 def test_zero_checkpoint_bitwise_resume(tmp_path):

@@ -127,7 +127,7 @@ def main():
         for _ in range(args.iters):
             out = fn(x)
         jax.block_until_ready(out)
-        onp.asarray(out.ravel()[0])  # force through any async tunnel
+        onp.asarray(out.ravel()[0])  # force a device->host fetch
         return (time.perf_counter() - t0) / args.iters
 
     col_defs = {
@@ -144,8 +144,6 @@ def main():
     rows = []
     for name in wanted:
         body, bus_bytes = col_defs[name]
-        # the version-portable shim (PR-8): jax.shard_map on new jax,
-        # jax.experimental.shard_map on the pinned one
         from mxnet_tpu.parallel.mesh import shard_map as _shard_map
         fn = jax.jit(_shard_map(  # mxlint: disable=MX002 -- one wrapper
             # per collective kind (<=3, not per hot-loop iteration),

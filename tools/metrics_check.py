@@ -45,10 +45,6 @@ REQUIRED_PIPELINE_METRICS = (
 REQUIRED_DECODE_METRICS = (
     "mxnet_decode_launches_total",
     "mxnet_serve_host_roundtrips_total",
-    # the DMA-resident paged fused round's trace-time async-copy ledger
-    "mxnet_decode_dma_copies_total",
-    "mxnet_decode_dma_bytes_total",
-    "mxnet_decode_dma_waits_total",
 )
 
 # families the self-speculative decode path must expose after one
@@ -342,7 +338,7 @@ def run_check():
             metrics.disable()
 
 
-def run_perf_check():
+def run_perf_check(chip=None):
     """One jitted train step + one serve bucket-ladder warmup under the
     cost ledger (observability/perf), then validate: every executable
     class built here has a ledger entry (TrainStep, every prefill/decode
@@ -352,7 +348,13 @@ def run_perf_check():
     uses (same flops source, same denominator), steady-state steps
     compile nothing under the ``no_recompile()`` guard (ledger capture
     is compile-time only), and the JSON dump/exposition parse. Returns
-    a summary dict; raises on any failure."""
+    a summary dict; raises on any failure.
+
+    ``chip`` names the ``perf.PEAKS`` entry both sides of the arithmetic
+    check divide by, for a run on a device that has no peaks on record
+    (the CPU this CI tool usually runs on — perf itself refuses to invent
+    them). The check is of agreement between gauge and ledger, not of a
+    speed."""
     import time as _time
 
     import numpy as onp
@@ -370,10 +372,13 @@ def run_perf_check():
 
     was_enabled = metrics.enabled()
     was_perf = perf.active()
+    kind_was = perf._CHIP_GEN
     metrics.reset()
     metrics.enable()
     perf.reset()
     perf.enable()
+    if chip is not None:
+        perf._CHIP_GEN = chip
     try:
         # --- train: tiny fused TrainStep (compile = ledger capture) ---
         mx.random.seed(0)
@@ -495,6 +500,7 @@ def run_perf_check():
                 "serve_buckets": len(expect),
                 "decode_regime": decode_roof["regime"]}
     finally:
+        perf._CHIP_GEN = kind_was
         if not was_perf:
             perf.disable()
         perf.reset()
@@ -774,22 +780,20 @@ def run_pipeline_check():
 
 
 def run_decode_check():
-    """Three fused multi-token serving rounds on tiny quantized GPTs,
-    then validate the decode metric families: launch sites recorded at
-    trace time (mxnet_decode_launches_total — the fused path's
-    fused_block/fused_head kinds, not per-matrix gemv), host round-trips
-    strictly fewer than decode tokens (the K-tokens-per-round-trip
-    overlap), the DMA-resident paged round's fused_block_paged_dma kind
-    plus its mxnet_decode_dma_{copies,bytes}_total async-copy ledger
-    (the VMEM budget is shrunk via MXNET_TUNE_FUSED_VMEM_BUDGET so the
-    pool exceeds the gate and the HBM-resident kernel routes), and the
-    int4 round's _int4 launch-kind variants. Returns a summary dict;
+    """Two multi-token serving rounds on tiny quantized GPTs (int8, then
+    int4 weights), then validate the decode metric families: launch
+    sites recorded at trace time (mxnet_decode_launches_total — off-TPU
+    every GEMV and head site runs its XLA reference and says so with
+    kind=reference; on a TPU the same sites count gemv / gemv_int4 /
+    fused_head) and host round-trips strictly fewer than decode tokens
+    (the K-tokens-per-round-trip overlap). Returns a summary dict;
     raises on failure."""
     import numpy as onp
 
     import mxnet_tpu as mx
     from mxnet_tpu import metrics, np
     from mxnet_tpu.contrib.quantization import quantize_net
+    from mxnet_tpu.device import on_tpu
     from mxnet_tpu.models import GPTModel
     from mxnet_tpu.models.gpt import GPTConfig
     from mxnet_tpu.serve import InferenceEngine
@@ -800,15 +804,12 @@ def run_decode_check():
 
     def mk_net(bits=8):
         mx.random.seed(0)
-        # hidden 128: the smallest lane-aligned width the fused block
-        # kernel accepts (ops/fused_block_gemv.fusable), so the tally
-        # records fused_block sites rather than the gemv fallback
         net = GPTModel(GPTConfig(vocab_size=256, hidden_size=128,
                                  num_layers=2, num_heads=4,
                                  max_position_embeddings=64, dropout=0.0))
         net.initialize()
         net(np.array(onp.zeros((1, 4), "int32")))
-        quantize_net(net, calib_mode="none", fused_decode=True, bits=bits)
+        quantize_net(net, calib_mode="none", bits=bits)
         return net
 
     def serve(net, **engine_kw):
@@ -829,27 +830,13 @@ def run_decode_check():
                 f"{[(r.status, r.error) for r in results]}")
         return len(prompts)
 
+    def sites(kind):
+        return metrics.get_sample_value("mxnet_decode_launches_total",
+                                        {"kind": kind}) or 0
+
     try:
         K = 3
         n_prompts = serve(mk_net(), max_len=32)
-
-        # DMA-resident paged round: a budget small enough that the pool
-        # blocks fail fusable_paged but the depth-buffered gather slots
-        # still fit fusable_paged_dma, so the fused step keeps its one-
-        # launch-per-block shape through HBM-resident pools
-        budget_was = os.environ.get("MXNET_TUNE_FUSED_VMEM_BUDGET")
-        os.environ["MXNET_TUNE_FUSED_VMEM_BUDGET"] = str(200 * 1024)
-        try:
-            serve(mk_net(), max_len=64, paged=True, page_size=8,
-                  fused=True)
-        finally:
-            if budget_was is None:
-                del os.environ["MXNET_TUNE_FUSED_VMEM_BUDGET"]
-            else:
-                os.environ["MXNET_TUNE_FUSED_VMEM_BUDGET"] = budget_was
-
-        # int4 round: packed-nibble tables through the same fused step
-        # (the launch kinds grow the _int4 suffix)
         serve(mk_net(bits=4), max_len=32)
 
         text = metrics.expose()
@@ -857,50 +844,25 @@ def run_decode_check():
         missing = [m for m in REQUIRED_DECODE_METRICS if m not in families]
         if missing:
             raise AssertionError(f"missing decode metrics: {missing}")
-        fused = metrics.get_sample_value("mxnet_decode_launches_total",
-                                         {"kind": "fused_block"}) or 0
-        fhead = metrics.get_sample_value("mxnet_decode_launches_total",
-                                         {"kind": "fused_head"}) or 0
-        if not fused or not fhead:
+        kernel_sites = {k: sites(k)
+                        for k in ("gemv", "gemv_int4", "fused_head")}
+        reference = sites("reference")
+        if on_tpu():
+            # (the packed-int4 head has no kernel: it counts reference)
+            if not all(kernel_sites.values()):
+                raise AssertionError(
+                    "on a TPU the decode sites run their kernels: "
+                    f"{kernel_sites}, reference={reference}")
+        elif any(kernel_sites.values()) or not reference:
             raise AssertionError(
-                "fused decode recorded no fused_block/fused_head launch "
-                f"sites (fused_block={fused}, fused_head={fhead})")
-        fdma = metrics.get_sample_value(
-            "mxnet_decode_launches_total",
-            {"kind": "fused_block_paged_dma"}) or 0
-        if not fdma:
-            raise AssertionError(
-                "the shrunken-budget paged round recorded no "
-                "fused_block_paged_dma launch sites — the pool-size cap "
-                "regressed to the unfused path")
-        f4 = metrics.get_sample_value("mxnet_decode_launches_total",
-                                      {"kind": "fused_block_int4"}) or 0
-        fh4 = metrics.get_sample_value("mxnet_decode_launches_total",
-                                       {"kind": "fused_head_int4"}) or 0
-        if not f4 or not fh4:
-            raise AssertionError(
-                "the int4 round recorded no _int4 launch kinds "
-                f"(fused_block_int4={f4}, fused_head_int4={fh4})")
-        copies = metrics.get_sample_value(
-            "mxnet_decode_dma_copies_total") or 0
-        nbytes = metrics.get_sample_value(
-            "mxnet_decode_dma_bytes_total") or 0
-        if not copies or not nbytes:
-            raise AssertionError(
-                "the DMA-resident paged round recorded no async-copy "
-                f"ledger (copies={copies}, bytes={nbytes})")
-        if nbytes < copies:
-            raise AssertionError(
-                f"DMA ledger implies <1 byte per copy ({nbytes} bytes / "
-                f"{copies} copies)")
-        # runtime face of mxlint MX101: every copy started was waited
-        from mxnet_tpu.analysis import guards
-        ledger = guards.dma_ledger_check(require_traffic=True)
+                "off-TPU every decode site runs its XLA reference and "
+                f"must count as kind=reference: {kernel_sites}, "
+                f"reference={reference}")
         rts = metrics.get_sample_value("mxnet_serve_host_roundtrips_total",
                                        {"path": "decode"}) or 0
         toks = metrics.get_sample_value("mxnet_serve_tokens_total") or 0
-        # tok0s come from prefill; 3 rounds x n_prompts requests
-        decode_toks = toks - 3 * n_prompts
+        # tok0s come from prefill; 2 rounds x n_prompts requests
+        decode_toks = toks - 2 * n_prompts
         if not rts:
             raise AssertionError("no decode host round-trips recorded")
         if rts >= decode_toks:
@@ -908,12 +870,7 @@ def run_decode_check():
                 f"multi-token overlap invisible: {rts} round-trips for "
                 f"{decode_toks} decode tokens")
         return {"ok": True, "multi_token": K,
-                "fused_block_sites": fused, "fused_head_sites": fhead,
-                "fused_block_paged_dma_sites": fdma,
-                "fused_block_int4_sites": f4,
-                "fused_head_int4_sites": fh4,
-                "dma_copies": copies, "dma_bytes": nbytes,
-                "dma_waits": ledger["waits"],
+                "kernel_sites": kernel_sites, "reference_sites": reference,
                 "decode_roundtrips": rts, "decode_tokens": decode_toks}
     finally:
         if not was_enabled:
@@ -2201,7 +2158,10 @@ def main() -> int:
     try:
         summary = run_check()
         summary["pipeline"] = run_pipeline_check()
-        summary["perf"] = run_perf_check()
+        from mxnet_tpu.observability import perf
+        summary["perf"] = run_perf_check(
+            chip=None if perf._device_kind() in perf.PEAKS
+            else "TPU v5 lite")
         summary["tune"] = run_tune_check()
         summary["aot"] = run_aot_check()
         summary["decode"] = run_decode_check()

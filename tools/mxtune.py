@@ -30,11 +30,10 @@ Workloads::
                milliseconds)
 
 Knob coverage note: the measured CPU workloads produce winners for the
-serve-site knobs and `gemv_max_m`. `quant_block` and `fused_block_bn`
-are resolved by the same layer (env-overridable, stored-config capable)
-but have no CPU-measurable objective — their sweeps belong to the TPU
-bench round (the fused-GEMV kernel and the collective wire both only
-exist there).
+serve-site knobs and `gemv_max_m`. `quant_block` is resolved by the
+same layer (env-overridable, stored-config capable) but has no
+CPU-measurable objective — the collective wire only exists across
+chips.
 
 Examples::
 

@@ -137,13 +137,9 @@ def build_model(args):
                         heads=args.heads, max_len=args.max_len)
     bits = getattr(args, "bits", None)
     if bits:
-        # weight-only int8/int4 decode with the fused packs baked in:
-        # the engine then serves the one-launch-per-block step (and, in
-        # paged mode with a pool past the VMEM budget, the DMA-resident
-        # kernel variant) — the regime bench_int4_decode and
-        # bench_paged_dma_decode measure
+        # weight-only int8/int4 decode (GEMV kernels + the fused head)
         from mxnet_tpu.contrib.quantization import quantize_net
-        quantize_net(net, calib_mode="none", fused_decode=True, bits=bits)
+        quantize_net(net, calib_mode="none", bits=bits)
     return net
 
 
@@ -887,14 +883,11 @@ def main():
                     help="page-pool size; default = the contiguous "
                          "layout's byte footprint (max_batch_size * "
                          "max_len / page_size). --pool-pages is an "
-                         "alias: oversize it (with a bits-quantized "
-                         "fused model) to reproduce the large-pool "
-                         "regime where the fused step runs the DMA-"
-                         "resident kernel instead of the VMEM one")
+                         "alias")
     ap.add_argument("--bits", type=int, default=None, choices=(4, 8),
-                    help="weight-only quantize the model (fused decode "
-                         "packs baked in): 8 = int8 tables, 4 = packed "
-                         "int4 nibble tables dequantized in-kernel")
+                    help="weight-only quantize the model: 8 = int8 "
+                         "tables, 4 = packed int4 nibble tables "
+                         "dequantized in-kernel")
     ap.add_argument("--prefill-chunk", type=int, default=None,
                     help="tokens per chunked-prefill step (paged mode; "
                          "default one page)")
