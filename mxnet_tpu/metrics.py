@@ -71,6 +71,8 @@ import threading
 from collections import OrderedDict
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax.monitoring
+
 from . import profiler as _profiler
 from .analysis import guards as _guards
 from .base import MXNetError, get_env
@@ -79,6 +81,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "REGISTRY",
     "enable", "disable", "enabled", "reset", "expose", "dumps",
     "get_sample_value", "register_collect_callback", "record_io",
+    "backend_compiles",
 ]
 
 # fast-path flag consulted by runtime hot paths; True only after enable().
@@ -1306,6 +1309,49 @@ def _sample_device_memory():
         HBM_BYTES_IN_USE._child((label,))._set_direct(in_use)
         pk = HBM_PEAK_BYTES._child((label,))
         pk._set_direct(max(pk.value, peak, in_use))
+
+
+# --- compilations, wherever they come from ----------------------------------
+# JAX wraps every backend compile, a load from the persistent cache
+# included, in one duration event; a load reports its retrieval time first,
+# on the same thread, which is how the two are told apart.
+_JAX_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_JAX_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_compiles = 0
+_COMPILES_LOCK = threading.Lock()
+_compile_tls = threading.local()
+
+
+def backend_compiles() -> int:
+    """Executables the XLA backend built or loaded from JAX's persistent
+    cache since import, whoever asked (eager one-off programs too): both
+    hold the calling thread. Counted with or without a registry; flat
+    across a measured window = nothing compiled there."""
+    return _compiles
+
+
+def _on_jax_duration(event: str, seconds: float, **kwargs):
+    if event == _JAX_CACHE_LOAD_EVENT:
+        _compile_tls.cached = True
+        return
+    if event != _JAX_COMPILE_EVENT:
+        return
+    global _compiles
+    cached = getattr(_compile_tls, "cached", False)
+    _compile_tls.cached = False
+    with _COMPILES_LOCK:
+        _compiles += 1
+    fun = str(kwargs.get("fun_name", ""))
+    from .observability import recorder as _recorder
+    _recorder.RECORDER.record("compile", fun, seconds=seconds, cached=cached)
+    # an instant span where the compilation ENDED, carrying its seconds:
+    # a traced window shows each one on the thread it held
+    with _profiler.scope("mx.compile", "compile", fun=fun,
+                         seconds=round(seconds, 6), cached=cached):
+        pass
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
 
 
 @register_collect_callback

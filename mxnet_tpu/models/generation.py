@@ -108,6 +108,7 @@ def _check_mask_live(mask):
             "automaton state")
 
 
+@jax.named_scope("mx.sample")
 def filter_logits(scaled, top_k, top_p, mask=None):
     """Top-k then nucleus (top-p) filtering of [B, V] logits: filtered-out
     entries become -inf. ``top_k``/``top_p`` accept python scalars (static,
@@ -145,6 +146,7 @@ def filter_logits(scaled, top_k, top_p, mask=None):
     return jnp.where((top_p >= 1.0) | (scaled >= thr), scaled, -jnp.inf)
 
 
+@jax.named_scope("mx.sample")
 def sample_tokens(logits, keys, temperature, top_k, top_p, mask=None):
     """Batched next-token selection from [B, V] logits with PER-ROW
     sampling parameters: rows with ``temperature == 0`` are greedy, the

@@ -1,9 +1,16 @@
 """GPT-2-family decoder LM (Gluon blocks): learned positions, pre-LN,
-GELU MLP, causal fused attention."""
+GELU MLP, causal fused attention.
+
+Device operations carry the program's names (``jax.named_scope``: metadata
+only, read back from a profiler trace by the operation's scope path):
+``mx.embed``, ``mx.attn`` (flash kernels included; on the paged path
+``mx.paged_attention`` nests inside it), ``mx.mlp``, ``mx.lm_head``.
+Backward operations keep the name inside ``transpose(jvp(...))``."""
 from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 
 from .. import numpy_extension as npx
@@ -49,7 +56,6 @@ class GPTBlock(HybridBlock):
         B, T, d = x.shape
         H = self._heads
         hd = d // H
-        qkv = self.attn_qkv(self.ln_1(x))
 
         def fn(qkv_v):
             q, k, v = jnp.split(qkv_v, 3, axis=-1)
@@ -59,10 +65,16 @@ class GPTBlock(HybridBlock):
             o = _flash_attention(qh, kh, vh, True, None)
             return o.transpose(0, 2, 1, 3).reshape(B, T, d)
 
-        x = x + self.dropout(self.attn_out(invoke_jnp(fn, (qkv,), {},
-                                                      name="gpt_attention")))
-        h = npx.gelu(self.mlp_fc(self.ln_2(x)))
-        return x + self.dropout(self.mlp_proj(h))
+        with jax.named_scope("mx.attn"):
+            qkv = self.attn_qkv(self.ln_1(x))
+            x = x + self.dropout(self.attn_out(
+                invoke_jnp(fn, (qkv,), {}, name="gpt_attention")))
+        return self._mlp(x)
+
+    def _mlp(self, x):
+        with jax.named_scope("mx.mlp"):
+            h = npx.gelu(self.mlp_fc(self.ln_2(x)))
+            return x + self.dropout(self.mlp_proj(h))
 
     def forward_cached(self, x, pos, k_cache, v_cache):
         """Incremental forward against the [B, H, L, hd] KV caches."""
@@ -70,7 +82,6 @@ class GPTBlock(HybridBlock):
         B, T, d = x.shape
         H = self._heads
         hd = d // H
-        qkv = self.attn_qkv(self.ln_1(x))
 
         def fn(qkv_v, kc, vc, posv):
             q, k, v = jnp.split(qkv_v, 3, axis=-1)
@@ -80,11 +91,12 @@ class GPTBlock(HybridBlock):
             out, kc, vc = _cached_attention(qh, kh, vh, kc, vc, posv, 1)
             return out.transpose(0, 2, 1, 3).reshape(B, T, d), kc, vc
 
-        ctx, kc, vc = invoke_jnp(fn, (qkv, k_cache, v_cache, pos), {},
-                                 name="gpt_attention_cached")
-        x = x + self.dropout(self.attn_out(ctx))
-        h = npx.gelu(self.mlp_fc(self.ln_2(x)))
-        return x + self.dropout(self.mlp_proj(h)), kc, vc
+        with jax.named_scope("mx.attn"):
+            qkv = self.attn_qkv(self.ln_1(x))
+            ctx, kc, vc = invoke_jnp(fn, (qkv, k_cache, v_cache, pos), {},
+                                     name="gpt_attention_cached")
+            x = x + self.dropout(self.attn_out(ctx))
+        return self._mlp(x), kc, vc
 
     def forward_cached_paged(self, x, pos, block_table, k_pages, v_pages):
         """Incremental forward against the shared PAGED KV pool
@@ -93,7 +105,6 @@ class GPTBlock(HybridBlock):
         B, T, d = x.shape
         H = self._heads
         hd = d // H
-        qkv = self.attn_qkv(self.ln_1(x))
 
         def fn(qkv_v, bt, kp, vp, posv):
             q, k, v = jnp.split(qkv_v, 3, axis=-1)
@@ -103,12 +114,13 @@ class GPTBlock(HybridBlock):
             out, kp, vp = _paged_attention(qh, kh, vh, kp, vp, bt, posv, 1)
             return out.transpose(0, 2, 1, 3).reshape(B, T, d), kp, vp
 
-        ctx, kp, vp = invoke_jnp(fn, (qkv, block_table, k_pages, v_pages,
-                                      pos), {},
-                                 name="gpt_attention_paged")
-        x = x + self.dropout(self.attn_out(ctx))
-        h = npx.gelu(self.mlp_fc(self.ln_2(x)))
-        return x + self.dropout(self.mlp_proj(h)), kp, vp
+        with jax.named_scope("mx.attn"):
+            qkv = self.attn_qkv(self.ln_1(x))
+            ctx, kp, vp = invoke_jnp(fn, (qkv, block_table, k_pages,
+                                          v_pages, pos), {},
+                                     name="gpt_attention_paged")
+            x = x + self.dropout(self.attn_out(ctx))
+        return self._mlp(x), kp, vp
 
 
 class GPTModel(HybridBlock):
@@ -129,7 +141,8 @@ class GPTModel(HybridBlock):
         from .. import numpy as np
         B, T = input_ids.shape
         pos = np.arange(T, dtype="int32")
-        x = self.drop(self.wte(input_ids) + self.wpe(pos))
+        with jax.named_scope("mx.embed"):
+            x = self.drop(self.wte(input_ids) + self.wpe(pos))
         x = self.blocks(x)
         x = self.ln_f(x)
         return self._lm_head(x)  # tied; int8-streamed at decode if quantized
@@ -175,9 +188,9 @@ class GPTModel(HybridBlock):
             p = _decode_positions(posv, T)
             return p[None, :].repeat(B, axis=0) if p.ndim == 1 else p
 
-        positions = invoke_jnp(_positions, (pos,), {})
-        x = self.wte(input_ids) + self.wpe(positions)
-        x = self.drop(x)
+        with jax.named_scope("mx.embed"):
+            positions = invoke_jnp(_positions, (pos,), {})
+            x = self.drop(self.wte(input_ids) + self.wpe(positions))
         new_caches = []
         for i, blk in enumerate(self.blocks):
             x, kc, vc = blk.forward_cached(
@@ -198,9 +211,9 @@ class GPTModel(HybridBlock):
             p = _decode_positions(posv, T)
             return p[None, :].repeat(B, axis=0) if p.ndim == 1 else p
 
-        positions = invoke_jnp(_positions, (pos,), {})
-        x = self.wte(input_ids) + self.wpe(positions)
-        x = self.drop(x)
+        with jax.named_scope("mx.embed"):
+            positions = invoke_jnp(_positions, (pos,), {})
+            x = self.drop(self.wte(input_ids) + self.wpe(positions))
         new_caches = []
         for i, blk in enumerate(self.blocks):
             x, kp, vp = blk.forward_cached_paged(
@@ -214,6 +227,7 @@ class GPTModel(HybridBlock):
         sampling path, or None when the tied head is not int8-quantized."""
         return getattr(self, "_q_lm_head", None)
 
+    @jax.named_scope("mx.lm_head")
     def _lm_head(self, x):
         """Tied LM head. When quantize_net stored a weight-only int8 table
         (contrib/quantization._quantize_tied_lm_head) and the row count is

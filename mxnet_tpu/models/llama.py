@@ -282,6 +282,7 @@ def _cached_attention(qh, kh, vh, k_cache, v_cache, pos, rep):
     return out, k_cache, v_cache
 
 
+@jax.named_scope("mx.paged_attention")
 def _paged_attention(qh, kh, vh, k_pages, v_pages, block_table, pos, rep):
     """Attention for incremental decode over a PAGED cache: the pool
     carries [num_pages + 1, n_kv, page_size, hd] physical pages shared by
@@ -324,16 +325,18 @@ def _paged_attention(qh, kh, vh, k_pages, v_pages, block_table, pos, rep):
                              jnp.minimum(cols // ps, maxp - 1), axis=1)
     pg = jnp.where(cols < L, pg, jnp.int32(k_pages.shape[0] - 1))      # [B,T]
     off = cols % ps
-    k_pages = k_pages.at[pg, :, off, :].set(
-        kh.transpose(0, 2, 1, 3).astype(k_pages.dtype))
-    v_pages = v_pages.at[pg, :, off, :].set(
-        vh.transpose(0, 2, 1, 3).astype(v_pages.dtype))
+    with jax.named_scope("mx.kv_write"):
+        k_pages = k_pages.at[pg, :, off, :].set(
+            kh.transpose(0, 2, 1, 3).astype(k_pages.dtype))
+        v_pages = v_pages.at[pg, :, off, :].set(
+            vh.transpose(0, 2, 1, 3).astype(v_pages.dtype))
     # logical full-length view: page i of the table lands at rows
     # [i*ps, (i+1)*ps) — position p maps to row p exactly
-    kf = k_pages[block_table].transpose(0, 2, 1, 3, 4) \
-        .reshape(B, G, L, hd).astype(jnp.float32)
-    vf = v_pages[block_table].transpose(0, 2, 1, 3, 4) \
-        .reshape(B, G, L, hd).astype(jnp.float32)
+    with jax.named_scope("mx.kv_gather"):
+        kf = k_pages[block_table].transpose(0, 2, 1, 3, 4) \
+            .reshape(B, G, L, hd).astype(jnp.float32)
+        vf = v_pages[block_table].transpose(0, 2, 1, 3, 4) \
+            .reshape(B, G, L, hd).astype(jnp.float32)
     mask3 = jnp.arange(L)[None, None, :] <= cols[:, :, None]           # [B,T,L]
     out = _attend(qh, kf, vf, mask3, rep)
     return out, k_pages, v_pages

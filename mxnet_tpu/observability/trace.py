@@ -53,6 +53,7 @@ import time
 from collections import OrderedDict
 from typing import Any, Dict, List, Optional
 
+from .. import profiler as _profiler
 from ..base import get_env
 
 __all__ = [
@@ -222,7 +223,6 @@ class Span:
         from . import recorder as _recorder
         _recorder.RECORDER.record_span(self.name, self.trace_id,
                                        self.t1 - self.t0, self.status)
-        from .. import profiler as _profiler
         if _profiler.ACTIVE:
             # wall-clock t0/t1 -> the profiler's perf_counter timeline:
             # shift by the (stable within a process) clock offset
@@ -449,34 +449,23 @@ def take_blocked() -> Dict[str, float]:
     return acc
 
 
-class _Phase:
-    __slots__ = ("_tl", "_name", "_t0")
+class _Phase(_profiler.scope):
+    """One step phase: the span helper under the name ``mx.train.<phase>``
+    (on the device trace's clock whenever a JAX trace runs, whether or not
+    a registry is on), handing its seconds to the timeline on exit."""
+
+    __slots__ = ("_tl", "_phase")
 
     def __init__(self, tl: "StepTimeline", name: str):
+        super().__init__(f"mx.train.{name}", "train")
         self._tl = tl
-        self._name = name
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
+        self._phase = name
 
     def __exit__(self, *exc):
-        self._tl._observe_phase(self._name,
-                                time.perf_counter() - self._t0)
+        super().__exit__(*exc)
+        if self._tl._active:
+            self._tl._observe_phase(self._phase, self.seconds)
         return False
-
-
-class _NoopPhase:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NOOP_PHASE = _NoopPhase()
 
 
 class StepTimeline:
@@ -499,8 +488,8 @@ class StepTimeline:
     enabled, closes one ``train.step`` span (phases as children) into
     the shared trace for this timeline.
 
-    Cost when both metrics and tracing are off: ``begin()`` is one bool
-    check returning a shared no-op.
+    With both metrics and tracing off ``begin()`` is one bool check and
+    a phase is only its ``mx.train.<phase>`` profiler span.
     """
 
     def __init__(self, path: str):
@@ -546,8 +535,6 @@ class StepTimeline:
         return self
 
     def phase(self, name: str):
-        if not self._active:
-            return _NOOP_PHASE
         return _Phase(self, name)
 
     def _observe_phase(self, name: str, dt: float):

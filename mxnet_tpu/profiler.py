@@ -184,25 +184,54 @@ def _emit(name: str, cat: str, ts_us: float, dur_us: float, args=None):
                     agg[3] = dur_us
 
 
-class scope:
-    """Time a python scope as one trace slice. ACTIVE-aware (near-free when
-    profiling is off) and exception-safe: a failing body still records its
-    span. The one runtime hook helper — CachedOp/TrainStep/DataLoader all
-    time through this."""
+class scope(jax.profiler.TraceAnnotation):
+    """Time a python scope: THE span helper of the runtime (CachedOp,
+    TrainStep, DataLoader, StepTimeline phases and the serving engine's
+    tick all time through this). One pair of clock reads per boundary
+    feeds three sinks:
 
-    __slots__ = ("name", "cat", "_t0")
+    - a ``jax.profiler.TraceAnnotation`` (TraceMe) of the same name, so the
+      span sits on the clock of the device trace whenever a JAX trace is
+      being taken — and is a no-op otherwise: the JAX profiler, not a flag
+      of ours, decides whether it is recorded. ``attrs`` (and ``set()``,
+      for what is known only inside the span) become its metadata;
+    - one chrome-trace slice while this module's profiler is ACTIVE;
+    - ``hist.observe(seconds)`` where a registry histogram is given.
 
-    def __init__(self, name: str, cat: str = "operation"):
+    After exit ``seconds`` holds the elapsed time and ``t1`` the closing
+    ``perf_counter`` stamp (for a caller that stamps on). Exception-safe: a
+    failing body still records its span. About 2 us a span with nothing
+    recording (PERF.md section 3)."""
+
+    __slots__ = ("name", "cat", "args", "seconds", "t1", "_hist", "_t0")
+
+    def __init__(self, name: str, cat: str = "operation", hist=None,
+                 **attrs):
+        super().__init__(name, **attrs)
         self.name = name
         self.cat = cat
+        self.args = attrs
+        self._hist = hist
+
+    def set(self, **attrs):
+        """Attributes known only inside the span (call between enter and
+        exit)."""
+        self.set_metadata(**attrs)
+        self.args.update(attrs)
 
     def __enter__(self):
-        self._t0 = time.perf_counter() if ACTIVE else None
+        super().__enter__()
+        self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        if self._t0 is not None:
-            record_span(self.name, self.cat, self._t0, time.perf_counter())
+        self.t1 = t1 = time.perf_counter()
+        self.seconds = t1 - self._t0
+        super().__exit__(*exc)
+        if self._hist is not None:
+            self._hist.observe(self.seconds)
+        if ACTIVE:
+            record_span(self.name, self.cat, self._t0, t1, self.args)
         return False
 
 

@@ -96,8 +96,9 @@ import jax.numpy as jnp
 import numpy as onp
 
 from .. import metrics as _metrics
+from .. import profiler as _profiler
 from ..analysis import guards as _guards
-from ..base import MXNetError
+from ..base import MXNetError, logger
 from ..device import on_tpu
 from ..models import generation as _gen
 from ..observability import perf as _perf
@@ -276,6 +277,40 @@ class _PendingStep:
     # step's token — the lookahead feedback twin of ``nxt`` (the host
     # ledger stays authoritative; it re-advances at the read)
     gstate: Any = None
+
+
+def _jit_named(fn, name: str):
+    """``jax.jit(fn)`` under the name of what it is: the device trace's
+    program line then reads ``jit_step_b16(...)``, ``jit_prefill_b32(...)``,
+    ``jit_chunk_c128(...)``."""
+    fn.__name__ = name
+    return jax.jit(fn)
+
+
+#: a tick whose wall time passes this is written to the flight recorder
+#: and the log with its child spans and the compilations since the tick
+#: before: the operator's view of a stall with no trace running
+_SLOW_TICK_S = 1.0
+
+
+class _TickSpan(_profiler.scope):
+    """A child span of the engine's current tick (``mx.serve.<name>``):
+    the span helper, stamped with the tick's number, adding its seconds to
+    the tick's per-child record that a slow tick reports."""
+
+    __slots__ = ("_children",)
+
+    def __init__(self, engine: "InferenceEngine", name: str, hist=None,
+                 **attrs):
+        super().__init__(f"mx.serve.{name}", "serve", hist,
+                         tick=engine._tick_no, **attrs)
+        self._children = engine._tick_children
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        self._children[self.name] = (self._children.get(self.name, 0.0)
+                                     + self.seconds)
+        return False
 
 
 class InferenceEngine:
@@ -777,6 +812,13 @@ class InferenceEngine:
         # fault injection for tests: per-step sleep to make deadlines and
         # backpressure deterministic on fast hosts
         self._step_delay = 0.0
+        # the engine thread's tick: its sequence number (carried by every
+        # span under it), the open mx.serve.tick span, seconds per child
+        # span, and the compile count when the previous tick closed
+        self._tick_no = 0
+        self._tick_span: Optional[_profiler.scope] = None
+        self._tick_children: Dict[str, float] = {}
+        self._compiles_seen = _metrics.backend_compiles()
 
         # counters for stats()
         self._submitted = 0
@@ -1598,7 +1640,7 @@ class InferenceEngine:
                     pool, nc.astype(pool.dtype), idx))
             return tok0[0], tuple(new_pools)
 
-        return jax.jit(prefill)
+        return _jit_named(prefill, f"prefill_b{pb}")
 
     def _build_step(self, sb: int):
         if self.K > 1:
@@ -1637,7 +1679,7 @@ class InferenceEngine:
                 return nxt, ngs, new_pools
             return nxt, new_pools
 
-        return jax.jit(step)
+        return _jit_named(step, f"step_b{sb}")
 
     def _build_step_multi(self, sb: int):
         """K tokens per dispatch: the on-device multi-token loop
@@ -1663,7 +1705,7 @@ class InferenceEngine:
                 for p, nc, ax in zip(pools, new_caches, baxes))
             return toks, last, steps, new_pools
 
-        return jax.jit(step)
+        return _jit_named(step, f"step_b{sb}")
 
     def _build_step_spec(self, sb: int):
         """Self-speculative verify step: ONE forward over the [sb, spec]
@@ -1757,7 +1799,7 @@ class InferenceEngine:
                                       mask=mask)
             return tok0[0], new_pools
 
-        return jax.jit(prefill)
+        return _jit_named(prefill, f"prefill_b{pb}")
 
     def _build_chunk(self, cs: int):
         """A middle prefill chunk: KV-page writes only (XLA dead-code-
@@ -1769,7 +1811,7 @@ class InferenceEngine:
                                                   pools, block_table=table)
             return new_pools
 
-        return jax.jit(chunk)
+        return _jit_named(chunk, f"chunk_c{cs}")
 
     def _build_step_paged(self, sb: int):
         """Paged decode step: the shared page pools replace the sliced
@@ -1788,7 +1830,7 @@ class InferenceEngine:
                         head=head, block_table=tables)
                 return toks, last, steps, new_pools
 
-            return jax.jit(step)
+            return _jit_named(step, f"step_b{sb}")
 
         grammar = self._grammar
 
@@ -1815,7 +1857,7 @@ class InferenceEngine:
                 return nxt, ngs, new_pools
             return nxt, new_pools
 
-        return jax.jit(step)
+        return _jit_named(step, f"step_b{sb}")
 
     def _build_copy(self, _bucket: int):
         """Copy one physical page (COW fork: src's rows into the freshly
@@ -1943,6 +1985,13 @@ class InferenceEngine:
                             error=str(e)))
                         self._slots[s] = None
 
+    def _idle(self) -> bool:
+        return (self._running and not self._queue and not any(self._slots)
+                and not self._swaps and not self._page_ops)
+
+    def _span(self, name: str, hist=None, **attrs) -> _TickSpan:
+        return _TickSpan(self, name, hist, **attrs)
+
     def _loop_inner(self):
         while True:
             # live weight refresh lands BETWEEN ticks: everything below
@@ -1952,83 +2001,117 @@ class InferenceEngine:
             # migrated KV pages land at the same boundary, for the same
             # reason: the loop owns self._pools
             self._apply_page_ops()
-            admits: List[Tuple[int, RequestHandle]] = []
-            dead: List[Tuple[RequestHandle, str]] = []
             with self._cond:
-                while (self._running and not self._queue
-                       and not any(self._slots) and not self._swaps
-                       and not self._page_ops):
+                while self._idle():
                     # a staged weight swap wakes the idle loop too: the
-                    # next iteration's tick boundary applies it
-                    self._cond.wait(0.1)
-                stopping = not self._running
-                if stopping:
-                    for req in self._queue:
-                        dead.append((req, STATUS_SHUTDOWN))
-                    self._queue.clear()
-                else:
-                    now = time.perf_counter()
-                    # purge dead entries ANYWHERE in the queue: a live head
-                    # blocked on a full slot pool must not delay cancelled/
-                    # expired completions (or their queue-depth credit)
-                    # behind it
-                    kept: "deque[RequestHandle]" = deque()
-                    for req in self._queue:
-                        if req._cancelled:
-                            dead.append((req, STATUS_CANCELLED))
-                        elif (req.deadline is not None
-                              and now > req.deadline):
-                            dead.append((req, STATUS_TIMEOUT))
-                        else:
-                            kept.append(req)
-                    self._queue = kept
-                    while self._queue:
-                        s = self._free_slot()
-                        if s is None:
-                            break
-                        if self._paged and not self._fits(self._queue[0]):
-                            # not enough pages even after reclaiming the
-                            # whole prefix cache: admitting would only
-                            # preempt-thrash — wait for retires (FIFO
-                            # order preserved)
-                            break
-                        head = self._queue.popleft()
-                        if head.admit_t is None:
-                            # re-admission after a preemption keeps the
-                            # ORIGINAL queue wait
-                            head.admit_t = now
-                        head._status = "running"
-                        self._slots[s] = _Slot(
-                            head, list(getattr(head, "_resume", ()) or ()),
-                            now, now)
-                        admits.append((s, head))
-                    _metrics.SERVE_QUEUE_DEPTH.set(len(self._queue))
-            for req, status in dead:
-                self._finish_unstarted(req, status)
-            if self._pending is not None and (
-                    stopping or (admits and not self._paged)):
-                # contiguous mode: the slot set (and pools, via prefill)
-                # is about to change — drain the lookahead step so its
-                # token reads and retires land before the world moves.
-                # Paged admits only start a PREFILL (the decode set is
-                # untouched until the final chunk), so the paged tick's
-                # own set check handles activation.
-                self._process_step(self._pending)
-                self._pending = None
-            if stopping and self._abort_inflight:
-                for s in range(self.S):
-                    if self._slots[s] is not None:
-                        self._retire(s, STATUS_SHUTDOWN)
-            self._prefill_admits(admits)
-            if self._paged:
-                self._advance_prefills(stopping)
-            if any(self._slots):
-                self._step_tick()
-                if self._step_delay:
-                    time.sleep(self._step_delay)
-            elif stopping:
+                    # next iteration's tick boundary applies it. One span
+                    # a wait: a profiler that starts or stops while the
+                    # engine sleeps loses a tenth of a second of it
+                    with _profiler.scope("mx.serve.idle", "serve"):
+                        self._cond.wait(0.1)
+            self._tick_no += 1
+            self._tick_children.clear()
+            with _profiler.scope(
+                    "mx.serve.tick", "serve", tick=self._tick_no,
+                    queued=len(self._queue), prefilling=(
+                        len(self._prefills) if self._paged else 0)) as tick:
+                self._tick_span = tick
+                more = self._tick()
+            self._close_tick(tick)
+            if not more:
                 break
-            self._observe_occupancy()
+
+    def _close_tick(self, tick: _profiler.scope):
+        compiles = _metrics.backend_compiles()
+        if tick.seconds > _SLOW_TICK_S:
+            fields = dict(
+                tick=self._tick_no, seconds=round(tick.seconds, 6),
+                rows=tick.args.get("rows", 0), sb=tick.args.get("sb", 0),
+                children={k: round(v, 6)
+                          for k, v in self._tick_children.items()},
+                compiles=compiles - self._compiles_seen)
+            _recorder.RECORDER.record("event", "serve.slow_tick", **fields)
+            logger.warning("serve: slow tick: %s", fields)
+        self._compiles_seen = compiles
+
+    def _tick(self) -> bool:
+        """One iteration of the loop that has work: admit, prefill, one
+        decode step. False once a stopping engine has nothing left."""
+        admits: List[Tuple[int, RequestHandle]] = []
+        dead: List[Tuple[RequestHandle, str]] = []
+        # opened before the lock is taken: a long admit span is the lock,
+        # whether this thread waited for it or held it
+        with self._span("admit") as admit, self._cond:
+            stopping = not self._running
+            if stopping:
+                for req in self._queue:
+                    dead.append((req, STATUS_SHUTDOWN))
+                self._queue.clear()
+            else:
+                now = time.perf_counter()
+                # purge dead entries ANYWHERE in the queue: a live head
+                # blocked on a full slot pool must not delay cancelled/
+                # expired completions (or their queue-depth credit)
+                # behind it
+                kept: "deque[RequestHandle]" = deque()
+                for req in self._queue:
+                    if req._cancelled:
+                        dead.append((req, STATUS_CANCELLED))
+                    elif (req.deadline is not None
+                          and now > req.deadline):
+                        dead.append((req, STATUS_TIMEOUT))
+                    else:
+                        kept.append(req)
+                self._queue = kept
+                while self._queue:
+                    s = self._free_slot()
+                    if s is None:
+                        break
+                    if self._paged and not self._fits(self._queue[0]):
+                        # not enough pages even after reclaiming the
+                        # whole prefix cache: admitting would only
+                        # preempt-thrash — wait for retires (FIFO
+                        # order preserved)
+                        break
+                    head = self._queue.popleft()
+                    if head.admit_t is None:
+                        # re-admission after a preemption keeps the
+                        # ORIGINAL queue wait
+                        head.admit_t = now
+                    head._status = "running"
+                    self._slots[s] = _Slot(
+                        head, list(getattr(head, "_resume", ()) or ()),
+                        now, now)
+                    admits.append((s, head))
+                _metrics.SERVE_QUEUE_DEPTH.set(len(self._queue))
+            admit.set(admitted=len(admits))
+        for req, status in dead:
+            self._finish_unstarted(req, status)
+        if self._pending is not None and (
+                stopping or (admits and not self._paged)):
+            # contiguous mode: the slot set (and pools, via prefill)
+            # is about to change — drain the lookahead step so its
+            # token reads and retires land before the world moves.
+            # Paged admits only start a PREFILL (the decode set is
+            # untouched until the final chunk), so the paged tick's
+            # own set check handles activation.
+            self._process_step(self._pending)
+            self._pending = None
+        if stopping and self._abort_inflight:
+            for s in range(self.S):
+                if self._slots[s] is not None:
+                    self._retire(s, STATUS_SHUTDOWN)
+        self._prefill_admits(admits)
+        if self._paged:
+            self._advance_prefills(stopping)
+        if any(self._slots):
+            self._step_tick()
+            if self._step_delay:
+                time.sleep(self._step_delay)
+        elif stopping:
+            return False
+        self._observe_occupancy()
+        return True
 
     def _free_slot(self) -> Optional[int]:
         for s in range(self.S):
@@ -2056,7 +2139,8 @@ class InferenceEngine:
             return
         dispatched = []
         for s, req in admits:
-            rec = self._prefill_dispatch(s, req)
+            with self._span("prefill_dispatch", slot=s):
+                rec = self._prefill_dispatch(s, req)
             if rec is not None:
                 dispatched.append(rec)
         for rec in dispatched:
@@ -2090,10 +2174,12 @@ class InferenceEngine:
                                   resumed=not first_admission)
         if req._trace is not None:
             if req._span_queue is not None:
+                req._span_queue.set("tick", self._tick_no)
                 req._span_queue.end()
                 req._span_queue = None
             req._span_prefill = req._trace.child(
-                "serve.prefill", slot=s, resumed=not first_admission)
+                "serve.prefill", slot=s, resumed=not first_admission,
+                tick=self._tick_no)
             if not first_admission:
                 req._trace.event("resume", tokens=len(resume))
         pages, matched = self._pages.match_prefix(ids)
@@ -2125,7 +2211,8 @@ class InferenceEngine:
             for s in list(self._prefills):
                 if budget <= 0:
                     break
-                rec = self._prefill_step_paged(s)
+                with self._span("prefill_dispatch", slot=s) as span:
+                    rec = self._prefill_step_paged(s, span)
                 if rec is not None:
                     pending.append(rec)
                 progressed = True
@@ -2160,8 +2247,9 @@ class InferenceEngine:
         """[1, max_pages] snapshot of the slot's block table."""
         return self._pages.table(s)[None, :].copy()
 
-    def _prefill_step_paged(self, s: int):
-        """Advance one slot's prefill by ONE chunk. A middle chunk only
+    def _prefill_step_paged(self, s: int, span: _profiler.scope):
+        """Advance one slot's prefill by ONE chunk (``span``, the caller's
+        open ``prefill_dispatch``, is told which). A middle chunk only
         writes KV pages (returns None); the final chunk (bucketed
         remainder) also samples token0 — its host sync is DEFERRED: the
         returned ``(s, pf, req, slot, tok0_dev)`` record is finalized by
@@ -2178,6 +2266,7 @@ class InferenceEngine:
             return
         P = len(pf.ids)
         end = min(pf.cursor + self._chunk, P)
+        span.set(start=pf.cursor, end=end, final=end == P)
         try:
             self._pages.lease(s, end)
             # the fork can ALSO exhaust the pool (lease satisfied from
@@ -2258,9 +2347,10 @@ class InferenceEngine:
                                 tok0_dev):
         """Host-sync one deferred final-chunk token0 and activate the
         slot for decode."""
-        t_sync = time.perf_counter()
         try:
-            tok0 = int(tok0_dev)
+            with self._span("prefill_sync", _metrics.SERVE_HOST_SYNC,
+                            slot=s) as sync:
+                tok0 = int(tok0_dev)
         except Exception as e:  # pragma: no cover - defensive
             warnings.warn(f"serve: paged prefill failed: {e!r}")
             # the prefix was published at dispatch, before the device
@@ -2269,8 +2359,7 @@ class InferenceEngine:
             self._pages.clear_prefix_cache()
             self._retire(s, STATUS_ERROR, error=str(e))
             return
-        now = time.perf_counter()
-        _metrics.SERVE_HOST_SYNC.observe(now - t_sync)
+        now = sync.t1
         _metrics.SERVE_ROUNDTRIPS.labels(path="prefill").inc()
         _metrics.SERVE_PREFILL_SECONDS.observe(now - pf.t0)
         if _metrics.ENABLED:
@@ -2371,8 +2460,10 @@ class InferenceEngine:
         _recorder.RECORDER.record("event", "serve.admit", slot=s,
                                   prompt_tokens=len(req.prompt_ids))
         if req._trace is not None:
+            req._span_queue.set("tick", self._tick_no)
             req._span_queue.end()
-            req._span_prefill = req._trace.child("serve.prefill", slot=s)
+            req._span_prefill = req._trace.child("serve.prefill", slot=s,
+                                                 tick=self._tick_no)
         P = len(req.prompt_ids)
         try:
             pb = bucket_for(P, self.min_prompt_bucket, self.L,
@@ -2441,17 +2532,17 @@ class InferenceEngine:
 
     def _prefill_finalize(self, s: int, req: RequestHandle, tok0_dev,
                           t0: float):
-        t_sync = time.perf_counter()
         try:
-            tok0 = int(tok0_dev)
+            with self._span("prefill_sync", _metrics.SERVE_HOST_SYNC,
+                            slot=s) as sync:
+                tok0 = int(tok0_dev)
         except Exception as e:  # pragma: no cover - defensive
             warnings.warn(f"serve: prefill failed: {e!r}")
             self._slots[s] = None
             self._reset_slot_state(s)
             self._finish_unstarted(req, STATUS_ERROR, error=str(e))
             return
-        now = time.perf_counter()
-        _metrics.SERVE_HOST_SYNC.observe(now - t_sync)
+        now = sync.t1
         _metrics.SERVE_ROUNDTRIPS.labels(path="prefill").inc()
         req.first_token_t = now
         _metrics.SERVE_PREFILL_SECONDS.observe(now - t0)
@@ -2493,7 +2584,10 @@ class InferenceEngine:
             self._step_tick_paged()
             return
         prev, self._pending = self._pending, None
-        rec = self._dispatch_step(prev)
+        with self._span("decode_dispatch") as disp:
+            rec = self._dispatch_step(prev)
+            if rec is not None:
+                disp.set(sb=rec.sb, rows=len(rec.slots))
         if rec is None:
             # dispatch failed; _dispatch_step salvaged prev's tokens and
             # retired the slots
@@ -2625,7 +2719,8 @@ class InferenceEngine:
         ``[pos, pos + _adv)`` — K for multi-token, the verify width for
         speculative rounds). Pool exhaustion preempts the youngest slot
         (prefilling or decoding) and retries — the oldest admitted work
-        always makes progress."""
+        always makes progress. Returns the number preempted."""
+        preempted = 0
         while True:
             try:
                 for s in range(self.S):
@@ -2633,7 +2728,7 @@ class InferenceEngine:
                         p = int(self._pos[s])
                         self._fork_range(s, p, p + self._adv)
                         self._pages.lease(s, min(p + self._adv, self.L))
-                return
+                return preempted
             except OutOfPages:
                 # youngest by ORIGINAL admission time (req.admit_t survives
                 # preemption; _Slot.t_admit resets on re-admission, which
@@ -2643,6 +2738,7 @@ class InferenceEngine:
                     (s for s in range(self.S) if self._slots[s] is not None),
                     key=lambda s: self._slots[s].req.admit_t)
                 self._preempt(victim)
+                preempted += 1
 
     def _step_tick_paged(self):
         """Paged analogue of the contiguous tick. The decode batch spans
@@ -2654,7 +2750,8 @@ class InferenceEngine:
         retires all force a drain first, exactly the boundary the
         contiguous engine handles with its admit/retire drains."""
         prev, self._pending = self._pending, None
-        self._lease_decode()                  # may preempt (changes the set)
+        with self._span("lease") as lease:    # may preempt (changes the set)
+            lease.set(preempted=self._lease_decode())
         cur = self._decoding()
         if not cur:
             if prev is not None:
@@ -2670,7 +2767,9 @@ class InferenceEngine:
                 if not cur:
                     return
                 sb = bucket_for(cur[-1][0] + 1, 1, self.S)
-        rec = self._dispatch_step_paged(prev, cur, sb)
+        self._tick_span.set(rows=len(cur), sb=sb)
+        with self._span("decode_dispatch", sb=sb, rows=len(cur)):
+            rec = self._dispatch_step_paged(prev, cur, sb)
         if rec is None:
             return
         if prev is not None:
@@ -2765,7 +2864,8 @@ class InferenceEngine:
         tokens per host round-trip ARE the overlap win."""
         from . import speculate as _spec
         if self._paged:
-            self._lease_decode()              # may preempt (changes the set)
+            with self._span("lease") as lease:    # may preempt
+                lease.set(preempted=self._lease_decode())
             cur = self._decoding()
         else:
             cur = [(s, self._slots[s]) for s in range(self.S)
@@ -2845,20 +2945,20 @@ class InferenceEngine:
         clocks per APPENDED token — acceptance is data, so the clocks
         move at the read, not the dispatch. EOS/budget/deadline scanning
         stops a row early exactly like the multi-token K-vector scan."""
-        t_sync = time.perf_counter()
         try:
-            toks = onp.asarray(toks_dev)              # [sb, T]
-            acc = onp.asarray(acc_dev)                # [sb]
+            with self._span("decode_sync", _metrics.SERVE_HOST_SYNC,
+                            sb=sb) as sync:
+                toks = onp.asarray(toks_dev)              # [sb, T]
+                acc = onp.asarray(acc_dev)                # [sb]
         except Exception as e:  # pragma: no cover - defensive
             warnings.warn(f"serve: speculative decode step failed: {e!r}")
             for s, slot in cur:
                 if self._slots[s] is slot:
                     self._retire(s, STATUS_ERROR, error=str(e))
             return
-        now = time.perf_counter()
+        now = sync.t1
         now_wall = time.time()
         chunk_t0w = now_wall - (now - t0)
-        _metrics.SERVE_HOST_SYNC.observe(now - t_sync)
         _metrics.SERVE_ROUNDTRIPS.labels(path="decode").inc()
         drafted = rejected = 0
         appended = 0
@@ -2893,7 +2993,8 @@ class InferenceEngine:
                 ch = slot.req._trace.child("serve.decode_chunk",
                                            t0=chunk_t0w,
                                            tokens=row_tokens,
-                                           speculative=True)
+                                           speculative=True,
+                                           tick=self._tick_no)
                 ch.end(t1=now_wall)
         self._spec_rounds += 1
         self._spec_drafted += drafted
@@ -2925,26 +3026,34 @@ class InferenceEngine:
         the row's EOS/budget/deadline — tokens past the stop are the
         speculative rows the parity contract discards. Returns True when
         any slot retired."""
-        t_sync = time.perf_counter()
         try:
-            if rec.toks is not None:
-                toks = onp.asarray(rec.toks)         # [sb, K]
-                steps = int(rec.steps)
-            else:
-                toks = onp.asarray(rec.nxt)[:, None]  # [sb, 1]
-                steps = 1
+            with self._span("decode_sync", _metrics.SERVE_HOST_SYNC,
+                            sb=rec.sb) as sync:
+                if rec.toks is not None:
+                    toks = onp.asarray(rec.toks)         # [sb, K]
+                    steps = int(rec.steps)
+                else:
+                    toks = onp.asarray(rec.nxt)[:, None]  # [sb, 1]
+                    steps = 1
         except Exception as e:  # pragma: no cover - defensive
             warnings.warn(f"serve: decode step failed: {e!r}")
             for s, slot in rec.slots:
                 if self._slots[s] is slot:
                     self._retire(s, STATUS_ERROR, error=str(e))
             return True
-        now = time.perf_counter()
+        with self._span("emit") as emit:
+            retired, appended = self._apply_step(rec, toks, steps, sync.t1)
+            emit.set(tokens=appended, retired=int(retired))
+        return retired
+
+    def _apply_step(self, rec: _PendingStep, toks, steps: int,
+                    now: float) -> Tuple[bool, int]:
+        """The host's part of a read step: ``(any slot retired, tokens
+        appended)``."""
         now_wall = time.time()
         # the dispatch stamp is perf_counter-based; shift it onto the
         # wall clock for the trace spans
         chunk_t0w = now_wall - (now - rec.t0)
-        _metrics.SERVE_HOST_SYNC.observe(now - t_sync)
         _metrics.SERVE_ROUNDTRIPS.labels(path="decode").inc()
         live = [(s, slot) for s, slot in rec.slots
                 if self._slots[s] is slot]
@@ -2975,7 +3084,8 @@ class InferenceEngine:
                 # one span per dispatched decode chunk per request
                 # (dispatch -> host read; K tokens ride one chunk)
                 ch = slot.req._trace.child("serve.decode_chunk",
-                                           t0=chunk_t0w, tokens=row_tokens)
+                                           t0=chunk_t0w, tokens=row_tokens,
+                                           tick=self._tick_no)
                 ch.end(t1=now_wall)
         # dispatch-to-read wall time: under lookahead consecutive spans
         # overlap by design (the read waits on compute that ran behind
@@ -2996,7 +3106,7 @@ class InferenceEngine:
             _perf.note_step("serve_decode", dt,
                             key=f"serve_decode:b{rec.sb}",
                             work=float(self.K))
-        return retired
+        return retired, appended
 
     def _check_finished(self, s: int, now: float):
         slot = self._slots[s]

@@ -60,12 +60,12 @@ def test_runtime_records_events():
     assert "train" in cats
     assert "data" in cats
     names = {e["name"] for e in profiler._EVENTS}
-    assert "TrainStep" in names
+    assert "mx.train.step" in names
     assert any(n.startswith("CachedOp::") for n in names)
 
     # aggregate table has rows
     table = profiler.dumps()
-    assert "TrainStep" in table
+    assert "mx.train.step" in table
 
     # chrome trace round trip
     with tempfile.TemporaryDirectory() as d:
