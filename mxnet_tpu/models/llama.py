@@ -16,6 +16,7 @@ src/operator/contrib/transformer.cc:675). Built TPU-first:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Optional
 
@@ -217,11 +218,10 @@ class LlamaAttention(HybridBlock):
 def _attend(qh, kf, vf, mask3, rep):
     """Masked attention of ``qh`` [B, H, T, hd] against a full-length f32
     KV view ``kf``/``vf`` [B, n_kv, L, hd] with validity mask ``mask3``
-    [B|1, T, L]. Shared by the contiguous (:func:`_cached_attention`) and
-    paged (:func:`_paged_attention`) cache layouts — both feed the SAME
-    elementwise/contraction program, which is what makes paged-vs-
-    contiguous greedy decode bitwise-identical (masked columns contribute
-    exact zeros regardless of what garbage the layout leaves there).
+    [B|1, T, L]: the contiguous layout's read (:func:`_cached_attention`)
+    and the reference that the paged walk (:func:`_paged_attention`) is
+    tested against. Masked columns contribute exact zeros regardless of
+    what garbage the layout leaves there.
 
     GQA attends grouped — q reshaped to [B, n_kv, rep, T, hd] and
     contracted straight against the unrepeated cache — so the repeated-KV
@@ -282,7 +282,24 @@ def _cached_attention(qh, kh, vh, k_cache, v_cache, pos, rep):
     return out, k_cache, v_cache
 
 
+# Tokens of K/V that one iteration of the paged read walks: 8 pages of 16,
+# one lane tile of scores. Settled on the v5e (PERF.md section 6, PR 27); a
+# constant, not a knob.
+KV_BLOCK = 128
+
+
+def kv_block(page_size: int, max_pages: int) -> int:
+    """Tokens a block of the paged read holds for a pool geometry:
+    ``KV_BLOCK`` in whole pages, at least one, at most the whole table (the
+    tiny geometries of the tests)."""
+    return min(max(KV_BLOCK // page_size, 1), max_pages) * page_size
+
+
+# jitted by itself so that a program of many layers traces and lowers the
+# walk once: unrolled, the loop's body made a step's lowering a third longer
+# and the engine's warm-up with it (PERF.md section 6, PR 27)
 @jax.named_scope("mx.paged_attention")
+@functools.partial(jax.jit, static_argnames="rep")
 def _paged_attention(qh, kh, vh, k_pages, v_pages, block_table, pos, rep):
     """Attention for incremental decode over a PAGED cache: the pool
     carries [num_pages + 1, n_kv, page_size, hd] physical pages shared by
@@ -290,30 +307,35 @@ def _paged_attention(qh, kh, vh, k_pages, v_pages, block_table, pos, rep):
     page i (token positions [i*ps, (i+1)*ps)) to a physical page (the
     serve/paging.PagePool ledger). The last physical page is the *sink*:
     unleased table entries point there, so pad/speculative writes land
-    harmlessly and gathers of unleased territory read garbage that the
+    harmlessly and reads of unleased territory see garbage that the
     validity mask turns into exact zeros.
 
     Writes scatter the T new K/V rows through the table
-    (page = table[col // ps], offset = col % ps); reads gather the
-    table's pages back into the logical [B, n_kv, max_pages*ps, hd] view
-    and run the SAME :func:`_attend` program as the contiguous layout.
-    With ``max_pages * ps == max_len`` the gathered view has the
-    contiguous cache's exact shape and values at every unmasked position,
-    so greedy decode is bitwise-identical between the two layouts (the
-    tier-1 parity contract; tests/test_serve_paging.py).
+    (page = table[col // ps], offset = col % ps). Reads WALK the table in
+    blocks of ``KV_BLOCK`` tokens (whole pages, aligned at column 0) under
+    a running float32 softmax, and the trip count is data: the deepest
+    ACTIVE row's ``pos + T`` (a row whose table starts at the sink is
+    inactive, so a stale ``pos`` there cannot lengthen the walk). Nothing
+    of width ``max_len`` is gathered or converted: a step costs the live
+    pages, not the table's.
 
-    T > 1 with per-row ``pos`` is the self-speculative VERIFY step
-    (serve engine ``speculate=K``): the K draft positions attend and
-    scatter in one forward, and because each query row's math is
-    row-wise (the chunked-prefill T-invariance contract), column j's
-    logits are bitwise what the sequential decode would compute —
-    rejected drafts leave stale K/V rows past the accepted point that
-    the causal mask hides until the rows are overwritten, exactly like
-    the multi-token loop's speculative rows."""
+    What is bitwise: a block that the mask hides entirely is an exact
+    no-op on (max, sum, accumulator), and column 0 is valid for every row,
+    so a row's output depends neither on the trip count (who else is in
+    the batch) nor on ``T``. T > 1 with per-row ``pos`` is the
+    self-speculative VERIFY step (serve engine ``speculate=K``): column
+    j's logits are bitwise what the sequential decode computes (the
+    chunked-prefill T-invariance contract) -- rejected drafts leave stale
+    K/V rows past the accepted point that the causal mask hides until they
+    are overwritten, exactly like the multi-token loop's speculative rows.
+    Against the CONTIGUOUS layout (:func:`_cached_attention`) the
+    summation order differs, so that parity is token identity of greedy
+    decode (tests/test_serve_paging.py), not bits."""
     B, H, T, hd = qh.shape
     G, ps = k_pages.shape[1], k_pages.shape[2]
     maxp = block_table.shape[1]
     L = maxp * ps
+    sink = k_pages.shape[0] - 1
     pos = jnp.asarray(pos, jnp.int32)
     if pos.ndim == 0:
         pos = jnp.broadcast_to(pos, (B,))
@@ -323,23 +345,66 @@ def _paged_attention(qh, kh, vh, k_pages, v_pages, block_table, pos, rep):
     # alias them onto the row's LAST real page and corrupt it)
     pg = jnp.take_along_axis(block_table,
                              jnp.minimum(cols // ps, maxp - 1), axis=1)
-    pg = jnp.where(cols < L, pg, jnp.int32(k_pages.shape[0] - 1))      # [B,T]
+    pg = jnp.where(cols < L, pg, jnp.int32(sink))                      # [B,T]
     off = cols % ps
     with jax.named_scope("mx.kv_write"):
         k_pages = k_pages.at[pg, :, off, :].set(
             kh.transpose(0, 2, 1, 3).astype(k_pages.dtype))
         v_pages = v_pages.at[pg, :, off, :].set(
             vh.transpose(0, 2, 1, 3).astype(v_pages.dtype))
-    # logical full-length view: page i of the table lands at rows
-    # [i*ps, (i+1)*ps) — position p maps to row p exactly
-    with jax.named_scope("mx.kv_gather"):
-        kf = k_pages[block_table].transpose(0, 2, 1, 3, 4) \
-            .reshape(B, G, L, hd).astype(jnp.float32)
-        vf = v_pages[block_table].transpose(0, 2, 1, 3, 4) \
-            .reshape(B, G, L, hd).astype(jnp.float32)
-    mask3 = jnp.arange(L)[None, None, :] <= cols[:, :, None]           # [B,T,L]
-    out = _attend(qh, kf, vf, mask3, rep)
+    with jax.named_scope("mx.kv_walk"):
+        out = _walk_pages(qh, k_pages, v_pages, block_table, cols, rep)
     return out, k_pages, v_pages
+
+
+def _walk_pages(qh, k_pages, v_pages, block_table, cols, rep):
+    """The read side of :func:`_paged_attention`: query row t of batch row
+    b (at column ``cols[b, t]``) attends the row's logical columns
+    ``j <= cols[b, t]``, one block of pages an iteration."""
+    B, H, T, hd = qh.shape
+    G, ps = k_pages.shape[1], k_pages.shape[2]
+    maxp = block_table.shape[1]
+    L = maxp * ps
+    sink = k_pages.shape[0] - 1
+    block = kv_block(ps, maxp)
+    bp = block // ps                              # pages a block
+    # a table whose width is no multiple of the block ends in sink pages
+    # (a clamped slice of the last block would misalign its columns)
+    table = jnp.pad(block_table, ((0, 0), (0, -maxp % bp)),
+                    constant_values=sink)
+    last = jnp.minimum(cols, L - 1)               # deepest column a query sees
+    active = block_table[:, 0] != sink
+    live = jnp.max(jnp.where(active, last[:, -1], 0)) + 1
+    n = (live + block - 1) // block
+    q = qh.reshape(B, G, rep, T, hd).astype(jnp.float32) / math.sqrt(hd)
+    # [P, ps, G, hd] is the order the scatter leaves a pool in on the TPU,
+    # so these views are free there, and the walk reads whole pages as
+    # [tokens, G, hd]. Gathered as declared, each pool cost one more layout
+    # copy a step (PERF.md section 6, PR 27).
+    kt = k_pages.transpose(0, 2, 1, 3)
+    vt = v_pages.transpose(0, 2, 1, 3)
+
+    def step(i, carry):
+        m, l, acc = carry
+        pages = jax.lax.dynamic_slice_in_dim(table, i * bp, bp, axis=1)
+        kb = kt[pages].reshape(B, block, G, hd).astype(jnp.float32)
+        vb = vt[pages].reshape(B, block, G, hd).astype(jnp.float32)
+        col = i * block + jnp.arange(block, dtype=jnp.int32)
+        mask = col[None, None, :] <= last[:, :, None]              # [B,T,blk]
+        s = jnp.einsum("bgrtd,bjgd->bgrtj", q, kb)
+        s = jnp.where(mask[:, None, None], s, -jnp.inf)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        scale = jnp.exp(m - m_new)
+        l = l * scale + p.sum(axis=-1)
+        acc = acc * scale[..., None] + jnp.einsum("bgrtj,bjgd->bgrtd", p, vb)
+        return m_new, l, acc
+
+    m0 = jnp.full((B, G, rep, T), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((B, G, rep, T), jnp.float32)
+    acc0 = jnp.zeros((B, G, rep, T, hd), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, n, step, (m0, l0, acc0))
+    return (acc / l[..., None]).reshape(B, H, T, hd).astype(qh.dtype)
 
 
 class LlamaMLP(HybridBlock):

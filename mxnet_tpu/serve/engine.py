@@ -55,8 +55,8 @@ Architecture (vLLM-style continuous batching, TPU-static shapes):
   and (c) preemption (pool exhaustion releases + requeues the youngest
   slot; the stateless per-request sampling streams make the resume
   exact). The contiguous path is kept verbatim (``paged=False``, the
-  off-TPU default) as the bitwise-parity reference: paged greedy decode
-  is token-identical to it (tests/test_serve_paging.py).
+  off-TPU default) as the parity reference: paged greedy decode is
+  token-identical to it (tests/test_serve_paging.py).
 - **Self-speculative decoding** (``speculate=K``): decode proceeds in
   draft-verify rounds — K-1 tokens drafted from the request's own token
   history (n-gram prompt lookup, serve/speculate.py; no draft model),
@@ -79,7 +79,9 @@ Architecture (vLLM-style continuous batching, TPU-static shapes):
 
 Single-host, single-device engine; params are captured at construction
 (weight updates require a new engine). Pools are carried functionally
-(no donation yet — a TPU deployment would donate the pool buffers).
+(no donation yet — a TPU deployment would donate the pool buffers); a
+paged step reads only the blocks of pages that its deepest row has
+reached (``stats()``: ``kv_walk_blocks`` of ``kv_table_blocks``).
 """
 from __future__ import annotations
 
@@ -101,6 +103,7 @@ from ..analysis import guards as _guards
 from ..base import MXNetError, logger
 from ..device import on_tpu
 from ..models import generation as _gen
+from ..models import llama as _llama
 from ..observability import perf as _perf
 from ..observability import recorder as _recorder
 from ..observability import trace as _trace
@@ -351,7 +354,7 @@ class InferenceEngine:
     paged : lease fixed-size KV pages on demand instead of reserving a
         contiguous ``max_len`` region per slot (module docstring).
         Default ``None`` resolves to True on TPU, False elsewhere —
-        the contiguous path stays the off-TPU bitwise-parity reference.
+        the contiguous path stays the off-TPU parity reference.
     page_size : tokens per KV page (paged mode); ``max_len`` must be a
         multiple of it
     num_pages : leasable pages in the pool. Default sizes the pool to
@@ -681,6 +684,13 @@ class InferenceEngine:
             self._pages = PagePool(num_pages, self.page_size, self.L,
                                    self.S, prefix_cache=prefix_cache)
             self.maxp = self.L // self.page_size
+            # the paged read walks the table a block at a time, as far as
+            # the deepest row reaches: what a dispatch walks and what the
+            # table holds are summed here (stats(): kv_walk_blocks /
+            # kv_table_blocks, the share of a max_len read still done)
+            self._kv_block = _llama.kv_block(self.page_size, self.maxp)
+            self._kv_walked = 0
+            self._kv_tabled = 0
             # page-axis inference, same trick as the batch axis (per-layer
             # pools: axis 0; stacked scan pools [layers, pages, ...]: 1)
             sp1 = model.cache_spec_paged(1, self.page_size)
@@ -1992,6 +2002,21 @@ class InferenceEngine:
     def _span(self, name: str, hist=None, **attrs) -> _TickSpan:
         return _TickSpan(self, name, hist, **attrs)
 
+    def _note_walk(self, span: _profiler.scope, deepest: int, T: int,
+                   substeps: int = 1):
+        """Tell a dispatch span how many blocks of the block table the
+        program it dispatches walks in each layer (``walk``: the deepest
+        row's ``pos + T`` in blocks, once per substep of the multi-token
+        loop) out of how many the table holds (``of``), and add both to
+        the sums that ``stats()`` reports."""
+        blk = self._kv_block
+        walk = sum(-(-min(deepest + j + T, self.L) // blk)
+                   for j in range(substeps))
+        of = substeps * -(-self.L // blk)
+        span.set(walk=walk, of=of)
+        self._kv_walked += walk
+        self._kv_tabled += of
+
     def _loop_inner(self):
         while True:
             # live weight refresh lands BETWEEN ticks: everything below
@@ -2284,6 +2309,7 @@ class InferenceEngine:
                 fn = self._get_chunk()
                 ids = onp.zeros((1, self._chunk), onp.int32)
                 ids[0, :] = pf.ids[pf.cursor:end]
+                self._note_walk(span, pf.cursor, self._chunk)
                 pools = fn(self._values, self._pools, ids,
                            onp.int32(pf.cursor), self._table_row(s))
                 self._pools = pools
@@ -2303,6 +2329,7 @@ class InferenceEngine:
             fn = self._get_prefill(pb)
             ids = onp.zeros((1, pb), onp.int32)
             ids[0, :rest] = pf.ids[pf.cursor:]
+            self._note_walk(span, pf.cursor, pb)
             gargs = ()
             if self._grammar:
                 gargs = (self._gcls[s:s + 1].copy(),
@@ -2768,7 +2795,9 @@ class InferenceEngine:
                     return
                 sb = bucket_for(cur[-1][0] + 1, 1, self.S)
         self._tick_span.set(rows=len(cur), sb=sb)
-        with self._span("decode_dispatch", sb=sb, rows=len(cur)):
+        with self._span("decode_dispatch", sb=sb, rows=len(cur)) as disp:
+            self._note_walk(disp, max(int(self._pos[s]) for s, _ in cur),
+                            1, self.K)
             rec = self._dispatch_step_paged(prev, cur, sb)
         if rec is None:
             return
@@ -3319,6 +3348,8 @@ class InferenceEngine:
             out["pages"] = pstats
             out["prefilling"] = len(self._prefills)
             out["preemptions"] = self._preempted
+            out["kv_walk_blocks"] = self._kv_walked
+            out["kv_table_blocks"] = self._kv_tabled
             # bounded prefix-cache advert for the router's affinity
             # scoring: top-N chained-hash roots by refcount (the
             # serve_prefix_advert knob caps N; 0 disables the advert)

@@ -11,7 +11,7 @@ of a pool of fixed-size pages instead:
   physical pages per cache_spec entry (``cache_spec_paged``); page
   ``num_pages`` is the *sink* — unleased block-table entries point at it,
   so padded/speculative writes land somewhere harmless and masked reads
-  of unleased territory gather garbage that contributes exact zeros
+  of unleased territory see garbage that contributes exact zeros
   (see models/llama._paged_attention).
 - **Block tables.** Each slot owns a ``[max_pages]`` int32 row mapping
   logical page ``i`` (token positions ``[i*page_size, (i+1)*page_size)``)
@@ -95,9 +95,9 @@ class PagePool:
     num_pages : leasable physical pages (the device pools carry one extra
         sink page at index ``num_pages``)
     page_size : tokens per page
-    max_len : per-request KV capacity; must be a page multiple so the
-        gathered cache length equals the contiguous layout's (the
-        bitwise-parity requirement, models/llama._paged_attention)
+    max_len : per-request KV capacity; must be a page multiple: the read
+        walks the block table in blocks of whole pages
+        (models/llama._paged_attention)
     slots : block-table rows (the engine's ``max_batch_size``)
     prefix_cache : publish/match shared prompt prefixes
     """
@@ -111,8 +111,7 @@ class PagePool:
         if max_len % page_size:
             raise MXNetError(
                 f"max_len ({max_len}) must be a multiple of page_size "
-                f"({page_size}) so the paged gather length equals the "
-                f"contiguous cache length (bitwise-parity requirement)")
+                f"({page_size}): the paged read walks whole pages")
         if num_pages * page_size < max_len:
             raise MXNetError(
                 f"page pool ({num_pages} pages x {page_size}) cannot hold "

@@ -1,0 +1,197 @@
+"""The read side of ``models/llama._paged_attention``: a loop over blocks of
+logical pages whose trip count is the deepest active row's, under a running
+float32 softmax.
+
+Held against ``_attend`` over the gathered contiguous view (what the read
+was before the loop) to float32 tolerance, and against itself bit for bit
+where the contract says so: a row alone and beside a deeper row, ``T = 1``
+against column j of ``T = K``.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as onp
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu.models import GPTModel
+from mxnet_tpu.models import llama
+from mxnet_tpu.models.gpt import GPTConfig
+from mxnet_tpu.models.llama import _attend, _paged_attention, kv_block
+from mxnet_tpu.serve import InferenceEngine
+
+G, HD = 3, 16
+
+
+def gathered_reference(qh, kh, vh, k_pages, v_pages, table, pos, rep):
+    """The same write, then ``_attend`` over all ``max_pages`` pages of
+    every row as one float32 ``[B, G, L, hd]`` view."""
+    B, H, T, hd = qh.shape
+    ps, maxp = k_pages.shape[2], table.shape[1]
+    L = maxp * ps
+    cols = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
+    pg = jnp.take_along_axis(table, jnp.minimum(cols // ps, maxp - 1), axis=1)
+    pg = jnp.where(cols < L, pg, k_pages.shape[0] - 1)
+    k_pages = k_pages.at[pg, :, cols % ps, :].set(
+        kh.transpose(0, 2, 1, 3).astype(k_pages.dtype))
+    v_pages = v_pages.at[pg, :, cols % ps, :].set(
+        vh.transpose(0, 2, 1, 3).astype(v_pages.dtype))
+    kf = k_pages[table].transpose(0, 2, 1, 3, 4).reshape(B, G, L, hd)
+    vf = v_pages[table].transpose(0, 2, 1, 3, 4).reshape(B, G, L, hd)
+    mask = jnp.arange(L)[None, None, :] <= cols[:, :, None]
+    out = _attend(qh, kf.astype(jnp.float32), vf.astype(jnp.float32), mask,
+                  rep)
+    return out, k_pages, v_pages
+
+
+def make(ps, maxp, depths, T, rep, seed=0, qdtype=jnp.float32):
+    """Random pools (bf16, as served), a table of distinct pages per row,
+    and the T new rows of q/k/v for rows at ``depths``."""
+    rng = onp.random.default_rng(seed)
+    B = len(depths)
+    n = B * maxp
+    pools = [jnp.asarray(rng.standard_normal((n + 1, G, ps, HD)),
+                         jnp.bfloat16) for _ in range(2)]
+    table = jnp.asarray(rng.permutation(n).reshape(B, maxp), jnp.int32)
+    q = jnp.asarray(rng.standard_normal((B, G * rep, T, HD)), qdtype)
+    k, v = (jnp.asarray(rng.standard_normal((B, G, T, HD)), qdtype)
+            for _ in range(2))
+    return q, k, v, pools[0], pools[1], table, jnp.asarray(depths, jnp.int32)
+
+
+paged = jax.jit(_paged_attention, static_argnums=7)
+reference = jax.jit(gathered_reference, static_argnums=7)
+
+# (page size, pages a row, T, depths): the block is 128 tokens
+GEOMETRIES = {
+    "decode-rows-end-in-different-blocks": (16, 16, 1, [5, 127, 128, 250]),
+    "verify-T4-across-a-block-edge": (16, 16, 4, [0, 126, 200]),
+    "chunk-with-pad-columns-past-L": (16, 16, 32, [240, 3]),
+    "L-smaller-than-the-block": (8, 4, 1, [3, 20, 31]),
+    "table-no-multiple-of-the-block": (16, 12, 1, [5, 130, 190]),
+    "table-no-multiple-chunk-past-L": (16, 12, 16, [180, 60]),
+}
+
+
+@pytest.mark.parametrize("rep", [1, 2])
+@pytest.mark.parametrize("name", list(GEOMETRIES))
+def test_walk_equals_attend_over_the_gathered_view(name, rep):
+    ps, maxp, T, depths = GEOMETRIES[name]
+    args = make(ps, maxp, depths, T, rep)
+    out, kp, vp = paged(*args, rep)
+    want, kp_w, vp_w = reference(*args, rep)
+    assert out.dtype == jnp.float32
+    onp.testing.assert_allclose(onp.asarray(out), onp.asarray(want),
+                                rtol=2e-5, atol=2e-6)
+    assert bool((kp == kp_w).all()) and bool((vp == vp_w).all())
+
+
+@pytest.mark.parametrize("rep", [1, 2])
+def test_row_is_bitwise_alone_and_beside_a_deeper_row(rep):
+    """The trip count is the deepest row's; a block the mask hides is an
+    exact no-op, so the shallow row does not see who else is there."""
+    q, k, v, kp, vp, table, pos = make(16, 16, [20, 250], 1, rep,
+                                       qdtype=jnp.bfloat16)
+    both, _, _ = paged(q, k, v, kp, vp, table, pos, rep)
+    alone, _, _ = paged(q[:1], k[:1], v[:1], kp, vp, table[:1], pos[:1], rep)
+    assert onp.array_equal(onp.asarray(both[0].astype(jnp.float32)),
+                           onp.asarray(alone[0].astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("rep", [1, 2])
+@pytest.mark.parametrize("j", [0, 1, 2, 3])
+def test_decode_is_bitwise_column_j_of_verify(j, rep):
+    """Column j of one T = K forward is what the sequential T = 1 decode
+    at ``pos + j`` computes (rows past it are written already, as after a
+    rejected draft, and the causal mask hides them)."""
+    K = 4
+    q, k, v, kp, vp, table, pos = make(16, 16, [126, 40], K, rep,
+                                       qdtype=jnp.bfloat16)
+    wide, kp, vp = paged(q, k, v, kp, vp, table, pos, rep)
+    one, _, _ = paged(q[:, :, j:j + 1], k[:, :, j:j + 1], v[:, :, j:j + 1],
+                      kp, vp, table, pos + j, rep)
+    assert onp.array_equal(onp.asarray(one[:, :, 0].astype(jnp.float32)),
+                           onp.asarray(wide[:, :, j].astype(jnp.float32)))
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("what", ["one-while", "no-f32-of-width-max_len",
+                                  "no-pool-sized-value-in-the-loop"])
+def test_jaxpr_of_the_walk(what):
+    ps, maxp = 16, 16                       # L = 256, unlike any other size
+    args = make(ps, maxp, [5, 200, 90], 1, 1, qdtype=jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(_paged_attention, static_argnums=7)(*args, 1).jaxpr
+    whiles = [e for e in _eqns(jaxpr) if e.primitive.name == "while"]
+    if what == "one-while":
+        assert len(whiles) == 1
+    elif what == "no-f32-of-width-max_len":
+        wide = [v.aval for e in _eqns(jaxpr) for v in e.outvars
+                if v.aval.dtype == jnp.float32 and ps * maxp in v.aval.shape]
+        assert not wide
+    else:
+        body = whiles[0].params["body_jaxpr"].jaxpr
+        pages = args[3].shape[0]
+        big = [v.aval for e in _eqns(body) for v in e.outvars
+               if v.aval.shape and v.aval.shape[0] == pages]
+        assert not big
+
+
+@pytest.mark.parametrize("second_row", ["inactive-stale-pos", "active"])
+def test_inactive_row_with_a_stale_pos_does_not_lengthen_the_walk(second_row):
+    """Row 0 is 20 deep; its pages past block 0 hold NaN. A masked block is
+    0 x NaN = NaN in the accumulator, so row 0 stays finite exactly when
+    the walk ends after block 0. Row 1 sits at 200: as an inactive row (its
+    table all sink) that is a stale ``pos`` and must not count."""
+    ps, maxp, rep = 16, 16, 1
+    q, k, v, kp, vp, table, _ = make(ps, maxp, [20, 200], 1, rep)
+    sink = kp.shape[0] - 1
+    poisoned = table[0, kv_block(ps, maxp) // ps:]
+    vp = vp.at[poisoned].set(jnp.nan)
+    if second_row == "inactive-stale-pos":
+        table = table.at[1].set(sink)
+    out, _, _ = paged(q, k, v, kp, vp, table, jnp.asarray([20, 200]), rep)
+    finite = bool(jnp.isfinite(out[0]).all())
+    assert finite == (second_row == "inactive-stale-pos")
+
+
+@pytest.mark.parametrize("geometry, want", [
+    ((16, 64), 128), ((8, 4), 32), ((16, 12), 128), ((256, 4), 256)])
+def test_block_is_whole_pages_clipped_to_the_table(geometry, want):
+    assert kv_block(*geometry) == want
+    assert want % geometry[0] == 0 and llama.KV_BLOCK == 128
+
+
+@pytest.fixture(scope="module")
+def engine():
+    mx.random.seed(0)
+    net = GPTModel(GPTConfig(vocab_size=64, hidden_size=32, num_layers=2,
+                             num_heads=2, max_position_embeddings=256,
+                             dropout=0.0))
+    net.initialize()
+    eng = InferenceEngine(net, max_batch_size=2, max_len=256, paged=True,
+                          page_size=16, prefill_chunk=64)
+    eng.start()
+    yield eng
+    eng.shutdown()
+
+
+@pytest.mark.parametrize("prompt, deep", [(5, False), (150, True)])
+def test_stats_sum_the_walk_and_the_table(engine, prompt, deep):
+    """max_len 256 is two blocks. A short request walks one of them in
+    every dispatch; a 150-token prompt reaches the second."""
+    before = engine.stats()
+    r = engine.submit(onp.arange(prompt) % 64, 6).result(120)
+    assert r.ok
+    after = engine.stats()
+    walked = after["kv_walk_blocks"] - before["kv_walk_blocks"]
+    table = after["kv_table_blocks"] - before["kv_table_blocks"]
+    assert table >= 2 * 6 and table % 2 == 0
+    if deep:
+        assert table // 2 < walked <= table
+    else:
+        assert walked == table // 2

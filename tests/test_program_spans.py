@@ -5,6 +5,7 @@ trace is there, under the names PERF.md section 3 lists.
 One parametrised test, one case per span or scope. The traced runs and the
 lowerings are made once a module, on the CPU: nothing here is a timing."""
 import glob
+import re
 import logging
 import time
 
@@ -29,9 +30,11 @@ TRAIN_SPANS = ["step", "h2d", "dispatch", "loss_sync"]
 # attributes that are known only inside the span and set on it there
 SET_INSIDE = ["tick.rows", "tick.sb", "prefill_dispatch.start",
               "prefill_dispatch.end", "prefill_dispatch.final",
-              "admit.admitted", "emit.tokens"]
+              "admit.admitted", "emit.tokens",
+              "prefill_dispatch.walk", "prefill_dispatch.of",
+              "decode_dispatch.walk", "decode_dispatch.of"]
 STEP_SCOPES = ["mx.embed", "mx.attn", "mx.paged_attention", "mx.kv_write",
-               "mx.kv_gather", "mx.mlp", "mx.lm_head", "mx.sample"]
+               "mx.kv_walk", "mx.mlp", "mx.lm_head", "mx.sample"]
 TRAIN_SCOPES = ["mx.embed", "mx.attn", "mx.mlp", "mx.lm_head", "mx.loss",
                 "mx.optimizer"]
 
@@ -186,7 +189,10 @@ def test_program_names(kind, name, request):
         lines = request.getfixturevalue("train_lines")
         assert any(n == f"mx.train.{name}" for ln in lines for n, *_ in ln)
     elif kind == "step_scope":
-        assert f"/{name}/" in request.getfixturevalue("step_text")
+        # a scope inside a function that is jitted by itself heads its own
+        # paths in the lowered text; the compiled program joins them
+        assert re.search(rf'[/"]{re.escape(name)}/',
+                         request.getfixturevalue("step_text"))
     elif kind == "train_scope":
         text = request.getfixturevalue("train_text")
         if name == "mx.optimizer":
