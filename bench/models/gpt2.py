@@ -4,16 +4,15 @@ configuration file and fills it with the reference's weights for a seed.
 This is the only file that knows both sides: the program's parameter names
 and layouts, and the reference's (``bench/reference/gpt2.py``). A new model
 family adds a module like this one, named by its configuration file's
-``builder``.
+``builder``, with its reference as ``ref`` and its count of operations and
+bytes (``bench/work/gpt2.py`` here) as ``work``: the harness and the traffic
+modules reach all three through the builder alone.
 """
 from __future__ import annotations
 
-import functools
-
-import jax
-import jax.numpy as jnp
-
+from mxbench.models.common import dtype_of, install, seeded
 from mxbench.reference import gpt2 as ref
+from mxbench.work import gpt2 as work  # noqa: F401  (the family's count)
 
 #: program parameter suffix -> (reference leaf, transposed?). The program's
 #: Dense stores [out, in]; the reference stores [in, out] as published.
@@ -59,67 +58,26 @@ def program_names(cfg: dict):
     return names + list(_TOP_MAP)[2:]
 
 
-def dtype_of(cfg: dict):
-    return jnp.dtype(cfg.get("torch_dtype_served", "bfloat16"))
-
-
-@functools.lru_cache(maxsize=None)
-def _weights_fn(cfg_key):
-    cfg = dict(cfg_key)
-
-    def make(words):
-        tree = ref.init_params(cfg, (words[0], words[1]), dtype_of(cfg))
-        out = {}
-        for name in program_names(cfg):
-            leaf, layer, transposed = leaf_of(name)
-            x = tree[leaf] if layer is None else tree["layers"][leaf][layer]
-            out[name] = x.T if transposed else x
-        return out
-
-    return jax.jit(make)
-
-
 cfg_key = ref.cfg_key
 
 
 def program_weights(cfg: dict, seed: int):
     """{program parameter name: array} on the default device, made in one
     jitted call from the seed, in the type they are served in."""
-    import numpy as np
-    return _weights_fn(cfg_key(cfg))(np.asarray(ref.seed_words(seed)))
+    return seeded(ref, cfg, seed, program_names(cfg), leaf_of)
 
 
 def reference_weights(cfg: dict, seed: int):
     """The same values as the reference wants them (stacked layers)."""
-    import numpy as np
-    fn = jax.jit(lambda w: ref.init_params(cfg, (w[0], w[1]),
-                                           dtype_of(cfg)))
-    return fn(np.asarray(ref.seed_words(seed)))
+    return seeded(ref, cfg, seed)
 
 
 def build_net(cfg: dict, seed: int, train: bool):
-    """The program's model with the seed's weights installed. For serving
-    the parameters carry no gradient buffer (``grad_req='null'``, MXNet's
-    own idiom for inference): the eager buffer would double the weights'
-    memory for nothing."""
+    """The program's model with the seed's weights installed."""
     from mxnet_tpu.models.gpt import GPTConfig, GPTModel
     V, D, L, H, P, eps = ref.sizes(cfg)
     net = GPTModel(GPTConfig(
         vocab_size=V, hidden_size=D, num_layers=L, num_heads=H,
         max_position_embeddings=P, dropout=0.0, layer_norm_eps=eps,
         dtype=dtype_of(cfg)))
-    weights = program_weights(cfg, seed)
-    params = net.collect_params()
-    missing = set(params) ^ set(weights)
-    if missing:
-        raise SystemExit(f"bench: parameter names differ between the "
-                         f"program and the builder: {sorted(missing)[:6]}")
-    for name, p in params.items():
-        w = weights[name]
-        if tuple(p.shape) != tuple(w.shape):
-            raise SystemExit(f"bench: {name}: program shape {p.shape}, "
-                             f"reference shape {w.shape}")
-        if not train:
-            p.grad_req = "null"
-        p.set_data(w)
-    return net
+    return install(net, program_weights(cfg, seed), train)

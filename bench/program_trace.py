@@ -32,7 +32,7 @@ the ``/host:metadata`` plane: one event-metadata entry per executed program,
 named as on the ``XLA Modules`` line (``jit_step_b4(4595889807412691761)``),
 whose one stat, ``Hlo Proto``, holds the optimised module as a serialised
 ``xla.HloProto``; there every instruction has ``metadata.op_name``, e.g.
-``jit(step_b4)/mx.attn/mx.paged_attention/mx.kv_gather/convert_element_type``
+``jit(step_b4)/mx.attn/mx.paged_attention/mx.kv_walk/convert_element_type``
 (a fusion carries its root's). ``ProfileData`` does not reach that plane's
 metadata, and the protobuf classes for it come only with TensorFlow, so
 ``hlo_scopes`` walks the protobuf wire format itself, descending only where

@@ -32,18 +32,15 @@ import math
 import jax
 import jax.numpy as jnp
 
-HI = jax.lax.Precision.HIGHEST
+from mxbench.reference import common
+from mxbench.reference.common import (HI, cfg_key, draw as _draw,  # noqa: F401
+                                      mm as _mm, round_to as _round,
+                                      seed_words)
 
 #: leaf name -> (shape as a function of sizes, init kind); per layer
 LAYER_LEAVES = ("ln1_g", "ln1_b", "qkv_w", "qkv_b", "out_w", "out_b",
                 "ln2_g", "ln2_b", "fc_w", "fc_b", "proj_w", "proj_b")
 TOP_LEAVES = ("wte", "wpe", "lnf_g", "lnf_b")
-
-
-def cfg_key(cfg: dict):
-    """The configuration's scalars as a hashable key (a static argument)."""
-    return tuple(sorted((k, v) for k, v in cfg.items()
-                        if isinstance(v, (int, float, str))))
 
 
 def sizes(cfg: dict):
@@ -58,19 +55,6 @@ def _layer_shapes(D):
             "qkv_b": (3 * D,), "out_w": (D, D), "out_b": (D,),
             "ln2_g": (D,), "ln2_b": (D,), "fc_w": (D, 4 * D),
             "fc_b": (4 * D,), "proj_w": (4 * D, D), "proj_b": (D,)}
-
-
-def _draw(key, shape, std, mean, dtype):
-    x = jax.random.normal(key, shape, jnp.float32) * jnp.float32(std)
-    return (x + jnp.float32(mean)).astype(dtype)
-
-
-def seed_words(seed: int):
-    """``seed`` as two uint32 words: seeds run past 2**31, and no cast may
-    wrap two of them onto one key."""
-    import numpy as np
-    seed = int(seed)
-    return np.uint32(seed & 0xFFFFFFFF), np.uint32((seed >> 32) & 0xFFFFFFFF)
 
 
 def init_params(cfg: dict, seed, dtype=jnp.bfloat16):
@@ -112,29 +96,6 @@ def init_params(cfg: dict, seed, dtype=jnp.bfloat16):
 
 
 # ---------------------------------------------------------------- forward
-def _round(x, fake):
-    """``x`` as a matmul operand: float32, or rounded through ``fake``. The
-    rounding is straight-through: the backward pass sees the identity, so a
-    lower-precision *forward* is what the control measures (a cast's own
-    gradient would be rounded to ``fake`` too, and fp8 without loss scaling
-    flushes every small gradient to zero: a crash, not a reading)."""
-    x = x.astype(jnp.float32)
-    if fake is None:
-        return x
-    if fake == "int8":
-        # symmetric int8 with one scale per row, the usual scheme
-        s = jnp.max(jnp.abs(x), axis=-1, keepdims=True) / 127.0
-        s = jnp.where(s == 0, 1.0, s)
-        low = jnp.round(x / s) * s
-    else:
-        low = x.astype(fake).astype(jnp.float32)
-    return x + jax.lax.stop_gradient(low - x)
-
-
-def _mm(a, b, fake):
-    return jnp.matmul(_round(a, fake), _round(b, fake), precision=HI)
-
-
 def _ln(x, g, b, eps):
     mu = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
@@ -290,39 +251,8 @@ def train_steps(params, batches, cfg: dict, opt: dict, fake=None, rows=2,
 
 
 # ---------------------------------------------------------------- serving
-@functools.partial(jax.jit, static_argnames=("cfg_key", "fake"))
-def _gap_rows(params, ids, cfg_key, fake):
-    cfg = dict(cfg_key)
-    ref = logits(params, ids, cfg, None)
-    best = jnp.max(ref, axis=-1)
-    if fake is None:
-        return ref, best, None
-    low = logits(params, ids, cfg, fake)
-    pick = jnp.argmax(low, axis=-1)
-    got = jnp.take_along_axis(ref, pick[..., None], axis=-1)[..., 0]
-    return ref, best, best - got
-
-
 def served_gaps(params, seqs, prompt_lens, cfg: dict, fake=None, pad_to=None):
-    """For each sequence (prompt + served tokens) the gaps, one per served
-    token, by which the served token's reference logit lies below the
-    reference's best at that position. With ``fake`` the token judged is
-    not the served one but the one the lower precision puts first (the
-    control). Sequences are padded to one length, so one program serves
-    them all; causal attention keeps the padding out of what is read."""
-    key = cfg_key(cfg)
-    pad_to = pad_to or max(len(s) for s in seqs)
-    out = []
-    for seq, n_prompt in zip(seqs, prompt_lens):
-        ids = jnp.zeros((1, pad_to), jnp.int32).at[0, :len(seq)].set(
-            jnp.asarray(seq, jnp.int32))
-        ref, best, low_gap = _gap_rows(params, ids, key, fake)
-        # the token at position i was chosen from the logits at i - 1
-        pos = jnp.arange(n_prompt - 1, len(seq) - 1)
-        if fake is None:
-            tok = jnp.asarray(seq[n_prompt:], jnp.int32)
-            gaps = best[0, pos] - ref[0, pos, tok]
-        else:
-            gaps = low_gap[0, pos]
-        out.append([float(g) for g in gaps])
-    return out
+    """The gaps of served tokens (``common.served_gaps``) under this
+    family's ``logits``."""
+    return common.served_gaps(logits, params, seqs, prompt_lens, cfg, fake,
+                              pad_to)

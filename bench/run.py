@@ -12,7 +12,8 @@ Nothing here names a cell, a configuration or a metric: a cell is
 ``bench/workloads/<cell>.json``, its configuration ``bench/configs/<config>
 .json``, its traffic a module under ``bench/traffic/`` named by the cell's
 ``traffic.kind``, its model a module under ``bench/models/`` named by the
-configuration's ``builder``, and each metric ``bench/metrics/<metric>.json``
+configuration's ``builder`` (which brings the family's reference and its
+count of operations and bytes), and each metric ``bench/metrics/<metric>.json``
 with the reader under ``bench/readers/`` that it names.
 
 It refuses — exits non-zero and prints no result — where JAX finds no TPU,
@@ -39,13 +40,19 @@ BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
 
 
-def alias_package():
+def alias_package(base=BENCH):
     """Make this directory importable as ``mxbench``. The name ``bench`` is
-    taken at the root of the repository by ``bench.py``."""
+    taken at the root of the repository by ``bench.py``. Modules are looked
+    for under ``base`` first (the rehearsal's directory, as ``read_metric``
+    does for a metric's file), then here: a builder, its reference and its
+    count may live beside the rehearsal cell that uses them."""
     if "mxbench" not in sys.modules:
         pkg = types.ModuleType("mxbench")
         pkg.__path__ = [BENCH]
         sys.modules["mxbench"] = pkg
+    path = sys.modules["mxbench"].__path__
+    if base not in path:
+        path.insert(0, base)
     if ROOT not in sys.path:
         sys.path.insert(0, ROOT)
 
@@ -179,6 +186,7 @@ def main(argv=None):
         raise Refuse("the program (mxnet_tpu/) is not in this checkout")
     benchmark = load_json(bfile)
     cell, spec, cfg = find_cell(benchmark, args.workload, base)
+    alias_package(base)
 
     # JAX's persistent compile cache: where JAX_COMPILATION_CACHE_DIR names
     # one, there; else at a fixed path inside the checkout (the program sets

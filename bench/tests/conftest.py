@@ -2,7 +2,9 @@
 
 ``bench.py`` at the root of the repository owns the module name ``bench``,
 so the benchmark's modules are imported under the alias ``mxbench``, which
-``bench/run.py`` sets up (see ``alias_package`` there)."""
+``bench/run.py`` sets up (see ``alias_package`` there). This directory is
+searched first, as in a rehearsal: the second family's builder, reference and
+count live here (``models/``, ``reference/``, ``work/``)."""
 import importlib.util
 import os
 import sys
@@ -18,3 +20,4 @@ _spec = importlib.util.spec_from_file_location(
 run = importlib.util.module_from_spec(_spec)
 sys.modules["mxbench_run"] = run
 _spec.loader.exec_module(run)
+run.alias_package(os.path.join(BENCH, "tests"))

@@ -62,22 +62,28 @@ def test_train_control_in_lower_precision_fails(seed):
 
 
 @pytest.mark.parametrize("seed", [6, 2147484001, 4000000005])
-def test_serve_control_in_lower_precision_fails(seed):
+@pytest.mark.parametrize("family,config,cell", [
+    ("gpt2", "gpt2-tiny", "tiny-chat"), ("gqa", "gqa-tiny", "tiny-gqa-chat")])
+def test_serve_control_in_lower_precision_fails(family, config, cell, seed):
     """The control need not decode: at each position of the same prompts and
-    tokens it reads the gap of the token the lower precision puts first."""
-    spec = spec_of("tiny-chat")
-    params = builder.reference_weights(CFG, seed)
+    tokens it reads the gap of the token the lower precision puts first.
+    Once for each family: the second lives wholly in this directory."""
+    import importlib
+    fam = importlib.import_module(f"mxbench.models.{family}")
+    cfg = json.load(open(os.path.join(TESTS, "configs", f"{config}.json")))
+    spec = spec_of(cell)
+    params = fam.reference_weights(cfg, seed)
     rng = np.random.RandomState(seed % 2 ** 32)
     seqs = [[int(t) for t in rng.randint(0, 256, 128)] for _ in range(8)]
     plens = [1] * len(seqs)
-    low = ref.served_gaps(params, seqs, plens, CFG, fake=jnp.bfloat16,
-                          pad_to=128)
+    low = fam.ref.served_gaps(params, seqs, plens, cfg, fake=jnp.bfloat16,
+                              pad_to=128)
     assert max(g for row in low for g in row) > spec["limits"]["logit_gap"]
     # the reference's own first choice at every position lies 0 below it
-    lg = ref.logits(params, jnp.asarray(seqs[:1], jnp.int32), CFG)
+    lg = fam.ref.logits(params, jnp.asarray(seqs[:1], jnp.int32), cfg)
     best = [int(t) for t in jnp.argmax(lg[0], axis=-1)]
     one = [seqs[0][:40] + [best[39]]]
-    gaps = ref.served_gaps(params, one, [40], CFG, pad_to=128)
+    gaps = fam.ref.served_gaps(params, one, [40], cfg, pad_to=128)
     assert gaps == [[0.0]]
 
 
@@ -141,7 +147,8 @@ def test_half_the_batch_left_out_is_not_correct(capsys, monkeypatch):
     assert c["value"] > c["limit"]
 
 
-@pytest.mark.parametrize("cell", ["tiny-chat", "tiny-batch"])
+@pytest.mark.parametrize("cell", ["tiny-chat", "tiny-batch",
+                                  "tiny-gqa-chat"])
 def test_a_token_altered_where_it_is_produced_is_not_correct(
         capsys, monkeypatch, cell):
     from mxnet_tpu.models import generation

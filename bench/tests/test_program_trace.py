@@ -89,14 +89,14 @@ def test_idle_split_between_tick_and_idle(run):
 
 
 @pytest.mark.parametrize("args, expected", [
-    # two steps of 0.10 s; in each 0.04 under the gather, 0.03 the sampler,
+    # two steps of 0.10 s; in each 0.04 under the walk, 0.03 the sampler,
     # and of the pools' two copies, 0.015 under no scope and 0.005 under
     # the scatter's by inheritance
     (dict(scope="mx.paged_attention", modules=["jit_step"]), 45.0),
     (dict(scope="mx.kv_write", modules=["jit_step"]), 5.0),
     (dict(unnamed=True, modules=["jit_step"]), 20.0),
     (dict(unnamed=True, modules=["jit_prefill"]), 0.0),
-    (dict(scope="mx.kv_gather", modules=["jit_step"]), 40.0),
+    (dict(scope="mx.kv_walk", modules=["jit_step"]), 40.0),
     (dict(scope="mx.sample", modules=["jit_step"]), 30.0),
     (dict(scope=["mx.sample", "mx.attn"], modules=["jit_step"]), 75.0),
     # %fusion.1#2 is the prefill's fusion.1, not the step's

@@ -4,6 +4,8 @@ The rehearsal names its platform truthfully and emits no device metric; the
 default path refuses without a TPU. Adding these cells took only the files
 in this directory: nothing in ``bench/run.py`` names a cell, a configuration
 or a metric."""
+import ast
+import glob
 import json
 import os
 import subprocess
@@ -27,7 +29,8 @@ def run_cell(capsys, cell, trace, seed=3000000019, seconds=1.5):
 
 @pytest.mark.parametrize("cell,trace", [
     ("tiny-train", 0), ("tiny-train", 1), ("tiny-chat", 0),
-    ("tiny-chat", 1), ("tiny-batch", 0), ("tiny-batch", 1)])
+    ("tiny-chat", 1), ("tiny-batch", 0), ("tiny-batch", 1),
+    ("tiny-gqa-chat", 0), ("tiny-gqa-chat", 1)])
 def test_tiny_cell_runs_and_is_correct(capsys, cell, trace):
     rc, result, out = run_cell(capsys, cell, trace)
     assert rc == 0
@@ -51,6 +54,13 @@ def test_tiny_cell_runs_and_is_correct(capsys, cell, trace):
     for name, c in result["compared"].items():
         assert f"compared {name}: {c['value']} limit {c['limit']}" in out.err
     assert out.err.strip().splitlines()[-1] == "correct: True"
+    if cell == "tiny-gqa-chat":
+        # the family's own count, found beside the cell: 2 layers of q, o
+        # (64 x 64), k, v (64 x 32), gate, up, down (64 x 128) and a head of
+        # 64 x 256, two bytes each (test_window_facts.py holds its cached
+        # bytes a token to the grouped-query count)
+        assert result["facts"]["weight_bytes"] == 2 * (
+            2 * (2 * 64 * 64 + 2 * 64 * 32 + 3 * 64 * 128) + 64 * 256)
 
 
 def test_same_seed_same_inputs():
@@ -101,3 +111,84 @@ def test_nothing_in_run_py_names_a_cell_config_or_metric():
             names |= {e["name"] for e in b[group]}
     names.discard("setup_s")   # the harness itself measures the set-up time
     assert not [n for n in names if n in text]
+
+
+#: a configuration's keys are its source's own, so the harness reads two:
+#: the vocabulary (token ids are drawn from it) and the builder's name
+GENERAL_KEYS = {"vocab_size", "builder"}
+
+
+def general_sources():
+    paths = [RUN, os.path.join(conftest.BENCH, "calibrate.py"),
+             os.path.join(conftest.BENCH, "sweep.py")]
+    for sub in ("traffic", "readers"):
+        paths += sorted(glob.glob(os.path.join(conftest.BENCH, sub, "*.py")))
+    return paths
+
+
+def is_config(node):
+    """``cfg`` / ``config``, or ``<anything>["cfg"]``."""
+    if isinstance(node, ast.Name):
+        return node.id in ("cfg", "config")
+    return (isinstance(node, ast.Subscript)
+            and isinstance(node.slice, ast.Constant)
+            and node.slice.value == "cfg")
+
+
+def config_keys_read(tree):
+    """The constant keys that the code reads from a configuration, by
+    subscript or by ``.get``."""
+    keys = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Subscript) and is_config(node.value) \
+                and isinstance(node.slice, ast.Constant):
+            keys.add(node.slice.value)
+        if isinstance(node, ast.Call) \
+                and isinstance(node.func, ast.Attribute) \
+                and node.func.attr in ("get", "pop", "setdefault") \
+                and is_config(node.func.value) and node.args \
+                and isinstance(node.args[0], ast.Constant):
+            keys.add(node.args[0].value)
+    return keys
+
+
+def family_imports(tree):
+    """Modules of one model family imported by name: a family is reached
+    through ``ctx["builder"]`` (its ``ref``, its ``work``) alone. What the
+    families share (``common``) is no family's."""
+    family = ("mxbench.models", "mxbench.reference", "mxbench.work")
+    found = []
+    for node in ast.walk(tree):
+        if isinstance(node, ast.ImportFrom) and node.module in family:
+            found += [f"{node.module}.{a.name}" for a in node.names]
+        elif isinstance(node, ast.ImportFrom) and node.module \
+                and node.module.startswith(tuple(f + "." for f in family)):
+            found.append(node.module)
+        elif isinstance(node, ast.Import):
+            found += [a.name for a in node.names
+                      if a.name.startswith(tuple(f + "." for f in family))]
+    return [m for m in found if not m.endswith(".common")]
+
+
+@pytest.mark.parametrize("path", general_sources(),
+                         ids=lambda p: os.path.relpath(p, conftest.BENCH))
+def test_general_code_reads_no_familys_configuration_key(path):
+    """The promise of ``bench/README.md``: a model family is its builder, its
+    reference and its count, and nothing general knows its keys."""
+    tree = ast.parse(open(path).read())
+    assert config_keys_read(tree) <= GENERAL_KEYS
+    assert not family_imports(tree)
+    gpt2_keys = {"n_embd", "n_layer", "n_head", "n_positions", "n_inner",
+                 "n_ctx"}
+    constants = {n.value for n in ast.walk(tree)
+                 if isinstance(n, ast.Constant) and isinstance(n.value, str)}
+    assert not constants & gpt2_keys
+
+
+def test_the_walk_sees_a_key_where_one_is_read():
+    tree = ast.parse(
+        'a = cfg["n_embd"]\nb = ctx["cfg"].get("hidden_size", 0)\n'
+        'c = run["cfg"]["vocab_size"]\nfrom mxbench.reference import gpt2\n'
+        'from mxbench.reference.common import seed_words')
+    assert config_keys_read(tree) == {"n_embd", "hidden_size", "vocab_size"}
+    assert family_imports(tree) == ["mxbench.reference.gpt2"]

@@ -177,13 +177,13 @@ def window_facts(state, records, t0, t_close, seconds):
     is {"req", "due", "sent", "handle", "feed", "refused"}; ``due`` and
     ``sent`` are offsets from ``t0``."""
     ctx = state["ctx"]
-    cfg = ctx["cfg"]
+    cfg, work = ctx["cfg"], ctx["builder"].work
     chunk = int(ctx["spec"]["engine"].get("prefill_chunk")
                 or state["engine"].stats()["page_size"])
     ttft, itl, qwait, late = [], [], [], []
     tokens = prompt_tokens = 0
     failed = 0
-    decode_kv_tokens = decode_tokens = 0
+    decode_kv_bytes = decode_tokens = 0
     model_flops = 0.0
     chunks = []
     done = []
@@ -202,7 +202,7 @@ def window_facts(state, records, t0, t_close, seconds):
             itl.extend((b - a) * 1e3 for a, b in zip(stamps, stamps[1:]))
             if stamps[0] <= t_close:
                 prompt_tokens += P
-                model_flops += flops.forward_flops(cfg, P, P * (P + 1) // 2)
+                model_flops += work.forward_flops(cfg, P, 0)
                 chunks.extend((min(chunk, P - s), s)
                               for s in range(0, P, chunk))
             for j, t in enumerate(stamps):
@@ -210,8 +210,8 @@ def window_facts(state, records, t0, t_close, seconds):
                     tokens += 1
                     if j >= 1:
                         decode_tokens += 1
-                        decode_kv_tokens += P + j
-                        model_flops += flops.forward_flops(cfg, 1, P + j)
+                        decode_kv_bytes += work.cache_bytes(cfg, P + j)
+                        model_flops += work.forward_flops(cfg, 1, P + j - 1)
         else:
             ttft.append(float("inf"))
         if res is None or rec.get("cancelled"):
@@ -234,8 +234,9 @@ def window_facts(state, records, t0, t_close, seconds):
         "generator_late_p50_ms": float(np.median(late)) if late else 0.0,
         "generator_late_max_ms": float(np.max(late)) if late else 0.0,
         "decode_tokens": decode_tokens,
-        "decode_kv_bytes": decode_kv_tokens * flops.kv_bytes_per_token(cfg),
-        "weight_bytes": flops.weight_bytes(cfg),
+        "decode_kv_bytes": decode_kv_bytes,
+        # what any decode step reads at the least: a pass that holds one row
+        "weight_bytes": work.weight_bytes(cfg, 1),
         "model_flops": model_flops,
     }
     fin = sorted(x for x in ttft if np.isfinite(x))
@@ -245,7 +246,7 @@ def window_facts(state, records, t0, t_close, seconds):
     for q in (50, 95):
         facts[f"itl_p{q}_ms"] = float(np.percentile(itl, q)) if itl else None
     if ctx["peaks"]:
-        pl = flops.prefill_least_s(cfg, chunks, ctx["peaks"])
+        pl = flops.prefill_least_s(work, cfg, chunks, ctx["peaks"])
         facts["prefill_least_s"] = pl["seconds"]
         facts["prefill_binds"] = pl["binds"]
     stats = state["engine"].stats()
@@ -329,7 +330,8 @@ def compare(builder, cfg, spec, seed, sample, mismatch, fake=None):
     seqs = [s["prompt"] + s["generated"] for s in sample]
     gaps = builder.ref.served_gaps(
         params, seqs, [len(s["prompt"]) for s in sample], cfg, fake=fake,
-        pad_to=int(spec.get("reference_pad_to", cfg["n_positions"])))
+        pad_to=int(spec.get("reference_pad_to",
+                            builder.work.max_positions(cfg))))
     flat = np.asarray([g for row in gaps for g in row])
     return {"logit_gap": float(flat.max()),
             "gap_mean": float(flat.mean()),

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from mxbench import flops
-from mxbench.reference import gpt2 as ref_gpt2  # seed_words only
+from mxbench.reference.common import seed_words
 
 CHECK_STEPS = 3
 
@@ -37,7 +37,7 @@ def make_batches(cfg, seed, n, batch, seq):
         return jax.random.randint(key, (n, batch, seq + 1), 0, vocab,
                                   jnp.int32)
 
-    return jax.jit(draw)(np.asarray(ref_gpt2.seed_words(seed)))
+    return jax.jit(draw)(np.asarray(seed_words(seed)))
 
 
 def _norms_fn(builder, names):
@@ -136,7 +136,7 @@ def delta_norms(builder, cfg, seed, names, slots, values):
                     for _, _, p in builder.parts(names[s], d)]
         return jnp.stack(out)
 
-    return fn(tuple(values), np.asarray(ref_gpt2.seed_words(seed)))
+    return fn(tuple(values), np.asarray(seed_words(seed)))
 
 
 def feed(state):
@@ -168,20 +168,21 @@ def window(state, seconds):
 
 def after_window(state, facts):
     ctx = state["ctx"]
-    cfg, B, T = ctx["cfg"], state["B"], state["T"]
+    cfg, work = ctx["cfg"], ctx["builder"].work
+    B, T = state["B"], state["T"]
     facts["attempted"] = facts["steps"]
     ok = np.isfinite(state["last_loss"])
     facts["failed"] = 0 if ok else facts["steps"]
     facts["last_loss"] = state["last_loss"]
-    facts["model_flops"] = facts["tokens"] * flops.train_flops_per_token(
+    facts["model_flops"] = facts["tokens"] * work.train_flops_per_token(
         cfg, T)
     if ctx["peaks"]:
-        heads = int(cfg["n_head"])
-        fa = flops.flash_attention_train(
-            B, heads, T, int(cfg["n_embd"]) // heads, ctx["peaks"])
-        # every layer of every step runs one forward and one backward kernel
+        heads, head_dim, layers = work.train_attention(cfg)
+        fa = flops.flash_attention_train(B, heads, T, head_dim, ctx["peaks"])
+        # every such layer of every step runs one forward and one backward
+        # kernel
         facts["flash_least_s_per_device"] = (
-            facts["steps"] * int(cfg["n_layer"]) * fa["seconds"])
+            facts["steps"] * layers * fa["seconds"])
         facts["flash_binds"] = fa["binds"]
     return facts
 
