@@ -10,6 +10,8 @@ from .bert import (BertConfig, BertModel, BertForSequenceClassification,
 from .gpt import GPTConfig, GPTModel, GPT2_SMALL, GPT_TINY
 from .vit import ViTConfig, ViTModel, VIT_B16, VIT_TINY
 from .t5 import T5Config, T5Model, T5_SMALL, T5_TINY
+from .minicpm_sala import (MiniCPMSALAConfig, MiniCPMSALAForCausalLM,
+                           SALA_TINY)
 from .generation import generate
 
 # attach the decode loop as a method on the causal-LM families (one
@@ -31,5 +33,6 @@ __all__ = [
     "GPTConfig", "GPTModel", "GPT2_SMALL", "GPT_TINY",
     "ViTConfig", "ViTModel", "VIT_B16", "VIT_TINY",
     "T5Config", "T5Model", "T5_SMALL", "T5_TINY",
+    "MiniCPMSALAConfig", "MiniCPMSALAForCausalLM", "SALA_TINY",
     "generate",
 ]

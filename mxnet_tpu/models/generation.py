@@ -223,7 +223,8 @@ def spec_verify_tokens(logits, inputs, temps, topks, topps, seeds, counters,
     return toks, (1 + jnp.sum(lead, axis=1)).astype(jnp.int32)
 
 
-def decode_step(fm, param_vals, tokens, pos, caches, block_table=None):
+def decode_step(fm, param_vals, tokens, pos, caches, block_table=None,
+                rows=None):
     """One incremental forward through the KV-cache protocol: attend
     ``tokens`` [B, T] at offset(s) ``pos`` (scalar, or [B] for per-row
     offsets — continuous batching) against ``caches``. Returns
@@ -233,11 +234,17 @@ def decode_step(fm, param_vals, tokens, pos, caches, block_table=None):
     With ``block_table`` [B, max_pages] the step routes through the
     model's ``forward_cached_paged`` entry point instead: ``caches`` are
     then the shared page pools and every row addresses its KV rows
-    through its table (serve/paging)."""
+    through its table (serve/paging). A model that keeps recurrent state
+    beside its pages (``cache_spec_state``) also takes ``rows``: each row's
+    slot in the state pools and how many of its T positions are real."""
     if block_table is None:
         out, _aux = fm.apply(list(param_vals), tokens, pos, *caches,
                              seed=0, training=False,
                              method="forward_cached")
+    elif rows is not None:
+        out, _aux = fm.apply(list(param_vals), tokens, pos, block_table,
+                             *rows, *caches, seed=0, training=False,
+                             method="forward_cached_paged")
     else:
         out, _aux = fm.apply(list(param_vals), tokens, pos, block_table,
                              *caches, seed=0, training=False,

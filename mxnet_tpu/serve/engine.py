@@ -321,6 +321,40 @@ class InferenceEngine:
     (``cache_spec``/``forward_cached`` protocol — GPT and Llama families,
     including stacked-scan decoders).
 
+    **Recurrent state beside the pages.** A model whose layers keep a
+    per-request state of fixed size (linear attention, a convolution) next
+    to, or in place of, paged keys and values says so with one method more
+    than the paged protocol, and the engine takes no argument for it:
+
+    - ``cache_spec_paged(num_pages, page_size)`` lists only the pools that
+      have a page axis (the engine infers that axis from them, as before);
+    - ``cache_spec_state(slots)`` lists ``[(shape, dtype)]`` of the state
+      pools, each indexed by slot on an axis the model knows. The engine
+      asks for ``max_batch_size + 1`` slots (the last is the *sink*, for
+      rows of a decode bucket that serve no request), allocates them behind
+      the page pools and hands all pools to every program, in that order;
+    - ``forward_cached_paged(ids, pos, block_table, slots, valid, *pools)``
+      takes, after the block table, each row's slot id ``[B]`` and how many
+      of its ``T`` positions are real ``[B]`` (a prompt's last chunk is
+      padded to a bucket, and padding must not move a state). A row whose
+      ``pos`` is 0 starts from a zero state: admission needs no clearing
+      program and compiles nothing. The model may leave out
+      ``cache_spec``/``forward_cached`` (then ``score()`` raises);
+    - optionally ``blocks_read(pos, T) -> (read, live)`` for a model that
+      selects among its pages: the engine puts ``sel``/``of`` on its two
+      dispatch spans and sums them in ``stats()``.
+
+    Preemption requeues and prefills again from position 0, which rebuilds
+    the state. ``stats()`` gains ``state_bytes``, ``sparse_blocks_read`` and
+    ``sparse_blocks_live``. **Refused with state, each with an
+    ``MXNetError`` that says why** (carrying snapshots of a state is not
+    built): ``prefix_cache=True`` (shared pages carry a prefix's keys and
+    values, not the state it left; pass ``prefix_cache=False``),
+    ``speculate`` and ``multi_token > 1`` (a rejected or surplus position
+    has already moved the state), ``paged=False``, and page migration
+    (COW ``copy``, ``export_pages``/``import_pages``, the preemption-rescue
+    hook: a request's pages alone do not resume it).
+
     Parameters
     ----------
     model : initialized causal LM block
@@ -531,7 +565,11 @@ class InferenceEngine:
             raise MXNetError("spec_lookup must be >= 1")
         if min_prompt_bucket < 1 or min_prompt_bucket & (min_prompt_bucket - 1):
             raise MXNetError("min_prompt_bucket must be a power of two")
-        if not _gen._can_cache(model):
+        # a model with recurrent state beside its pages speaks the paged
+        # protocol only (cache_spec_paged / cache_spec_state /
+        # forward_cached_paged with each row's slot): class docstring
+        self._stateful = hasattr(model, "cache_spec_state")
+        if not (self._stateful or _gen._can_cache(model)):
             raise MXNetError(
                 "InferenceEngine requires the KV-cache decode protocol "
                 "(cache_spec/forward_cached) and a config that supports it")
@@ -634,8 +672,9 @@ class InferenceEngine:
 
         # slot-pool caches + batch-axis inference (per-layer: axis 0;
         # stacked scan caches [layers, B, ...]: axis 1)
-        self._spec1 = model.cache_spec(1, self.L)
-        spec2 = model.cache_spec(2, self.L)
+        contiguous = hasattr(model, "cache_spec")
+        self._spec1 = model.cache_spec(1, self.L) if contiguous else []
+        spec2 = model.cache_spec(2, self.L) if contiguous else []
         self._baxes: List[int] = []
         for (s1, _), (s2, _) in zip(self._spec1, spec2):
             diffs = [i for i, (a, b) in enumerate(zip(s1, s2)) if a != b]
@@ -645,6 +684,10 @@ class InferenceEngine:
                     f"{s1} vs {s2}")
             self._baxes.append(diffs[0])
 
+        if self._stateful:
+            self._refuse_with_state(paged, prefix_cache, speculate,
+                                    multi_token)
+            paged = True
         if paged is None:
             # auto: paged on TPU — but only when the model speaks the
             # paged protocol and max_len is a page multiple, so existing
@@ -710,8 +753,22 @@ class InferenceEngine:
             # territory contribute exact zeros
             pool_spec = model.cache_spec_paged(num_pages + 1,
                                                self.page_size)
+            # per-slot recurrent state, a second kind of pool behind the
+            # page pools: indexed by slot, no page axis, one slot more than
+            # the engine serves (the sink, for rows that serve no request)
+            state_spec = (model.cache_spec_state(self.S + 1)
+                          if self._stateful else [])
             self._pools: Tuple[jax.Array, ...] = tuple(
-                jnp.zeros(s, d) for s, d in pool_spec)
+                jnp.zeros(s, d) for s, d in list(pool_spec) + state_spec)
+            self._state_bytes = sum(
+                int(onp.prod(s)) * onp.dtype(d).itemsize
+                for s, d in state_spec)
+            # blocks of one sparse layer's cache that the dispatched
+            # programs read, of those live (stats(): sparse_blocks_read /
+            # sparse_blocks_live), where the model selects among its pages
+            self._blocks_read = getattr(model, "blocks_read", None)
+            self._sel_read = 0
+            self._sel_live = 0
             self._tok_bytes = sum(
                 int(onp.prod(s)) * onp.dtype(d).itemsize
                 // ((num_pages + 1) * self.page_size)
@@ -1056,6 +1113,11 @@ class InferenceEngine:
         no KV pool traffic, so it runs from any thread concurrently with
         serving; the weight read is one atomic tuple load). Returns
         ``{"tokens", "logprob", "token_logprobs"}``."""
+        if not self._spec1:
+            raise MXNetError(
+                "score() runs on fresh contiguous caches "
+                "(cache_spec/forward_cached), which this model does not "
+                "have")
         prompt = self._as_prompt(input_ids)
         if len(prompt) < 2:
             raise MXNetError(
@@ -1205,12 +1267,44 @@ class InferenceEngine:
             rec["ok"] = True
             rec["evt"].set()
 
+    @staticmethod
+    def _refuse_with_state(paged, prefix_cache, speculate, multi_token):
+        """What a model with recurrent state cannot be served with yet,
+        each with its reason (class docstring)."""
+        if paged is False:
+            raise MXNetError(
+                "a model with recurrent state (cache_spec_state) is served "
+                "through the paged protocol only: paged=False has no "
+                "per-slot state")
+        if prefix_cache:
+            raise MXNetError(
+                "prefix_cache=True cannot serve a model with recurrent "
+                "state: shared pages give a request its prefix's keys and "
+                "values but not the state the prefix left, and no snapshot "
+                "of a state is kept per page; pass prefix_cache=False")
+        if speculate:
+            raise MXNetError(
+                "speculate cannot serve a model with recurrent state: a "
+                "rejected draft has already moved the state, and a state "
+                "has no stale rows for the causal mask to hide")
+        if multi_token > 1:
+            raise MXNetError(
+                "multi_token > 1 cannot serve a model with recurrent "
+                "state: the device loop writes past a finished row's "
+                "budget, which a page forgives and a state does not")
+
     # ------------------------------------------------- page migration
     def _require_paged(self):
         if not self._paged:
             raise MXNetError(
                 "cross-replica page migration requires the paged engine "
                 "(paged=True)")
+        if self._stateful:
+            raise MXNetError(
+                "page migration (copy / extract / inject) cannot move a "
+                "request of a model with recurrent state: its pages hold "
+                "the sparse layers' keys and values only, and a snapshot "
+                "of the per-slot state is not carried")
 
     def _export_entries(self, toks: List[int], phys_pages: Sequence[int]
                         ) -> dict:
@@ -1403,7 +1497,7 @@ class InferenceEngine:
         if self._paged and self._pages.prefix_cache_enabled:
             out = self._get_copy()(*self._example_args("copy", 0))
             jax.block_until_ready(out[0])
-        if self._paged:
+        if self._paged and not self._stateful:
             # migration executables: warmed so a first preemption rescue
             # or tier page-stream inside steady-state serving hits cached
             # code (the no_recompile() contract with migration enabled).
@@ -1471,17 +1565,21 @@ class InferenceEngine:
         if self._paged:
             sink_tbl = lambda rows: onp.full(       # noqa: E731
                 (rows, self.maxp), self._pages.sink, onp.int32)
+            # with state every row names its slot: the examples' is the sink
+            sink_slot = lambda rows: self._slot_rows(  # noqa: E731
+                [self.S] * rows)
             if label == "prefill":
                 return (self._values, self._pools,
                         onp.zeros((1, bucket), onp.int32), onp.int32(1),
-                        onp.int32(0), sink_tbl(1)) + gram_args(1, 1) + (
+                        onp.int32(0), sink_tbl(1)) + sink_slot(1) \
+                    + gram_args(1, 1) + (
                         onp.zeros(1, onp.float32), onp.zeros(1, onp.int32),
                         onp.ones(1, onp.float32), onp.zeros(1, onp.uint32),
                         onp.zeros(1, onp.int32))
             if label == "chunk":
                 return (self._values, self._pools,
                         onp.zeros((1, bucket), onp.int32), onp.int32(0),
-                        sink_tbl(1))
+                        sink_tbl(1)) + sink_slot(1)
             if label == "copy":
                 return (self._pools, onp.int32(0), onp.int32(0))
             if label == "extract":
@@ -1492,7 +1590,7 @@ class InferenceEngine:
             args = (self._values, self._pools,
                     onp.zeros(bucket, onp.int32),
                     onp.zeros(bucket, onp.int32), sink_tbl(bucket)) + \
-                gram_args(self.S, bucket) + (
+                sink_slot(bucket) + gram_args(self.S, bucket) + (
                     onp.zeros(bucket, onp.float32),
                     onp.zeros(bucket, onp.int32),
                     onp.ones(bucket, onp.float32),
@@ -1791,15 +1889,22 @@ class InferenceEngine:
         counter ``counter0`` so preempted requests resume mid-stream)."""
         fm = self._fm
         grammar = self._grammar
+        stateful = self._stateful
 
         def prefill(values, pools, ids, true_len, start, table, *rest):
+            rows = None
+            if stateful:
+                # the row's slot, and how many of the bucket's positions
+                # are the prompt's: padding must not move a state
+                rows, rest = (rest[0], jnp.reshape(true_len, (1,))), rest[1:]
             if grammar:
                 (gcls, gnxt, gacc, gstate, geos,
                  temps, topks, topps, seeds, counter0) = rest
             else:
                 temps, topks, topps, seeds, counter0 = rest
             logits, new_pools = _gen.decode_step(fm, values, ids, start,
-                                                 pools, block_table=table)
+                                                 pools, block_table=table,
+                                                 rows=rows)
             last = jax.lax.dynamic_index_in_dim(
                 logits, true_len - 1, axis=1, keepdims=False)   # [1, V]
             keys = self._slot_keys(seeds, counter0)
@@ -1816,9 +1921,11 @@ class InferenceEngine:
         eliminates the LM head — the chunk's logits are never used)."""
         fm = self._fm
 
-        def chunk(values, pools, ids, start, table):
+        def chunk(values, pools, ids, start, table, *slot):
+            rows = (slot[0], jnp.full(1, cs, jnp.int32)) if slot else None
             _logits, new_pools = _gen.decode_step(fm, values, ids, start,
-                                                  pools, block_table=table)
+                                                  pools, block_table=table,
+                                                  rows=rows)
             return new_pools
 
         return _jit_named(chunk, f"chunk_c{cs}")
@@ -1843,8 +1950,12 @@ class InferenceEngine:
             return _jit_named(step, f"step_b{sb}")
 
         grammar = self._grammar
+        stateful = self._stateful
 
         def step(values, pools, tokens, pos, tables, *rest):
+            rows = None
+            if stateful:
+                rows, rest = (rest[0], jnp.ones(sb, jnp.int32)), rest[1:]
             if grammar:
                 (gcls, gnxt, gacc, gstate, geos,
                  temps, topks, topps, seeds, counters) = rest
@@ -1855,7 +1966,8 @@ class InferenceEngine:
                 temps, topks, topps, seeds, counters = rest
             logits, new_pools = _gen.decode_step(fm, values,
                                                  tokens[:, None], pos,
-                                                 pools, block_table=tables)
+                                                 pools, block_table=tables,
+                                                 rows=rows)
             keys = self._slot_keys(seeds, counters)
             mask = (_grammar.grammar_mask(gcls, gnxt, gacc, gstate, geos)
                     if grammar else None)
@@ -2016,6 +2128,27 @@ class InferenceEngine:
         span.set(walk=walk, of=of)
         self._kv_walked += walk
         self._kv_tabled += of
+
+    def _note_select(self, span: _profiler.scope, rows):
+        """Where the model selects among its pages (``blocks_read``): tell
+        a dispatch span how many blocks of one sparse layer's cache the
+        program reads for its rows' ``(pos, T)`` (``sel``: a decoding row
+        its selection, a chunk every live block once) of how many those
+        rows hold (``of``), and add both to the sums of ``stats()``."""
+        if self._blocks_read is None:
+            return
+        sel = of = 0
+        for pos, T in rows:
+            read, live = self._blocks_read(min(pos, self.L - T), T)
+            sel, of = sel + read, of + live
+        span.set(sel=sel, of=of)
+        self._sel_read += sel
+        self._sel_live += of
+
+    def _slot_rows(self, slots) -> Tuple[onp.ndarray, ...]:
+        """The rows' slot ids as one more argument of a paged program, for
+        a model with per-slot state; nothing for any other."""
+        return (onp.asarray(slots, onp.int32),) if self._stateful else ()
 
     def _loop_inner(self):
         while True:
@@ -2310,8 +2443,10 @@ class InferenceEngine:
                 ids = onp.zeros((1, self._chunk), onp.int32)
                 ids[0, :] = pf.ids[pf.cursor:end]
                 self._note_walk(span, pf.cursor, self._chunk)
+                self._note_select(span, [(pf.cursor, self._chunk)])
                 pools = fn(self._values, self._pools, ids,
-                           onp.int32(pf.cursor), self._table_row(s))
+                           onp.int32(pf.cursor), self._table_row(s),
+                           *self._slot_rows([s]))
                 self._pools = pools
                 if req._span_prefill is not None:
                     ch = req._span_prefill.child(
@@ -2330,6 +2465,7 @@ class InferenceEngine:
             ids = onp.zeros((1, pb), onp.int32)
             ids[0, :rest] = pf.ids[pf.cursor:]
             self._note_walk(span, pf.cursor, pb)
+            self._note_select(span, [(pf.cursor, pb)])
             gargs = ()
             if self._grammar:
                 gargs = (self._gcls[s:s + 1].copy(),
@@ -2340,7 +2476,8 @@ class InferenceEngine:
                                     else req.eos_token_id], onp.int32))
             tok0, pools = fn(
                 self._values, self._pools, ids, onp.int32(rest),
-                onp.int32(pf.cursor), self._table_row(s), *gargs,
+                onp.int32(pf.cursor), self._table_row(s),
+                *self._slot_rows([s]), *gargs,
                 onp.array([req.temperature], onp.float32),
                 onp.array([req.top_k], onp.int32),
                 onp.array([req.top_p], onp.float32),
@@ -2436,7 +2573,7 @@ class InferenceEngine:
         req = slot.req
         req._resume = list(slot.generated)
         doc = None
-        if self._migrate_hook is not None:
+        if self._migrate_hook is not None and not self._stateful:
             # capture the victim's leased pages BEFORE release() frees
             # them — this is the engine thread, so the pools are stable
             try:
@@ -2798,6 +2935,7 @@ class InferenceEngine:
         with self._span("decode_dispatch", sb=sb, rows=len(cur)) as disp:
             self._note_walk(disp, max(int(self._pos[s]) for s, _ in cur),
                             1, self.K)
+            self._note_select(disp, [(int(self._pos[s]), 1) for s, _ in cur])
             rec = self._dispatch_step_paged(prev, cur, sb)
         if rec is None:
             return
@@ -2827,6 +2965,9 @@ class InferenceEngine:
         else:
             tokens = self._tokens[:sb].copy()
         fn = self._get_step(sb)
+        # with state: row r serves slot r, a row that decodes nothing the sink
+        slot_rows = self._slot_rows(
+            [r if self._active[r] else self.S for r in range(sb)])
         try:
             ngs = None
             if self.K > 1:
@@ -2844,7 +2985,7 @@ class InferenceEngine:
                           else self._gstate[:sb].copy())
                 nxt, ngs, pools = fn(
                     self._values, self._pools,
-                    tokens, self._pos[:sb].copy(), tables,
+                    tokens, self._pos[:sb].copy(), tables, *slot_rows,
                     gcls_d, gnxt_d, gacc_d, gstate,
                     self._eos[:sb].copy(),
                     self._temps[:sb].copy(), self._topks[:sb].copy(),
@@ -2854,7 +2995,7 @@ class InferenceEngine:
                 toks = steps = None
                 nxt, pools = fn(
                     self._values, self._pools,
-                    tokens, self._pos[:sb].copy(), tables,
+                    tokens, self._pos[:sb].copy(), tables, *slot_rows,
                     self._temps[:sb].copy(), self._topks[:sb].copy(),
                     self._topps[:sb].copy(), self._seeds[:sb].copy(),
                     self._counters[:sb].copy())
@@ -3350,6 +3491,9 @@ class InferenceEngine:
             out["preemptions"] = self._preempted
             out["kv_walk_blocks"] = self._kv_walked
             out["kv_table_blocks"] = self._kv_tabled
+            out["state_bytes"] = self._state_bytes
+            out["sparse_blocks_read"] = self._sel_read
+            out["sparse_blocks_live"] = self._sel_live
             # bounded prefix-cache advert for the router's affinity
             # scoring: top-N chained-hash roots by refcount (the
             # serve_prefix_advert knob caps N; 0 disables the advert)

@@ -264,7 +264,7 @@ def test_spec_verify_launch_accounting():
     net = _quantized(vocab=256, hidden=256, layers=layers, heads=4)
     eng = InferenceEngine(net, max_batch_size=2, max_len=32, paged=True,
                           page_size=8, speculate=3)
-    tally = _step_tally(eng, "spec", eng._get_spec, n=2)
+    tally = _step_tally(eng, "spec", eng._build_step_spec, n=2)
     assert tally.pop("spec_verify") == 1
     assert tally == {"reference": 4 * layers + 1}
 

@@ -213,3 +213,35 @@ def test_trainstep_body_compiles_data_parallel(topo, one_chip, as_tpu):
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 2 * layers
     assert "all-reduce" in text          # the dp gradient reduction
+
+
+# ------------------------------------- MiniCPM-SALA's two mixers, real widths
+# the long-document cell's geometry: 16 slots, 2,560 pages of 64 (+ sink), a
+# block table of 520 pages, 32 query heads over 2 KV heads of 128
+@pytest.mark.parametrize("rows,T", [(16, 1), (1, 1024)],
+                         ids=["decode_b16", "chunk_c1024"])
+def test_sparse_paged_attention_compiles(one_chip, rows, T):
+    sa = importlib.import_module("mxnet_tpu.ops.sparse_attention")
+    bf, i32 = jnp.bfloat16, jnp.int32
+    pages = lambda last: _s(one_chip, (2561, 2, last, 128), bf)
+    _compile(
+        lambda q, k, v, kp, vp, kc, bt, pos, valid: sa.sparse_paged_attention(
+            q, k, v, kp, vp, kc, bt, pos, valid, rep=16,
+            sc=sa.SparseConfig()),
+        _s(one_chip, (rows, 32, T, 128), bf),
+        _s(one_chip, (rows, 2, T, 128), bf),
+        _s(one_chip, (rows, 2, T, 128), bf), pages(64), pages(64), pages(4),
+        _s(one_chip, (rows, 520), i32), _s(one_chip, (rows,), i32),
+        _s(one_chip, (rows,), i32))
+
+
+@pytest.mark.parametrize("rows,T", [(16, 1), (1, 1024)],
+                         ids=["decode_b16", "chunk_c1024"])
+def test_lightning_attention_compiles(one_chip, rows, T):
+    la = importlib.import_module("mxnet_tpu.ops.linear_attention")
+    x = _s(one_chip, (rows, 32, T, 128), jnp.bfloat16)
+    i = _s(one_chip, (rows,), jnp.int32)
+    _compile(
+        lambda q, k, v, pool, slots, pos, valid: la.lightning_attention_slots(
+            q, k, v, pool, slots, pos, la.decay_slopes(32, 16, 32), valid),
+        x, x, x, _s(one_chip, (17, 32, 128, 128), jnp.float32), i, i, i)
