@@ -155,11 +155,13 @@ class GPTModel(HybridBlock):
 
     def cache_spec_paged(self, num_pages: int, page_size: int):
         """[(shape, dtype)] for the PAGED KV pool (serve/paging): k0, v0,
-        ... of [num_pages, H, page_size, hd]. The caller passes the
-        physical page count (the engine adds its sink page)."""
+        ... of [num_pages, page_size, hidden_size]: one row a token, its
+        heads side by side, the shape a TPU keeps as declared and updates
+        in place (models/llama._paged_attention). The caller passes the
+        physical page count (the engine adds its sink page, and donates
+        the pools to every program that writes them)."""
         cfg = self.cfg
-        shp = (num_pages, cfg.num_heads, page_size,
-               cfg.hidden_size // cfg.num_heads)
+        shp = (num_pages, page_size, cfg.hidden_size)
         return [(shp, cfg.dtype)] * (2 * cfg.num_layers)
 
     def forward_cached(self, input_ids, pos, *caches):

@@ -302,15 +302,26 @@ def kv_block(page_size: int, max_pages: int) -> int:
 @functools.partial(jax.jit, static_argnames="rep")
 def _paged_attention(qh, kh, vh, k_pages, v_pages, block_table, pos, rep):
     """Attention for incremental decode over a PAGED cache: the pool
-    carries [num_pages + 1, n_kv, page_size, hd] physical pages shared by
-    every request; ``block_table`` [B, max_pages] maps each row's logical
+    carries [num_pages + 1, page_size, n_kv * hd] physical pages shared by
+    every request, one row a token: the K (or V) vectors of all its heads
+    side by side. ``block_table`` [B, max_pages] maps each row's logical
     page i (token positions [i*ps, (i+1)*ps)) to a physical page (the
     serve/paging.PagePool ledger). The last physical page is the *sink*:
     unleased table entries point there, so pad/speculative writes land
     harmlessly and reads of unleased territory see garbage that the
     validity mask turns into exact zeros.
 
-    Writes scatter the T new K/V rows through the table
+    The shape is what keeps a pool where it lies. A TPU stores an array
+    of this shape as declared (rows of n_kv * hd lanes, padded to whole
+    tiles of 128), so a scatter of whole rows updates the caller's buffer
+    and nothing else of it moves; with the heads and their width as
+    separate trailing dimensions (64 lanes of 128 used) the chip keeps
+    the pages minor-most instead, and every program that wrote one row
+    relaid the whole pool out and back (PERF.md section 6, PR 31). The
+    serving engine donates the pools to each program that writes them, so
+    ``k_pages`` in and ``k_pages`` out are one buffer.
+
+    Writes scatter the T new K/V rows through the table, whole rows
     (page = table[col // ps], offset = col % ps). Reads WALK the table in
     blocks of ``KV_BLOCK`` tokens (whole pages, aligned at column 0) under
     a running float32 softmax, and the trip count is data: the deepest
@@ -332,7 +343,7 @@ def _paged_attention(qh, kh, vh, k_pages, v_pages, block_table, pos, rep):
     summation order differs, so that parity is token identity of greedy
     decode (tests/test_serve_paging.py), not bits."""
     B, H, T, hd = qh.shape
-    G, ps = k_pages.shape[1], k_pages.shape[2]
+    G, ps = kh.shape[1], k_pages.shape[1]
     maxp = block_table.shape[1]
     L = maxp * ps
     sink = k_pages.shape[0] - 1
@@ -348,10 +359,12 @@ def _paged_attention(qh, kh, vh, k_pages, v_pages, block_table, pos, rep):
     pg = jnp.where(cols < L, pg, jnp.int32(sink))                      # [B,T]
     off = cols % ps
     with jax.named_scope("mx.kv_write"):
-        k_pages = k_pages.at[pg, :, off, :].set(
-            kh.transpose(0, 2, 1, 3).astype(k_pages.dtype))
-        v_pages = v_pages.at[pg, :, off, :].set(
-            vh.transpose(0, 2, 1, 3).astype(v_pages.dtype))
+        k_pages = k_pages.at[pg, off].set(
+            kh.transpose(0, 2, 1, 3).reshape(B, T, G * hd)
+            .astype(k_pages.dtype))
+        v_pages = v_pages.at[pg, off].set(
+            vh.transpose(0, 2, 1, 3).reshape(B, T, G * hd)
+            .astype(v_pages.dtype))
     with jax.named_scope("mx.kv_walk"):
         out = _walk_pages(qh, k_pages, v_pages, block_table, cols, rep)
     return out, k_pages, v_pages
@@ -360,9 +373,12 @@ def _paged_attention(qh, kh, vh, k_pages, v_pages, block_table, pos, rep):
 def _walk_pages(qh, k_pages, v_pages, block_table, cols, rep):
     """The read side of :func:`_paged_attention`: query row t of batch row
     b (at column ``cols[b, t]``) attends the row's logical columns
-    ``j <= cols[b, t]``, one block of pages an iteration."""
+    ``j <= cols[b, t]``, one block of pages an iteration. A block is
+    gathered as the pool lies, ``[B, pages, ps, G * hd]``, and split into
+    its heads there: ``[B, block, G, hd]``. The pool itself is only
+    indexed."""
     B, H, T, hd = qh.shape
-    G, ps = k_pages.shape[1], k_pages.shape[2]
+    G, ps = H // rep, k_pages.shape[1]
     maxp = block_table.shape[1]
     L = maxp * ps
     sink = k_pages.shape[0] - 1
@@ -377,18 +393,12 @@ def _walk_pages(qh, k_pages, v_pages, block_table, cols, rep):
     live = jnp.max(jnp.where(active, last[:, -1], 0)) + 1
     n = (live + block - 1) // block
     q = qh.reshape(B, G, rep, T, hd).astype(jnp.float32) / math.sqrt(hd)
-    # [P, ps, G, hd] is the order the scatter leaves a pool in on the TPU,
-    # so these views are free there, and the walk reads whole pages as
-    # [tokens, G, hd]. Gathered as declared, each pool cost one more layout
-    # copy a step (PERF.md section 6, PR 27).
-    kt = k_pages.transpose(0, 2, 1, 3)
-    vt = v_pages.transpose(0, 2, 1, 3)
 
     def step(i, carry):
         m, l, acc = carry
         pages = jax.lax.dynamic_slice_in_dim(table, i * bp, bp, axis=1)
-        kb = kt[pages].reshape(B, block, G, hd).astype(jnp.float32)
-        vb = vt[pages].reshape(B, block, G, hd).astype(jnp.float32)
+        kb = k_pages[pages].reshape(B, block, G, hd).astype(jnp.float32)
+        vb = v_pages[pages].reshape(B, block, G, hd).astype(jnp.float32)
         col = i * block + jnp.arange(block, dtype=jnp.int32)
         mask = col[None, None, :] <= last[:, :, None]              # [B,T,blk]
         s = jnp.einsum("bgrtd,bjgd->bgrtj", q, kb)
@@ -582,7 +592,7 @@ def _stacked_layer_cached(cfg: LlamaConfig, p, x, pos, k_cache, v_cache):
 def _stacked_layer_paged(cfg: LlamaConfig, p, x, pos, block_table,
                          k_pages, v_pages):
     """Paged-cache variant of ``_stacked_layer_cached``: one dense layer
-    against its own [num_pages+1, n_kv, ps, hd] page-pool slice (the
+    against its own [num_pages+1, ps, n_kv * hd] page-pool slice (the
     block table is shared across layers)."""
     B, T, _ = x.shape
     hd = cfg.hd
@@ -704,7 +714,7 @@ class LlamaStackedDecoder(HybridBlock):
 
     def forward_cached_paged(self, x, pos, block_table, k_pages, v_pages):
         """Paged incremental forward: scan consumes each layer's parameter
-        slice + page-pool slice ([num_layers, num_pages+1, n_kv, ps, hd]);
+        slice + page-pool slice ([num_layers, num_pages+1, ps, n_kv * hd]);
         the block table is loop-invariant (all layers share one table)."""
         cfg = self.cfg
         names = ["ln1", "ln2"] + list(self._WEIGHTS)
@@ -775,14 +785,16 @@ class LlamaModel(HybridBlock):
 
     def cache_spec_paged(self, num_pages: int, page_size: int):
         """[(shape, dtype)] for the PAGED KV pool (serve/paging): per-layer
-        decoder k0, v0, ... of [num_pages, n_kv, page_size, hd]; stacked
-        decoder one stacked K and one stacked V of
-        [num_layers, num_pages, n_kv, page_size, hd]. The caller passes
-        the physical count (the engine adds its sink page). Same
+        decoder k0, v0, ... of [num_pages, page_size, n_kv * hd], one row
+        a token (:func:`_paged_attention` says why); stacked decoder one
+        stacked K and one stacked V of
+        [num_layers, num_pages, page_size, n_kv * hd]. The caller passes
+        the physical count (the engine adds its sink page, and donates
+        the pools to every program that writes them). Same
         unsupported-config refusals as :meth:`cache_spec`."""
         self.cache_spec(1, page_size)        # shared pp/MoE/sp refusals
         cfg = self.cfg
-        shp = (num_pages, cfg.num_kv_heads, page_size, cfg.hd)
+        shp = (num_pages, page_size, cfg.num_kv_heads * cfg.hd)
         if cfg.stacked:
             return [((cfg.num_layers,) + shp, cfg.dtype)] * 2
         return [(shp, cfg.dtype)] * (2 * cfg.num_layers)

@@ -78,10 +78,17 @@ Architecture (vLLM-style continuous batching, TPU-static shapes):
   chunk shapes are data/static, never novel avals).
 
 Single-host, single-device engine; params are captured at construction
-(weight updates require a new engine). Pools are carried functionally
-(no donation yet — a TPU deployment would donate the pool buffers); a
-paged step reads only the blocks of pages that its deepest row has
-reached (``stats()``: ``kv_walk_blocks`` of ``kv_table_blocks``).
+(weight updates require a new engine). The paged pools are rows of
+``[pages, page_size, kv_heads * head_dim]`` (``model.cache_spec_paged``)
+and every paged program that writes them is given them: prefill, chunk,
+step, verify, copy and inject donate the pools, so a program updates the
+few rows it writes in the buffer where they lie and returns that buffer
+(``stats()["pool_bytes_in_place"]`` against ``kv_bytes``). Only the engine
+loop touches ``self._pools``: an array read from another thread may have
+been donated since, so page exports and imports run at a tick boundary.
+The contiguous pools are carried functionally. A paged step reads only the
+blocks of pages that its deepest row has reached (``stats()``:
+``kv_walk_blocks`` of ``kv_table_blocks``).
 """
 from __future__ import annotations
 
@@ -282,12 +289,26 @@ class _PendingStep:
     gstate: Any = None
 
 
-def _jit_named(fn, name: str):
+def _jit_named(fn, name: str, donate_argnums=()):
     """``jax.jit(fn)`` under the name of what it is: the device trace's
     program line then reads ``jit_step_b16(...)``, ``jit_prefill_b32(...)``,
-    ``jit_chunk_c128(...)``."""
+    ``jit_chunk_c128(...)``. ``donate_argnums``: the pools, for a program
+    that writes them."""
     fn.__name__ = name
-    return jax.jit(fn)
+    return jax.jit(fn, donate_argnums=donate_argnums)
+
+
+def _alias_bytes(fn, args) -> int:
+    """Bytes of its arguments that the program ``fn`` compiles to updates
+    where they lie (``memory_analysis().alias_size_in_bytes``; 0 where
+    the backend gives no analysis). The compilation is the one the first
+    call would have made: the jit keeps the executable."""
+    try:
+        compiled = (getattr(fn, "_compiled", None)
+                    or fn.lower(*args).compile())
+        return int(compiled.memory_analysis().alias_size_in_bytes)
+    except Exception:
+        return 0
 
 
 #: a tick whose wall time passes this is written to the flight recorder
@@ -449,6 +470,10 @@ class InferenceEngine:
     steady-state serving never consults anything (the
     ``no_recompile()``-clean contract is untouched).
     """
+
+    #: labels (``_get_compiled``) of the programs that write the pools:
+    #: the paged ones are given them, and return them last
+    _POOL_WRITERS = ("prefill", "chunk", "decode", "spec", "copy", "inject")
 
     def __init__(self, model, max_batch_size: int = 8, max_len: int = 256,
                  max_queue_depth: int = 64,
@@ -792,6 +817,13 @@ class InferenceEngine:
             pool_spec = model.cache_spec(self.S, self.L)
             self._pools = tuple(jnp.zeros(s, d) for s, d in pool_spec)
 
+        # what the pools take on the device, which pads a row to whole
+        # tiles (a TPU holds GPT-2 XL's rows of 1,600 lanes as 1,664): the
+        # bytes that memory_analysis() counts, so that
+        # stats()["pool_bytes_in_place"] can equal this
+        self._kv_bytes = sum(int(p.on_device_size_in_bytes())
+                             for p in self._pools)
+
         # host-side per-slot state (mutated only by the engine thread)
         self._slots: List[Optional[_Slot]] = [None] * self.S
         self._tokens = onp.zeros(self.S, onp.int32)
@@ -851,6 +883,9 @@ class InferenceEngine:
         self._prefill_fns: Dict[int, Any] = {}
         self._step_fns: Dict[int, Any] = {}
         self._spec_fns: Dict[int, Any] = {}
+        # stats()["pool_bytes_in_place"]: None until a paged program that
+        # writes the pools is built
+        self._in_place: Optional[int] = None
         # batched scoring (teacher-forced logprobs): its own bucket
         # ladder over the prompt geometry — warmed by warmup_score()
         self._score_fns: Dict[int, Any] = {}
@@ -1328,7 +1363,7 @@ class InferenceEngine:
                 pages=len(entries), tokens=entries[-1][0])
         return encode_kv_pages(toks[:len(phys_pages) * ps], entries)
 
-    def export_pages(self, input_ids) -> dict:
+    def export_pages(self, input_ids, timeout: float = 60.0) -> dict:
         """Export the FULL cached pages of the longest prefix-cache
         match of ``input_ids`` as a migration wire doc
         (kvstore/comm.encode_kv_pages): exact page payloads, each with
@@ -1337,12 +1372,18 @@ class InferenceEngine:
         Pages are read live; call on an engine whose pool is not under
         allocation pressure (the prefill tier streams right after its
         prefill published the pages, when every exported page is pinned
-        by its cache entry)."""
+        by its cache entry). Runs at a tick boundary of the engine loop,
+        like :meth:`import_pages`."""
         self._require_paged()
         toks = self._as_prompt(input_ids)
-        pages, matched = self._pages.match_prefix(toks, count=False)
-        full = min(matched // self.page_size, len(pages))
-        return self._export_entries(toks, [int(p) for p in pages[:full]])
+
+        def export():
+            pages, matched = self._pages.match_prefix(toks, count=False)
+            full = min(matched // self.page_size, len(pages))
+            return self._export_entries(toks,
+                                        [int(p) for p in pages[:full]])
+
+        return self._on_loop(export, timeout, "page export")
 
     def _export_slot_pages(self, s: int, toks: List[int]) -> dict:
         """Preempt-time capture (engine thread): the victim slot's
@@ -1375,50 +1416,77 @@ class InferenceEngine:
         self._require_paged()
         from ..kvstore.comm import decode_kv_pages
         tokens, pages = decode_kv_pages(doc)
-        rec: Dict[str, Any] = {"tokens": tokens, "pages": pages,
-                               "evt": threading.Event(), "result": None,
-                               "error": None}
+        return self._on_loop(
+            lambda: self._apply_page_import(tokens, pages), timeout,
+            "page import")
+
+    def _on_loop(self, work, timeout: Optional[float], what: str):
+        """Run ``work()`` where the pools may be touched, and return what
+        it returns: staged for the next tick boundary of a running engine
+        loop (only the loop owns ``self._pools``; each program that writes
+        them is given them, so a pool read from another thread may be a
+        deleted array by the time it is used), inline on a stopped
+        engine or on the loop's own thread."""
+        rec: Dict[str, Any] = {"work": work, "evt": threading.Event(),
+                               "result": None, "error": None}
         with self._cond:
-            running = self._running
-            if running:
+            staged = (self._running
+                      and threading.current_thread() is not self._thread)
+            if staged:
                 self._page_ops.append(rec)
                 self._cond.notify_all()
-        if not running:
-            self._apply_page_import(rec)
-        elif not rec["evt"].wait(timeout):
-            raise MXNetError("page import timed out waiting for a tick "
+        if not staged:
+            return work()
+        if not rec["evt"].wait(timeout):
+            raise MXNetError(f"{what} timed out waiting for a tick "
                              "boundary")
         if rec["error"]:
             raise MXNetError(rec["error"])
         return rec["result"]
 
     def _apply_page_ops(self):
-        """Engine-loop side: land staged page imports between ticks."""
+        """Engine-loop side: run what :meth:`_on_loop` staged, between
+        ticks."""
         with self._lock:
             ops, self._page_ops = self._page_ops, []
+        failed = None
         for rec in ops:
             try:
-                self._apply_page_import(rec)
+                rec["result"] = rec["work"]()
             except Exception as e:
                 rec["error"] = str(e)
+                failed = failed or e
             finally:
                 rec["evt"].set()
+        if failed is not None:
+            self._pools_lost(failed)
 
     def _fail_page_ops(self):
-        """Crash/shutdown path: wake import waiters with the failure."""
+        """Crash/shutdown path: wake the waiters with the failure."""
         with self._lock:
             ops, self._page_ops = self._page_ops, []
         for rec in ops:
             rec["error"] = rec["error"] or "engine stopped before the " \
-                                           "import landed"
+                                           "staged work ran"
             rec["evt"].set()
 
-    def _apply_page_import(self, rec: Dict[str, Any]):
-        tokens = [int(t) for t in rec["tokens"]]
+    def _pools_lost(self, e: Exception):
+        """After a dispatch failed. A program that writes the pools is
+        given them, so one that failed after it took them has left
+        ``self._pools`` deleted arrays, and every later program would
+        fail on them: raise instead, and the loop's backstop fails every
+        outstanding request with this reason and closes the engine."""
+        if any(p.is_deleted() for p in self._pools):
+            raise MXNetError(
+                "serve: the KV pools were donated to a program that "
+                f"failed ({e!r}); the engine cannot serve on")
+
+    def _apply_page_import(self, tokens, pages) -> Dict[str, Any]:
+        tokens = [int(t) for t in tokens]
         spec = self._page_payload_spec()
         verified: Dict[int, Any] = {}
         failures = 0
-        for ln, key, payload in rec["pages"]:
+        for ln, key, payload in pages:
             ok = (0 < ln <= len(tokens) and ln % self.page_size == 0
                   and prefix_key(tokens[:ln]) == int(key)
                   and len(payload) == len(spec)
@@ -1451,13 +1519,10 @@ class InferenceEngine:
                 "event", "serve.page_import", reason="page_migration",
                 received=len(verified), adopted=adopted,
                 verify_failures=failures)
-        rec["result"] = {"received": len(verified), "adopted": adopted,
-                         "verify_failures": failures,
-                         "skipped_cached": len(verified) - adopted
-                         - (1 if reason else 0) if not reason
-                         else len(verified) - adopted,
-                         "out_of_pages": reason}
-        rec["evt"].set()
+        return {"received": len(verified), "adopted": adopted,
+                "verify_failures": failures,
+                "skipped_cached": len(verified) - adopted,
+                "out_of_pages": reason}
 
     @staticmethod
     def _as_prompt(input_ids) -> List[int]:
@@ -1482,41 +1547,41 @@ class InferenceEngine:
         ``aot.enable``), every ladder executable a previous process
         compiled is deserialized from disk instead — the cold-start
         warmup measured in ``mxnet_aot_warmup_seconds{path=serve}`` drops
-        to IO + dispatch."""
+        to IO + dispatch.
+
+        The paged programs are given the pools they write (donation), so
+        each example runs on the live pools and the engine keeps what it
+        returns: the examples' tables are all-sink and their slot the sink
+        slot, so no page and no state of a request is written. On a
+        running engine the whole ladder runs at a tick boundary of the
+        loop, which owns the pools."""
+        self._on_loop(self._warmup, None, "warmup")
+        return self
+
+    def _warmup(self):
         t0 = time.perf_counter()
         prefill_hi = self._chunk if self._paged else self.L
         for pb in bucket_ladder(self.min_prompt_bucket, prefill_hi,
                                 self._growth):
-            fn = self._get_prefill(pb)
-            out = fn(*self._example_args("prefill", pb))
-            jax.block_until_ready(out[0])
+            self._warm(self._get_prefill(pb), "prefill", pb)
         if self._paged and self._chunk < self.L:
-            out = self._get_chunk()(
-                *self._example_args("chunk", self._chunk))
-            jax.block_until_ready(out[0])
+            self._warm(self._get_chunk(), "chunk", self._chunk)
         if self._paged and self._pages.prefix_cache_enabled:
-            out = self._get_copy()(*self._example_args("copy", 0))
-            jax.block_until_ready(out[0])
+            self._warm(self._get_copy(), "copy", 0)
         if self._paged and not self._stateful:
             # migration executables: warmed so a first preemption rescue
             # or tier page-stream inside steady-state serving hits cached
             # code (the no_recompile() contract with migration enabled).
-            # The inject example writes zeros into the SINK page — live
-            # pools are untouched either way (the result is discarded).
-            out = self._get_extract()(*self._example_args("extract", 0))
-            jax.block_until_ready(out[0])
-            out = self._get_inject()(*self._example_args("inject", 0))
-            jax.block_until_ready(out[0])
+            # The inject example writes zeros into the SINK page.
+            self._warm(self._get_extract(), "extract", 0)
+            self._warm(self._get_inject(), "inject", 0)
         for sb in bucket_ladder(1, self.S):
             # speculative engines decode exclusively through the verify
             # executables — warm those; plain engines warm the step fns
             if self.spec:
-                fn = self._get_spec(sb)
-                out = fn(*self._example_args("spec", sb))
+                self._warm(self._get_spec(sb), "spec", sb)
             else:
-                fn = self._get_step(sb)
-                out = fn(*self._example_args("decode", sb))
-            jax.block_until_ready(out[0])
+                self._warm(self._get_step(sb), "decode", sb)
         self.last_warmup_s = time.perf_counter() - t0
         from .. import aot as _aot
         if _aot.get_cache() is not None:
@@ -1524,7 +1589,17 @@ class InferenceEngine:
             # cache-less warmup must not feed cold/warm dashboards
             _metrics.AOT_WARMUP_SECONDS.labels(path="serve").observe(
                 self.last_warmup_s)
-        return self
+
+    def _warm(self, fn, label: str, bucket: int):
+        """Run one program on its example arguments and wait for it. The
+        pools are the last thing a program returns that writes them (all
+        of it, for a chunk, a copy and an inject): a paged one was given
+        them, so from here on these are the engine's."""
+        out = fn(*self._example_args(label, bucket))
+        if self._paged and label in self._POOL_WRITERS:
+            self._pools = (out if label in ("chunk", "copy", "inject")
+                           else out[-1])
+        jax.block_until_ready(out)
 
     def _example_args(self, label: str, bucket: int):
         """Representative arguments for one bucket executable — what
@@ -1629,11 +1704,11 @@ class InferenceEngine:
                 _metrics.RECOMPILATIONS.labels(block=f"serve_{label}",
                                                kind=kind).inc()
                 fn = builder(bucket)
+                args = self._example_args(label, bucket)
                 from .. import aot as _aot
                 if _aot.get_cache() is not None:
                     fn = _aot.compile_cached(
-                        fn, self._example_args(label, bucket),
-                        label=f"serve_{label}",
+                        fn, args, label=f"serve_{label}",
                         extra={"bucket": bucket, "slots": self.S,
                                "max_len": self.L})
                 else:
@@ -1641,12 +1716,15 @@ class InferenceEngine:
                     # cache on, compile_cached records the same entry
                     # from the lowering it already holds)
                     _perf.capture_build(
-                        f"serve_{label}", fn,
-                        self._example_args(label, bucket),
+                        f"serve_{label}", fn, args,
                         key=f"serve_{label}:b{bucket}",
                         meta={"bucket": bucket, "slots": self.S,
                               "max_len": self.L, "paged": self._paged,
                               "multi_token": self.K})
+                if self._paged and label in self._POOL_WRITERS:
+                    alias = _alias_bytes(fn, args)
+                    self._in_place = (alias if self._in_place is None
+                                      else min(self._in_place, alias))
                 cache[bucket] = fn
             else:
                 _metrics.CACHE_HITS.labels(block=f"serve_{label}").inc()
@@ -1860,7 +1938,7 @@ class InferenceEngine:
                     masks=masks)
                 return toks, acc, new_pools
 
-            return jax.jit(step)
+            return jax.jit(step, donate_argnums=1)
 
         def step(values, pools, inputs, pos, *rest):
             record_launch("spec_verify")
@@ -1914,7 +1992,7 @@ class InferenceEngine:
                                       mask=mask)
             return tok0[0], new_pools
 
-        return _jit_named(prefill, f"prefill_b{pb}")
+        return _jit_named(prefill, f"prefill_b{pb}", donate_argnums=1)
 
     def _build_chunk(self, cs: int):
         """A middle prefill chunk: KV-page writes only (XLA dead-code-
@@ -1928,7 +2006,7 @@ class InferenceEngine:
                                                   rows=rows)
             return new_pools
 
-        return _jit_named(chunk, f"chunk_c{cs}")
+        return _jit_named(chunk, f"chunk_c{cs}", donate_argnums=1)
 
     def _build_step_paged(self, sb: int):
         """Paged decode step: the shared page pools replace the sliced
@@ -1947,7 +2025,7 @@ class InferenceEngine:
                         head=head, block_table=tables)
                 return toks, last, steps, new_pools
 
-            return _jit_named(step, f"step_b{sb}")
+            return _jit_named(step, f"step_b{sb}", donate_argnums=1)
 
         grammar = self._grammar
         stateful = self._stateful
@@ -1979,7 +2057,7 @@ class InferenceEngine:
                 return nxt, ngs, new_pools
             return nxt, new_pools
 
-        return _jit_named(step, f"step_b{sb}")
+        return _jit_named(step, f"step_b{sb}", donate_argnums=1)
 
     def _build_copy(self, _bucket: int):
         """Copy one physical page (COW fork: src's rows into the freshly
@@ -1995,7 +2073,7 @@ class InferenceEngine:
                     p, page, dst, axis=ax))
             return tuple(out)
 
-        return jax.jit(copy)
+        return jax.jit(copy, donate_argnums=0)
 
     def _build_extract(self, _bucket: int):
         """Slice one physical page out of every pool entry (the export
@@ -2019,7 +2097,7 @@ class InferenceEngine:
                 p, q, dst, axis=ax)
                 for p, q, ax in zip(pools, payload, paxes))
 
-        return jax.jit(inject)
+        return jax.jit(inject, donate_argnums=0)
 
     def _build_score(self, pb: int):
         """Batched scoring executable: teacher-forced per-token
@@ -2496,6 +2574,7 @@ class InferenceEngine:
         except Exception as e:  # pragma: no cover - defensive
             warnings.warn(f"serve: paged prefill failed: {e!r}")
             self._retire(s, STATUS_ERROR, error=str(e))
+            self._pools_lost(e)
             return None
         # the whole prompt's KV is live (on the device stream): publish it
         # for future prefix reuse BEFORE decode writes dirty the tail page
@@ -3007,6 +3086,7 @@ class InferenceEngine:
             for s in range(self.S):
                 if self._slots[s] is not None:
                     self._retire(s, STATUS_ERROR, error=str(e))
+            self._pools_lost(e)
             return None
         rec = _PendingStep(nxt=nxt, sb=sb, t0=t0, toks=toks, steps=steps,
                            gstate=ngs, slots=cur)
@@ -3099,6 +3179,7 @@ class InferenceEngine:
             for s in range(self.S):
                 if self._slots[s] is not None:
                     self._retire(s, STATUS_ERROR, error=str(e))
+            self._pools_lost(e)
             return
         try:
             for dev in (toks, acc):
@@ -3468,7 +3549,14 @@ class InferenceEngine:
             # the engine's KV HBM footprint (loadgen's requests/HBM-GB
             # denominator): identical pool bytes, paged vs contiguous,
             # when num_pages defaults to the contiguous layout's size
-            "kv_bytes": sum(int(p.nbytes) for p in self._pools),
+            "kv_bytes": self._kv_bytes,
+            # of those, the bytes that every program built so far that
+            # writes the pools updates where they lie: the least
+            # memory_analysis().alias_size_in_bytes among them. Equal to
+            # kv_bytes when no program copies a pool; 0 for the
+            # contiguous layout (not donated), where the backend gives no
+            # analysis, and before the first program is built
+            "pool_bytes_in_place": self._in_place or 0,
         }
         if self.spec:
             out["spec"] = {

@@ -26,34 +26,36 @@ def gathered_reference(qh, kh, vh, k_pages, v_pages, table, pos, rep):
     """The same write, then ``_attend`` over all ``max_pages`` pages of
     every row as one float32 ``[B, G, L, hd]`` view."""
     B, H, T, hd = qh.shape
-    ps, maxp = k_pages.shape[2], table.shape[1]
+    G = H // rep
+    ps, maxp = k_pages.shape[1], table.shape[1]
     L = maxp * ps
     cols = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
     pg = jnp.take_along_axis(table, jnp.minimum(cols // ps, maxp - 1), axis=1)
     pg = jnp.where(cols < L, pg, k_pages.shape[0] - 1)
-    k_pages = k_pages.at[pg, :, cols % ps, :].set(
-        kh.transpose(0, 2, 1, 3).astype(k_pages.dtype))
-    v_pages = v_pages.at[pg, :, cols % ps, :].set(
-        vh.transpose(0, 2, 1, 3).astype(v_pages.dtype))
-    kf = k_pages[table].transpose(0, 2, 1, 3, 4).reshape(B, G, L, hd)
-    vf = v_pages[table].transpose(0, 2, 1, 3, 4).reshape(B, G, L, hd)
+    # a pool is [pages, ps, G * hd]: one row a token, its heads side by side
+    k_pages = k_pages.at[pg, cols % ps].set(
+        kh.transpose(0, 2, 1, 3).reshape(B, T, G * hd).astype(k_pages.dtype))
+    v_pages = v_pages.at[pg, cols % ps].set(
+        vh.transpose(0, 2, 1, 3).reshape(B, T, G * hd).astype(v_pages.dtype))
+    kf = k_pages[table].reshape(B, L, G, hd).transpose(0, 2, 1, 3)
+    vf = v_pages[table].reshape(B, L, G, hd).transpose(0, 2, 1, 3)
     mask = jnp.arange(L)[None, None, :] <= cols[:, :, None]
     out = _attend(qh, kf.astype(jnp.float32), vf.astype(jnp.float32), mask,
                   rep)
     return out, k_pages, v_pages
 
 
-def make(ps, maxp, depths, T, rep, seed=0, qdtype=jnp.float32):
+def make(ps, maxp, depths, T, rep, seed=0, qdtype=jnp.float32, g=G, hd=HD):
     """Random pools (bf16, as served), a table of distinct pages per row,
     and the T new rows of q/k/v for rows at ``depths``."""
     rng = onp.random.default_rng(seed)
     B = len(depths)
     n = B * maxp
-    pools = [jnp.asarray(rng.standard_normal((n + 1, G, ps, HD)),
+    pools = [jnp.asarray(rng.standard_normal((n + 1, ps, g * hd)),
                          jnp.bfloat16) for _ in range(2)]
     table = jnp.asarray(rng.permutation(n).reshape(B, maxp), jnp.int32)
-    q = jnp.asarray(rng.standard_normal((B, G * rep, T, HD)), qdtype)
-    k, v = (jnp.asarray(rng.standard_normal((B, G, T, HD)), qdtype)
+    q = jnp.asarray(rng.standard_normal((B, g * rep, T, hd)), qdtype)
+    k, v = (jnp.asarray(rng.standard_normal((B, g, T, hd)), qdtype)
             for _ in range(2))
     return q, k, v, pools[0], pools[1], table, jnp.asarray(depths, jnp.int32)
 
@@ -80,6 +82,24 @@ def test_walk_equals_attend_over_the_gathered_view(name, rep):
     out, kp, vp = paged(*args, rep)
     want, kp_w, vp_w = reference(*args, rep)
     assert out.dtype == jnp.float32
+    onp.testing.assert_allclose(onp.asarray(out), onp.asarray(want),
+                                rtol=2e-5, atol=2e-6)
+    assert bool((kp == kp_w).all()) and bool((vp == vp_w).all())
+
+
+@pytest.mark.parametrize("g, hd, rep, T", [(5, 40, 3, 1), (5, 40, 3, 4),
+                                           (25, 64, 1, 1), (2, 128, 4, 16)],
+                         ids=["grouped-200-lanes", "grouped-200-lanes-T4",
+                              "xl-1600-lanes", "grouped-256-lanes-chunk"])
+def test_row_width_need_not_be_whole_lane_tiles(g, hd, rep, T):
+    """A pool's row is ``G * hd`` lanes whatever that comes to: grouped
+    heads whose product is no multiple of 128 (200), GPT-2 XL's 1,600, and
+    one that is (256)."""
+    args = make(16, 16, [7, 129, 230], T, rep, seed=3, g=g, hd=hd)
+    assert args[3].shape == (3 * 16 + 1, 16, g * hd)
+    out, kp, vp = paged(*args, rep)
+    want, kp_w, vp_w = reference(*args, rep)
+    assert kp.shape == args[3].shape and out.shape == args[0].shape
     onp.testing.assert_allclose(onp.asarray(out), onp.asarray(want),
                                 rtol=2e-5, atol=2e-6)
     assert bool((kp == kp_w).all()) and bool((vp == vp_w).all())

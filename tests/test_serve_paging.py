@@ -408,6 +408,219 @@ def test_preemption_resume_is_exact(gpt_model):
         assert list(r.generated_ids) == _reference(gpt_model, p, 18)
 
 
+# ------------------------------------------- pools in place (PR 31)
+def _tiny_llama(stacked):
+    mx.random.seed(0)
+    net = LlamaForCausalLM(LlamaConfig(
+        vocab_size=32, hidden_size=32, intermediate_size=64, num_layers=2,
+        num_heads=4, num_kv_heads=2, dtype=onp.float32, stacked=stacked))
+    net.initialize()
+    return net
+
+
+@pytest.mark.parametrize("stacked", [False, True],
+                         ids=["per-layer", "stacked"])
+def test_pool_is_rows_of_all_kv_heads(stacked):
+    """One row a token, ``kv_heads * head_dim`` wide (16 here: grouped
+    heads, no multiple of 128 lanes); the stacked decoder puts its layers
+    in front and the engine still finds the page axis."""
+    net = _tiny_llama(stacked)
+    spec = net.cache_spec_paged(5, 8)
+    lead = (2,) if stacked else ()
+    assert [s for s, _ in spec] == [lead + (5, 8, 2 * 8)] * (
+        2 if stacked else 4)
+    eng = InferenceEngine(net, max_batch_size=2, max_len=32, paged=True,
+                          page_size=8, num_pages=6)
+    assert eng._paxes == [len(lead)] * len(spec)
+    assert eng._pools[0].shape == lead + (7, 8, 16)
+    assert [a.shape for a in eng._page_payload_spec()] == [
+        lead + (1, 8, 16)] * len(spec)
+
+
+@pytest.mark.parametrize("stacked", [False, True],
+                         ids=["per-layer", "stacked"])
+def test_paged_llama_grouped_heads_match_generate(stacked):
+    """Grouped K/V heads through the row-shaped pools, per layer and under
+    the stacked decoder's scan: greedy tokens of ``generate()``."""
+    net = _tiny_llama(stacked)
+    prompts = _prompts(3, vocab=30, seed=2)
+    served = _serve_all(net, prompts, 6, max_batch_size=2, max_len=32,
+                        paged=True, page_size=8)
+    assert served == [_reference(net, p, 6) for p in prompts]
+
+
+IN_PLACE_ENGINES = {
+    "plain": dict(),
+    "chunked": dict(prefill_chunk=8, max_len=64),
+    "multi-token-2": dict(multi_token=2),
+    "speculate-4": dict(speculate=4),
+    "grammar": dict(grammar=True),
+    "stacked-llama": dict(),
+}
+
+
+@pytest.mark.parametrize("name", list(IN_PLACE_ENGINES))
+def test_every_program_updates_the_pools_in_place(gpt_model, name):
+    """After ``warmup()`` every program that writes the pools has been
+    built, and the least of their aliased bytes is all of the pools': no
+    program copies a pool. The examples ran on the live pools, which the
+    programs were given: the engine holds what they returned."""
+    net = _tiny_llama(True) if name == "stacked-llama" else gpt_model
+    kw = dict(max_batch_size=2, max_len=32, page_size=8)
+    kw.update(IN_PLACE_ENGINES[name])
+    eng = InferenceEngine(net, paged=True, **kw)
+    assert eng.stats()["pool_bytes_in_place"] == 0      # nothing built yet
+    given = eng._pools
+    eng.warmup()
+    stats = eng.stats()
+    if given[0].is_deleted():       # the backend donates (the CPU's does)
+        assert stats["pool_bytes_in_place"] == stats["kv_bytes"] > 0
+    assert not any(p.is_deleted() for p in eng._pools)
+    # the examples wrote the sink page only
+    for p, ax in zip(eng._pools, eng._paxes):
+        live = onp.take(onp.asarray(p, onp.float32),
+                        range(p.shape[ax] - 1), axis=ax)
+        assert not live.any()
+
+
+def test_contiguous_pools_are_not_donated(gpt_model):
+    eng = InferenceEngine(gpt_model, max_batch_size=2, max_len=32,
+                          paged=False)
+    given = eng._pools
+    eng.warmup()
+    assert not any(p.is_deleted() for p in given)
+    assert eng.stats()["pool_bytes_in_place"] == 0
+
+
+@pytest.mark.parametrize("running", [False, True],
+                         ids=["stopped", "running"])
+def test_second_warmup_then_fork_and_inject_serve_identically(gpt_model,
+                                                              running):
+    """``warmup()`` twice (on a running engine the ladder runs on the
+    loop, between ticks), then a COW fork of a shared prefix and an
+    injected page: no program is handed an array that an earlier one
+    was given, and the tokens are ``generate()``'s."""
+    rng = onp.random.RandomState(5)
+    shared = rng.randint(1, 30, size=16).astype(onp.int32)    # two pages
+    a = onp.concatenate([shared, rng.randint(1, 30, size=3)]).astype(
+        onp.int32)
+    b = onp.concatenate([shared, rng.randint(1, 30, size=5)]).astype(
+        onp.int32)
+    kw = dict(max_batch_size=2, max_len=48, paged=True, page_size=8)
+    src = InferenceEngine(gpt_model, **kw)
+    dst = InferenceEngine(gpt_model, **kw)
+    if running:
+        src.start(), dst.start()
+    src.warmup()
+    src.warmup()
+    dst.warmup()
+    if not running:
+        src.start(), dst.start()
+    try:
+        ra = src.generate(a, 6)
+        src.warmup()                        # mid-life, pages leased
+        rb = src.generate(b, 6)             # maps the prefix, forks its tail
+        assert src.stats()["pages"]["prefix_hits"] >= 1
+        doc = src.export_pages(a)
+        got = dst.import_pages(doc)
+        assert got["adopted"] >= 2 and got["verify_failures"] == 0
+        dst.warmup()
+        rc = dst.generate(a, 6)
+        assert dst.stats()["pages"]["prefix_hits"] >= 1
+    finally:
+        src.shutdown()
+        dst.shutdown()
+    assert list(ra.generated_ids) == _reference(gpt_model, a, 6)
+    assert list(rb.generated_ids) == _reference(gpt_model, b, 6)
+    assert list(rc.generated_ids) == list(ra.generated_ids)
+
+
+def test_a_program_that_fails_with_the_pools_closes_the_engine(gpt_model):
+    """A dispatch that fails after the program took the pools leaves
+    deleted arrays behind: the engine fails its requests with that
+    reason and closes; it does not serve on."""
+    eng = InferenceEngine(gpt_model, max_batch_size=2, max_len=32,
+                          paged=True, page_size=8).start()
+    try:
+        assert eng.generate(onp.arange(1, 6), 3).status == "ok"
+        real = eng._get_step(1)
+
+        def takes_the_pools_and_fails(values, pools, *rest):
+            for p in pools:
+                p.delete()
+            raise RuntimeError("injected failure after donation")
+
+        eng._step_fns[1] = takes_the_pools_and_fails
+        with pytest.warns(UserWarning):
+            r = eng.submit(onp.arange(1, 6), 3).result(120)
+        assert r.status == "error" and "injected failure" in r.error
+        eng._step_fns[1] = real
+        deadline = time.time() + 30
+        while eng.stats()["running"] and time.time() < deadline:
+            time.sleep(0.01)
+        assert not eng.stats()["running"]
+        with pytest.raises(mx.MXNetError):
+            eng.submit(onp.arange(1, 6), 3)
+    finally:
+        eng.shutdown()
+
+
+# greedy tokens of the tiny GPT (``gpt_model``), as the parent commit of
+# PR 31 served them from pools of [pages, heads, page_size, head_dim]
+PARENT_TOKENS = {
+    "prefill-decode": (
+        (4, 3, 13, 0), 10, dict(max_batch_size=4, max_len=64),
+        [[22, 22, 22, 28, 6, 6, 6, 6, 6, 6],
+         [25, 25, 25, 25, 25, 25, 25, 25, 25, 13],
+         [15, 15, 12, 12, 12, 28, 28, 28, 6, 6],
+         [17, 7, 7, 13, 13, 13, 13, 13, 13, 13]]),
+    "chunked-prefill": (
+        (3, 30, 50, 1), 8,
+        dict(max_batch_size=2, max_len=96, prefill_chunk=16),
+        [[21, 21, 21, 1, 1, 22, 22, 22], [24, 16, 16, 16, 16, 16, 16, 16],
+         [1, 1, 1, 22, 22, 22, 22, 22]]),
+    "speculate-4": (
+        (3, 5, 20, 2), 12, dict(max_batch_size=4, max_len=64, speculate=4),
+        [[22, 13, 13, 13, 13, 13, 13, 13, 13, 13, 13, 13],
+         [0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 16, 16],
+         [19, 19, 19, 5, 5, 5, 5, 5, 5, 5, 16, 16]]),
+    "multi-token-2": (
+        (3, 5, 20, 4), 9, dict(max_batch_size=4, max_len=64, multi_token=2),
+        [[16, 16, 16, 16, 16, 16, 16, 16, 16],
+         [13, 13, 13, 13, 13, 13, 13, 13, 13],
+         [22, 22, 22, 22, 22, 22, 22, 22, 28]]),
+    "preemption-resume": (
+        (3, 18, 19, 3), 18,
+        dict(max_batch_size=2, max_len=64, num_pages=8, prefix_cache=False),
+        [[11, 11, 11, 11, 11, 11, 11, 11, 0, 16, 16, 16, 16, 16, 16, 16, 16,
+          16],
+         [15, 15, 15, 1, 1, 1, 1, 1, 1, 6, 6, 22, 22, 22, 22, 22, 16, 16],
+         [11, 11, 11, 11, 11, 11, 11, 11, 0, 16, 16, 16, 16, 16, 16, 16, 16,
+          16]]),
+}
+
+
+@pytest.mark.parametrize("path", list(PARENT_TOKENS))
+def test_greedy_tokens_are_the_parents(gpt_model, path):
+    """The pool's shape and the donation change where bytes lie, not one
+    product or sum: every serving path emits the parent's tokens."""
+    (n, lo, hi, seed), new, kw, want = PARENT_TOKENS[path]
+    rng = onp.random.RandomState(seed)
+    prompts = [rng.randint(1, 30, size=rng.randint(lo, hi)).astype(onp.int32)
+               for _ in range(n)]
+    eng = InferenceEngine(gpt_model, paged=True, page_size=8, **kw).start()
+    try:
+        handles = [eng.submit(p, new, seed=i) for i, p in enumerate(prompts)]
+        results = [h.result(300) for h in handles]
+        stats = eng.stats()
+    finally:
+        eng.shutdown()
+    assert [r.status for r in results] == ["ok"] * n
+    assert [list(r.generated_ids) for r in results] == want
+    if path == "preemption-resume":
+        assert stats["preemptions"] > 0
+
+
 @pytest.mark.slow
 def test_page_accounting_clean_after_mixed_traffic(gpt_model):
     """After deadline/cancel/success churn the pool must hold ZERO leased

@@ -245,3 +245,83 @@ def test_lightning_attention_compiles(one_chip, rows, T):
         lambda q, k, v, pool, slots, pos, valid: la.lightning_attention_slots(
             q, k, v, pool, slots, pos, la.decay_slopes(32, 16, 32), valid),
         x, x, x, _s(one_chip, (17, 32, 128, 128), jnp.float32), i, i, i)
+
+
+# ------------------------------- the KV pools stay where they lie (PR 31)
+# the chat cell's geometry (16 slots, 320 pages of 16 + the sink, chunks of
+# 128) at two row widths: GPT-2-small's 768 lanes, six whole tiles of 128,
+# and GPT-2 XL's 1,600, twelve and a half
+_POOL_WIDTHS = {"small_768": (768, 12), "xl_1600": (1600, 25)}
+
+
+@pytest.fixture(scope="module")
+def pool_engines():
+    """One single-layer paged engine a width, made on the CPU: its builders
+    give the programs that the described chip compiles."""
+    from mxnet_tpu.models import GPTModel
+    from mxnet_tpu.models.gpt import GPTConfig
+    from mxnet_tpu.serve import InferenceEngine
+    made = {}
+
+    def engine(width):
+        if width not in made:
+            d, heads = _POOL_WIDTHS[width]
+            net = GPTModel(GPTConfig(
+                vocab_size=2048, hidden_size=d, num_layers=1,
+                num_heads=heads, max_position_embeddings=1024, dropout=0.0,
+                dtype="bfloat16"))
+            net.initialize()
+            made[width] = InferenceEngine(
+                net, max_batch_size=16, max_len=1024, paged=True,
+                page_size=16, num_pages=320, prefill_chunk=128)
+        return made[width]
+
+    return engine
+
+
+def _opcode(line):
+    """The opcode of one instruction of an optimized HLO text, and the
+    text of its result's shape."""
+    import re
+    m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\(.*?\)|\S+) ([\w\-]+)\(",
+                 line)
+    return (m.group(2), m.group(1)) if m else (None, "")
+
+
+@pytest.mark.parametrize("program,bucket",
+                         [("decode", 8), ("prefill", 64)],
+                         ids=["step_b8", "prefill_b64"])
+@pytest.mark.parametrize("width", list(_POOL_WIDTHS))
+def test_pools_are_written_in_place(one_chip, pool_engines, width, program,
+                                    bucket):
+    """A decode step of 8 rows and a final prefill of 64 tokens, the pools
+    donated: the chip keeps a ``[321, 16, kv_heads * head_dim]`` pool as
+    declared, no instruction copies one, and every pool that goes in comes
+    out as the same buffer. With ``[321, heads, 16, 64]`` pools the same
+    programs relaid every pool out and back, donated or not: two thirds of
+    a step (PERF.md section 6, PR 31). A CPU run cannot see this."""
+    import re
+    eng = pool_engines(width)
+    build = (eng._build_step_paged if program == "decode"
+             else eng._build_prefill_paged)
+    compiled = build(bucket).lower(
+        *_shapes(eng._example_args(program, bucket), one_chip)).compile()
+    text = compiled.as_text()
+    d = _POOL_WIDTHS[width][0]
+    pool = f"bf16[321,16,{d}]"
+    assert eng._pools[0].shape == (321, 16, d) and len(eng._pools) == 2
+    # as declared: rows of d lanes, minor-most
+    entry = re.search(r"entry_computation_layout=\{\((.*?)\)->", text).group(1)
+    assert re.findall(re.escape(pool) + r"\{([\d,]*)", entry) == ["2,1,0"] * 2
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if _opcode(line)[0] in ("copy", "copy-start", "copy-done")
+              and pool in _opcode(line)[1]]
+    assert not copies
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry_computation",
+                        text).group(1)
+    assert aliased.count("alias") == 2
+    # the bytes of both pools as the chip holds them (1,600 lanes pad to
+    # 1,664), which is what stats()["pool_bytes_in_place"] reports there
+    lanes = -(-d // 128) * 128
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == 2 * 321 * 16 * lanes * 2
