@@ -379,7 +379,7 @@ def bench_spec_decode(speculate: int = 6, trials: int = 5):
         # explicit speculate (even 0): a tuned serve_speculate winner
         # must not silently re-enable speculation in the baseline sweep
         eng = InferenceEngine(net, max_batch_size=2, max_len=128,
-                              paged=True, page_size=16,
+                              page_size=16,
                               speculate=spec).start()
         eng.warmup()
         times, outs = [], None
@@ -461,7 +461,7 @@ def bench_grammar_decode(speculate: int = 4, trials: int = 5):
 
     def sweep(grammar):
         eng = InferenceEngine(net, max_batch_size=2, max_len=128,
-                              paged=True, page_size=16,
+                              page_size=16,
                               speculate=speculate,
                               grammar=grammar is not None).start()
         eng.warmup()
@@ -513,7 +513,7 @@ def bench_grammar_decode(speculate: int = 4, trials: int = 5):
         "n": {"enum": [0, 1, 2]}}}
     g = compile_grammar(schema, 256)
     eng = InferenceEngine(net, max_batch_size=2, max_len=128,
-                          paged=True, page_size=16, speculate=speculate,
+                          page_size=16, speculate=speculate,
                           grammar=True).start()
     eng.warmup()
     try:
@@ -574,7 +574,7 @@ def bench_prefix_affinity(replicas: int = 4):
         max_len=256, max_new_tokens=1, temperature=0.0, top_k=0,
         top_p=1.0, concurrency=16, requests=5, shared_prefix=240,
         prompt_min=1, prompt_max=8, multi_token=1, speculate=0,
-        spec_lookup=None, max_batch_size=16, paged=True, page_size=16,
+        spec_lookup=None, max_batch_size=16, page_size=16,
         num_pages=320, prefill_chunk=None, no_prefix_cache=False,
         fleet_replicas=replicas, fleet_workers=2)
     prompts = lg.make_tenant_prompts(args)

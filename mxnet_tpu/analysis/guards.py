@@ -32,7 +32,7 @@ production can observe without crashing.
 
 Debug wiring: ``MXNET_DEBUG_GUARDS=1`` (or :func:`enable_debug`) makes
 ``make_lock`` return witness locks and turns on the alias sentinel inside
-``DevicePrefetcher`` and the serve engine's per-slot staging buffers.
+``DevicePrefetcher``.
 The disabled path is a plain ``threading.Lock`` and ``None`` sentinels —
 zero overhead in production.
 """
@@ -76,7 +76,7 @@ class LockOrderError(GuardViolation):
 _DEBUG = bool(get_env(
     "MXNET_DEBUG_GUARDS", False, dtype=bool,
     doc="enable runtime hazard guards: witness locks, alias sentinels on "
-        "prefetcher/serve staging buffers"))
+        "prefetcher staging buffers"))
 
 
 def debug_guards_enabled() -> bool:

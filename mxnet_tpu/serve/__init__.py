@@ -5,7 +5,7 @@ bucketed executables (zero recompiles after warmup), admission control
 (bounded queue, deadlines, cancellation, graceful drain), full telemetry,
 and a stdlib HTTP frontend. See ``engine.py`` for the architecture.
 
-Paged KV mode (the TPU default; ``paged=True`` anywhere) leases
+The engine's one cache layout is paged KV, on every backend: it leases
 fixed-size cache pages per slot on demand (`paging.py` PagePool ledger)
 with copy-on-write shared-prefix caching and chunked prefill; fused
 block decode composes with it (the kernel addresses KV through the
@@ -47,7 +47,7 @@ Quickstart::
     from mxnet_tpu.serve import InferenceEngine, HTTPFrontend, Router
 
     engine = InferenceEngine(model, max_batch_size=8, max_len=256,
-                             paged=True, page_size=16)
+                             page_size=16)
     engine.start(); engine.warmup()
     res = engine.generate([1, 2, 3], max_new_tokens=16)   # in-process
     HTTPFrontend(engine, port=8000).start()               # or over HTTP

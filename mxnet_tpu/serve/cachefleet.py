@@ -166,7 +166,7 @@ def install_preempt_rescue(engine: InferenceEngine,
     def hook(src: InferenceEngine, req, doc: dict) -> bool:
         try:
             cands = [e for e in (peers() if callable(peers) else peers)
-                     if e is not src and e._paged and e._running
+                     if e is not src and e._running
                      and not e._draining]
             if not cands:
                 _metrics.MIGRATE_RESCUES.labels(outcome="failed").inc()

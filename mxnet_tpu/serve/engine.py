@@ -8,15 +8,13 @@ keeping a single static-shape executable hot, not from per-request graphs).
 
 Architecture (vLLM-style continuous batching, TPU-static shapes):
 
-- **Slots.** The engine owns ``max_batch_size`` KV-cache slots, allocated
-  as one pooled cache per ``model.cache_spec(max_batch_size, max_len)``
-  entry (batch axis inferred by diffing cache_spec(1)/cache_spec(2), so
-  per-layer AND stacked-scan cache layouts both work). A request occupies
-  one slot from prefill to completion; finished slots are refilled from
-  the queue *mid-flight* — the batch never drains to refill.
+- **Slots.** The engine owns ``max_batch_size`` slots: rows of the decode
+  batch, each with a block table into the page pools (below). A request
+  occupies one slot from prefill to completion; finished slots are
+  refilled from the queue *mid-flight* — the batch never drains to refill.
 - **Prefill** runs per-request at batch 1 over a power-of-two
   prompt-length bucket (right-padded; pad rows are masked/overwritten so
-  they never contaminate attention), writes the slot's cache, and samples
+  they never contaminate attention), writes the slot's pages, and samples
   token0 (time-to-first-token).
 - **Decode** advances ALL active slots one token per step with a single
   executable: per-slot positions (models accept vector ``pos``), per-slot
@@ -40,23 +38,23 @@ Architecture (vLLM-style continuous batching, TPU-static shapes):
   backpressure), per-request deadlines (expired requests complete with
   whatever tokens they have — partial output), cancellation, and graceful
   shutdown that drains in-flight slots.
-- **Paged KV mode** (``paged=True``; the default on TPU): the per-slot
-  contiguous ``max_len`` cache regions are replaced by one pooled cache
-  of fixed-size pages (``model.cache_spec_paged``) plus a host-side
-  :class:`~mxnet_tpu.serve.paging.PagePool` ledger. Slots lease pages on
-  demand as their decode position advances — a request costs its ACTUAL
-  length in HBM, so the same pool bytes carry several times more
-  concurrent requests. On top of paging: (a) a copy-on-write
-  shared-prefix cache (repeated system prompts map their cached prefix
-  pages instead of re-prefilling; a write into a shared page forks it
-  first), (b) chunked prefill (long prompts split into
-  ``prefill_chunk``-token chunks interleaved with decode steps, so one
-  long prompt no longer stalls every in-flight request's next token),
+- **Paged KV, the engine's one cache layout**, on every backend: one
+  pooled cache of fixed-size pages (``model.cache_spec_paged``) plus a
+  host-side :class:`~mxnet_tpu.serve.paging.PagePool` ledger. Slots lease
+  pages on demand as their decode position advances — a request costs
+  its ACTUAL length in HBM, not a reserved ``max_len`` region. On top of
+  paging: (a) a copy-on-write shared-prefix cache (repeated system
+  prompts map their cached prefix pages instead of re-prefilling; a
+  write into a shared page forks it first), (b) chunked prefill (long
+  prompts split into ``prefill_chunk``-token chunks interleaved with
+  decode steps, so one long prompt no longer stalls every in-flight
+  request's next token),
   and (c) preemption (pool exhaustion releases + requeues the youngest
   slot; the stateless per-request sampling streams make the resume
-  exact). The contiguous path is kept verbatim (``paged=False``, the
-  off-TPU default) as the parity reference: paged greedy decode is
-  token-identical to it (tests/test_serve_paging.py).
+  exact). The contiguous cache (``cache_spec``/``forward_cached``) is
+  what ``models.generate`` decodes through, and the reference the
+  parity tests hold the engine to: greedy decode is token-identical to
+  it (tests/test_serve_paging.py). Here only ``score()`` traces one.
 - **Self-speculative decoding** (``speculate=K``): decode proceeds in
   draft-verify rounds — K-1 tokens drafted from the request's own token
   history (n-gram prompt lookup, serve/speculate.py; no draft model),
@@ -66,29 +64,28 @@ Architecture (vLLM-style continuous batching, TPU-static shapes):
   stateless ``fold_in`` sampling keys), so acceptance is plain equality
   and output is token-identical to ``speculate=0`` — greedy AND
   sampled. Each round is one host round-trip for 1..K true tokens;
-  acceptance/rounds ride ``mxnet_spec_*``. Composes with paging, fused
+  acceptance/rounds ride ``mxnet_spec_*``. Composes with fused
   decode, prefix COW and chunked prefill.
 - **Telemetry.** queue wait / TTFT / inter-token / step latency
   histograms, slot-occupancy + tokens/sec gauges, per-bucket compile
-  counters, and in paged mode the ``mxnet_serve_page_*`` family (pages
-  in use, prefix hits/tokens/bytes saved, COW forks, prefill chunks,
-  preemptions). ``mxnet_serve_compiles_total`` /
+  counters, and the ``mxnet_serve_page_*`` family (pages in use, prefix
+  hits/tokens/bytes saved, COW forks, prefill chunks, preemptions).
+  ``mxnet_serve_compiles_total`` /
   ``mxnet_recompilations_total{block=serve_*}`` stay zero after warmup —
-  the shape-bucketing contract holds in both layouts (block tables and
-  chunk shapes are data/static, never novel avals).
+  the shape-bucketing contract (block tables and chunk shapes are
+  data/static, never novel avals).
 
 Single-host, single-device engine; params are captured at construction
-(weight updates require a new engine). The paged pools are rows of
+(weight updates require a new engine). The pools are rows of
 ``[pages, page_size, kv_heads * head_dim]`` (``model.cache_spec_paged``)
-and every paged program that writes them is given them: prefill, chunk,
+and every program that writes them is given them: prefill, chunk,
 step, verify, copy and inject donate the pools, so a program updates the
 few rows it writes in the buffer where they lie and returns that buffer
 (``stats()["pool_bytes_in_place"]`` against ``kv_bytes``). Only the engine
 loop touches ``self._pools``: an array read from another thread may have
 been donated since, so page exports and imports run at a tick boundary.
-The contiguous pools are carried functionally. A paged step reads only the
-blocks of pages that its deepest row has reached (``stats()``:
-``kv_walk_blocks`` of ``kv_table_blocks``).
+A step reads only the blocks of pages that its deepest row has reached
+(``stats()``: ``kv_walk_blocks`` of ``kv_table_blocks``).
 """
 from __future__ import annotations
 
@@ -108,7 +105,6 @@ from .. import metrics as _metrics
 from .. import profiler as _profiler
 from ..analysis import guards as _guards
 from ..base import MXNetError, logger
-from ..device import on_tpu
 from ..models import generation as _gen
 from ..models import llama as _llama
 from ..observability import perf as _perf
@@ -183,7 +179,7 @@ class RequestHandle:
         self.submit_t = time.perf_counter()
         self.admit_t: Optional[float] = None
         self.first_token_t: Optional[float] = None
-        # tokens generated before a preemption (paged engine resume)
+        # tokens generated before a preemption (resume)
         self._resume: Optional[List[int]] = None
         # request span tree (observability.trace): root + currently-open
         # phase spans; None while tracing is disabled (the per-token
@@ -256,7 +252,7 @@ class _Slot:
 
 @dataclasses.dataclass
 class _Prefill:
-    """Chunked-prefill progress for one paged slot. ``ids`` is the full
+    """Chunked-prefill progress for one slot. ``ids`` is the full
     token sequence to prefill (prompt, plus already-generated tokens when
     resuming a preempted request); ``cursor`` is the next position to
     write (starts past the mapped prefix-cache pages); ``counter0`` is
@@ -338,9 +334,9 @@ class _TickSpan(_profiler.scope):
 
 
 class InferenceEngine:
-    """Continuous-batching serving engine for a KV-cache-capable causal LM
-    (``cache_spec``/``forward_cached`` protocol — GPT and Llama families,
-    including stacked-scan decoders).
+    """Continuous-batching serving engine for a causal LM that speaks the
+    paged KV protocol (``cache_spec_paged``/``forward_cached_paged`` — GPT
+    and Llama families, including stacked-scan decoders).
 
     **Recurrent state beside the pages.** A model whose layers keep a
     per-request state of fixed size (linear attention, a convolution) next
@@ -372,7 +368,7 @@ class InferenceEngine:
     built): ``prefix_cache=True`` (shared pages carry a prefix's keys and
     values, not the state it left; pass ``prefix_cache=False``),
     ``speculate`` and ``multi_token > 1`` (a rejected or surplus position
-    has already moved the state), ``paged=False``, and page migration
+    has already moved the state), and page migration
     (COW ``copy``, ``export_pages``/``import_pages``, the preemption-rescue
     hook: a request's pages alone do not resume it).
 
@@ -406,18 +402,17 @@ class InferenceEngine:
         budget). When the model carries an int8 tied LM head
         (quantize_net), sampling fuses into the head GEMV
         (ops/fused_block_gemv.fused_lm_head_sample).
-    paged : lease fixed-size KV pages on demand instead of reserving a
-        contiguous ``max_len`` region per slot (module docstring).
-        Default ``None`` resolves to True on TPU, False elsewhere —
-        the contiguous path stays the off-TPU parity reference.
-    page_size : tokens per KV page (paged mode); ``max_len`` must be a
-        multiple of it
-    num_pages : leasable pages in the pool. Default sizes the pool to
-        the contiguous layout's footprint
-        (``max_batch_size * max_len / page_size``) — same HBM, several
-        times the concurrency when requests are shorter than max_len.
-    prefix_cache : publish/match shared prompt prefixes (paged mode)
-    prefill_chunk : tokens per prefill chunk (paged mode). Prompts
+    paged : vestigial. The engine has one cache layout, pages (module
+        docstring); ``None`` and ``True`` mean that, ``False`` raises
+        (``models.generate`` is the contiguous reference). Kept while
+        the benchmark's rehearsal cells pass it (``ROADMAP.md`` D13).
+    page_size : tokens per KV page; ``max_len`` must be a multiple of it
+    num_pages : leasable pages in the pool. Default
+        ``max_batch_size * max_len / page_size``: every slot can reach
+        ``max_len`` at once; requests shorter than that leave pages for
+        the prefix cache.
+    prefix_cache : publish/match shared prompt prefixes
+    prefill_chunk : tokens per prefill chunk. Prompts
         longer than this are prefilled one chunk per engine tick,
         interleaved with decode steps. Default = one page; pass
         ``max_len`` to disable chunking.
@@ -433,7 +428,7 @@ class InferenceEngine:
         greedy AND sampled requests — speculation changes latency,
         never content. Each round is one host round-trip emitting 1..K
         true tokens; acceptance rides ``mxnet_spec_*``. Composes with
-        paging, fused decode, COW prefix sharing and chunked prefill;
+        fused decode, COW prefix sharing and chunked prefill;
         mutually exclusive with ``multi_token > 1`` (both own the
         decode dispatch). Wrong drafts cost only the (overlapped)
         verify compute: repetitive/structured traffic accepts most
@@ -454,7 +449,7 @@ class InferenceEngine:
         every grammar — zero steady-state recompiles). Unconstrained
         requests on a grammar engine carry identity tables and batch
         with constrained ones. Mutually exclusive with
-        ``multi_token > 1``; composes with paging, speculation
+        ``multi_token > 1``; composes with speculation
         (drafts are pre-constrained host-side, the verify masks every
         draft position) and streaming.
 
@@ -472,7 +467,7 @@ class InferenceEngine:
     """
 
     #: labels (``_get_compiled``) of the programs that write the pools:
-    #: the paged ones are given them, and return them last
+    #: they are given them, and return them last
     _POOL_WRITERS = ("prefill", "chunk", "decode", "spec", "copy", "inject")
 
     def __init__(self, model, max_batch_size: int = 8, max_len: int = 256,
@@ -495,6 +490,12 @@ class InferenceEngine:
             raise MXNetError("max_batch_size must be >= 1")
         if max_len < 2:
             raise MXNetError("max_len must be >= 2")
+        if paged is False:
+            raise MXNetError(
+                "paged must be None or True: the engine has one cache "
+                "layout, pages, on every backend; the contiguous cache is "
+                "models.generate's, the reference the parity tests hold "
+                "the engine to")
         # tuned-config consult: one lookup keyed on this engine's
         # workload context; every knob left None resolves env > tuned >
         # hand-picked default (tune/config.py resolution contract)
@@ -510,10 +511,20 @@ class InferenceEngine:
         mt_explicit = multi_token is not None
         multi_token = _tuneconf.resolve(
             "serve_multi_token", multi_token, _tuned)
-        page_tuned = page_size is None
+        page_explicit = page_size is not None
         page_size = _tuneconf.resolve("serve_page_size", page_size, _tuned)
-        page_tuned = page_tuned and \
-            page_size != _tuneconf.knob_default("serve_page_size")
+        if not page_explicit and max_len % page_size:
+            # a tuned/env page size measured at another max_len must not
+            # brick a default-constructed engine (the PR-13 contract); an
+            # explicit one that does not divide is PagePool's error
+            fallback = _tuneconf.knob_default("serve_page_size")
+            if fallback != page_size:
+                warnings.warn(
+                    f"serve: tuned serve_page_size={page_size} does not "
+                    f"divide max_len={max_len}; using the default "
+                    f"{fallback} — re-tune page size for this geometry or "
+                    "pass page_size explicitly")
+                page_size = fallback
         self._growth = _tuneconf.resolve(
             "serve_bucket_growth", bucket_growth, _tuned)
         if self._growth < 2:
@@ -523,7 +534,7 @@ class InferenceEngine:
             raise MXNetError("bucket_growth must be >= 2")
         if prefill_chunk is None:
             # serve_prefill_chunk's 0 default = the engine's legacy
-            # derivation (one page), applied below in the paged branch;
+            # derivation (one page), applied below;
             # an EXPLICIT 0 is not collapsed — it still fails the >= 1
             # validation loudly
             prefill_chunk = _tuneconf.resolve(
@@ -590,14 +601,19 @@ class InferenceEngine:
             raise MXNetError("spec_lookup must be >= 1")
         if min_prompt_bucket < 1 or min_prompt_bucket & (min_prompt_bucket - 1):
             raise MXNetError("min_prompt_bucket must be a power of two")
-        # a model with recurrent state beside its pages speaks the paged
-        # protocol only (cache_spec_paged / cache_spec_state /
-        # forward_cached_paged with each row's slot): class docstring
+        # a model with recurrent state beside its pages adds
+        # cache_spec_state, and forward_cached_paged takes each row's
+        # slot: class docstring
         self._stateful = hasattr(model, "cache_spec_state")
         if not (self._stateful or _gen._can_cache(model)):
             raise MXNetError(
                 "InferenceEngine requires the KV-cache decode protocol "
                 "(cache_spec/forward_cached) and a config that supports it")
+        if not (hasattr(model, "cache_spec_paged")
+                and hasattr(model, "forward_cached_paged")):
+            raise MXNetError(
+                "InferenceEngine requires the paged KV protocol "
+                "(cache_spec_paged/forward_cached_paged)")
         max_pos = getattr(getattr(model, "cfg", None),
                           "max_position_embeddings", None)
         if max_pos is not None and max_len > max_pos:
@@ -695,127 +711,78 @@ class InferenceEngine:
         # another replica); False/raise = requeue locally as before
         self._migrate_hook = None
 
-        # slot-pool caches + batch-axis inference (per-layer: axis 0;
-        # stacked scan caches [layers, B, ...]: axis 1)
-        contiguous = hasattr(model, "cache_spec")
-        self._spec1 = model.cache_spec(1, self.L) if contiguous else []
-        spec2 = model.cache_spec(2, self.L) if contiguous else []
-        self._baxes: List[int] = []
-        for (s1, _), (s2, _) in zip(self._spec1, spec2):
+        # the contiguous cache of one sequence: what score() traces
+        # privately (a model with state may have none; score() then raises)
+        self._spec1 = (model.cache_spec(1, self.L)
+                       if hasattr(model, "cache_spec") else [])
+
+        if self._stateful:
+            self._refuse_with_state(prefix_cache, speculate, multi_token)
+        self.page_size = int(page_size)
+        if num_pages is None:
+            num_pages = (self.S * self.L) // self.page_size
+        self._pages = PagePool(num_pages, self.page_size, self.L, self.S,
+                               prefix_cache=prefix_cache)
+        self.maxp = self.L // self.page_size
+        # the paged read walks the table a block at a time, as far as
+        # the deepest row reaches: what a dispatch walks and what the
+        # table holds are summed here (stats(): kv_walk_blocks /
+        # kv_table_blocks, the share of a max_len read still done)
+        self._kv_block = _llama.kv_block(self.page_size, self.maxp)
+        self._kv_walked = 0
+        self._kv_tabled = 0
+        # page-axis inference by diffing cache_spec_paged(1)/(2) (per-layer
+        # pools: axis 0; stacked scan pools [layers, pages, ...]: 1)
+        sp1 = model.cache_spec_paged(1, self.page_size)
+        sp2 = model.cache_spec_paged(2, self.page_size)
+        self._paxes: List[int] = []
+        for (s1, _), (s2, _) in zip(sp1, sp2):
             diffs = [i for i, (a, b) in enumerate(zip(s1, s2)) if a != b]
             if len(diffs) != 1:
                 raise MXNetError(
-                    f"cannot infer cache batch axis from cache_spec shapes "
-                    f"{s1} vs {s2}")
-            self._baxes.append(diffs[0])
-
-        if self._stateful:
-            self._refuse_with_state(paged, prefix_cache, speculate,
-                                    multi_token)
-            paged = True
-        if paged is None:
-            # auto: paged on TPU — but only when the model speaks the
-            # paged protocol and max_len is a page multiple, so existing
-            # contiguous-only configurations keep working unchanged
-            # (explicit paged=True still raises with the specific
-            # reason)
-            paged = (on_tpu()
-                     and hasattr(model, "cache_spec_paged")
-                     and hasattr(model, "forward_cached_paged")
-                     and self.L % int(page_size) == 0)
-            if (not paged and page_tuned
-                    and on_tpu()
-                    and hasattr(model, "cache_spec_paged")
-                    and hasattr(model, "forward_cached_paged")
-                    and self.L % int(page_size) != 0):
-                # a tuned/env page size measured at another max_len must
-                # not silently trade away paged serving — the operator
-                # asked for paging implicitly (paged=None on TPU)
-                warnings.warn(
-                    f"serve: tuned serve_page_size={page_size} does not "
-                    f"divide max_len={self.L}; paged KV auto-detection "
-                    "falls back to the contiguous layout — re-tune page "
-                    "size for this geometry or pass page_size/paged "
-                    "explicitly")
-        self._paged = bool(paged)
-        self._pages: Optional[PagePool] = None
-        if self._paged:
-            if not (hasattr(model, "cache_spec_paged")
-                    and hasattr(model, "forward_cached_paged")):
-                raise MXNetError(
-                    "paged=True requires the paged KV protocol "
-                    "(cache_spec_paged/forward_cached_paged); pass "
-                    "paged=False for the contiguous layout")
-            self.page_size = int(page_size)
-            if num_pages is None:
-                num_pages = (self.S * self.L) // self.page_size
-            self._pages = PagePool(num_pages, self.page_size, self.L,
-                                   self.S, prefix_cache=prefix_cache)
-            self.maxp = self.L // self.page_size
-            # the paged read walks the table a block at a time, as far as
-            # the deepest row reaches: what a dispatch walks and what the
-            # table holds are summed here (stats(): kv_walk_blocks /
-            # kv_table_blocks, the share of a max_len read still done)
-            self._kv_block = _llama.kv_block(self.page_size, self.maxp)
-            self._kv_walked = 0
-            self._kv_tabled = 0
-            # page-axis inference, same trick as the batch axis (per-layer
-            # pools: axis 0; stacked scan pools [layers, pages, ...]: 1)
-            sp1 = model.cache_spec_paged(1, self.page_size)
-            sp2 = model.cache_spec_paged(2, self.page_size)
-            self._paxes: List[int] = []
-            for (s1, _), (s2, _) in zip(sp1, sp2):
-                diffs = [i for i, (a, b) in enumerate(zip(s1, s2))
-                         if a != b]
-                if len(diffs) != 1:
-                    raise MXNetError(
-                        f"cannot infer page axis from cache_spec_paged "
-                        f"shapes {s1} vs {s2}")
-                self._paxes.append(diffs[0])
-            # device pools carry one extra SINK page (index num_pages):
-            # unleased block-table entries point at it, so pad/empty-row
-            # writes land harmlessly and masked reads of unleased
-            # territory contribute exact zeros
-            pool_spec = model.cache_spec_paged(num_pages + 1,
-                                               self.page_size)
-            # per-slot recurrent state, a second kind of pool behind the
-            # page pools: indexed by slot, no page axis, one slot more than
-            # the engine serves (the sink, for rows that serve no request)
-            state_spec = (model.cache_spec_state(self.S + 1)
-                          if self._stateful else [])
-            self._pools: Tuple[jax.Array, ...] = tuple(
-                jnp.zeros(s, d) for s, d in list(pool_spec) + state_spec)
-            self._state_bytes = sum(
-                int(onp.prod(s)) * onp.dtype(d).itemsize
-                for s, d in state_spec)
-            # blocks of one sparse layer's cache that the dispatched
-            # programs read, of those live (stats(): sparse_blocks_read /
-            # sparse_blocks_live), where the model selects among its pages
-            self._blocks_read = getattr(model, "blocks_read", None)
-            self._sel_read = 0
-            self._sel_live = 0
-            self._tok_bytes = sum(
-                int(onp.prod(s)) * onp.dtype(d).itemsize
-                // ((num_pages + 1) * self.page_size)
-                for s, d in pool_spec)
-            if prefill_chunk is None:
-                prefill_chunk = self.page_size
-            self._chunk = min(int(prefill_chunk), self.L)
-            if self._chunk < 1:
-                raise MXNetError("prefill_chunk must be >= 1")
-            self._chunks_per_tick = 1
-            self._prefills: Dict[int, _Prefill] = {}
-            self._active = onp.zeros(self.S, bool)
-            self._preempted = 0
-            self._chunk_fns: Dict[int, Any] = {}
-            self._copy_fns: Dict[int, Any] = {}
-            # cross-replica page migration executables (extract = one
-            # page out of every pool, inject = one shipped page in)
-            self._extract_fns: Dict[int, Any] = {}
-            self._inject_fns: Dict[int, Any] = {}
-        else:
-            pool_spec = model.cache_spec(self.S, self.L)
-            self._pools = tuple(jnp.zeros(s, d) for s, d in pool_spec)
+                    f"cannot infer page axis from cache_spec_paged "
+                    f"shapes {s1} vs {s2}")
+            self._paxes.append(diffs[0])
+        # device pools carry one extra SINK page (index num_pages):
+        # unleased block-table entries point at it, so pad/empty-row
+        # writes land harmlessly and masked reads of unleased
+        # territory contribute exact zeros
+        pool_spec = model.cache_spec_paged(num_pages + 1, self.page_size)
+        # per-slot recurrent state, a second kind of pool behind the
+        # page pools: indexed by slot, no page axis, one slot more than
+        # the engine serves (the sink, for rows that serve no request)
+        state_spec = (model.cache_spec_state(self.S + 1)
+                      if self._stateful else [])
+        self._pools: Tuple[jax.Array, ...] = tuple(
+            jnp.zeros(s, d) for s, d in list(pool_spec) + state_spec)
+        self._state_bytes = sum(
+            int(onp.prod(s)) * onp.dtype(d).itemsize
+            for s, d in state_spec)
+        # blocks of one sparse layer's cache that the dispatched
+        # programs read, of those live (stats(): sparse_blocks_read /
+        # sparse_blocks_live), where the model selects among its pages
+        self._blocks_read = getattr(model, "blocks_read", None)
+        self._sel_read = 0
+        self._sel_live = 0
+        self._tok_bytes = sum(
+            int(onp.prod(s)) * onp.dtype(d).itemsize
+            // ((num_pages + 1) * self.page_size)
+            for s, d in pool_spec)
+        if prefill_chunk is None:
+            prefill_chunk = self.page_size
+        self._chunk = min(int(prefill_chunk), self.L)
+        if self._chunk < 1:
+            raise MXNetError("prefill_chunk must be >= 1")
+        self._chunks_per_tick = 1
+        self._prefills: Dict[int, _Prefill] = {}
+        self._active = onp.zeros(self.S, bool)
+        self._preempted = 0
+        self._chunk_fns: Dict[int, Any] = {}
+        self._copy_fns: Dict[int, Any] = {}
+        # cross-replica page migration executables (extract = one
+        # page out of every pool, inject = one shipped page in)
+        self._extract_fns: Dict[int, Any] = {}
+        self._inject_fns: Dict[int, Any] = {}
 
         # what the pools take on the device, which pads a row to whole
         # tiles (a TPU holds GPT-2 XL's rows of 1,600 lanes as 1,664): the
@@ -859,31 +826,11 @@ class InferenceEngine:
         # decode lookahead: at most one dispatched-but-unread step
         self._lookahead = bool(lookahead)
         self._pending: Optional[_PendingStep] = None
-        # preallocated prefill staging buffers, PER SLOT (one standalone
-        # array per slot, not rows of a shared base): on CPU backends jit
-        # arg conversion can zero-copy-alias a host numpy buffer, so a
-        # buffer must not be rewritten while a dispatch that read it may
-        # still be executing. Slot-keyed reuse is race-free: two prefills
-        # share a buffer only when they share a slot, and a slot is only
-        # refilled after its previous prefill was forced by the tok0 read.
-        # Under MXNET_DEBUG_GUARDS=1 an AliasSentinel write-protects each
-        # slot's buffers from dispatch until its next refill, so any code
-        # that breaks the contract fails at the write site (the PR-4 bug
-        # class, caught at dispatch time instead of as corrupted tokens).
-        self._pf_temp = [onp.zeros(1, onp.float32) for _ in range(self.S)]
-        self._pf_topk = [onp.zeros(1, onp.int32) for _ in range(self.S)]
-        self._pf_topp = [onp.ones(1, onp.float32) for _ in range(self.S)]
-        self._pf_seed = [onp.zeros(1, onp.uint32) for _ in range(self.S)]
-        self._pf_ids: Dict[Tuple[int, int], onp.ndarray] = {}
-        self._sentinel = (_guards.AliasSentinel()
-                          if _guards.debug_guards_enabled() else None)
-        self._pf_sealed: Dict[int, list] = {}
-
         # shape-bucketed executables (bucket key -> jitted fn)
         self._prefill_fns: Dict[int, Any] = {}
         self._step_fns: Dict[int, Any] = {}
         self._spec_fns: Dict[int, Any] = {}
-        # stats()["pool_bytes_in_place"]: None until a paged program that
+        # stats()["pool_bytes_in_place"]: None until a program that
         # writes the pools is built
         self._in_place: Optional[int] = None
         # batched scoring (teacher-forced logprobs): its own bucket
@@ -988,8 +935,6 @@ class InferenceEngine:
                     return
             self._apply_swaps()  # loop is dead: unblock swap waiters
             self._apply_page_ops()
-            if self._sentinel is not None:
-                self._sentinel.release_all()
             return
         if self._thread is not None:
             self._thread.join(timeout)
@@ -997,8 +942,6 @@ class InferenceEngine:
                 return            # begin_drain: the loop finishes async
         self._apply_swaps()      # loop is dead: unblock swap waiters
         self._apply_page_ops()
-        if self._sentinel is not None:
-            self._sentinel.release_all()
 
     def __enter__(self):
         return self.start()
@@ -1303,14 +1246,9 @@ class InferenceEngine:
             rec["evt"].set()
 
     @staticmethod
-    def _refuse_with_state(paged, prefix_cache, speculate, multi_token):
+    def _refuse_with_state(prefix_cache, speculate, multi_token):
         """What a model with recurrent state cannot be served with yet,
         each with its reason (class docstring)."""
-        if paged is False:
-            raise MXNetError(
-                "a model with recurrent state (cache_spec_state) is served "
-                "through the paged protocol only: paged=False has no "
-                "per-slot state")
         if prefix_cache:
             raise MXNetError(
                 "prefix_cache=True cannot serve a model with recurrent "
@@ -1329,11 +1267,7 @@ class InferenceEngine:
                 "budget, which a page forgives and a state does not")
 
     # ------------------------------------------------- page migration
-    def _require_paged(self):
-        if not self._paged:
-            raise MXNetError(
-                "cross-replica page migration requires the paged engine "
-                "(paged=True)")
+    def _refuse_migration_with_state(self):
         if self._stateful:
             raise MXNetError(
                 "page migration (copy / extract / inject) cannot move a "
@@ -1374,7 +1308,7 @@ class InferenceEngine:
         prefill published the pages, when every exported page is pinned
         by its cache entry). Runs at a tick boundary of the engine loop,
         like :meth:`import_pages`."""
-        self._require_paged()
+        self._refuse_migration_with_state()
         toks = self._as_prompt(input_ids)
 
         def export():
@@ -1413,7 +1347,7 @@ class InferenceEngine:
         the engine loop (the loop owns the pools); on a stopped engine
         it applies inline. Returns ``{"received", "adopted",
         "verify_failures", ...}``."""
-        self._require_paged()
+        self._refuse_migration_with_state()
         from ..kvstore.comm import decode_kv_pages
         tokens, pages = decode_kv_pages(doc)
         return self._on_loop(
@@ -1549,7 +1483,7 @@ class InferenceEngine:
         warmup measured in ``mxnet_aot_warmup_seconds{path=serve}`` drops
         to IO + dispatch.
 
-        The paged programs are given the pools they write (donation), so
+        The programs are given the pools they write (donation), so
         each example runs on the live pools and the engine keeps what it
         returns: the examples' tables are all-sink and their slot the sink
         slot, so no page and no state of a request is written. On a
@@ -1560,15 +1494,14 @@ class InferenceEngine:
 
     def _warmup(self):
         t0 = time.perf_counter()
-        prefill_hi = self._chunk if self._paged else self.L
-        for pb in bucket_ladder(self.min_prompt_bucket, prefill_hi,
+        for pb in bucket_ladder(self.min_prompt_bucket, self._chunk,
                                 self._growth):
             self._warm(self._get_prefill(pb), "prefill", pb)
-        if self._paged and self._chunk < self.L:
+        if self._chunk < self.L:
             self._warm(self._get_chunk(), "chunk", self._chunk)
-        if self._paged and self._pages.prefix_cache_enabled:
+        if self._pages.prefix_cache_enabled:
             self._warm(self._get_copy(), "copy", 0)
-        if self._paged and not self._stateful:
+        if not self._stateful:
             # migration executables: warmed so a first preemption rescue
             # or tier page-stream inside steady-state serving hits cached
             # code (the no_recompile() contract with migration enabled).
@@ -1593,10 +1526,10 @@ class InferenceEngine:
     def _warm(self, fn, label: str, bucket: int):
         """Run one program on its example arguments and wait for it. The
         pools are the last thing a program returns that writes them (all
-        of it, for a chunk, a copy and an inject): a paged one was given
+        of it, for a chunk, a copy and an inject): it was given
         them, so from here on these are the engine's."""
         out = fn(*self._example_args(label, bucket))
-        if self._paged and label in self._POOL_WRITERS:
+        if label in self._POOL_WRITERS:
             self._pools = (out if label in ("chunk", "copy", "inject")
                            else out[-1])
         jax.block_until_ready(out)
@@ -1604,7 +1537,7 @@ class InferenceEngine:
     def _example_args(self, label: str, bucket: int):
         """Representative arguments for one bucket executable — what
         warmup calls, and what the AOT cache lowers/fingerprints (runtime
-        calls differ only in values, never avals). Paged example tables
+        calls differ only in values, never avals). Example tables
         are all-sink, so warmup's writes land in the sink page of the
         live pools. Grammar example operands are identity-safe: all-zero
         ``nxt`` tables mean every transition lands in state 0 and is
@@ -1624,69 +1557,48 @@ class InferenceEngine:
         if label == "score":
             return (self._values, onp.zeros((1, bucket), onp.int32),
                     onp.int32(2))
+        sink_tbl = lambda rows: onp.full(       # noqa: E731
+            (rows, self.maxp), self._pages.sink, onp.int32)
         if label == "spec":
-            args = (self._values, self._pools,
+            return (self._values, self._pools,
                     onp.zeros((bucket, self.spec), onp.int32),
-                    onp.zeros(bucket, onp.int32))
-            if self._paged:
-                args = args + (onp.full((bucket, self.maxp),
-                                        self._pages.sink, onp.int32),)
-            return args + gram_args(self.S, bucket) + (
-                           onp.zeros(bucket, onp.float32),
-                           onp.zeros(bucket, onp.int32),
-                           onp.ones(bucket, onp.float32),
-                           onp.zeros(bucket, onp.uint32),
-                           onp.zeros(bucket, onp.int32))
-        if self._paged:
-            sink_tbl = lambda rows: onp.full(       # noqa: E731
-                (rows, self.maxp), self._pages.sink, onp.int32)
-            # with state every row names its slot: the examples' is the sink
-            sink_slot = lambda rows: self._slot_rows(  # noqa: E731
-                [self.S] * rows)
-            if label == "prefill":
-                return (self._values, self._pools,
-                        onp.zeros((1, bucket), onp.int32), onp.int32(1),
-                        onp.int32(0), sink_tbl(1)) + sink_slot(1) \
-                    + gram_args(1, 1) + (
-                        onp.zeros(1, onp.float32), onp.zeros(1, onp.int32),
-                        onp.ones(1, onp.float32), onp.zeros(1, onp.uint32),
-                        onp.zeros(1, onp.int32))
-            if label == "chunk":
-                return (self._values, self._pools,
-                        onp.zeros((1, bucket), onp.int32), onp.int32(0),
-                        sink_tbl(1)) + sink_slot(1)
-            if label == "copy":
-                return (self._pools, onp.int32(0), onp.int32(0))
-            if label == "extract":
-                return (self._pools, onp.int32(0))
-            if label == "inject":
-                return (self._pools, self._page_payload_spec(),
-                        onp.int32(self._pages.sink))
-            args = (self._values, self._pools,
-                    onp.zeros(bucket, onp.int32),
-                    onp.zeros(bucket, onp.int32), sink_tbl(bucket)) + \
-                sink_slot(bucket) + gram_args(self.S, bucket) + (
+                    onp.zeros(bucket, onp.int32), sink_tbl(bucket)) \
+                + gram_args(self.S, bucket) + (
                     onp.zeros(bucket, onp.float32),
                     onp.zeros(bucket, onp.int32),
                     onp.ones(bucket, onp.float32),
                     onp.zeros(bucket, onp.uint32),
                     onp.zeros(bucket, onp.int32))
-            if self.K > 1:
-                args = args + (onp.full(bucket, -1, onp.int32),
-                               onp.ones(bucket, onp.int32))
-            return args
+        # with state every row names its slot: the examples' is the sink
+        sink_slot = lambda rows: self._slot_rows(  # noqa: E731
+            [self.S] * rows)
         if label == "prefill":
             return (self._values, self._pools,
                     onp.zeros((1, bucket), onp.int32), onp.int32(1),
-                    onp.int32(0)) + gram_args(1, 1) + (
-                    onp.zeros(1, onp.float32),
-                    onp.zeros(1, onp.int32), onp.ones(1, onp.float32),
-                    onp.zeros(1, onp.uint32))
+                    onp.int32(0), sink_tbl(1)) + sink_slot(1) \
+                + gram_args(1, 1) + (
+                    onp.zeros(1, onp.float32), onp.zeros(1, onp.int32),
+                    onp.ones(1, onp.float32), onp.zeros(1, onp.uint32),
+                    onp.zeros(1, onp.int32))
+        if label == "chunk":
+            return (self._values, self._pools,
+                    onp.zeros((1, bucket), onp.int32), onp.int32(0),
+                    sink_tbl(1)) + sink_slot(1)
+        if label == "copy":
+            return (self._pools, onp.int32(0), onp.int32(0))
+        if label == "extract":
+            return (self._pools, onp.int32(0))
+        if label == "inject":
+            return (self._pools, self._page_payload_spec(),
+                    onp.int32(self._pages.sink))
         args = (self._values, self._pools,
-                onp.zeros(bucket, onp.int32), onp.zeros(bucket, onp.int32)) \
-            + gram_args(self.S, bucket) + (
-                onp.zeros(bucket, onp.float32), onp.zeros(bucket, onp.int32),
-                onp.ones(bucket, onp.float32), onp.zeros(bucket, onp.uint32),
+                onp.zeros(bucket, onp.int32),
+                onp.zeros(bucket, onp.int32), sink_tbl(bucket)) + \
+            sink_slot(bucket) + gram_args(self.S, bucket) + (
+                onp.zeros(bucket, onp.float32),
+                onp.zeros(bucket, onp.int32),
+                onp.ones(bucket, onp.float32),
+                onp.zeros(bucket, onp.uint32),
                 onp.zeros(bucket, onp.int32))
         if self.K > 1:
             args = args + (onp.full(bucket, -1, onp.int32),
@@ -1719,9 +1631,8 @@ class InferenceEngine:
                         f"serve_{label}", fn, args,
                         key=f"serve_{label}:b{bucket}",
                         meta={"bucket": bucket, "slots": self.S,
-                              "max_len": self.L, "paged": self._paged,
-                              "multi_token": self.K})
-                if self._paged and label in self._POOL_WRITERS:
+                              "max_len": self.L, "multi_token": self.K})
+                if label in self._POOL_WRITERS:
                     alias = _alias_bytes(fn, args)
                     self._in_place = (alias if self._in_place is None
                                       else min(self._in_place, alias))
@@ -1731,14 +1642,12 @@ class InferenceEngine:
         return fn
 
     def _get_prefill(self, pb: int):
-        builder = (self._build_prefill_paged if self._paged
-                   else self._build_prefill)
-        return self._get_compiled(self._prefill_fns, pb, builder, "prefill")
+        return self._get_compiled(self._prefill_fns, pb,
+                                  self._build_prefill, "prefill")
 
     def _get_step(self, sb: int):
-        builder = (self._build_step_paged if self._paged
-                   else self._build_step)
-        return self._get_compiled(self._step_fns, sb, builder, "decode")
+        return self._get_compiled(self._step_fns, sb, self._build_step,
+                                  "decode")
 
     def _get_spec(self, sb: int):
         return self._get_compiled(self._spec_fns, sb,
@@ -1795,104 +1704,6 @@ class InferenceEngine:
         diverge (the cross-K sampling-parity contract)."""
         return _gen._fold_keys(seeds, counters)
 
-    def _build_prefill(self, pb: int):
-        fm, spec1, baxes = self._fm, self._spec1, self._baxes
-        grammar = self._grammar
-
-        def prefill(values, pools, ids, true_len, slot, *rest):
-            if grammar:
-                (gcls, gnxt, gacc, gstate, geos,
-                 temps, topks, topps, seeds) = rest
-            else:
-                temps, topks, topps, seeds = rest
-            caches = tuple(jnp.zeros(s, d) for s, d in spec1)
-            logits, new_caches = _gen.decode_step(fm, values, ids,
-                                                  jnp.int32(0), caches)
-            # last REAL prompt row (right padding rows are discarded; their
-            # K/V rows beyond true_len are masked now and overwritten by
-            # decode writes before the mask ever reaches them)
-            last = jax.lax.dynamic_index_in_dim(
-                logits, true_len - 1, axis=1, keepdims=False)   # [1, V]
-            keys = self._slot_keys(seeds, jnp.zeros(1, jnp.int32))
-            mask = (_grammar.grammar_mask(gcls, gnxt, gacc, gstate, geos)
-                    if grammar else None)
-            tok0 = _gen.sample_tokens(last, keys, temps, topks, topps,
-                                      mask=mask)
-            new_pools = []
-            for pool, nc, ax in zip(pools, new_caches, baxes):
-                idx = tuple(jnp.asarray(slot, jnp.int32) if i == ax
-                            else jnp.int32(0) for i in range(pool.ndim))
-                new_pools.append(jax.lax.dynamic_update_slice(
-                    pool, nc.astype(pool.dtype), idx))
-            return tok0[0], tuple(new_pools)
-
-        return _jit_named(prefill, f"prefill_b{pb}")
-
-    def _build_step(self, sb: int):
-        if self.K > 1:
-            return self._build_step_multi(sb)
-        fm, baxes = self._fm, self._baxes
-        grammar = self._grammar
-
-        def step(values, pools, tokens, pos, *rest):
-            if grammar:
-                (gcls, gnxt, gacc, gstate, geos,
-                 temps, topks, topps, seeds, counters) = rest
-                # full-[S] device tables, sliced to the bucket statically
-                gcls = jax.lax.slice_in_dim(gcls, 0, sb, axis=0)
-                gnxt = jax.lax.slice_in_dim(gnxt, 0, sb, axis=0)
-                gacc = jax.lax.slice_in_dim(gacc, 0, sb, axis=0)
-            else:
-                temps, topks, topps, seeds, counters = rest
-            caches = tuple(
-                jax.lax.slice_in_dim(p, 0, sb, axis=ax)
-                for p, ax in zip(pools, baxes))
-            logits, new_caches = _gen.decode_step(fm, values,
-                                                  tokens[:, None], pos,
-                                                  caches)
-            keys = self._slot_keys(seeds, counters)
-            mask = (_grammar.grammar_mask(gcls, gnxt, gacc, gstate, geos)
-                    if grammar else None)
-            nxt = _gen.sample_tokens(logits[:, -1], keys, temps, topks,
-                                     topps, mask=mask)
-            new_pools = tuple(
-                jax.lax.dynamic_update_slice_in_dim(p, nc.astype(p.dtype),
-                                                    0, axis=ax)
-                for p, nc, ax in zip(pools, new_caches, baxes))
-            if grammar:
-                ngs = _grammar.grammar_advance(gcls, gnxt, gstate, nxt,
-                                               geos)
-                return nxt, ngs, new_pools
-            return nxt, new_pools
-
-        return _jit_named(step, f"step_b{sb}")
-
-    def _build_step_multi(self, sb: int):
-        """K tokens per dispatch: the on-device multi-token loop
-        (models/generation.decode_multi_tokens) with per-slot eos ids and
-        token budgets as data. Returns (toks [sb, K], last [sb], steps,
-        pools); the loop exits early only when EVERY row is done, so the
-        host clocks (pos/counters advanced by K at dispatch) stay
-        consistent for any live slot."""
-        fm, baxes, K, head = self._fm, self._baxes, self.K, self._head_pack
-
-        def step(values, pools, tokens, pos, temps, topks, topps, seeds,
-                 counters, eos_ids, remaining):
-            caches = tuple(
-                jax.lax.slice_in_dim(p, 0, sb, axis=ax)
-                for p, ax in zip(pools, baxes))
-            toks, last, steps, _done, new_caches = _gen.decode_multi_tokens(
-                fm, values, tokens, pos, caches, K, temps, topks, topps,
-                seeds, counters, eos_ids=eos_ids, remaining=remaining,
-                done=remaining <= 0, head=head)
-            new_pools = tuple(
-                jax.lax.dynamic_update_slice_in_dim(p, nc.astype(p.dtype),
-                                                    0, axis=ax)
-                for p, nc, ax in zip(pools, new_caches, baxes))
-            return toks, last, steps, new_pools
-
-        return _jit_named(step, f"step_b{sb}")
-
     def _build_step_spec(self, sb: int):
         """Self-speculative verify step: ONE forward over the [sb, spec]
         input matrix (current token + spec-1 drafts per row, written at
@@ -1908,7 +1719,7 @@ class InferenceEngine:
         launch site marks the trace next to the underlying GEMV/fused
         tallies."""
         from ..ops.int8_gemv import record_launch
-        fm, baxes = self._fm, self._baxes
+        fm = self._fm
         grammar = self._grammar
 
         def _vmasks(rest):
@@ -1926,43 +1737,21 @@ class InferenceEngine:
                 gstates, geos)
             return masks, rest
 
-        if self._paged:
-            def step(values, pools, inputs, pos, tables, *rest):
-                record_launch("spec_verify")
-                masks, rest = _vmasks(rest)
-                temps, topks, topps, seeds, counters = rest
-                logits, new_pools = _gen.decode_step(
-                    fm, values, inputs, pos, pools, block_table=tables)
-                toks, acc = _gen.spec_verify_tokens(
-                    logits, inputs, temps, topks, topps, seeds, counters,
-                    masks=masks)
-                return toks, acc, new_pools
-
-            return jax.jit(step, donate_argnums=1)
-
-        def step(values, pools, inputs, pos, *rest):
+        def step(values, pools, inputs, pos, tables, *rest):
             record_launch("spec_verify")
             masks, rest = _vmasks(rest)
             temps, topks, topps, seeds, counters = rest
-            caches = tuple(
-                jax.lax.slice_in_dim(p, 0, sb, axis=ax)
-                for p, ax in zip(pools, baxes))
-            logits, new_caches = _gen.decode_step(fm, values, inputs, pos,
-                                                  caches)
+            logits, new_pools = _gen.decode_step(
+                fm, values, inputs, pos, pools, block_table=tables)
             toks, acc = _gen.spec_verify_tokens(
                 logits, inputs, temps, topks, topps, seeds, counters,
                 masks=masks)
-            new_pools = tuple(
-                jax.lax.dynamic_update_slice_in_dim(p, nc.astype(p.dtype),
-                                                    0, axis=ax)
-                for p, nc, ax in zip(pools, new_caches, baxes))
             return toks, acc, new_pools
 
-        return jax.jit(step)
+        return jax.jit(step, donate_argnums=1)
 
-    # ------------------------------------------------------ paged executables
-    def _build_prefill_paged(self, pb: int):
-        """Paged prefill: attend ``ids`` at offset ``start`` through the
+    def _build_prefill(self, pb: int):
+        """Prefill: attend ``ids`` at offset ``start`` through the
         slot's block table (the final/only chunk — samples token0 at
         counter ``counter0`` so preempted requests resume mid-stream)."""
         fm = self._fm
@@ -2008,10 +1797,10 @@ class InferenceEngine:
 
         return _jit_named(chunk, f"chunk_c{cs}", donate_argnums=1)
 
-    def _build_step_paged(self, sb: int):
-        """Paged decode step: the shared page pools replace the sliced
-        slot caches; every row addresses its KV rows through its block-
-        table row (inactive rows: all-sink)."""
+    def _build_step(self, sb: int):
+        """Decode step over the shared page pools: every row addresses
+        its KV rows through its block-table row (inactive rows:
+        all-sink)."""
         fm, K, head = self._fm, self.K, self._head_pack
 
         if K > 1:
@@ -2103,9 +1892,9 @@ class InferenceEngine:
         """Batched scoring executable: teacher-forced per-token
         log-probabilities of ``ids[0, 1:true_len]`` — ONE prefill-shaped
         forward over the prompt bucket ladder, no decode loop. Runs on
-        FRESH length-L contiguous caches traced in (even on paged
-        engines): the serving pools are never read or written, so
-        scoring is safe from any thread, concurrent with decode."""
+        FRESH length-L contiguous caches traced in: the serving pools are
+        never read or written, so scoring is safe from any thread,
+        concurrent with decode."""
         fm, spec1 = self._fm, self._spec1
 
         def score(values, ids, true_len):
@@ -2224,7 +2013,7 @@ class InferenceEngine:
         self._sel_live += of
 
     def _slot_rows(self, slots) -> Tuple[onp.ndarray, ...]:
-        """The rows' slot ids as one more argument of a paged program, for
+        """The rows' slot ids as one more argument of a program, for
         a model with per-slot state; nothing for any other."""
         return (onp.asarray(slots, onp.int32),) if self._stateful else ()
 
@@ -2249,8 +2038,8 @@ class InferenceEngine:
             self._tick_children.clear()
             with _profiler.scope(
                     "mx.serve.tick", "serve", tick=self._tick_no,
-                    queued=len(self._queue), prefilling=(
-                        len(self._prefills) if self._paged else 0)) as tick:
+                    queued=len(self._queue),
+                    prefilling=len(self._prefills)) as tick:
                 self._tick_span = tick
                 more = self._tick()
             self._close_tick(tick)
@@ -2303,7 +2092,7 @@ class InferenceEngine:
                     s = self._free_slot()
                     if s is None:
                         break
-                    if self._paged and not self._fits(self._queue[0]):
+                    if not self._fits(self._queue[0]):
                         # not enough pages even after reclaiming the
                         # whole prefix cache: admitting would only
                         # preempt-thrash — wait for retires (FIFO
@@ -2323,23 +2112,20 @@ class InferenceEngine:
             admit.set(admitted=len(admits))
         for req, status in dead:
             self._finish_unstarted(req, status)
-        if self._pending is not None and (
-                stopping or (admits and not self._paged)):
-            # contiguous mode: the slot set (and pools, via prefill)
-            # is about to change — drain the lookahead step so its
-            # token reads and retires land before the world moves.
-            # Paged admits only start a PREFILL (the decode set is
-            # untouched until the final chunk), so the paged tick's
-            # own set check handles activation.
+        if self._pending is not None and stopping:
+            # drain the lookahead step so its token reads and retires
+            # land before the abort below. An admission only starts a
+            # PREFILL (the decode set is untouched until the final
+            # chunk): the step tick's own set check handles activation.
             self._process_step(self._pending)
             self._pending = None
         if stopping and self._abort_inflight:
             for s in range(self.S):
                 if self._slots[s] is not None:
                     self._retire(s, STATUS_SHUTDOWN)
-        self._prefill_admits(admits)
-        if self._paged:
-            self._advance_prefills(stopping)
+        for s, req in admits:
+            self._admit(s, req)
+        self._advance_prefills(stopping)
         if any(self._slots):
             self._step_tick()
             if self._step_delay:
@@ -2362,27 +2148,6 @@ class InferenceEngine:
         _metrics.SERVE_SLOT_OCCUPANCY.set(n / self.S)
 
     # ------------------------------------------------------------ prefill
-    def _prefill_admits(self, admits: List[Tuple[int, RequestHandle]]):
-        """Prefill every admitted request: all forwards are dispatched
-        first (so the device pipelines them back-to-back), then the tok0
-        reads — each started early with ``copy_to_host_async`` — are
-        finalized. Paged mode only REGISTERS the prefill here (prefix-
-        cache match + page mapping); ``_advance_prefills`` dispatches the
-        chunks."""
-        if self._paged:
-            for s, req in admits:
-                self._admit_paged(s, req)
-            return
-        dispatched = []
-        for s, req in admits:
-            with self._span("prefill_dispatch", slot=s):
-                rec = self._prefill_dispatch(s, req)
-            if rec is not None:
-                dispatched.append(rec)
-        for rec in dispatched:
-            self._prefill_finalize(*rec)
-
-    # ------------------------------------------------------------ paged mode
     def _fits(self, req: RequestHandle) -> bool:
         """Conservative admission gate: the pool (free + reclaimable
         prefix-cache pages) can hold the request's prompt plus its first
@@ -2393,9 +2158,10 @@ class InferenceEngine:
         return (self._pages.free_pages()
                 + self._pages.cached_pages()) >= need
 
-    def _admit_paged(self, s: int, req: RequestHandle):
-        """Start a paged prefill: map the longest cached prefix into the
-        slot's block table, then register the chunk cursor past it."""
+    def _admit(self, s: int, req: RequestHandle):
+        """Register an admitted request's prefill (``_advance_prefills``
+        dispatches the chunks): map the longest cached prefix into the
+        slot's block table, then set the chunk cursor past it."""
         first_admission = req._resume is None
         resume = list(req._resume or ())
         ids = list(req.prompt_ids) + resume
@@ -2448,7 +2214,7 @@ class InferenceEngine:
                 if budget <= 0:
                     break
                 with self._span("prefill_dispatch", slot=s) as span:
-                    rec = self._prefill_step_paged(s, span)
+                    rec = self._prefill_step(s, span)
                 if rec is not None:
                     pending.append(rec)
                 progressed = True
@@ -2460,7 +2226,7 @@ class InferenceEngine:
         # syncs below overlap the remaining device work instead of
         # serializing dispatch->sync per slot
         for rec in pending:
-            self._prefill_finalize_paged(*rec)
+            self._prefill_finalize(*rec)
 
     def _fork_range(self, s: int, start: int, end: int) -> int:
         """Copy-on-write: fork every shared page the slot is about to
@@ -2483,7 +2249,7 @@ class InferenceEngine:
         """[1, max_pages] snapshot of the slot's block table."""
         return self._pages.table(s)[None, :].copy()
 
-    def _prefill_step_paged(self, s: int, span: _profiler.scope):
+    def _prefill_step(self, s: int, span: _profiler.scope):
         """Advance one slot's prefill by ONE chunk (``span``, the caller's
         open ``prefill_dispatch``, is told which). A middle chunk only
         writes KV pages (returns None); the final chunk (bucketed
@@ -2572,7 +2338,7 @@ class InferenceEngine:
             except Exception:
                 pass
         except Exception as e:  # pragma: no cover - defensive
-            warnings.warn(f"serve: paged prefill failed: {e!r}")
+            warnings.warn(f"serve: prefill failed: {e!r}")
             self._retire(s, STATUS_ERROR, error=str(e))
             self._pools_lost(e)
             return None
@@ -2585,9 +2351,8 @@ class InferenceEngine:
         del self._prefills[s]
         return (s, pf, req, slot, tok0)
 
-    def _prefill_finalize_paged(self, s: int, pf: "_Prefill",
-                                req: RequestHandle, slot: "_Slot",
-                                tok0_dev):
+    def _prefill_finalize(self, s: int, pf: "_Prefill",
+                          req: RequestHandle, slot: "_Slot", tok0_dev):
         """Host-sync one deferred final-chunk token0 and activate the
         slot for decode."""
         try:
@@ -2595,7 +2360,7 @@ class InferenceEngine:
                             slot=s) as sync:
                 tok0 = int(tok0_dev)
         except Exception as e:  # pragma: no cover - defensive
-            warnings.warn(f"serve: paged prefill failed: {e!r}")
+            warnings.warn(f"serve: prefill failed: {e!r}")
             # the prefix was published at dispatch, before the device
             # program proved itself — don't let a failed prefill leave
             # suspect KV pages matchable by future prompts
@@ -2608,7 +2373,7 @@ class InferenceEngine:
         if _metrics.ENABLED:
             # the final chunk's bucket (pf.cursor stops at the last
             # chunk boundary); the note's dt spans the whole chunked
-            # admission, so paged-prefill MFU reads per-admission
+            # admission, so prefill MFU reads per-admission
             pb = bucket_for(max(1, len(pf.ids) - pf.cursor),
                             self.min_prompt_bucket, self._chunk,
                             self._growth)
@@ -2697,252 +2462,7 @@ class InferenceEngine:
             self._queue.appendleft(req)
             _metrics.SERVE_QUEUE_DEPTH.set(len(self._queue))
 
-    def _prefill_dispatch(self, s: int, req: RequestHandle):
-        t0 = time.perf_counter()
-        _metrics.SERVE_QUEUE_WAIT.observe(t0 - req.submit_t)
-        _recorder.RECORDER.record("event", "serve.admit", slot=s,
-                                  prompt_tokens=len(req.prompt_ids))
-        if req._trace is not None:
-            req._span_queue.set("tick", self._tick_no)
-            req._span_queue.end()
-            req._span_prefill = req._trace.child("serve.prefill", slot=s,
-                                                 tick=self._tick_no)
-        P = len(req.prompt_ids)
-        try:
-            pb = bucket_for(P, self.min_prompt_bucket, self.L,
-                            self._growth)
-            fn = self._get_prefill(pb)
-            ids = self._pf_ids.get((s, pb))
-            if ids is None:
-                ids = self._pf_ids.setdefault(
-                    (s, pb), onp.zeros((1, pb), onp.int32))
-            if self._sentinel is not None:
-                # this slot is being refilled, so its previous prefill was
-                # forced: its staging buffers may be rewritten again
-                self._sentinel.release(*self._pf_sealed.pop(s, ()))
-            ids[:] = 0
-            ids[0, :P] = req.prompt_ids
-            self._pf_temp[s][0] = req.temperature
-            self._pf_topk[s][0] = req.top_k
-            self._pf_topp[s][0] = req.top_p
-            self._pf_seed[s][0] = req.seed & 0xFFFFFFFF
-            gargs = ()
-            if self._grammar:
-                # per-request automaton rows, FRESH arrays per dispatch
-                # (nothing for jit arg conversion to alias)
-                self._install_grammar(s, req)
-                gargs = (self._gcls[s:s + 1].copy(),
-                         self._gnxt[s:s + 1].copy(),
-                         self._gacc[s:s + 1].copy(),
-                         self._gstate[s:s + 1].copy(),
-                         onp.array([-1 if req.eos_token_id is None
-                                    else req.eos_token_id], onp.int32))
-            # slot-keyed staging reuse is race-free (refill postdates the
-            # tok0 force); the sentinel below enforces exactly that under
-            # MXNET_DEBUG_GUARDS=1
-            tok0, pools = fn(
-                self._values, self._pools, ids, onp.int32(P), onp.int32(s),
-                *gargs,
-                self._pf_temp[s],   # mxlint: disable=MX004 -- slot-keyed
-                self._pf_topk[s],   # mxlint: disable=MX004 -- slot-keyed
-                self._pf_topp[s],   # mxlint: disable=MX004 -- slot-keyed
-                self._pf_seed[s])   # mxlint: disable=MX004 -- slot-keyed
-            self._pools = pools
-            if self._sentinel is not None:
-                bufs = [ids, self._pf_temp[s], self._pf_topk[s],
-                        self._pf_topp[s], self._pf_seed[s]]
-                self._sentinel.seal(*bufs)
-                self._pf_sealed[s] = bufs
-            try:
-                tok0.copy_to_host_async()
-            except Exception:
-                pass
-        except Exception as e:  # pragma: no cover - defensive
-            warnings.warn(f"serve: prefill failed: {e!r}")
-            self._slots[s] = None
-            self._finish_unstarted(req, STATUS_ERROR, error=str(e))
-            return None
-        # host slot state fills while the device runs the prefill forward
-        self._pos[s] = P
-        self._counters[s] = 1
-        self._temps[s] = req.temperature
-        self._topks[s] = req.top_k
-        self._topps[s] = req.top_p
-        self._seeds[s] = req.seed & 0xFFFFFFFF
-        self._eos[s] = -1 if req.eos_token_id is None else req.eos_token_id
-        self._remaining[s] = req.max_new_tokens - 1   # tok0 is the first
-        return (s, req, tok0, t0)
-
-    def _prefill_finalize(self, s: int, req: RequestHandle, tok0_dev,
-                          t0: float):
-        try:
-            with self._span("prefill_sync", _metrics.SERVE_HOST_SYNC,
-                            slot=s) as sync:
-                tok0 = int(tok0_dev)
-        except Exception as e:  # pragma: no cover - defensive
-            warnings.warn(f"serve: prefill failed: {e!r}")
-            self._slots[s] = None
-            self._reset_slot_state(s)
-            self._finish_unstarted(req, STATUS_ERROR, error=str(e))
-            return
-        now = sync.t1
-        _metrics.SERVE_ROUNDTRIPS.labels(path="prefill").inc()
-        req.first_token_t = now
-        _metrics.SERVE_PREFILL_SECONDS.observe(now - t0)
-        _metrics.SERVE_TTFT.observe(now - req.submit_t)
-        _metrics.SERVE_TOKENS.inc()
-        if _metrics.ENABLED:
-            pb = bucket_for(len(req.prompt_ids), self.min_prompt_bucket,
-                            self.L, self._growth)
-            _perf.note_step("serve_prefill", now - t0,
-                            key=f"serve_prefill:b{pb}")
-        if req._span_prefill is not None:
-            req._span_prefill.set("ttft_s", round(now - req.submit_t, 6))
-            req._span_prefill.end()
-            req._span_prefill = None
-        slot = self._slots[s]
-        slot.generated.append(tok0)
-        req._emit(tok0)
-        slot.t_last = now
-        self._tokens[s] = tok0
-        if self._grammar:
-            self._advance_gstate(s, tok0)
-        self._check_finished(s, now)
-        self._observe_occupancy()
-
     # ------------------------------------------------------------ decode
-    def _step_tick(self):
-        """Advance decode one tick. Synchronous mode dispatches one step
-        and reads it. Lookahead mode dispatches step N+1 — feeding step
-        N's device token vector straight back in — BEFORE reading step N,
-        so the host sync overlaps the next step's compute; a retire at
-        the read drains the speculative step (its rows for dead slots are
-        discarded) so the loop can shrink/refill before re-dispatching.
-        Speculative mode (speculate=K) replaces the per-token step with
-        draft-verify rounds."""
-        if self.spec:
-            self._step_tick_spec()
-            return
-        if self._paged:
-            self._step_tick_paged()
-            return
-        prev, self._pending = self._pending, None
-        with self._span("decode_dispatch") as disp:
-            rec = self._dispatch_step(prev)
-            if rec is not None:
-                disp.set(sb=rec.sb, rows=len(rec.slots))
-        if rec is None:
-            # dispatch failed; _dispatch_step salvaged prev's tokens and
-            # retired the slots
-            return
-        if prev is not None:
-            retired = self._process_step(prev)
-            if retired and rec is not None:
-                self._process_step(rec)
-                rec = None
-        if self._lookahead:
-            self._pending = rec
-        elif rec is not None:
-            self._process_step(rec)
-
-    def _dispatch_step(self, prev: Optional[_PendingStep] = None
-                       ) -> Optional[_PendingStep]:
-        """Dispatch one batched decode step without waiting for it.
-        ``prev`` (lookahead) feeds the previous step's device-resident
-        output tokens back in; None reads the host token array. Advances
-        the host pos/counter clocks to match the dispatched step. On
-        dispatch failure, first processes ``prev`` — its tokens were
-        already computed and must not be lost (a request finishing there
-        completes OK, not error) — then retires the remaining slots and
-        returns None."""
-        tokens_dev = prev.nxt if prev is not None else None
-        t0 = time.perf_counter()
-        # batch bucket = pow2 ceil of the highest OCCUPIED slot index.
-        # Lowest-free-index allocation keeps the prefix compact under
-        # sustained load, but a straggler in a high slot does pin the
-        # wider bucket until it finishes (no cache-row compaction — that
-        # would cost a per-retire cache copy; known fragmentation
-        # tradeoff).
-        hi = max(s for s in range(self.S) if self._slots[s] is not None) + 1
-        sb = bucket_for(hi, 1, self.S)
-        # SNAPSHOT the host arrays (.copy()): with a step left in flight,
-        # jit arg conversion can still be reading these buffers when the
-        # loop mutates them (pos/counter advance below, retire resets,
-        # token writes at process time) — the pre-lookahead engine was
-        # safe only because it blocked on every step before mutating
-        if tokens_dev is not None:
-            if tuple(getattr(tokens_dev, "shape", ())) != (sb,):
-                raise MXNetError(  # pragma: no cover - invariant guard
-                    "serve: lookahead token vector does not match the "
-                    "active bucket (retire/admit must drain the pipeline)")
-            tokens = tokens_dev
-        else:
-            tokens = self._tokens[:sb].copy()
-        fn = self._get_step(sb)
-        try:
-            ngs = None
-            if self.K > 1:
-                toks, nxt, steps, pools = fn(
-                    self._values, self._pools,
-                    tokens, self._pos[:sb].copy(), self._temps[:sb].copy(),
-                    self._topks[:sb].copy(), self._topps[:sb].copy(),
-                    self._seeds[:sb].copy(), self._counters[:sb].copy(),
-                    self._eos[:sb].copy(), self._remaining[:sb].copy())
-            elif self._grammar:
-                toks = steps = None
-                gcls_d, gnxt_d, gacc_d = self._gram_dev()
-                gstate = (prev.gstate if prev is not None
-                          else self._gstate[:sb].copy())
-                nxt, ngs, pools = fn(
-                    self._values, self._pools,
-                    tokens, self._pos[:sb].copy(),
-                    gcls_d, gnxt_d, gacc_d, gstate,
-                    self._eos[:sb].copy(),
-                    self._temps[:sb].copy(), self._topks[:sb].copy(),
-                    self._topps[:sb].copy(), self._seeds[:sb].copy(),
-                    self._counters[:sb].copy())
-            else:
-                toks = steps = None
-                nxt, pools = fn(
-                    self._values, self._pools,
-                    tokens, self._pos[:sb].copy(), self._temps[:sb].copy(),
-                    self._topks[:sb].copy(), self._topps[:sb].copy(),
-                    self._seeds[:sb].copy(), self._counters[:sb].copy())
-            self._pools = pools
-        except Exception as e:  # pragma: no cover - defensive
-            warnings.warn(f"serve: decode step failed: {e!r}")
-            if prev is not None:
-                # prev's tokens already exist on device: read them so no
-                # generated token is lost (and a request completing on
-                # that token retires OK, not error)
-                self._process_step(prev)
-            for s in range(self.S):
-                if self._slots[s] is not None:
-                    self._retire(s, STATUS_ERROR, error=str(e))
-            return None
-        rec = _PendingStep(
-            nxt=nxt, sb=sb, t0=t0, toks=toks, steps=steps, gstate=ngs,
-            slots=[(s, self._slots[s]) for s in range(sb)
-                   if self._slots[s] is not None])
-        # the dispatched program owns its snapshot of this tick's
-        # pos/counters; advance the host clocks now so the NEXT dispatch
-        # — possibly before this one is read — sees post-step values.
-        # K > 1 advances by K: the device runs K substeps whenever ANY
-        # row is live (the early exit fires only with every row done, and
-        # then every slot retires at the read and its clocks reset).
-        for s, _ in rec.slots:
-            self._pos[s] += self.K
-            self._counters[s] += self.K
-            self._remaining[s] -= self.K
-        try:
-            for dev in (rec.toks, rec.steps, nxt):
-                if dev is not None:
-                    dev.copy_to_host_async()   # start the D2H early
-        except Exception:
-            pass
-        return rec
-
-    # ------------------------------------------------------------ paged decode
     def _decoding(self) -> List[Tuple[int, "_Slot"]]:
         """(slot index, slot) for every decode-active slot, in row order.
         Mid-prefill slots are excluded — their decode rows are all-sink."""
@@ -2983,15 +2503,25 @@ class InferenceEngine:
                 self._preempt(victim)
                 preempted += 1
 
-    def _step_tick_paged(self):
-        """Paged analogue of the contiguous tick. The decode batch spans
-        the slot-index prefix up to the highest ACTIVE slot; inactive
-        rows in the bucket carry all-sink block tables (their writes land
-        in the sink page, their sampled tokens are discarded). The
-        lookahead token vector is fed back only while the active row set
-        is unchanged — activation (a prefill finishing), preemption and
-        retires all force a drain first, exactly the boundary the
-        contiguous engine handles with its admit/retire drains."""
+    def _step_tick(self):
+        """Advance decode one tick. Synchronous mode dispatches one step
+        and reads it. Lookahead mode dispatches step N+1 — feeding step
+        N's device token vector straight back in — BEFORE reading step N,
+        so the host sync overlaps the next step's compute; a retire at
+        the read drains the speculative step (its rows for dead slots are
+        discarded) so the loop can shrink/refill before re-dispatching.
+        Speculative mode (speculate=K) replaces the per-token step with
+        draft-verify rounds.
+
+        The decode batch spans the slot-index prefix up to the highest
+        ACTIVE slot; inactive rows in the bucket carry all-sink block
+        tables (their writes land in the sink page, their sampled tokens
+        are discarded). The lookahead token vector is fed back only while
+        the active row set is unchanged — activation (a prefill
+        finishing), preemption and retires all force a drain first."""
+        if self.spec:
+            self._step_tick_spec()
+            return
         prev, self._pending = self._pending, None
         with self._span("lease") as lease:    # may preempt (changes the set)
             lease.set(preempted=self._lease_decode())
@@ -3015,7 +2545,7 @@ class InferenceEngine:
             self._note_walk(disp, max(int(self._pos[s]) for s, _ in cur),
                             1, self.K)
             self._note_select(disp, [(int(self._pos[s]), 1) for s, _ in cur])
-            rec = self._dispatch_step_paged(prev, cur, sb)
+            rec = self._dispatch_step(prev, cur, sb)
         if rec is None:
             return
         if prev is not None:
@@ -3028,17 +2558,34 @@ class InferenceEngine:
         elif rec is not None:
             self._process_step(rec)
 
-    def _dispatch_step_paged(self, prev: Optional[_PendingStep],
-                             cur: List[Tuple[int, "_Slot"]], sb: int
-                             ) -> Optional[_PendingStep]:
-        """Dispatch one paged decode step over slot rows [0, sb): block
-        tables are snapshotted per dispatch (fresh arrays — nothing for
-        jit arg conversion to alias), inactive rows point every logical
-        page at the sink."""
-        t0 = time.perf_counter()
+    def _tables(self, cur: List[Tuple[int, "_Slot"]], sb: int
+                ) -> onp.ndarray:
+        """[sb, max_pages] snapshot of the decoding rows' block tables
+        for one dispatch (a fresh array — nothing for jit arg conversion
+        to alias); a row that decodes nothing points every logical page
+        at the sink."""
         tables = onp.full((sb, self.maxp), self._pages.sink, onp.int32)
         for s, _ in cur:
             tables[s] = self._pages.table(s)
+        return tables
+
+    def _dispatch_step(self, prev: Optional[_PendingStep],
+                       cur: List[Tuple[int, "_Slot"]], sb: int
+                       ) -> Optional[_PendingStep]:
+        """Dispatch one decode step over slot rows [0, sb) without
+        waiting for it. ``prev`` (lookahead) feeds the previous step's
+        device-resident output tokens back in; None reads the host token
+        array. Advances the host pos/counter clocks to match the
+        dispatched step. On dispatch failure, first processes ``prev`` —
+        its tokens were already computed and must not be lost (a request
+        finishing there completes OK, not error) — then retires the
+        remaining slots and returns None."""
+        t0 = time.perf_counter()
+        tables = self._tables(cur, sb)
+        # SNAPSHOT the host arrays (.copy()): with a step left in flight,
+        # jit arg conversion can still be reading these buffers when the
+        # loop mutates them (pos/counter advance below, retire resets,
+        # token writes at process time)
         if prev is not None:
             tokens = prev.nxt
         else:
@@ -3090,6 +2637,12 @@ class InferenceEngine:
             return None
         rec = _PendingStep(nxt=nxt, sb=sb, t0=t0, toks=toks, steps=steps,
                            gstate=ngs, slots=cur)
+        # the dispatched program owns its snapshot of this tick's
+        # pos/counters; advance the host clocks now so the NEXT dispatch
+        # — possibly before this one is read — sees post-step values.
+        # K > 1 advances by K: the device runs K substeps whenever ANY
+        # row is live (the early exit fires only with every row done, and
+        # then every slot retires at the read and its clocks reset).
         for s, _ in cur:
             self._pos[s] += self.K
             self._counters[s] += self.K
@@ -3104,8 +2657,8 @@ class InferenceEngine:
 
     # ------------------------------------------------------ speculative decode
     def _step_tick_spec(self):
-        """One self-speculative draft-verify round over every live slot
-        (both cache layouts). Drafts come from each request's OWN token
+        """One self-speculative draft-verify round over every live slot.
+        Drafts come from each request's OWN token
         history (serve/speculate.draft_from_history — n-gram prompt
         lookup, no draft model); ONE dispatch verifies all of them and
         emits 1..K true tokens per row. Rounds are synchronous by
@@ -3113,13 +2666,9 @@ class InferenceEngine:
         round accepts, so there is no pending step to overlap — the K
         tokens per host round-trip ARE the overlap win."""
         from . import speculate as _spec
-        if self._paged:
-            with self._span("lease") as lease:    # may preempt
-                lease.set(preempted=self._lease_decode())
-            cur = self._decoding()
-        else:
-            cur = [(s, self._slots[s]) for s in range(self.S)
-                   if self._slots[s] is not None]
+        with self._span("lease") as lease:    # may preempt
+            lease.set(preempted=self._lease_decode())
+        cur = self._decoding()
         if not cur:
             return
         sb = bucket_for(cur[-1][0] + 1, 1, self.S)
@@ -3127,7 +2676,7 @@ class InferenceEngine:
         t0 = time.perf_counter()
         # fresh arrays per dispatch (nothing for jit arg conversion to
         # alias); inactive bucket rows verify zeros against zeros at the
-        # sink/sliced rows and are discarded at the read
+        # sink rows and are discarded at the read
         inputs = onp.zeros((sb, T), onp.int32)
         gstates = (onp.zeros((sb, T), onp.int32) if self._grammar
                    else None)
@@ -3157,13 +2706,7 @@ class InferenceEngine:
         fn = self._get_spec(sb)
         try:
             args = (self._values, self._pools, inputs,
-                    self._pos[:sb].copy())
-            if self._paged:
-                tables = onp.full((sb, self.maxp), self._pages.sink,
-                                  onp.int32)
-                for s, _ in cur:
-                    tables[s] = self._pages.table(s)
-                args = args + (tables,)
+                    self._pos[:sb].copy(), self._tables(cur, sb))
             if self._grammar:
                 gcls_d, gnxt_d, gacc_d = self._gram_dev()
                 args = args + (gcls_d, gnxt_d, gacc_d, gstates,
@@ -3452,11 +2995,10 @@ class InferenceEngine:
             slot = self._slots[s]
             self._slots[s] = None
             self._completed[status] = self._completed.get(status, 0) + 1
-        if self._paged:
-            self._active[s] = False
-            self._prefills.pop(s, None)
-            # shared pages survive under their prefix-cache/other-slot refs
-            self._pages.release(s)
+        self._active[s] = False
+        self._prefills.pop(s, None)
+        # shared pages survive under their prefix-cache/other-slot refs
+        self._pages.release(s)
         self._reset_slot_state(s)
         req = slot.req
         now = time.perf_counter()
@@ -3543,19 +3085,17 @@ class InferenceEngine:
             "compiled_buckets": buckets,
             "max_len": self.L,
             "last_warmup_s": self.last_warmup_s,
-            "paged": self._paged,
+            "paged": True,    # the one layout; readers: http.py, bench/
             "grammar": self._grammar,
             "tier": self.tier,
             # the engine's KV HBM footprint (loadgen's requests/HBM-GB
-            # denominator): identical pool bytes, paged vs contiguous,
-            # when num_pages defaults to the contiguous layout's size
+            # denominator)
             "kv_bytes": self._kv_bytes,
             # of those, the bytes that every program built so far that
             # writes the pools updates where they lie: the least
             # memory_analysis().alias_size_in_bytes among them. Equal to
-            # kv_bytes when no program copies a pool; 0 for the
-            # contiguous layout (not donated), where the backend gives no
-            # analysis, and before the first program is built
+            # kv_bytes when no program copies a pool; 0 where the backend
+            # gives no analysis, and before the first program is built
             "pool_bytes_in_place": self._in_place or 0,
         }
         if self.spec:
@@ -3571,29 +3111,28 @@ class InferenceEngine:
         # pressure, plus queue backlog (0 = idle, 1 ≈ saturated, > 1 =
         # queueing)
         load = in_use / self.S
-        if self._paged:
-            pstats = self._pages.stats()
-            out["page_size"] = self.page_size
-            out["pages"] = pstats
-            out["prefilling"] = len(self._prefills)
-            out["preemptions"] = self._preempted
-            out["kv_walk_blocks"] = self._kv_walked
-            out["kv_table_blocks"] = self._kv_tabled
-            out["state_bytes"] = self._state_bytes
-            out["sparse_blocks_read"] = self._sel_read
-            out["sparse_blocks_live"] = self._sel_live
-            # bounded prefix-cache advert for the router's affinity
-            # scoring: top-N chained-hash roots by refcount (the
-            # serve_prefix_advert knob caps N; 0 disables the advert)
-            roots = self._pages.prefix_summary(self._prefix_advert)
-            out["prefix_summary"] = {"page_size": self.page_size,
-                                     "roots": roots}
-            _metrics.CACHE_ADVERT_ROOTS.set(len(roots))
-            # cache-only pins are reclaimable on demand (the admission
-            # gate already treats them as free) — a cache-warm idle
-            # replica must NOT advertise a saturated pool to the router
-            held = pstats["pages_in_use"] - pstats["pages_cached_only"]
-            load = max(load, held / pstats["pages"])
+        pstats = self._pages.stats()
+        out["page_size"] = self.page_size
+        out["pages"] = pstats
+        out["prefilling"] = len(self._prefills)
+        out["preemptions"] = self._preempted
+        out["kv_walk_blocks"] = self._kv_walked
+        out["kv_table_blocks"] = self._kv_tabled
+        out["state_bytes"] = self._state_bytes
+        out["sparse_blocks_read"] = self._sel_read
+        out["sparse_blocks_live"] = self._sel_live
+        # bounded prefix-cache advert for the router's affinity
+        # scoring: top-N chained-hash roots by refcount (the
+        # serve_prefix_advert knob caps N; 0 disables the advert)
+        roots = self._pages.prefix_summary(self._prefix_advert)
+        out["prefix_summary"] = {"page_size": self.page_size,
+                                 "roots": roots}
+        _metrics.CACHE_ADVERT_ROOTS.set(len(roots))
+        # cache-only pins are reclaimable on demand (the admission
+        # gate already treats them as free) — a cache-warm idle
+        # replica must NOT advertise a saturated pool to the router
+        held = pstats["pages_in_use"] - pstats["pages_cached_only"]
+        load = max(load, held / pstats["pages"])
         out["load"] = round(
             load + queue_depth / max(self.max_queue_depth, 1), 4)
         return out

@@ -62,7 +62,7 @@ def ref_eng(gpt_model):
     amply-sized engine — what any fleet dispatch must reproduce bitwise
     (stateless sampling: seed + position, never which replica)."""
     eng = InferenceEngine(gpt_model, max_batch_size=4, max_len=64,
-                          paged=True, page_size=8, num_pages=96).start()
+                          page_size=8, num_pages=96).start()
     yield eng
     eng.shutdown()
 
@@ -75,7 +75,7 @@ def pair(gpt_model):
     dispatch, so the untiered affinity/migration tests are unaffected
     while the tier tests ride the same engines."""
     engines = [InferenceEngine(gpt_model, max_batch_size=2, max_len=64,
-                               paged=True, page_size=8, num_pages=64,
+                               page_size=8, num_pages=64,
                                prefix_advert=32, tier=t).start()
                for t in ("prefill", "decode")]
     yield engines
@@ -144,7 +144,7 @@ def _wait_root(router, prompt, timeout=30.0):
 # ------------------------------------------------------------ adverts
 def test_prefix_advert_bounded_by_knob(gpt_model):
     eng = InferenceEngine(gpt_model, max_batch_size=2, max_len=64,
-                          paged=True, page_size=8, prefix_advert=2).start()
+                          page_size=8, prefix_advert=2).start()
     try:
         for s in (100, 200, 300):     # three distinct 16-token prefixes
             assert eng.generate(_prompt(s), 2, seed=s).status == "ok"
@@ -159,7 +159,7 @@ def test_prefix_advert_bounded_by_knob(gpt_model):
     finally:
         eng.shutdown()
     with pytest.raises(MXNetError, match="prefix_advert"):
-        InferenceEngine(gpt_model, max_len=64, paged=True, page_size=8,
+        InferenceEngine(gpt_model, max_len=64, page_size=8,
                         prefix_advert=-1)
 
 
@@ -315,10 +315,10 @@ def test_preempt_rescue_resumes_token_exact(gpt_model, ref_eng,
     ref = _reference(ref_eng, prompts, 8, seeds, temperature=0.7)
 
     victim = InferenceEngine(gpt_model, max_batch_size=3, max_len=32,
-                             paged=True, page_size=8, num_pages=5,
+                             page_size=8, num_pages=5,
                              prefix_cache=False).start()
     peer = InferenceEngine(gpt_model, max_batch_size=3, max_len=32,
-                           paged=True, page_size=8, num_pages=16,
+                           page_size=8, num_pages=16,
                            prefix_cache=False).start()
     install_preempt_rescue(victim, [peer])
     try:
@@ -415,7 +415,7 @@ def test_steady_state_no_recompile_with_affinity_and_migration(gpt_model,
     doc = peer.export_pages(migrated)
 
     eng = InferenceEngine(gpt_model, max_batch_size=2, max_len=32,
-                          paged=True, page_size=8).start()
+                          page_size=8).start()
     try:
         eng.warmup()
         with guards.no_recompile(block="serve"):

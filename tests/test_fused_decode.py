@@ -262,8 +262,8 @@ def test_spec_verify_launch_accounting():
     from mxnet_tpu.serve import InferenceEngine
     layers = 2
     net = _quantized(vocab=256, hidden=256, layers=layers, heads=4)
-    eng = InferenceEngine(net, max_batch_size=2, max_len=32, paged=True,
-                          page_size=8, speculate=3)
+    eng = InferenceEngine(net, max_batch_size=2, max_len=32, page_size=8,
+                          speculate=3)
     tally = _step_tally(eng, "spec", eng._build_step_spec, n=2)
     assert tally.pop("spec_verify") == 1
     assert tally == {"reference": 4 * layers + 1}

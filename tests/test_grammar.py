@@ -214,13 +214,14 @@ def test_submit_validation(gpt_model):
         eng.shutdown()
 
 
-def test_greedy_constrained_determinism_both_layouts(gpt_model):
-    """The same constrained greedy request emits IDENTICAL tokens on the
-    dense and the paged cache layouts, and both conform to the schema."""
+def test_greedy_constrained_determinism_across_page_sizes(gpt_model):
+    """The same constrained greedy request emits IDENTICAL tokens whatever
+    the page size (the default 16, and 8), and both conform to the
+    schema."""
     gram = compile_grammar(SCHEMA, V)
     prompt = onp.asarray([65, 66, 67, 68], onp.int32)
     outs = []
-    for kw in ({}, {"paged": True, "page_size": 8}):
+    for kw in ({}, {"page_size": 8}):
         eng = InferenceEngine(gpt_model, max_batch_size=2, max_len=64,
                               grammar=True, **kw).start()
         try:
@@ -242,7 +243,7 @@ def test_spec_passthrough_grammar_is_token_identical(gpt_model):
     touching accept/reject decisions."""
     prompt = onp.asarray([7, 8, 9, 7, 8, 9, 7], onp.int32)
     eng = InferenceEngine(gpt_model, max_batch_size=2, max_len=64,
-                          paged=True, page_size=8, speculate=3,
+                          page_size=8, speculate=3,
                           grammar=True).start()
     try:
         free = eng.generate(prompt, 10, seed=0)
@@ -261,7 +262,7 @@ def test_grammar_stream_spec_zero_recompiles(gpt_model):
     final result exactly."""
     from mxnet_tpu.analysis import guards
     eng = InferenceEngine(gpt_model, max_batch_size=2, max_len=64,
-                          paged=True, page_size=8, speculate=3,
+                          page_size=8, speculate=3,
                           grammar=True).start()
     eng.warmup()
     gram = compile_grammar(SCHEMA, V)

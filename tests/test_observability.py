@@ -170,7 +170,7 @@ def test_engine_http_span_tree_and_endpoints(gpt_model, traced):
     p2 = onp.concatenate([shared, rng.randint(1, 31, size=4)
                           .astype(onp.int32)])
     eng = InferenceEngine(gpt_model, max_batch_size=2, max_len=64,
-                          paged=True, page_size=8).start()
+                          page_size=8).start()
     fe = HTTPFrontend(eng, port=0).start()
 
     def generate(prompt, tp=None):

@@ -193,8 +193,8 @@ def engine():
                              num_heads=2, max_position_embeddings=256,
                              dropout=0.0))
     net.initialize()
-    eng = InferenceEngine(net, max_batch_size=2, max_len=256, paged=True,
-                          page_size=16, prefill_chunk=64)
+    eng = InferenceEngine(net, max_batch_size=2, max_len=256, page_size=16,
+                          prefill_chunk=64)
     eng.start()
     yield eng
     eng.shutdown()

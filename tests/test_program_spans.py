@@ -69,7 +69,7 @@ def host_lines(trace_dir):
 @pytest.fixture(scope="module")
 def engine():
     eng = InferenceEngine(tiny_gpt(), max_batch_size=2, max_len=32,
-                          paged=True, page_size=8)
+                          page_size=8)
     eng.warmup()
     eng.start()
     yield eng

@@ -538,6 +538,70 @@ def test_engine_rejects_uncacheable_model():
         InferenceEngine(net, max_batch_size=2, max_len=32)
 
 
+# ------------------------------------------------------------ one layout
+def test_default_engine_is_paged_off_the_chip(gpt_model):
+    """No backend switch: a default-constructed engine on the CPU serves
+    through pages, and the pool's keys of ``stats()`` are always there."""
+    stats = InferenceEngine(gpt_model, max_batch_size=2, max_len=32).stats()
+    assert stats["paged"] is True
+    assert stats["page_size"] == 16 and stats["pages"]["pages"] == 4
+    assert {"prefilling", "preemptions", "kv_walk_blocks", "state_bytes",
+            "prefix_summary"} <= set(stats)
+
+
+def _tiny_llama():
+    net = LlamaForCausalLM(LlamaConfig(
+        vocab_size=32, hidden_size=32, intermediate_size=64, num_layers=1,
+        num_heads=4, num_kv_heads=2, dtype=onp.float32))
+    net.initialize()
+    return net
+
+
+@pytest.mark.parametrize("family", ["gpt", "llama"])
+def test_paged_false_is_refused_with_the_reason(gpt_model, family):
+    net = gpt_model if family == "gpt" else _tiny_llama()
+    with pytest.raises(mx.MXNetError, match="one cache layout") as err:
+        InferenceEngine(net, max_batch_size=2, max_len=32, paged=False)
+    assert "models.generate" in str(err.value)
+    assert InferenceEngine(net, max_batch_size=2, max_len=32,
+                           paged=True).stats()["paged"] is True
+
+
+def test_explicit_page_size_must_divide_max_len(gpt_model):
+    with pytest.raises(mx.MXNetError, match="multiple of page_size"):
+        InferenceEngine(gpt_model, max_batch_size=2, max_len=40,
+                        page_size=16)
+
+
+def test_tuned_page_size_that_does_not_divide_falls_back(gpt_model,
+                                                         monkeypatch):
+    """A page size from the environment or the tuned layer, measured at
+    another max_len, must not brick a default-constructed engine: it
+    warns and serves with the knob's default; where that does not divide
+    either, the pool's own error stands."""
+    monkeypatch.setenv("MXNET_TUNE_SERVE_PAGE_SIZE", "12")
+    with pytest.warns(UserWarning, match="serve_page_size=12"):
+        eng = InferenceEngine(gpt_model, max_batch_size=2, max_len=32)
+    assert eng.page_size == 16 and eng.stats()["page_size"] == 16
+    assert InferenceEngine(gpt_model, max_batch_size=2,
+                           max_len=48).page_size == 12    # divides: kept
+    with pytest.warns(UserWarning, match="serve_page_size=12"), \
+            pytest.raises(mx.MXNetError, match="multiple of page_size"):
+        InferenceEngine(gpt_model, max_batch_size=2, max_len=40)
+
+
+def test_model_without_the_paged_protocol_is_refused(gpt_model):
+    """``cache_spec``/``forward_cached`` alone served the contiguous
+    layout; the engine now says what is missing."""
+    class ContiguousOnly:
+        cfg = gpt_model.cfg
+        cache_spec = gpt_model.cache_spec
+        forward_cached = gpt_model.forward_cached
+
+    with pytest.raises(mx.MXNetError, match="cache_spec_paged"):
+        InferenceEngine(ContiguousOnly(), max_batch_size=2, max_len=32)
+
+
 # ------------------------------------------------------------ telemetry
 def test_zero_recompiles_after_warmup(gpt_model):
     """The tier-1 serving smoke: boot the engine in-process, warm the
@@ -553,8 +617,13 @@ def test_zero_recompiles_after_warmup(gpt_model):
                           min_prompt_bucket=8).start()
     try:
         eng.warmup()
-        buckets = eng.stats()["compiled_buckets"]
-        assert len(buckets["prefill"]) + len(buckets["decode"]) >= 6
+        # the ladder: prefill buckets up to prefill_chunk (one page), the
+        # step buckets, and one chunk, copy, extract and inject program
+        assert eng.stats()["compiled_buckets"] == {"prefill": [8, 16],
+                                                   "decode": [1, 2, 4]}
+        assert [len(d) for d in (eng._chunk_fns, eng._copy_fns,
+                                 eng._extract_fns, eng._inject_fns)] \
+            == [1, 1, 1, 1]
         prompts = _mixed_prompts(8, lo=2, hi=20, seed=3)
         results = [None] * 8
         errors = []

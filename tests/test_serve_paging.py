@@ -1,12 +1,12 @@
-"""Paged KV serving (mxnet_tpu/serve/paging + paged engine + router).
+"""Paged KV serving (mxnet_tpu/serve/paging + engine + router).
 
-The tier-1 contracts of the paged rebuild:
+The tier-1 contracts of the engine's cache layout:
 
 - ledger invariants: page lease/free accounting never leaks across slot
   refills, copy-on-write forks on the first divergent token, prefix-hash
   collisions fall back to full prefill;
-- bitwise parity: paged greedy decode is token-identical to the
-  contiguous engine AND to ``generate()`` — gpt, llama (per-layer and
+- bitwise parity: greedy decode is token-identical to ``generate()``,
+  which decodes through the contiguous cache — gpt, llama (per-layer and
   stacked-scan caches), ``multi_token=K``, prefix reuse, chunked
   prefill, preemption-resume;
 - capacity: 4x the contiguous slot count served on the SAME pool bytes,
@@ -198,20 +198,16 @@ def test_pages_for():
 # ------------------------------------------------------ engine bitwise parity
 @pytest.mark.slow
 def test_paged_vs_contiguous_parity_gpt(gpt_model):
-    """Greedy decode must be token-identical between the contiguous and
-    paged layouts through the on-device multi-token loop. (K=1 paged
-    output is asserted against the same generate() reference by the
+    """Greedy decode through the on-device multi-token loop must be
+    token-identical to generate(), the contiguous reference. (K=1
+    output is asserted against the same reference by the
     prefix/chunked/preemption tests below, so only the K>1 engine is
     built here — tier-1 budget.)"""
     prompts = _prompts(4, seed=1)
-    base = _serve_all(gpt_model, prompts, 8, max_batch_size=2, max_len=32,
-                      paged=False)
     paged = _serve_all(gpt_model, prompts, 8, max_batch_size=2,
-                       max_len=32, paged=True, page_size=8,
+                       max_len=32, page_size=8,
                        multi_token=3)
-    assert paged == base
-    for p, out in zip(prompts, base):
-        assert out == _reference(gpt_model, p, 8)
+    assert paged == [_reference(gpt_model, p, 8) for p in prompts]
 
 
 @pytest.mark.slow
@@ -221,7 +217,7 @@ def test_paged_fused_parity_llama():
     ``_q_lm_head``, so ``head_weights()`` feeds the fused LM-head
     sampler through ``forward_cached_paged_hidden``) decoded through
     the on-device multi-token loop over the PAGED pool must be
-    token-identical to the contiguous engine at K∈{1,4} — tier-1,
+    token-identical to generate() on the same net at K∈{1,4} — tier-1,
     per-layer decoder (llama has no fused block kernel; its fused
     decode surface is the head + the device loop)."""
     from mxnet_tpu.contrib.quantization import quantize_net
@@ -232,17 +228,16 @@ def test_paged_fused_parity_llama():
     net = LlamaForCausalLM(cfg)
     net.initialize()
     net(np.array(onp.zeros((1, 4), "int32")))
-    # int8 weight-only everywhere incl. the tied head — BOTH engines
-    # below serve this same quantized net, so the comparison isolates
-    # the paged fused-head/multi-token machinery, not quantization
+    # int8 weight-only everywhere incl. the tied head — the engines and
+    # the reference below run this same quantized net, so the comparison
+    # isolates the paged fused-head/multi-token machinery, not quantization
     quantize_net(net, calib_mode="none", quantize_tied_head=True)
     assert net.head_weights() is not None
     prompts = _prompts(3, vocab=30, seed=5)
-    base = _serve_all(net, prompts, 6, max_batch_size=2, max_len=32,
-                      paged=False)
+    base = [_reference(net, p, 6) for p in prompts]
     for K in (1, 4):
         paged = _serve_all(net, prompts, 6, max_batch_size=2, max_len=32,
-                           paged=True, page_size=8, multi_token=K)
+                           page_size=8, multi_token=K)
         assert paged == base, f"multi_token={K}"
 
 
@@ -250,8 +245,8 @@ def test_paged_fused_parity_llama():
 def test_paged_fused_parity_llama_int4():
     """The int4 llama surface: bits=4 packs the tied head as nibble
     codes (``head_weights()`` hands the uint8 table to the fused
-    sampler), and paged multi-token decode stays token-identical to the
-    contiguous engine — same contract as the int8 test one up, on the
+    sampler), and paged multi-token decode stays token-identical to
+    generate() — same contract as the int8 test one up, on the
     quartered weight stream."""
     import jax.numpy as jnp
     from mxnet_tpu.contrib.quantization import quantize_net
@@ -265,12 +260,11 @@ def test_paged_fused_parity_llama_int4():
     quantize_net(net, calib_mode="none", quantize_tied_head=True, bits=4)
     assert net.head_weights()[0].dtype == jnp.uint8
     prompts = _prompts(3, vocab=30, seed=5)
-    base = _serve_all(net, prompts, 6, max_batch_size=2, max_len=32,
-                      paged=False)
+    base = [_reference(net, p, 6) for p in prompts]
     # K=4 is the full surface (fused int4 head + device loop); K=1 adds
     # only engine builds (the int8 twin above covers it)
     paged = _serve_all(net, prompts, 6, max_batch_size=2, max_len=32,
-                       paged=True, page_size=8, multi_token=4)
+                       page_size=8, multi_token=4)
     assert paged == base
 
 
@@ -287,11 +281,10 @@ def test_paged_parity_llama_per_layer_and_stacked():
                           stacked=stacked)
         net = LlamaForCausalLM(cfg)
         net.initialize()
-        base = _serve_all(net, prompts, 6, max_batch_size=2, max_len=32,
-                          paged=False)
+        base = [_reference(net, p, 6) for p in prompts]
         for K in (1, 4):
             paged = _serve_all(net, prompts, 6, max_batch_size=2,
-                               max_len=32, paged=True, page_size=8,
+                               max_len=32, page_size=8,
                                multi_token=K)
             assert paged == base, f"stacked={stacked} multi_token={K}"
 
@@ -308,7 +301,7 @@ def test_prefix_reuse_parity_and_cow(gpt_model):
                                 .astype(onp.int32)])
                for i in range(5)]
     eng = InferenceEngine(gpt_model, max_batch_size=1, max_len=64,
-                          paged=True, page_size=8).start()
+                          page_size=8).start()
     try:
         outs = []
         for i, p in enumerate(prompts):     # sequential: prefix publishes
@@ -334,7 +327,7 @@ def test_prefix_collision_engine_fallback(gpt_model):
     stays tier-1 in test_pool_hash_collision_falls_back_to_prefill.)"""
     prompts = _prompts(3, lo=6, hi=12, seed=4)
     eng = InferenceEngine(gpt_model, max_batch_size=1, max_len=32,
-                          paged=True, page_size=8).start()
+                          page_size=8).start()
     eng._pages._hash = lambda toks: 13
     try:
         outs = []
@@ -366,7 +359,7 @@ def test_chunked_prefill_interleaves_with_decode(gpt_model):
     chunks0 = metrics.get_sample_value(
         "mxnet_serve_page_prefill_chunks_total") or 0
     eng = InferenceEngine(gpt_model, max_batch_size=2, max_len=64,
-                          paged=True, page_size=8).start()
+                          page_size=8).start()
     try:
         h_short = eng.submit(short_p, 12)
         h_long = eng.submit(long_p, 6)
@@ -392,7 +385,7 @@ def test_preemption_resume_is_exact(gpt_model):
                .astype(onp.int32) for i in range(3)]
     # 2 slots but pages for ~1.5 requests: preemption is forced
     eng = InferenceEngine(gpt_model, max_batch_size=2, max_len=64,
-                          paged=True, page_size=8, num_pages=8,
+                          page_size=8, num_pages=8,
                           prefix_cache=False).start()
     try:
         handles = [eng.submit(p, 18, seed=i)
@@ -429,8 +422,8 @@ def test_pool_is_rows_of_all_kv_heads(stacked):
     lead = (2,) if stacked else ()
     assert [s for s, _ in spec] == [lead + (5, 8, 2 * 8)] * (
         2 if stacked else 4)
-    eng = InferenceEngine(net, max_batch_size=2, max_len=32, paged=True,
-                          page_size=8, num_pages=6)
+    eng = InferenceEngine(net, max_batch_size=2, max_len=32, page_size=8,
+                          num_pages=6)
     assert eng._paxes == [len(lead)] * len(spec)
     assert eng._pools[0].shape == lead + (7, 8, 16)
     assert [a.shape for a in eng._page_payload_spec()] == [
@@ -445,7 +438,7 @@ def test_paged_llama_grouped_heads_match_generate(stacked):
     net = _tiny_llama(stacked)
     prompts = _prompts(3, vocab=30, seed=2)
     served = _serve_all(net, prompts, 6, max_batch_size=2, max_len=32,
-                        paged=True, page_size=8)
+                        page_size=8)
     assert served == [_reference(net, p, 6) for p in prompts]
 
 
@@ -468,7 +461,7 @@ def test_every_program_updates_the_pools_in_place(gpt_model, name):
     net = _tiny_llama(True) if name == "stacked-llama" else gpt_model
     kw = dict(max_batch_size=2, max_len=32, page_size=8)
     kw.update(IN_PLACE_ENGINES[name])
-    eng = InferenceEngine(net, paged=True, **kw)
+    eng = InferenceEngine(net, **kw)
     assert eng.stats()["pool_bytes_in_place"] == 0      # nothing built yet
     given = eng._pools
     eng.warmup()
@@ -481,15 +474,6 @@ def test_every_program_updates_the_pools_in_place(gpt_model, name):
         live = onp.take(onp.asarray(p, onp.float32),
                         range(p.shape[ax] - 1), axis=ax)
         assert not live.any()
-
-
-def test_contiguous_pools_are_not_donated(gpt_model):
-    eng = InferenceEngine(gpt_model, max_batch_size=2, max_len=32,
-                          paged=False)
-    given = eng._pools
-    eng.warmup()
-    assert not any(p.is_deleted() for p in given)
-    assert eng.stats()["pool_bytes_in_place"] == 0
 
 
 @pytest.mark.parametrize("running", [False, True],
@@ -506,7 +490,7 @@ def test_second_warmup_then_fork_and_inject_serve_identically(gpt_model,
         onp.int32)
     b = onp.concatenate([shared, rng.randint(1, 30, size=5)]).astype(
         onp.int32)
-    kw = dict(max_batch_size=2, max_len=48, paged=True, page_size=8)
+    kw = dict(max_batch_size=2, max_len=48, page_size=8)
     src = InferenceEngine(gpt_model, **kw)
     dst = InferenceEngine(gpt_model, **kw)
     if running:
@@ -540,7 +524,7 @@ def test_a_program_that_fails_with_the_pools_closes_the_engine(gpt_model):
     deleted arrays behind: the engine fails its requests with that
     reason and closes; it does not serve on."""
     eng = InferenceEngine(gpt_model, max_batch_size=2, max_len=32,
-                          paged=True, page_size=8).start()
+                          page_size=8).start()
     try:
         assert eng.generate(onp.arange(1, 6), 3).status == "ok"
         real = eng._get_step(1)
@@ -608,7 +592,7 @@ def test_greedy_tokens_are_the_parents(gpt_model, path):
     rng = onp.random.RandomState(seed)
     prompts = [rng.randint(1, 30, size=rng.randint(lo, hi)).astype(onp.int32)
                for _ in range(n)]
-    eng = InferenceEngine(gpt_model, paged=True, page_size=8, **kw).start()
+    eng = InferenceEngine(gpt_model, page_size=8, **kw).start()
     try:
         handles = [eng.submit(p, new, seed=i) for i, p in enumerate(prompts)]
         results = [h.result(300) for h in handles]
@@ -628,7 +612,7 @@ def test_page_accounting_clean_after_mixed_traffic(gpt_model):
     the cache off."""
     prompts = _prompts(10, seed=6)
     eng = InferenceEngine(gpt_model, max_batch_size=2, max_len=32,
-                          paged=True, page_size=8,
+                          page_size=8,
                           prefix_cache=False).start()
     try:
         handles = [eng.submit(p, 6 + (i % 5), timeout_s=(
@@ -659,7 +643,7 @@ def test_4x_concurrency_on_contiguous_hbm_budget(gpt_model):
     contiguous_rows = 4 * 32
     prompts = _prompts(16, lo=3, hi=6, seed=7)
     eng = InferenceEngine(gpt_model, max_batch_size=16, max_len=32,
-                          paged=True, page_size=8,
+                          page_size=8,
                           num_pages=contiguous_rows // 8,
                           prefix_cache=False, max_queue_depth=32).start()
     try:
@@ -690,7 +674,7 @@ def test_http_drain_endpoint_and_healthz_pages(gpt_model):
     router's failover signal) while in-flight requests finish; /healthz
     carries the page occupancy + load the router keys on."""
     eng = InferenceEngine(gpt_model, max_batch_size=2, max_len=32,
-                          paged=True, page_size=8).start()
+                          page_size=8).start()
     with HTTPFrontend(eng, port=0) as fe:
         doc = json.loads(urllib.request.urlopen(
             fe.url + "/healthz", timeout=10).read())
@@ -740,7 +724,7 @@ def test_router_drain_rejoin_no_failed_requests(gpt_model):
     eject and the rejoin."""
     def boot(port=0):
         e = InferenceEngine(gpt_model, max_batch_size=2, max_len=32,
-                            paged=True, page_size=8).start()
+                            page_size=8).start()
         f = HTTPFrontend(e, port=port).start()
         return e, f
 
@@ -803,7 +787,7 @@ def test_router_failover_and_no_backend_error(gpt_model):
     an empty rotation raises NoBackendError."""
     from mxnet_tpu.serve import NoBackendError
     eng = InferenceEngine(gpt_model, max_batch_size=2, max_len=32,
-                          paged=True, page_size=8).start()
+                          page_size=8).start()
     fe = HTTPFrontend(eng, port=0).start()
     # second backend: a port nothing listens on
     dead = "http://127.0.0.1:1"

@@ -123,17 +123,18 @@ def test_spec_verify_tokens_acceptance_arithmetic():
 
 
 # ------------------------------------------------------- engine token-exact
-@pytest.mark.parametrize("paged", [False, True])
-def test_spec_token_exact_mixed_sampling_gpt(gpt_model, paged):
+@pytest.mark.parametrize("page_size", [8, 16])
+def test_spec_token_exact_mixed_sampling_gpt(gpt_model, page_size):
     """speculate=K output must be IDENTICAL to speculate=0 for a mix of
-    greedy, temperature-sampled and filtered requests, both layouts —
+    greedy, temperature-sampled and filtered requests, whether a verify
+    round crosses a page boundary often (8) or rarely (16) —
     the sampled rows are the sharp edge: the verify recomputes the same
     categorical draw from the same stateless fold_in key."""
     prompts = _prompts(6, seed=1)
     reqs = [dict(temperature=(0.0 if i % 2 == 0 else 0.9),
                  top_k=(5 if i % 3 == 0 else 0), seed=i * 11)
             for i in range(6)]
-    kw = dict(paged=True, page_size=8) if paged else dict(paged=False)
+    kw = dict(page_size=page_size)
     base, _ = _serve_all(gpt_model, prompts, 9, reqs, max_batch_size=2,
                          max_len=48, **kw)
     spec, st = _serve_all(gpt_model, prompts, 9, reqs, max_batch_size=2,
@@ -149,16 +150,15 @@ def test_spec_eos_mid_round(gpt_model):
     the result matches the non-speculative engine exactly."""
     prompts = _prompts(3, seed=2)
     base, _ = _serve_all(gpt_model, prompts, 10, max_batch_size=2,
-                         max_len=48, paged=True, page_size=8)
+                         max_len=48, page_size=8)
     # pick an eos that actually occurs mid-stream for at least one row
     eos = next((t for out in base for t in out[:-1]), None)
     reqs = [dict(eos_token_id=int(eos))] * 3
     base_eos, _ = _serve_all(gpt_model, prompts, 10, reqs,
-                             max_batch_size=2, max_len=48, paged=True,
-                             page_size=8)
+                             max_batch_size=2, max_len=48, page_size=8)
     spec_eos, _ = _serve_all(gpt_model, prompts, 10, reqs,
-                             max_batch_size=2, max_len=48, paged=True,
-                             page_size=8, speculate=5)
+                             max_batch_size=2, max_len=48, page_size=8,
+                             speculate=5)
     assert spec_eos == base_eos
 
 
@@ -172,7 +172,7 @@ def test_spec_composes_with_prefix_cache_and_chunked_prefill(gpt_model):
                                 rng.randint(1, 60, size=3 + i)
                                 .astype(onp.int32)])
                for i in range(4)]
-    kw = dict(max_batch_size=2, max_len=64, paged=True, page_size=8,
+    kw = dict(max_batch_size=2, max_len=64, page_size=8,
               prefill_chunk=8, prefix_cache=True)
     base, _ = _serve_all(gpt_model, prompts, 8, **kw)
     spec, st = _serve_all(gpt_model, prompts, 8, speculate=4, **kw)
@@ -193,9 +193,9 @@ def test_spec_with_quantized_paged_decode():
     quantize_net(net, calib_mode="none")
     prompts = _prompts(4, seed=6)
     base, _ = _serve_all(net, prompts, 8, max_batch_size=2,
-                         max_len=48, paged=True, page_size=8)
+                         max_len=48, page_size=8)
     spec, _ = _serve_all(net, prompts, 8, max_batch_size=2,
-                         max_len=48, paged=True, page_size=8,
+                         max_len=48, page_size=8,
                          speculate=4)
     assert spec == base
 
@@ -211,9 +211,9 @@ def test_spec_parity_llama(gpt_model):
     net.initialize()
     prompts = _prompts(3, vocab=30, seed=7)
     base, _ = _serve_all(net, prompts, 6, max_batch_size=2, max_len=32,
-                         paged=True, page_size=8)
+                         page_size=8)
     spec, _ = _serve_all(net, prompts, 6, max_batch_size=2, max_len=32,
-                         paged=True, page_size=8, speculate=3)
+                         page_size=8, speculate=3)
     assert spec == base
 
 
@@ -234,8 +234,8 @@ def test_router_serves_paged_quantized_speculative_no_recompiles():
     net.initialize()
     net(np.array(onp.zeros((1, 4), "int32")))
     quantize_net(net, calib_mode="none")
-    eng = InferenceEngine(net, max_batch_size=2, max_len=48, paged=True,
-                          page_size=8, speculate=4).start()
+    eng = InferenceEngine(net, max_batch_size=2, max_len=48, page_size=8,
+                          speculate=4).start()
     eng.warmup()
     rounds0 = metrics.get_sample_value("mxnet_spec_rounds_total") or 0
     prompts = _prompts(5, seed=8)

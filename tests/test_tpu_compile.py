@@ -272,8 +272,8 @@ def pool_engines():
                 dtype="bfloat16"))
             net.initialize()
             made[width] = InferenceEngine(
-                net, max_batch_size=16, max_len=1024, paged=True,
-                page_size=16, num_pages=320, prefill_chunk=128)
+                net, max_batch_size=16, max_len=1024, page_size=16,
+                num_pages=320, prefill_chunk=128)
         return made[width]
 
     return engine
@@ -302,8 +302,7 @@ def test_pools_are_written_in_place(one_chip, pool_engines, width, program,
     a step (PERF.md section 6, PR 31). A CPU run cannot see this."""
     import re
     eng = pool_engines(width)
-    build = (eng._build_step_paged if program == "decode"
-             else eng._build_prefill_paged)
+    build = eng._build_step if program == "decode" else eng._build_prefill
     compiled = build(bucket).lower(
         *_shapes(eng._example_args(program, bucket), one_chip)).compile()
     text = compiled.as_text()

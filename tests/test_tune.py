@@ -383,9 +383,9 @@ def test_engine_defaults_bitwise_without_tuned_config(no_tune):
     explicit = InferenceEngine(net, max_batch_size=2, max_len=32,
                                min_prompt_bucket=8, multi_token=1,
                                page_size=16, bucket_growth=2)
-    assert (eng.K, eng.min_prompt_bucket, eng._growth, eng._paged) == \
+    assert (eng.K, eng.min_prompt_bucket, eng._growth, eng.page_size) == \
         (explicit.K, explicit.min_prompt_bucket, explicit._growth,
-         explicit._paged) == (1, 8, 2, False)
+         explicit.page_size) == (1, 8, 2, 16)
 
 
 def test_engine_consults_tuned_config_and_explicit_wins(tune_dir):

@@ -82,8 +82,7 @@ def prewarm(args) -> dict:
 
     t0 = time.perf_counter()
     eng = InferenceEngine(net, max_batch_size=args.max_batch_size,
-                          max_len=args.max_len, paged=args.paged or None,
-                          page_size=args.page_size)
+                          max_len=args.max_len, page_size=args.page_size)
     eng.warmup()
     serve_s = eng.last_warmup_s
 
@@ -214,11 +213,6 @@ def main() -> int:
     ap.add_argument("--heads", type=int, default=4)
     ap.add_argument("--max-batch-size", type=int, default=16)
     ap.add_argument("--max-len", type=int, default=128)
-    ap.add_argument("--paged", action="store_true",
-                    help="prewarm the PAGED serve ladder (block-table "
-                         "executables) — match what the fleet's replicas "
-                         "run (serve_router --paged; on TPU paged is "
-                         "already the engine default)")
     ap.add_argument("--page-size", type=int, default=16)
     ap.add_argument("--train-batch", type=int, default=0,
                     help="also prewarm the fused TrainStep for this batch "

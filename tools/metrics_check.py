@@ -412,20 +412,29 @@ def run_perf_check(chip=None):
 
         # --- serve: tiny GPT bucket ladder (one entry per bucket) ---
         # the SMALLEST model/ladder that still exercises per-bucket
-        # ledger keys (2 prefill + 1 decode buckets): every extra bucket
-        # is a compile + capture lowering on the tier-1 clock
+        # ledger keys (2 prefill + 1 decode buckets, and the page copy,
+        # extract and inject; one page, so no chunk program): every extra
+        # bucket is a compile + capture lowering on the tier-1 clock
         net2 = GPTModel(GPTConfig(
             vocab_size=64, hidden_size=16, num_layers=1, num_heads=2,
             max_position_embeddings=32, dropout=0.0))
         net2.initialize()
-        eng = InferenceEngine(net2, max_batch_size=1, max_len=16)
+        # two pages: the request below forks its prompt's page, which the
+        # prefix cache shares, at its first decode write; the default pool
+        # of this geometry is that one page and would preempt instead
+        eng = InferenceEngine(net2, max_batch_size=1, max_len=16,
+                              num_pages=2)
         eng.warmup()
         # enumerate via the engine's RESOLVED knobs (min bucket/growth
         # may come from MXNET_TUNE_* env or a tuned config — recomputing
         # at the defaults would false-fail the check under operator env)
         expect = ([f"serve_prefill:b{pb}"
-                   for pb in bucket_ladder(eng.min_prompt_bucket, eng.L,
-                                           eng._growth)]
+                   for pb in bucket_ladder(eng.min_prompt_bucket,
+                                           eng._chunk, eng._growth)]
+                  + ([f"serve_chunk:b{eng._chunk}"]
+                     if eng._chunk < eng.L else [])
+                  + ["serve_copy:b0", "serve_extract:b0",
+                     "serve_inject:b0"]
                   + [f"serve_decode:b{sb}"
                      for sb in bucket_ladder(1, eng.S)])
         missing_entries = [k for k in expect if perf.LEDGER.get(k) is None]
@@ -915,7 +924,7 @@ def run_spec_check():
             # must compare against a REALLY non-speculative baseline
             # even when a tuned serve_speculate winner is active
             eng = InferenceEngine(net, max_batch_size=2, max_len=64,
-                                  paged=True, page_size=8,
+                                  page_size=8,
                                   speculate=spec).start()
             try:
                 return [list(eng.generate(p, 12).generated_ids)
@@ -1009,7 +1018,7 @@ def run_grammar_check():
         prompts = [onp.asarray([65] * 6 + [int(rng.randint(1, 120))],
                                onp.int32) for _ in range(3)]
         eng = InferenceEngine(net, max_batch_size=2, max_len=64,
-                              paged=True, page_size=8, speculate=4,
+                              page_size=8, speculate=4,
                               grammar=True).start()
         try:
             results = [eng.generate(p, 40, grammar=schema,
@@ -1420,7 +1429,7 @@ def run_paging_check():
 
         # --- paged engine: prefix reuse + chunked prefill + COW ---
         eng = InferenceEngine(build(), max_batch_size=2, max_len=64,
-                              paged=True, page_size=8).start()
+                              page_size=8).start()
         try:
             for i, p in enumerate(prompts):   # sequential: prefixes publish
                 res = eng.submit(p, 6, seed=i).result(300)
@@ -1459,7 +1468,7 @@ def run_paging_check():
 
         # --- 2-replica router: least-loaded dispatch + drain eject ---
         engines = [InferenceEngine(build(), max_batch_size=1, max_len=32,
-                                   paged=True, page_size=8).start()
+                                   page_size=8).start()
                    for _ in range(2)]
         fronts = [HTTPFrontend(e, port=0).start() for e in engines]
         router = Router([f.url for f in fronts],
@@ -1806,7 +1815,7 @@ def run_cache_check():
 
         # --- (a) bounded advert -> affinity hit at the router ---
         engines = [InferenceEngine(build(), max_batch_size=2, max_len=64,
-                                   paged=True, page_size=8,
+                                   page_size=8,
                                    prefix_advert=4).start()
                    for _ in range(2)]
         fronts = [HTTPFrontend(e, port=0).start() for e in engines]
@@ -2045,7 +2054,7 @@ def run_trace_check():
         client_trace = "11" * 16
         tp = f"00-{client_trace}-{'22' * 8}-01"
         eng = InferenceEngine(net, max_batch_size=2, max_len=64,
-                              paged=True, page_size=8).start()
+                              page_size=8).start()
         try:
             res = eng.submit(prompt, 6, traceparent=tp).result(300)
         finally:

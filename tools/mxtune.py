@@ -177,11 +177,9 @@ def _engine_rounds(args, engine_kwargs, prompts, max_new):
     net = _build_model(args)
     # every knob pinned explicitly: a trial measures exactly its config,
     # never a previously committed tuned config the engine would
-    # otherwise consult (explicit args outrank the tune layer). paged
-    # is pinned too — the TPU default would otherwise flip it mid-sweep
+    # otherwise consult (explicit args outrank the tune layer)
     kwargs = {"min_prompt_bucket": 8, "multi_token": 1, "page_size": 16,
-              "bucket_growth": 2, "prefill_chunk": 16, "paged": False,
-              "speculate": 0}
+              "bucket_growth": 2, "prefill_chunk": 16, "speculate": 0}
     kwargs.update(engine_kwargs)
     eng = InferenceEngine(net, max_batch_size=args.max_batch_size,
                           max_len=args.max_len,
@@ -273,8 +271,7 @@ def spec_workload(args):
         spec = cfg["serve_speculate"]
         # every knob pinned explicitly (incl. speculate=0): a previously
         # committed winner must never leak into a trial's measurement
-        kw = {"min_prompt_bucket": 8, "multi_token": 1, "paged": False,
-              "speculate": spec}
+        kw = {"min_prompt_bucket": 8, "multi_token": 1, "speculate": spec}
         if spec:
             kw["spec_lookup"] = cfg["serve_spec_lookup"]
         eng = InferenceEngine(net, max_batch_size=2,
@@ -333,8 +330,7 @@ def prefill_workload(args):
 
     def measure(cfg):
         times, regime, mfu = _engine_rounds(
-            args, {"paged": True,
-                   "page_size": cfg["serve_page_size"],
+            args, {"page_size": cfg["serve_page_size"],
                    "prefill_chunk": cfg["serve_prefill_chunk"]},
             prompts, NEW)
         return {"values": [B * (P + NEW) / t for t in times],

@@ -37,7 +37,7 @@ Examples::
     # own history verified in one dispatch; --spec-compare reruns the
     # identical traffic with --speculate 0 and prints the tok/s duel +
     # acceptance rate (the >=1.5x acceptance scenario)
-    JAX_PLATFORMS=cpu python tools/serve_loadgen.py --paged \
+    JAX_PLATFORMS=cpu python tools/serve_loadgen.py \
         --structured --speculate 6 --concurrency 1 --requests 8 \
         --max-new-tokens 80 --spec-compare
 
@@ -45,10 +45,10 @@ Examples::
     JAX_PLATFORMS=cpu python tools/serve_loadgen.py \
         --aot-cache-dir /tmp/aot --aot-compare
 
-    # paged KV on the 16-slot contiguous HBM budget, 64-way concurrency
-    # (the >=4x requests/HBM acceptance): short mixed traffic, report
+    # 64-way concurrency on the pool bytes of 16 slots x max_len (the
+    # >=4x requests/HBM acceptance): short mixed traffic, report
     # includes in-flight peak per pool GB
-    JAX_PLATFORMS=cpu python tools/serve_loadgen.py --paged \
+    JAX_PLATFORMS=cpu python tools/serve_loadgen.py \
         --max-batch-size 64 --num-pages 128 --prompt-max 12 \
         --max-new-tokens 12 --concurrency 64 --requests 2
 
@@ -64,12 +64,12 @@ Examples::
     # shared system-prompt traffic: every request carries the same
     # 24-token prefix; --prefix-compare reruns with the prefix cache off
     # and prints the mean-TTFT delta
-    JAX_PLATFORMS=cpu python tools/serve_loadgen.py --paged \
+    JAX_PLATFORMS=cpu python tools/serve_loadgen.py \
         --shared-prefix 24 --prefix-compare
 
     # mixed long-prompt traffic: 25% of prompts near max_len exercise
     # chunked prefill (bounded TTFT p99 for the short requests in flight)
-    JAX_PLATFORMS=cpu python tools/serve_loadgen.py --paged \
+    JAX_PLATFORMS=cpu python tools/serve_loadgen.py \
         --long-prompt-mix 0.25
 
     # self-managing fleet under step traffic: OPEN-loop ramp-hold-drop
@@ -248,14 +248,11 @@ def engine_kwargs(args, prefix_cache=True, speculate=None, grammar=None):
     # in a measurement baseline (explicit args outrank the tune layer)
     kw = dict(max_batch_size=args.max_batch_size, max_len=args.max_len,
               multi_token=args.multi_token, speculate=spec,
-              grammar=gram)
+              grammar=gram, page_size=args.page_size,
+              num_pages=args.num_pages, prefill_chunk=args.prefill_chunk,
+              prefix_cache=prefix_cache and not args.no_prefix_cache)
     if spec and args.spec_lookup is not None:
         kw["spec_lookup"] = args.spec_lookup
-    if args.paged:
-        kw.update(paged=True, page_size=args.page_size,
-                  num_pages=args.num_pages,
-                  prefill_chunk=args.prefill_chunk,
-                  prefix_cache=prefix_cache and not args.no_prefix_cache)
     return kw
 
 
@@ -379,14 +376,12 @@ def run_inprocess(args, prompts, prefix_cache=True, speculate=None,
                   "guarantee is broken")
 
     # HBM efficiency: how many concurrent requests one GB of KV pool
-    # carried. Paged mode defaults num_pages to the CONTIGUOUS layout's
-    # byte footprint, so this is the apples-to-apples >=4x number.
+    # carried. num_pages defaults to max_batch_size * max_len tokens (a
+    # contiguous cache's byte footprint), so this is the apples-to-apples
+    # >=4x number.
     st = eng.stats()
     kv_gb = st["kv_bytes"] / 1e9
-    layout = ("paged, %d pages x %d" % (st["pages"]["pages"],
-                                        st["page_size"])
-              if st["paged"] else
-              "contiguous, %d slots x %d" % (st["slots"], st["max_len"]))
+    layout = "%d pages x %d" % (st["pages"]["pages"], st["page_size"])
     # numerator is the concurrency the engine actually sustained
     # (max_active), not the requested --concurrency: an admission-gated
     # run must not overstate the >=4x acceptance number
@@ -394,16 +389,15 @@ def run_inprocess(args, prompts, prefix_cache=True, speculate=None,
           f"-> {st['max_active'] / kv_gb:.0f} concurrent requests/HBM-GB "
           f"(peak {st['max_active']} in flight of {args.concurrency} "
           f"offered)")
-    if st["paged"]:
-        p = st["pages"]
-        chunks = (_counter("mxnet_serve_page_prefill_chunks_total")
-                  - base["mxnet_serve_page_prefill_chunks_total"])
-        print(f"  pages: {p['leases']} leased, {p['cow_forks']} COW forks, "
-              f"{st['preemptions']} preemptions, "
-              f"{chunks:.0f} prefill chunks")
-        print(f"  prefix cache: {p['prefix_hits']} hits / "
-              f"{p['prefix_misses']} misses, "
-              f"{p['prefix_tokens_saved']} prompt tokens not re-prefilled")
+    p = st["pages"]
+    chunks = (_counter("mxnet_serve_page_prefill_chunks_total")
+              - base["mxnet_serve_page_prefill_chunks_total"])
+    print(f"  pages: {p['leases']} leased, {p['cow_forks']} COW forks, "
+          f"{st['preemptions']} preemptions, "
+          f"{chunks:.0f} prefill chunks")
+    print(f"  prefix cache: {p['prefix_hits']} hits / "
+          f"{p['prefix_misses']} misses, "
+          f"{p['prefix_tokens_saved']} prompt tokens not re-prefilled")
 
     compiles = (_counter("mxnet_serve_compiles_total")
                 - base["mxnet_serve_compiles_total"])
@@ -871,28 +865,23 @@ def main():
     ap.add_argument("--layers", type=int, default=DEFAULTS["layers"])
     ap.add_argument("--heads", type=int, default=DEFAULTS["heads"])
     ap.add_argument("--seed", type=int, default=DEFAULTS["seed"])
-    ap.add_argument("--paged", action="store_true",
-                    help="paged KV engine: lease fixed-size cache pages "
-                         "on demand instead of reserving max_len per slot "
-                         "(the report adds page/prefix-cache stats and "
-                         "requests/HBM-GB)")
-    ap.add_argument("--page-size", type=int, default=16,
-                    help="tokens per KV page (paged mode)")
+    ap.add_argument("--page-size", type=int, default=None,
+                    help="tokens per KV page (default: the engine's, 16 "
+                         "unless tuned)")
     ap.add_argument("--num-pages", "--pool-pages", type=int, default=None,
                     dest="num_pages", metavar="N",
-                    help="page-pool size; default = the contiguous "
-                         "layout's byte footprint (max_batch_size * "
-                         "max_len / page_size). --pool-pages is an "
+                    help="page-pool size; default max_batch_size * "
+                         "max_len / page_size. --pool-pages is an "
                          "alias")
     ap.add_argument("--bits", type=int, default=None, choices=(4, 8),
                     help="weight-only quantize the model: 8 = int8 "
                          "tables, 4 = packed int4 nibble tables "
                          "dequantized in-kernel")
     ap.add_argument("--prefill-chunk", type=int, default=None,
-                    help="tokens per chunked-prefill step (paged mode; "
-                         "default one page)")
+                    help="tokens per chunked-prefill step (default one "
+                         "page)")
     ap.add_argument("--no-prefix-cache", action="store_true",
-                    help="disable shared-prefix page reuse (paged mode)")
+                    help="disable shared-prefix page reuse")
     ap.add_argument("--shared-prefix", type=int, default=0, metavar="N",
                     help="prepend the SAME N-token system prompt to every "
                          "request (prefix-cache traffic)")
@@ -903,7 +892,7 @@ def main():
                     help="closed-loop TENANT traffic (worker w's requests "
                          "all share prefix_w) against a fixed in-process "
                          "fleet behind the prefix-affinity router; needs "
-                         "--paged and --shared-prefix N")
+                         "--shared-prefix N")
     ap.add_argument("--fleet-replicas", type=int, default=4,
                     help="--fleet: replica count (fixed, no autoscaler)")
     ap.add_argument("--fleet-workers", type=int, default=None,
@@ -1037,8 +1026,8 @@ def main():
         if args.url or args.traffic_pattern == "step":
             ap.error("--fleet drives its own fixed in-process fleet "
                      "(no --url / --traffic-pattern step)")
-        if not (args.paged and args.shared_prefix):
-            ap.error("--fleet needs --paged and --shared-prefix N "
+        if not args.shared_prefix:
+            ap.error("--fleet needs --shared-prefix N "
                      "(per-tenant prefixes are what affinity routes on)")
         prompts = make_tenant_prompts(args)
         ref = affinity_reference(args, prompts)
@@ -1069,8 +1058,8 @@ def main():
     if args.url:
         run_http(args, prompts)
         return
-    if args.prefix_compare and not (args.paged and args.shared_prefix):
-        ap.error("--prefix-compare needs --paged and --shared-prefix N")
+    if args.prefix_compare and not args.shared_prefix:
+        ap.error("--prefix-compare needs --shared-prefix N")
     withc = run_inprocess(args, prompts)
     if args.prefix_compare:
         print("\n--- same traffic, prefix cache OFF ---")

@@ -88,7 +88,7 @@ def run_replica(args):
     net = default_model(max_len=args.max_len)
     eng = InferenceEngine(
         net, max_batch_size=args.max_batch_size, max_len=args.max_len,
-        paged=args.paged, page_size=args.page_size)
+        page_size=args.page_size)
     eng.start()
     if args.weights_dir:
         WeightRefresher(eng, args.weights_dir,
@@ -124,8 +124,6 @@ def replica_argv(args, port: int):
            "--max-batch-size", str(args.max_batch_size),
            "--max-len", str(args.max_len),
            "--page-size", str(args.page_size)]
-    if args.paged:
-        cmd.append("--paged")
     if args.weights_dir:
         cmd += ["--weights-dir", args.weights_dir,
                 "--weights-poll-s", str(args.weights_poll_s)]
@@ -164,9 +162,6 @@ def main() -> int:
     ap.add_argument("--replica-base-port", type=int, default=8100)
     ap.add_argument("--max-batch-size", type=int, default=16)
     ap.add_argument("--max-len", type=int, default=128)
-    ap.add_argument("--paged", action="store_true", default=None,
-                    help="paged KV engine in spawned replicas (default: "
-                         "backend-dependent)")
     ap.add_argument("--page-size", type=int, default=16)
     ap.add_argument("--aot-cache-dir", default=None,
                     help="shared prewarmed AOT cache for spawned replicas "
