@@ -35,14 +35,16 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as onp
 
 from ..analysis import guards as _guards
 from ..base import MXNetError
-from ..ndarray import NDArray
+from ..ndarray import NDArray, invoke_jnp
 from ..parallel.functional import functionalize
 
 __all__ = ["generate", "clear_cache", "decode_step", "decode_multi_tokens",
-           "filter_logits", "sample_tokens", "spec_verify_tokens"]
+           "embed_operand", "filter_logits", "sample_tokens",
+           "spec_verify_tokens"]
 
 # Bounded LRU cache of compiled decode loops (jit is keyed on function
 # identity; without this every generate() call would recompile). Entries
@@ -108,6 +110,61 @@ def _check_mask_live(mask):
             "automaton state")
 
 
+# bits of the threshold that one pass of _greatest_key settles: 2**bits - 1
+# pivots a pass, 32 / bits passes. Timed on the chip (PERF.md section 6,
+# PR 33): 2 bits are quicker by a third at 2-4 rows, 4 bits half the device
+# operations a program (the benchmark's traced window keeps a fixed number)
+_SEARCH_BITS = 4
+
+
+def _order_key(x):
+    """int32 keys that compare as the float32 ``x`` do (-0.0 beside 0.0)."""
+    i = jax.lax.bitcast_convert_type(jnp.where(x == 0, 0.0, x), jnp.int32)
+    return jnp.where(i < 0, i ^ jnp.int32(0x7FFFFFFF), i)
+
+
+def _greatest_key(key, measure, target):
+    """Per row of ``key`` [B, V], the greatest int32 ``t`` for which
+    ``measure(key >= t) >= target`` ([B, 1]): ``measure`` reduces a
+    [B, P, V] mask over V and must not grow as the mask shrinks, so the
+    answer is one of the row's keys and is built from its top bit down,
+    ``_SEARCH_BITS`` bits a pass. No sort: a pass is one masked reduction
+    over the row, and a row's answer depends on that row alone."""
+    bits = _SEARCH_BITS
+    sign = jnp.uint32(0x80000000)
+    rows = key.shape[0]
+    if rows == 1:
+        # a TPU tiles [1, P, V] worse than [2, P, V] (a third slower, and
+        # one row is a final prefill and most decode steps): the row twice
+        key = jnp.concatenate([key, key])
+    # [passes, P]: the digits 1 .. 2**bits - 1 at each pass's place
+    places = (onp.arange(1, 2 ** bits, dtype=onp.uint32)[None, :]
+              << onp.arange(32 - bits, -1, -bits, dtype=onp.uint32)[:, None])
+
+    def one_pass(i, t):
+        cand = t | jnp.asarray(places)[i][None, :]                  # [B, P]
+        # built as unsigned from the top bit down, compared as signed
+        at = jax.lax.bitcast_convert_type(cand ^ sign, jnp.int32)
+        ok = measure(key[:, None, :] >= at[:, :, None]) >= target   # [B, P]
+        # ok falls from True to False along P: the last True is the digit
+        return jnp.max(jnp.where(ok, cand, t), axis=-1, keepdims=True)
+
+    t = jax.lax.fori_loop(0, 32 // bits, one_pass,
+                          jnp.zeros((key.shape[0], 1), jnp.uint32))
+    return jax.lax.bitcast_convert_type(t ^ sign, jnp.int32)[:rows]
+
+
+def _where_any(asked, search, rows):
+    """``search()`` ([rows, 1] int32 keys) if any row asks for it, else
+    zeros nothing reads: a skip for the whole batch, which leaves the
+    arithmetic of a row that asks as it is."""
+    need = jnp.any(asked)
+    if not isinstance(need, jax.core.Tracer):   # python scalars: generate()
+        return search() if bool(need) else jnp.zeros((rows, 1), jnp.int32)
+    return jax.lax.cond(need, search,
+                        lambda: jnp.zeros((rows, 1), jnp.int32))
+
+
 @jax.named_scope("mx.sample")
 def filter_logits(scaled, top_k, top_p, mask=None):
     """Top-k then nucleus (top-p) filtering of [B, V] logits: filtered-out
@@ -119,31 +176,43 @@ def filter_logits(scaled, top_k, top_p, mask=None):
     ``mask`` (optional bool [B, V] or [V], True = allowed) applies a
     grammar constraint BEFORE the filters, so top-k counts and the
     nucleus mass are computed over the legal tokens only — a constrained
-    row can never end up with every survivor masked out."""
+    row can never end up with every survivor masked out.
+
+    The kept set needs no order. Top-k keeps a token iff fewer than ``k``
+    tokens are strictly greater (ties with the k-th kept); the nucleus,
+    over the softmax of what top-k left, keeps a token iff the mass of the
+    tokens strictly greater than it is under ``top_p`` (the argmax and ties
+    at the edge kept). Each is ``logit >= threshold``, and each threshold
+    is the greatest value that still has ``k`` tokens, or ``top_p`` of the
+    mass, at or above it: :func:`_greatest_key` finds it by masked
+    reductions over the row, where a sort of the vocabulary was a tenth of
+    a decode step (and ``lax.top_k`` is the same sort on a TPU)."""
     if mask is not None:
         _check_mask_live(mask)
         scaled = jnp.where(mask, scaled, -jnp.inf)
-    V = scaled.shape[-1]
+    B, V = scaled.shape
     top_k = jnp.reshape(jnp.asarray(top_k, jnp.int32), (-1, 1))     # [B|1, 1]
     top_p = jnp.reshape(jnp.asarray(top_p, jnp.float32), (-1, 1))
-    sdesc = jnp.sort(scaled, axis=-1)[:, ::-1]                      # descending
-    kth = jnp.take_along_axis(
-        sdesc, jnp.clip(top_k - 1, 0, V - 1)
-        * jnp.ones((scaled.shape[0], 1), jnp.int32), axis=-1)       # [B, 1]
-    keep_k = (top_k <= 0) | (scaled >= kth)
-    scaled = jnp.where(keep_k, scaled, -jnp.inf)
-    # nucleus over the post-top-k distribution: keep the smallest prefix of
-    # the sorted probabilities whose mass reaches top_p (exclusive-cumsum
-    # formulation keeps at least the argmax). The top-k filter only -infs a
-    # suffix of sdesc (everything < kth), so the filtered sorted view is
-    # derivable without a second O(V log V) sort — this runs per decode
-    # step in the serving hot path.
-    sdesc = jnp.where((top_k <= 0) | (sdesc >= kth), sdesc, -jnp.inf)
-    probs = jax.nn.softmax(sdesc, axis=-1)
-    csum = jnp.cumsum(probs, axis=-1)
-    ncut = jnp.sum((csum - probs) < top_p, axis=-1, keepdims=True)  # >= 1
-    thr = jnp.take_along_axis(sdesc, jnp.clip(ncut - 1, 0, V - 1), axis=-1)
-    return jnp.where((top_p >= 1.0) | (scaled >= thr), scaled, -jnp.inf)
+    key = _order_key(scaled)
+    kth = _where_any(
+        top_k > 0,
+        lambda: _greatest_key(
+            key, lambda ge: jnp.sum(ge, axis=-1, dtype=jnp.int32),
+            jnp.clip(top_k, 1, V)), B)
+    keep_k = (top_k <= 0) | (key >= kth)
+
+    def nucleus():
+        # over the post-top-k distribution, unnormalised: mass >= top_p * all
+        mass = jnp.where(keep_k, jnp.exp(
+            scaled - jnp.max(scaled, axis=-1, keepdims=True)), 0.0)  # [B, V]
+        return _greatest_key(
+            key, lambda ge: jnp.sum(
+                jnp.where(ge, mass[:, None, :], 0.0), axis=-1),
+            top_p * jnp.sum(mass, axis=-1, keepdims=True))
+
+    thr = _where_any(top_p < 1.0, nucleus, B)
+    keep = keep_k & ((top_p >= 1.0) | (key >= thr))
+    return jnp.where(keep, scaled, -jnp.inf)
 
 
 @jax.named_scope("mx.sample")
@@ -166,6 +235,12 @@ def sample_tokens(logits, keys, temperature, top_k, top_p, mask=None):
     t = jnp.reshape(jnp.asarray(temperature, jnp.float32), (-1, 1))
     greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     scaled = logits / jnp.where(t > 0, t, 1.0)
+    # a greedy row asks for no filter: its sample is not read, and a batch
+    # of such rows skips filter_logits' search whole
+    top_k = jnp.where(t > 0, jnp.reshape(jnp.asarray(top_k, jnp.int32),
+                                         (-1, 1)), 0)
+    top_p = jnp.where(t > 0, jnp.reshape(jnp.asarray(top_p, jnp.float32),
+                                         (-1, 1)), 1.0)
     filt = filter_logits(scaled, top_k, top_p)
     sampled = jax.vmap(jax.random.categorical)(keys, filt).astype(jnp.int32)
     return jnp.where(jnp.reshape(t > 0, (-1,)), sampled, greedy_tok)
@@ -196,7 +271,7 @@ def spec_verify_tokens(logits, inputs, temps, topks, topps, seeds, counters,
     plus the one correction/bonus token — so ``acc`` is in [1, T].
 
     The T per-position selections run as ONE flattened [B*T, V]
-    ``sample_tokens`` call (one sort, one categorical sweep instead of
+    ``sample_tokens`` call (one search, one categorical sweep instead of
     T): every op in the selection chain is row-wise, so the packing is
     bitwise-invisible — the parity contract survives the batching.
 
@@ -223,6 +298,53 @@ def spec_verify_tokens(logits, inputs, temps, topks, topps, seeds, counters,
     return toks, (1 + jnp.sum(lead, axis=1)).astype(jnp.int32)
 
 
+def embed_operand(fm, param_vals):
+    """``(table [vocab, lanes],)``: the embedding's table with its rows
+    padded to whole tiles of 128 lanes, where the model names its table
+    (``embed_table()``) and its width is not a multiple of 128; else ``()``.
+    The serving engine makes it once for each set of weights it takes and
+    holds it behind them; :func:`decode_step` hands it to the cached forward
+    as its last operand, and the embedding gathers its rows from it
+    (:func:`embed_lookup`).
+
+    Why: a TPU holds a ``[vocab, hidden]`` table whose width is not whole
+    lane tiles (GPT-2 XL: 1,600 is 12.5) with the vocabulary minor-most,
+    whatever reads it. That is how the head's product wants it, tied or
+    not, but the embedding's gather wants rows, and relays all of
+    2 * vocab * hidden bytes out in every program, a tenth of a decode
+    step. With rows of whole tiles the chip keeps the table as declared and
+    gathers from it where it lies; at a lane multiple it does so anyway,
+    and no second table is held."""
+    named = getattr(fm.block, "embed_table", lambda: None)()
+    if named is None:
+        return ()
+    table = param_vals[next(i for i, p in enumerate(fm.params) if p is named)]
+    pad = -table.shape[1] % 128
+    return (jnp.pad(table, ((0, 0), (0, pad))),) if pad else ()
+
+
+def embed_lookup(embedding, input_ids, table=None):
+    """``embedding(input_ids)``, its rows gathered from the padded ``table``
+    of :func:`embed_operand` where a serving program brings one."""
+    if table is None:
+        return embedding(input_ids)
+    width = embedding.weight.shape[1]
+    return invoke_jnp(
+        lambda t, i: jnp.take(t, i.astype(jnp.int32), axis=0)[..., :width],
+        (table, input_ids), {}, name="embedding")
+
+
+def _apply_cached(fm, param_vals, method, inputs, caches):
+    """One cached forward: the model's parameters from the front of
+    ``param_vals``; what the engine holds behind them
+    (:func:`embed_operand`) goes to the forward behind the caches."""
+    n = len(fm.params)
+    out, _aux = fm.apply(list(param_vals[:n]), *inputs, *caches,
+                         *param_vals[n:], seed=0, training=False,
+                         method=method)
+    return out[0], tuple(out[1:])
+
+
 def decode_step(fm, param_vals, tokens, pos, caches, block_table=None,
                 rows=None):
     """One incremental forward through the KV-cache protocol: attend
@@ -238,18 +360,11 @@ def decode_step(fm, param_vals, tokens, pos, caches, block_table=None,
     beside its pages (``cache_spec_state``) also takes ``rows``: each row's
     slot in the state pools and how many of its T positions are real."""
     if block_table is None:
-        out, _aux = fm.apply(list(param_vals), tokens, pos, *caches,
-                             seed=0, training=False,
-                             method="forward_cached")
-    elif rows is not None:
-        out, _aux = fm.apply(list(param_vals), tokens, pos, block_table,
-                             *rows, *caches, seed=0, training=False,
-                             method="forward_cached_paged")
-    else:
-        out, _aux = fm.apply(list(param_vals), tokens, pos, block_table,
-                             *caches, seed=0, training=False,
-                             method="forward_cached_paged")
-    return out[0], tuple(out[1:])
+        return _apply_cached(fm, param_vals, "forward_cached",
+                             (tokens, pos), caches)
+    return _apply_cached(fm, param_vals, "forward_cached_paged",
+                         (tokens, pos, block_table) + tuple(rows or ()),
+                         caches)
 
 
 def decode_step_hidden(fm, param_vals, tokens, pos, caches,
@@ -261,14 +376,10 @@ def decode_step_hidden(fm, param_vals, tokens, pos, caches,
     fused_lm_head_sample) can fold the head GEMV into token selection
     without materializing [B, V] logits."""
     if block_table is None:
-        out, _aux = fm.apply(list(param_vals), tokens, pos, *caches,
-                             seed=0, training=False,
-                             method="forward_cached_hidden")
-    else:
-        out, _aux = fm.apply(list(param_vals), tokens, pos, block_table,
-                             *caches, seed=0, training=False,
-                             method="forward_cached_paged_hidden")
-    return out[0], tuple(out[1:])
+        return _apply_cached(fm, param_vals, "forward_cached_hidden",
+                             (tokens, pos), caches)
+    return _apply_cached(fm, param_vals, "forward_cached_paged_hidden",
+                         (tokens, pos, block_table), caches)
 
 
 def _fold_keys(seeds, counters):

@@ -192,7 +192,7 @@ class GPTModel(HybridBlock):
 
         with jax.named_scope("mx.embed"):
             positions = invoke_jnp(_positions, (pos,), {})
-            x = self.drop(self.wte(input_ids) + self.wpe(positions))
+            x = self.drop(self._embed(input_ids, caches) + self.wpe(positions))
         new_caches = []
         for i, blk in enumerate(self.blocks):
             x, kc, vc = blk.forward_cached(
@@ -215,7 +215,7 @@ class GPTModel(HybridBlock):
 
         with jax.named_scope("mx.embed"):
             positions = invoke_jnp(_positions, (pos,), {})
-            x = self.drop(self.wte(input_ids) + self.wpe(positions))
+            x = self.drop(self._embed(input_ids, caches) + self.wpe(positions))
         new_caches = []
         for i, blk in enumerate(self.blocks):
             x, kp, vp = blk.forward_cached_paged(
@@ -223,6 +223,18 @@ class GPTModel(HybridBlock):
             new_caches += [kp, vp]
         x = self.ln_f(x)
         return (x, *new_caches)
+
+    def embed_table(self):
+        """The embedding's Parameter: what generation.embed_operand makes a
+        serving program's gather table from."""
+        return self.wte.weight
+
+    def _embed(self, input_ids, caches):
+        """The token embedding of a cached forward; behind its two caches a
+        layer a serving program may bring the table to gather from."""
+        from .generation import embed_lookup
+        return embed_lookup(self.wte, input_ids,
+                            *caches[2 * len(self.blocks):])
 
     def head_weights(self):
         """(int8 table [Vp, D], scales [Vp], vocab) for the fused LM-head

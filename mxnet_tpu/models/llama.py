@@ -799,8 +799,16 @@ class LlamaModel(HybridBlock):
             return [((cfg.num_layers,) + shp, cfg.dtype)] * 2
         return [(shp, cfg.dtype)] * (2 * cfg.num_layers)
 
+    def _embed(self, input_ids, caches):
+        """The token embedding of a cached forward; behind its caches (a
+        stacked pair, or two a layer) a serving program may bring the table
+        to gather from (generation.embed_operand)."""
+        from .generation import embed_lookup
+        n = 2 if self.cfg.stacked else 2 * self.cfg.num_layers
+        return embed_lookup(self.embed_tokens, input_ids, *caches[n:])
+
     def forward_cached(self, input_ids, pos, *caches):
-        x = self.embed_tokens(input_ids)
+        x = self._embed(input_ids, caches)
         if self.cfg.stacked:
             x, new_k, new_v = self.layers.forward_cached(
                 x, pos, caches[0], caches[1])
@@ -813,7 +821,7 @@ class LlamaModel(HybridBlock):
         return (self.norm(x), *new_caches)
 
     def forward_cached_paged(self, input_ids, pos, block_table, *caches):
-        x = self.embed_tokens(input_ids)
+        x = self._embed(input_ids, caches)
         if self.cfg.stacked:
             x, new_k, new_v = self.layers.forward_cached_paged(
                 x, pos, block_table, caches[0], caches[1])
@@ -867,6 +875,10 @@ class LlamaForCausalLM(HybridBlock):
             return invoke_jnp(fn, (h,), {}, name="lm_head_int8")
         w = self.model.embed_tokens.weight.data()
         return invoke_jnp(lambda hv, wv: hv @ wv.T, (h, w), {})
+
+    def embed_table(self):
+        """The embedding's Parameter (generation.embed_operand)."""
+        return self.model.embed_tokens.weight
 
     def head_weights(self):
         """(int8 table, scales, vocab) for fused LM-head sampling, or None

@@ -5,9 +5,9 @@ top-k / top-p and token selection in one step. On TPU the greedy and
 pure-temperature rows stream the int8 table once through a Pallas kernel
 with a running (Gumbel-)argmax in the reduction epilogue — no [B, V]
 materialization, no full-vocab sort; rows with top-k/top-p filters take
-the XLA path under ``lax.cond`` (exact ``filter_logits`` semantics need
-the sorted tail), and so does a packed-int4 table. Off-TPU the reference
-matches ``models.generation.sample_tokens`` bitwise.
+the XLA path under ``lax.cond`` (``filter_logits`` needs the whole row
+of logits for its thresholds), and so does a packed-int4 table. Off-TPU
+the reference matches ``models.generation.sample_tokens`` bitwise.
 
 Vocab padding: ``contrib.quantization._quantize_tied_lm_head`` pads the
 int8 table's vocab dim to a 128-lane multiple (50257 -> 50304) so the

@@ -85,7 +85,9 @@ few rows it writes in the buffer where they lie and returns that buffer
 loop touches ``self._pools``: an array read from another thread may have
 been donated since, so page exports and imports run at a tick boundary.
 A step reads only the blocks of pages that its deepest row has reached
-(``stats()``: ``kv_walk_blocks`` of ``kv_table_blocks``).
+(``stats()``: ``kv_walk_blocks`` of ``kv_table_blocks``), and searches for
+a top-k or nucleus threshold only where one of its rows asks for one
+(``sample_rows_filtered`` of ``sample_rows``).
 """
 from __future__ import annotations
 
@@ -676,7 +678,7 @@ class InferenceEngine:
         self._fm = functionalize(
             model, NDArray(onp.zeros((1, self.min_prompt_bucket), onp.int32)),
             training=False)
-        self._values = tuple(self._fm.values())
+        self._values = self._with_embed(self._fm.values())
         # canonical publish naming: collect_params names where available
         # (what snapshot_params/publish_weights write), functional
         # structural names as the fallback
@@ -731,6 +733,11 @@ class InferenceEngine:
         self._kv_block = _llama.kv_block(self.page_size, self.maxp)
         self._kv_walked = 0
         self._kv_tabled = 0
+        # rows whose token a program selects, and those among them whose
+        # selection runs filter_logits' search (stats(): sample_rows /
+        # sample_rows_filtered)
+        self._sample_rows = 0
+        self._sample_filtered = 0
         # page-axis inference by diffing cache_spec_paged(1)/(2) (per-layer
         # pools: axis 0; stacked scan pools [layers, pages, ...]: 1)
         sp1 = model.cache_spec_paged(1, self.page_size)
@@ -1184,7 +1191,8 @@ class InferenceEngine:
             # is defined by what the engine serves, not what the trainer
             # published
             new_values.append(jnp.asarray(arr, dtype=cur.dtype))
-        rec = {"values": tuple(new_values), "version": version,
+        new_values = self._with_embed(new_values)
+        rec = {"values": new_values, "version": version,
                "evt": threading.Event(), "ok": False}
         with self._cond:
             # gate on the loop THREAD being alive, not _running: during
@@ -1198,7 +1206,7 @@ class InferenceEngine:
                 self._cond.notify_all()
         if not alive:
             # no loop to race: apply inline
-            self._values = tuple(new_values)
+            self._values = new_values
             self._note_swap(version)
             return version
         if not rec["evt"].wait(timeout):
@@ -1211,6 +1219,14 @@ class InferenceEngine:
                 f"applying v{version}; still serving "
                 f"v{self.weight_version}")
         return version
+
+    def _with_embed(self, params):
+        """What every program takes as ``values``: the model's parameters
+        and, behind them, the embedding's table in rows of whole lane tiles
+        where its width is none (made here, once for each set of weights;
+        models/generation.embed_operand says why)."""
+        params = tuple(params)
+        return params + _gen.embed_operand(self._fm, params)
 
     def swap_weights_from(self, directory: str,
                           version: Optional[int] = None) -> int:
@@ -1996,6 +2012,17 @@ class InferenceEngine:
         self._kv_walked += walk
         self._kv_tabled += of
 
+    def _note_sample(self, span: _profiler.scope, rows):
+        """Tell a dispatch span how many of the ``(temperature, top_k,
+        top_p)`` rows it selects a token for need filter_logits' search
+        (``filtered``: sampled, under a top-k or a nucleus; a program none
+        of whose rows does skips the search), and add rows and filtered to
+        the sums of ``stats()``."""
+        filtered = sum(1 for t, k, p in rows if t > 0 and (k > 0 or p < 1.0))
+        span.set(filtered=filtered)
+        self._sample_rows += len(rows)
+        self._sample_filtered += filtered
+
     def _note_select(self, span: _profiler.scope, rows):
         """Where the model selects among its pages (``blocks_read``): tell
         a dispatch span how many blocks of one sparse layer's cache the
@@ -2310,6 +2337,7 @@ class InferenceEngine:
             ids[0, :rest] = pf.ids[pf.cursor:]
             self._note_walk(span, pf.cursor, pb)
             self._note_select(span, [(pf.cursor, pb)])
+            self._note_sample(span, [(req.temperature, req.top_k, req.top_p)])
             gargs = ()
             if self._grammar:
                 gargs = (self._gcls[s:s + 1].copy(),
@@ -2545,6 +2573,9 @@ class InferenceEngine:
             self._note_walk(disp, max(int(self._pos[s]) for s, _ in cur),
                             1, self.K)
             self._note_select(disp, [(int(self._pos[s]), 1) for s, _ in cur])
+            self._note_sample(disp, [
+                (float(self._temps[s]), int(self._topks[s]),
+                 float(self._topps[s])) for s, _ in cur])
             rec = self._dispatch_step(prev, cur, sb)
         if rec is None:
             return
@@ -3118,6 +3149,8 @@ class InferenceEngine:
         out["preemptions"] = self._preempted
         out["kv_walk_blocks"] = self._kv_walked
         out["kv_table_blocks"] = self._kv_tabled
+        out["sample_rows"] = self._sample_rows
+        out["sample_rows_filtered"] = self._sample_filtered
         out["state_bytes"] = self._state_bytes
         out["sparse_blocks_read"] = self._sel_read
         out["sparse_blocks_live"] = self._sel_live

@@ -207,6 +207,25 @@ def test_live_swap_parity_no_recompile(tmp_path, net_a, net_b,
         ref.shutdown()
 
 
+def test_swap_remakes_the_embedding_table(net_a, net_b):
+    """Behind the parameters the engine holds the embedding's table in rows
+    of whole lane tiles (32 wide here: padded to 128), which every program
+    gathers from: a swap makes it again from the weights it takes."""
+    eng = InferenceEngine(net_a, max_batch_size=2, max_len=64)
+    assert len(eng._values) == len(eng._param_names) + 1
+
+    def table_of(net):
+        wte = onp.asarray(net.wte.weight.data()._data)
+        return onp.pad(wte, ((0, 0), (0, 128 - wte.shape[1])))
+
+    onp.testing.assert_array_equal(onp.asarray(eng._values[-1]),
+                                   table_of(net_a))
+    eng.swap_weights(snapshot_params(net_b))
+    assert len(eng._values) == len(eng._param_names) + 1
+    onp.testing.assert_array_equal(onp.asarray(eng._values[-1]),
+                                   table_of(net_b))
+
+
 def test_swap_mid_flight_keeps_stream(net_a, net_b):
     """The zero-downtime half: a swap while a stream decodes completes
     that stream (full token budget, no drop) — tokens after the swap

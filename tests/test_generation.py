@@ -251,3 +251,168 @@ def test_use_cache_rejected_for_unsupported_configs():
     # and the automatic default silently falls back to the cache-free path
     out = generate(net, prompt, 4)
     assert out.shape == (1, 8)
+
+
+# ------------------------------------------- filter_logits without a sort
+# the kept set against a float64 reference that sorts: every combination of
+# top_k and top_p a case, as python scalars and as per-row arrays, over rows
+# that are peaked, flat, tied at the k-th value and at the nucleus's edge,
+# and masked. Logits lie on a grid, so ties are exact and a whole group of
+# tokens enters the nucleus at once: each row is drawn until every group's
+# edge is 1e-4 of mass or more away from every top_p (float32 sums cannot
+# tell a nearer one from the float64 reference's)
+_FILTER_V = {"gpt2": 50257, "small": 257}
+_FILTER_ROWS = {"gpt2": 96, "small": 256}
+_TOP_P = [1e-6, 0.5, 0.95, 1.0]
+
+
+def _top_ks(V):
+    return [0, 1, 40, V, V + 7]
+
+
+def _mass_above(x, top_k):
+    """float64, by sorting: for each token of the row the softmax mass,
+    over what top-k leaves, of the tokens strictly greater than it (NaN for
+    a token that top-k drops or the mask forbids)."""
+    V = x.size
+    order = onp.argsort(-x, kind="stable")
+    s = x[order].astype(onp.float64)
+    keep = onp.ones(V, bool) if top_k <= 0 else s >= s[min(top_k, V) - 1]
+    e = onp.where(keep, onp.exp(s - s[0]), 0.0)
+    before = onp.cumsum(e) - e                    # mass sorted before it
+    first = onp.maximum.accumulate(               # where its tie group starts
+        onp.where(onp.r_[True, s[1:] != s[:-1]], onp.arange(V), 0))
+    above = onp.where(keep & onp.isfinite(s), before[first] / e.sum(),
+                      onp.nan)
+    out = onp.empty(V)
+    out[order] = above
+    return out
+
+
+def _reference_kept(x, top_k, top_p):
+    """The filter as it was written with a sort, in float64: the k-th of the
+    descending row, the softmax of what is left, the exclusive cumulative
+    sum under top_p, the last such value as threshold; ``>=`` keeps ties."""
+    V = x.size
+    x = x.astype(onp.float64)
+    s = onp.sort(x)[::-1]
+    keep = onp.ones(V, bool)
+    if top_k > 0:
+        kth = s[min(top_k, V) - 1]
+        keep &= x >= kth
+        s = onp.where(s >= kth, s, -onp.inf)
+    if top_p < 1.0:
+        e = onp.exp(s - s[0])
+        probs = e / e.sum()
+        ncut = int(((onp.cumsum(probs) - probs) < top_p).sum())
+        keep &= x >= s[max(ncut, 1) - 1]
+    return keep & onp.isfinite(x)
+
+
+def _draw_row(rng, V, kind):
+    if kind == 0:        # peaked: a few tokens hold the mass
+        x = onp.round(rng.normal(size=V) * 6.0 * 2) / 2
+    elif kind == 1:      # flat: thousands of tokens inside the nucleus
+        x = onp.round(rng.normal(size=V) * 0.6 * 4) / 4
+    elif kind == 2:      # one value for most of the row: ties at every edge
+        x = onp.where(rng.random(V) < 0.7, 1.0, onp.round(
+            rng.normal(size=V) * 2.0))
+    else:                # masked: a third of the row forbidden
+        x = onp.round(rng.normal(size=V) * 2.0 * 2) / 2
+        x[rng.random(V) < 0.33] = -onp.inf
+    return x.astype(onp.float32)
+
+
+@pytest.fixture(scope="module", params=list(_FILTER_V))
+def filter_rows(request):
+    V, n = _FILTER_V[request.param], _FILTER_ROWS[request.param]
+    rng = onp.random.default_rng(V)
+    rows, tries = [], 0
+    while len(rows) < n:
+        assert tries < 8 * n
+        x = _draw_row(rng, V, len(rows) % 4)
+        tries += 1
+        edges = onp.concatenate([_mass_above(x, k) for k in _top_ks(V)])
+        edges = edges[edges > 0]                   # NaN and the argmax: no
+        if all(onp.abs(edges - p).min() >= 1e-4 for p in _TOP_P[:-1]):
+            rows.append(x)
+    return onp.stack(rows)
+
+
+@pytest.mark.parametrize("per_row", [False, True], ids=["scalar", "per_row"])
+@pytest.mark.parametrize("top_p", _TOP_P)
+@pytest.mark.parametrize("k_index", range(5),
+                         ids=["k0", "k1", "k40", "kV", "k_beyond_V"])
+def test_filter_logits_keeps_what_a_sort_keeps(filter_rows, k_index, top_p,
+                                               per_row):
+    import jax.numpy as jnp
+    from mxnet_tpu.models.generation import filter_logits
+    n, V = filter_rows.shape
+    ks, top_k = _top_ks(V), _top_ks(V)[k_index]
+    if per_row:
+        # the case's pair on every other row, the other pairs between them
+        i = onp.arange(n)
+        k_rows = onp.where(i % 2 == 0, top_k,
+                           onp.asarray(ks)[(i // 2) % 5]).astype(onp.int32)
+        p_rows = onp.where(i % 2 == 0, top_p, onp.asarray(_TOP_P)[
+            (i // 10) % 4]).astype(onp.float32)
+        got = filter_logits(jnp.asarray(filter_rows), jnp.asarray(k_rows),
+                            jnp.asarray(p_rows))
+    else:
+        k_rows, p_rows = [top_k] * n, [top_p] * n
+        got = filter_logits(jnp.asarray(filter_rows), top_k, top_p)
+    got = onp.asarray(got)
+    want = onp.stack([_reference_kept(x, int(k), float(p))
+                      for x, k, p in zip(filter_rows, k_rows, p_rows)])
+    onp.testing.assert_array_equal(onp.isfinite(got), want)
+    # what is kept is kept as it was
+    onp.testing.assert_array_equal(got[want], filter_rows[want])
+    assert want.any(axis=-1).all()
+
+
+def test_filter_logits_mask_is_applied_first():
+    """The grammar's mask before the filters: top-k counts and the nucleus
+    hold legal tokens only, as if the forbidden ones were -inf."""
+    import jax.numpy as jnp
+    from mxnet_tpu.models.generation import filter_logits
+    rng = onp.random.default_rng(5)
+    x = (onp.round(rng.normal(size=(8, 257)) * 4) / 2).astype(onp.float32)
+    mask = rng.random((8, 257)) < 0.5
+    got = onp.asarray(filter_logits(jnp.asarray(x), 5, 0.9,
+                                    mask=jnp.asarray(mask)))
+    want = onp.asarray(filter_logits(
+        jnp.asarray(onp.where(mask, x, -onp.inf)), 5, 0.9))
+    onp.testing.assert_array_equal(got, want)
+    assert not onp.isfinite(got[~mask]).any()
+
+
+@pytest.mark.parametrize("V", [50257, 1031])
+def test_sampled_token_depends_on_its_row_alone(V):
+    """The engine's contract (``_slot_keys``): a row's token is bitwise the
+    same alone, in a batch of 16 beside other rows' parameters, and beside
+    15 greedy rows; a greedy row's beside rows that filter."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.models.generation import _fold_keys, sample_tokens
+    rng = onp.random.default_rng(V)
+    B = 16
+    logits = jnp.asarray(rng.normal(size=(B, V)).astype(onp.float32) * 3)
+    temps = onp.array([0.7, 1.0, 0.0, 1.3] * 4, onp.float32)
+    topks = onp.array([0, 40, 0, 5, 0, 0, 1, V] * 2, onp.int32)
+    topps = onp.array([0.95, 1.0, 0.5, 0.9, 1.0] * 3 + [0.3], onp.float32)
+    keys = _fold_keys(jnp.arange(B, dtype=jnp.uint32),
+                      jnp.arange(B, dtype=jnp.int32) * 3)
+    select = jax.jit(sample_tokens)
+    mixed = onp.asarray(select(logits, keys, temps, topks, topps))
+    for r in range(B):
+        one = slice(r, r + 1)
+        alone = onp.asarray(select(logits[one], keys[one], temps[one],
+                                   topks[one], topps[one]))
+        assert alone[0] == mixed[r]
+        # beside 15 greedy rows that ask for nothing
+        t, k, p = onp.zeros(B, onp.float32), onp.zeros(B, onp.int32), \
+            onp.ones(B, onp.float32)
+        t[r], k[r], p[r] = temps[r], topks[r], topps[r]
+        assert onp.asarray(select(logits, keys, t, k, p))[r] == mixed[r]
+    greedy = onp.asarray(jnp.argmax(logits, axis=-1))
+    onp.testing.assert_array_equal(mixed[temps == 0], greedy[temps == 0])

@@ -32,7 +32,8 @@ SET_INSIDE = ["tick.rows", "tick.sb", "prefill_dispatch.start",
               "prefill_dispatch.end", "prefill_dispatch.final",
               "admit.admitted", "emit.tokens",
               "prefill_dispatch.walk", "prefill_dispatch.of",
-              "decode_dispatch.walk", "decode_dispatch.of"]
+              "decode_dispatch.walk", "decode_dispatch.of",
+              "prefill_dispatch.filtered", "decode_dispatch.filtered"]
 STEP_SCOPES = ["mx.embed", "mx.attn", "mx.paged_attention", "mx.kv_write",
                "mx.kv_walk", "mx.mlp", "mx.lm_head", "mx.sample"]
 TRAIN_SCOPES = ["mx.embed", "mx.attn", "mx.mlp", "mx.lm_head", "mx.loss",

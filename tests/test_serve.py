@@ -549,6 +549,28 @@ def test_default_engine_is_paged_off_the_chip(gpt_model):
             "prefix_summary"} <= set(stats)
 
 
+def test_stats_count_the_rows_that_filter(gpt_model):
+    """``sample_rows`` counts every row a program selects a token for (a
+    final prefill one, a decode step its decoding rows) and
+    ``sample_rows_filtered`` those among them that run filter_logits'
+    search: sampled, under a top-k or a nucleus. A greedy row asks for
+    none whatever top_k and top_p it carries, nor a sampled row with
+    neither. Without lookahead a request's rows are its tokens."""
+    eng = InferenceEngine(gpt_model, max_batch_size=4, max_len=32,
+                          lookahead=False)
+    asked = [dict(temperature=0.0, top_p=0.5), dict(temperature=0.0),
+             dict(temperature=0.8, top_p=0.9), dict(temperature=1.0, top_k=5),
+             dict(temperature=0.9)]
+    with eng:
+        hs = [eng.submit(onp.arange(4 + i) % 30 + 1, 6, seed=i, **kw)
+              for i, kw in enumerate(asked)]
+        done = [h.result(120) for h in hs]
+        stats = eng.stats()
+    assert all(r.ok and len(r.generated_ids) == 6 for r in done)
+    assert stats["sample_rows"] == 5 * 6
+    assert stats["sample_rows_filtered"] == 2 * 6
+
+
 def _tiny_llama():
     net = LlamaForCausalLM(LlamaConfig(
         vocab_size=32, hidden_size=32, intermediate_size=64, num_layers=1,

@@ -324,3 +324,91 @@ def test_pools_are_written_in_place(one_chip, pool_engines, width, program,
     lanes = -(-d // 128) * 128
     assert compiled.memory_analysis().alias_size_in_bytes \
         == 2 * 321 * 16 * lanes * 2
+
+
+# ------------- the tail of a serving program: no sort, no table relaid out
+# one GPT-2 layer over the whole vocabulary at two widths: GPT-2 XL's 1,600
+# lanes (12.5 tiles) and GPT-2 medium's 1,024 (8 tiles)
+_TAIL_WIDTHS = {"xl_1600": (1600, 25), "medium_1024": (1024, 16)}
+
+
+@pytest.fixture(scope="module")
+def tail_engines():
+    from mxnet_tpu.models import GPTModel
+    from mxnet_tpu.models.gpt import GPTConfig
+    from mxnet_tpu.serve import InferenceEngine
+    made = {}
+
+    def engine(width):
+        if width not in made:
+            d, heads = _TAIL_WIDTHS[width]
+            net = GPTModel(GPTConfig(
+                vocab_size=VOCAB, hidden_size=d, num_layers=1,
+                num_heads=heads, max_position_embeddings=1024, dropout=0.0,
+                dtype="bfloat16"))
+            net.initialize()
+            made[width] = InferenceEngine(
+                net, max_batch_size=16, max_len=1024, page_size=16,
+                num_pages=320, prefill_chunk=128)
+        return made[width]
+
+    return engine
+
+
+@pytest.mark.parametrize("program,bucket",
+                         [("decode", 2), ("prefill", 64)],
+                         ids=["step_b2", "prefill_b64"])
+@pytest.mark.parametrize("width", list(_TAIL_WIDTHS))
+def test_tail_neither_sorts_nor_relays_a_table(one_chip, tail_engines, width,
+                                               program, bucket):
+    """A decode step of 2 rows and a final prefill of 64 tokens: no ``sort``
+    (``filter_logits`` searches for its thresholds; ``lax.top_k`` would be
+    the same sort here), and no instruction makes a second array of the
+    embedding's shape. The chip holds a ``[50257, 1600]`` table vocabulary
+    minor-most whatever reads it: the head's product reads it so, and the
+    embedding gathers from rows padded to 1,664 lanes, which the chip keeps
+    as declared. With one table for both, the gather relaid 160 MB out in
+    every program (PERF.md section 6, PR 33). At 1,024 lanes the table is
+    kept as declared and the engine holds no second one. A CPU run cannot
+    see either fact."""
+    import re
+    eng = tail_engines(width)
+    d = _TAIL_WIDTHS[width][0]
+    lanes = -(-d // 128) * 128
+    held = [tuple(v.shape) for v in eng._values
+            if v.ndim == 2 and v.shape[0] == VOCAB]
+    assert held == ([(VOCAB, d), (VOCAB, lanes)] if lanes != d
+                    else [(VOCAB, d)])
+    build = eng._build_step if program == "decode" else eng._build_prefill
+    text = build(bucket).lower(
+        *_shapes(eng._example_args(program, bucket), one_chip)
+    ).compile().as_text()
+    ops = [_opcode(line) for line in text.splitlines()]
+    assert not [shape for op, shape in ops if op == "sort"]
+    entry = re.search(r"entry_computation_layout=\{\((.*?)\)->", text).group(1)
+    layouts = dict(re.findall(r"(bf16\[%d,\d+\])\{([\d,]*)" % VOCAB, entry))
+    # the table goes into the head's product and into the gather as it
+    # came: nothing but a parameter has a table's shape, or a bitcast, which
+    # moves nothing, or a prefetch, which keeps the layout
+    tables = [f"bf16[{VOCAB},{d}]", f"bf16[{d},{VOCAB}]",
+              f"bf16[{VOCAB},{lanes}]"]
+    made = []
+    for line in text.splitlines():
+        op, shape = _opcode(line)
+        if (op in (None, "parameter", "bitcast", "get-tuple-element")
+                or not any(t in shape for t in tables)
+                or (op == "fusion" and "calls=%bitcast_fusion" in line)):
+            continue
+        laid = re.findall(r"(bf16\[[\d,]+\])\{([\d,]*)", shape)
+        if op in ("copy-start", "copy-done") and all(
+                layouts.get(t) == lay for t, lay in laid):
+            continue
+        made.append(line.strip()[:160])
+    assert not made
+    if lanes != d:
+        # by its shape, not by its use: vocabulary minor-most, and the
+        # padded rows as declared
+        assert layouts == {f"bf16[{VOCAB},{d}]": "0,1",
+                           f"bf16[{VOCAB},{lanes}]": "1,0"}
+    else:
+        assert layouts == {f"bf16[{VOCAB},{d}]": "1,0"}
