@@ -982,6 +982,11 @@ SERVE_PAGE_COW = Counter(
     "mxnet_serve_page_cow_forks_total",
     "Copy-on-write forks: a slot wrote into a page shared with the "
     "prefix cache or another slot, so the page was copied first")
+SERVE_PAGE_FOLDS = Counter(
+    "mxnet_serve_page_folds_total",
+    "Pages that went back to the pool while their request lived: a model "
+    "whose cache folds finished a window, and the window's pages were "
+    "replaced by one page of summaries")
 SERVE_PAGE_PREEMPTIONS = Counter(
     "mxnet_serve_page_preemptions_total",
     "Slots preempted on pool exhaustion (released + requeued; resumed "

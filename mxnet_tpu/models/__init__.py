@@ -12,6 +12,7 @@ from .vit import ViTConfig, ViTModel, VIT_B16, VIT_TINY
 from .t5 import T5Config, T5Model, T5_SMALL, T5_TINY
 from .minicpm_sala import (MiniCPMSALAConfig, MiniCPMSALAForCausalLM,
                            SALA_TINY)
+from .evabyte import EvaByteConfig, EvaByteForCausalLM, EVABYTE_TINY
 from .generation import generate
 
 # attach the decode loop as a method on the causal-LM families (one
@@ -34,5 +35,6 @@ __all__ = [
     "ViTConfig", "ViTModel", "VIT_B16", "VIT_TINY",
     "T5Config", "T5Model", "T5_SMALL", "T5_TINY",
     "MiniCPMSALAConfig", "MiniCPMSALAForCausalLM", "SALA_TINY",
+    "EvaByteConfig", "EvaByteForCausalLM", "EVABYTE_TINY",
     "generate",
 ]

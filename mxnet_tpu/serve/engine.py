@@ -116,7 +116,7 @@ from ..ndarray import NDArray
 from ..parallel.functional import functionalize
 from . import grammar as _grammar
 from .bucketing import bucket_for, bucket_ladder
-from .paging import OutOfPages, PagePool, pages_for, prefix_key
+from .paging import FlatPages, OutOfPages, PagePool, pages_for, prefix_key
 
 __all__ = ["InferenceEngine", "RequestHandle", "ServeResult",
            "QueueFullError", "EngineClosedError",
@@ -374,11 +374,51 @@ class InferenceEngine:
     (COW ``copy``, ``export_pages``/``import_pages``, the preemption-rescue
     hook: a request's pages alone do not resume it).
 
+    **A cache that folds.** A model whose attention keeps a finished window
+    of positions only as one page of chunk summaries (EvaByte's EVA
+    attention) says so with one method more than the paged protocol, and the
+    engine takes no argument for it:
+
+    - ``cache_fold(page_size)`` returns the arithmetic of its table
+      (``ops.eva_attention.FoldedPages``: ``window``, ``window_pages``,
+      ``column(pos)``, ``entries(depth)``, ``peak(depth)``,
+      ``ends_window(depth)``; every other model's is ``FlatPages``). The page
+      pool counts a request's need with it: in pages held, not in positions;
+    - ``forward_cached_paged(ids, pos, block_table, valid, *pools)`` takes,
+      after the block table, how many of each row's ``T`` positions are real
+      ``[B]`` (padding must not end a window), and ``pos`` is the row's TRUE
+      position. A dispatch that brings a row to the end of a window is handed
+      a table whose entry behind the window's pages is a fresh page: the
+      program writes the window's summaries there, and once it is dispatched
+      the host replaces the window's entries by that page and gives the
+      window's pages back to the pool (``PagePool.fold``), while the request
+      lives and under the lookahead (the host knows every row's next position,
+      and the donated pools order the programs on the device);
+    - ``prefill_chunk`` must divide the window, so that a chunk lies in one.
+
+    For such a model ``max_len`` is still a request's most positions, but a
+    request of ``max_len`` holds only ``pages.max_pages`` pages (EvaByte at
+    32,768 positions: 32 of 128 rows, not 256), ``num_pages`` defaults to
+    that many for every slot, ``stats()["kv_bytes"]`` is the pools' bytes as
+    ever, and ``pages["pages_in_use"]`` counts folded pages once. ``stats()``
+    gains ``windows_folded``, ``pages_folded`` (pages given back by folds)
+    and ``pages_held`` / ``pages_unfolded`` (what the dispatched rows'
+    tables held, of what their depths would hold unfolded; the two dispatch
+    spans carry the same as ``held`` / ``depth_pages``, and ``folded``).
+    Preemption requeues and prefills again from 0, which rebuilds the
+    summaries. **Refused with folding, each with an ``MXNetError`` that says
+    why**: ``prefix_cache=True``, COW ``copy`` and page migration
+    (``export_pages`` / ``import_pages``, the preemption-rescue hook): a
+    shared or shipped page is keyed by the tokens of one page, and a folded
+    page stands for a window of 2,048; ``speculate`` and ``multi_token > 1``:
+    a position written past the accepted or the finished one may have ended
+    a window, and a fold cannot be taken back.
+
     Parameters
     ----------
     model : initialized causal LM block
     max_batch_size : slot-pool size (concurrent in-flight requests)
-    max_len : per-slot KV capacity; prompt + new tokens must fit
+    max_len : per-slot capacity in positions; prompt + new tokens must fit
     max_queue_depth : admission-control bound; ``submit`` raises
         :class:`QueueFullError` beyond it
     min_prompt_bucket : smallest prompt-length bucket (power of two)
@@ -607,7 +647,8 @@ class InferenceEngine:
         # cache_spec_state, and forward_cached_paged takes each row's
         # slot: class docstring
         self._stateful = hasattr(model, "cache_spec_state")
-        if not (self._stateful or _gen._can_cache(model)):
+        if not (self._stateful or hasattr(model, "cache_fold")
+                or _gen._can_cache(model)):
             raise MXNetError(
                 "InferenceEngine requires the KV-cache decode protocol "
                 "(cache_spec/forward_cached) and a config that supports it")
@@ -721,11 +762,21 @@ class InferenceEngine:
         if self._stateful:
             self._refuse_with_state(prefix_cache, speculate, multi_token)
         self.page_size = int(page_size)
+        # a model whose cache folds says so with cache_fold (class
+        # docstring): the table's arithmetic, which the pool counts with
+        self._fold = (model.cache_fold(self.page_size)
+                      if hasattr(model, "cache_fold") else None)
+        if self._fold is not None:
+            self._refuse_with_fold(prefix_cache, speculate, multi_token)
+        #: a request is nothing but its pages, a page nothing but its
+        #: positions' rows: what page sharing and migration rest on
+        self._pages_portable = not (self._stateful or self._fold)
+        layout = self._fold or FlatPages(self.page_size)
         if num_pages is None:
-            num_pages = (self.S * self.L) // self.page_size
+            num_pages = self.S * layout.peak(self.L)
         self._pages = PagePool(num_pages, self.page_size, self.L, self.S,
-                               prefix_cache=prefix_cache)
-        self.maxp = self.L // self.page_size
+                               prefix_cache=prefix_cache, layout=layout)
+        self.maxp = self._pages.max_pages
         # the paged read walks the table a block at a time, as far as
         # the deepest row reaches: what a dispatch walks and what the
         # table holds are summed here (stats(): kv_walk_blocks /
@@ -733,6 +784,12 @@ class InferenceEngine:
         self._kv_block = _llama.kv_block(self.page_size, self.maxp)
         self._kv_walked = 0
         self._kv_tabled = 0
+        # folding: pages the dispatched rows' tables held, of the pages
+        # their depths would hold unfolded (stats(): pages_held /
+        # pages_unfolded), and the windows the open tick's dispatches ended
+        self._held = 0
+        self._unfolded = 0
+        self._tick_folded = 0
         # rows whose token a program selects, and those among them whose
         # selection runs filter_logits' search (stats(): sample_rows /
         # sample_rows_filtered)
@@ -780,6 +837,11 @@ class InferenceEngine:
         self._chunk = min(int(prefill_chunk), self.L)
         if self._chunk < 1:
             raise MXNetError("prefill_chunk must be >= 1")
+        if self._fold is not None and self._fold.window % self._chunk:
+            raise MXNetError(
+                f"prefill_chunk ({self._chunk}) must divide the model's "
+                f"window ({self._fold.window}): a chunk lies in one window, "
+                "whose end folds it")
         self._chunks_per_tick = 1
         self._prefills: Dict[int, _Prefill] = {}
         self._active = onp.zeros(self.S, bool)
@@ -1282,14 +1344,43 @@ class InferenceEngine:
                 "state: the device loop writes past a finished row's "
                 "budget, which a page forgives and a state does not")
 
+    @staticmethod
+    def _refuse_with_fold(prefix_cache, speculate, multi_token):
+        """What a model whose cache folds cannot be served with, each with
+        its reason (class docstring)."""
+        if prefix_cache:
+            raise MXNetError(
+                "prefix_cache=True cannot serve a model whose cache folds: "
+                "a shared page is keyed by the tokens of one page, and a "
+                "folded page stands for a whole window of them (and a COW "
+                "copy of a window's page would have to be folded again); "
+                "pass prefix_cache=False")
+        if speculate:
+            raise MXNetError(
+                "speculate cannot serve a model whose cache folds: a "
+                "rejected draft may have ended a window, and a fold cannot "
+                "be taken back (the window's pages are already summarised "
+                "and given away)")
+        if multi_token > 1:
+            raise MXNetError(
+                "multi_token > 1 cannot serve a model whose cache folds: "
+                "the device loop writes past a finished row's budget and "
+                "would end windows the host has leased no summary page for")
+
     # ------------------------------------------------- page migration
-    def _refuse_migration_with_state(self):
+    def _refuse_migration(self):
         if self._stateful:
             raise MXNetError(
                 "page migration (copy / extract / inject) cannot move a "
                 "request of a model with recurrent state: its pages hold "
                 "the sparse layers' keys and values only, and a snapshot "
                 "of the per-slot state is not carried")
+        if self._fold is not None:
+            raise MXNetError(
+                "page migration (copy / extract / inject) cannot move a "
+                "request of a model whose cache folds: a shipped page is "
+                "verified by the hash of one page of tokens, and a folded "
+                "page stands for a whole window of them")
 
     def _export_entries(self, toks: List[int], phys_pages: Sequence[int]
                         ) -> dict:
@@ -1324,7 +1415,7 @@ class InferenceEngine:
         prefill published the pages, when every exported page is pinned
         by its cache entry). Runs at a tick boundary of the engine loop,
         like :meth:`import_pages`."""
-        self._refuse_migration_with_state()
+        self._refuse_migration()
         toks = self._as_prompt(input_ids)
 
         def export():
@@ -1363,7 +1454,7 @@ class InferenceEngine:
         the engine loop (the loop owns the pools); on a stopped engine
         it applies inline. Returns ``{"received", "adopted",
         "verify_failures", ...}``."""
-        self._refuse_migration_with_state()
+        self._refuse_migration()
         from ..kvstore.comm import decode_kv_pages
         tokens, pages = decode_kv_pages(doc)
         return self._on_loop(
@@ -1517,7 +1608,7 @@ class InferenceEngine:
             self._warm(self._get_chunk(), "chunk", self._chunk)
         if self._pages.prefix_cache_enabled:
             self._warm(self._get_copy(), "copy", 0)
-        if not self._stateful:
+        if self._pages_portable:
             # migration executables: warmed so a first preemption rescue
             # or tier page-stream inside steady-state serving hits cached
             # code (the no_recompile() contract with migration enabled).
@@ -1772,7 +1863,7 @@ class InferenceEngine:
         counter ``counter0`` so preempted requests resume mid-stream)."""
         fm = self._fm
         grammar = self._grammar
-        stateful = self._stateful
+        stateful, folding = self._stateful, self._fold is not None
 
         def prefill(values, pools, ids, true_len, start, table, *rest):
             rows = None
@@ -1780,6 +1871,9 @@ class InferenceEngine:
                 # the row's slot, and how many of the bucket's positions
                 # are the prompt's: padding must not move a state
                 rows, rest = (rest[0], jnp.reshape(true_len, (1,))), rest[1:]
+            elif folding:
+                # ... nor end a window
+                rows = (jnp.reshape(true_len, (1,)),)
             if grammar:
                 (gcls, gnxt, gacc, gstate, geos,
                  temps, topks, topps, seeds, counter0) = rest
@@ -1803,9 +1897,11 @@ class InferenceEngine:
         """A middle prefill chunk: KV-page writes only (XLA dead-code-
         eliminates the LM head — the chunk's logits are never used)."""
         fm = self._fm
+        folding = self._fold is not None
 
         def chunk(values, pools, ids, start, table, *slot):
-            rows = (slot[0], jnp.full(1, cs, jnp.int32)) if slot else None
+            real = (jnp.full(1, cs, jnp.int32),)
+            rows = slot + real if slot else real if folding else None
             _logits, new_pools = _gen.decode_step(fm, values, ids, start,
                                                   pools, block_table=table,
                                                   rows=rows)
@@ -1833,12 +1929,14 @@ class InferenceEngine:
             return _jit_named(step, f"step_b{sb}", donate_argnums=1)
 
         grammar = self._grammar
-        stateful = self._stateful
+        stateful, folding = self._stateful, self._fold is not None
 
         def step(values, pools, tokens, pos, tables, *rest):
             rows = None
             if stateful:
                 rows, rest = (rest[0], jnp.ones(sb, jnp.int32)), rest[1:]
+            elif folding:
+                rows = (jnp.ones(sb, jnp.int32),)
             if grammar:
                 (gcls, gnxt, gacc, gstate, geos,
                  temps, topks, topps, seeds, counters) = rest
@@ -1997,20 +2095,48 @@ class InferenceEngine:
     def _span(self, name: str, hist=None, **attrs) -> _TickSpan:
         return _TickSpan(self, name, hist, **attrs)
 
-    def _note_walk(self, span: _profiler.scope, deepest: int, T: int,
+    def _note_walk(self, span: _profiler.scope, positions, T: int,
                    substeps: int = 1):
         """Tell a dispatch span how many blocks of the block table the
-        program it dispatches walks in each layer (``walk``: the deepest
-        row's ``pos + T`` in blocks, once per substep of the multi-token
-        loop) out of how many the table holds (``of``), and add both to
-        the sums that ``stats()`` reports."""
+        program it dispatches walks in each layer (``walk``: the columns
+        that the deepest of its rows, each at its ``positions`` entry,
+        reaches with ``T`` new positions, in blocks, once per substep of the
+        multi-token loop) out of how many the table holds (``of``), and add
+        both to the sums that ``stats()`` reports. A folded table's columns
+        are the model's (``cache_fold().column``)."""
         blk = self._kv_block
-        walk = sum(-(-min(deepest + j + T, self.L) // blk)
+        column = self._pages.layout.column
+        walk = sum(-(-max(column(min(p + j + T, self.L) - 1) + 1
+                          for p in positions) // blk)
                    for j in range(substeps))
-        of = substeps * -(-self.L // blk)
+        of = substeps * -(-self.maxp * self.page_size // blk)
         span.set(walk=walk, of=of)
         self._kv_walked += walk
         self._kv_tabled += of
+
+    def _fold_ended(self, span: _profiler.scope, rows):
+        """After the dispatch of ``rows``, ``(slot, depth it brought the
+        slot to)``: tell the span what their tables held when it ran
+        (``held``) of the pages their depths would hold unfolded
+        (``depth_pages``), and fold the table of every row whose depth ended
+        a window: the program was handed the window's pages and the page for
+        their summaries, and from here on the window's pages are anyone's
+        (``folded``: how many did). Nothing, for a model that does not
+        fold."""
+        fold = self._fold
+        if fold is None:
+            return
+        held = unfolded = folded = 0
+        for s, depth in rows:
+            held += fold.entries(depth)
+            unfolded += pages_for(depth, self.page_size)
+            if fold.ends_window(depth):
+                self._pages.fold(s)
+                folded += 1
+        span.set(held=held, depth_pages=unfolded, folded=folded)
+        self._held += held
+        self._unfolded += unfolded
+        self._tick_folded += folded
 
     def _note_sample(self, span: _profiler.scope, rows):
         """Tell a dispatch span how many of the ``(temperature, top_k,
@@ -2063,6 +2189,7 @@ class InferenceEngine:
                         self._cond.wait(0.1)
             self._tick_no += 1
             self._tick_children.clear()
+            self._tick_folded = 0
             with _profiler.scope(
                     "mx.serve.tick", "serve", tick=self._tick_no,
                     queued=len(self._queue),
@@ -2081,6 +2208,7 @@ class InferenceEngine:
                 rows=tick.args.get("rows", 0), sb=tick.args.get("sb", 0),
                 children={k: round(v, 6)
                           for k, v in self._tick_children.items()},
+                folded=self._tick_folded,
                 compiles=compiles - self._compiles_seen)
             _recorder.RECORDER.record("event", "serve.slow_tick", **fields)
             logger.warning("serve: slow tick: %s", fields)
@@ -2181,7 +2309,7 @@ class InferenceEngine:
         decode writes. Prefix-cache hits only reduce the real need."""
         resume = getattr(req, "_resume", None) or ()
         tokens = min(len(req.prompt_ids) + len(resume) + self._adv, self.L)
-        need = pages_for(tokens, self.page_size)
+        need = self._pages.layout.peak(tokens)
         return (self._pages.free_pages()
                 + self._pages.cached_pages()) >= need
 
@@ -2313,12 +2441,13 @@ class InferenceEngine:
                 fn = self._get_chunk()
                 ids = onp.zeros((1, self._chunk), onp.int32)
                 ids[0, :] = pf.ids[pf.cursor:end]
-                self._note_walk(span, pf.cursor, self._chunk)
+                self._note_walk(span, [pf.cursor], self._chunk)
                 self._note_select(span, [(pf.cursor, self._chunk)])
                 pools = fn(self._values, self._pools, ids,
                            onp.int32(pf.cursor), self._table_row(s),
                            *self._slot_rows([s]))
                 self._pools = pools
+                self._fold_ended(span, [(s, end)])
                 if req._span_prefill is not None:
                     ch = req._span_prefill.child(
                         "serve.prefill_chunk", t0=t0w,
@@ -2335,7 +2464,7 @@ class InferenceEngine:
             fn = self._get_prefill(pb)
             ids = onp.zeros((1, pb), onp.int32)
             ids[0, :rest] = pf.ids[pf.cursor:]
-            self._note_walk(span, pf.cursor, pb)
+            self._note_walk(span, [pf.cursor], pb)
             self._note_select(span, [(pf.cursor, pb)])
             self._note_sample(span, [(req.temperature, req.top_k, req.top_p)])
             gargs = ()
@@ -2356,6 +2485,7 @@ class InferenceEngine:
                 onp.array([req.seed & 0xFFFFFFFF], onp.uint32),
                 onp.array([pf.counter0], onp.int32))
             self._pools = pools
+            self._fold_ended(span, [(s, P)])
             if req._span_prefill is not None:
                 ch = req._span_prefill.child(
                     "serve.prefill_chunk", t0=t0w, start=pf.cursor, end=P,
@@ -2445,7 +2575,7 @@ class InferenceEngine:
         req = slot.req
         req._resume = list(slot.generated)
         doc = None
-        if self._migrate_hook is not None and not self._stateful:
+        if self._migrate_hook is not None and self._pages_portable:
             # capture the victim's leased pages BEFORE release() frees
             # them — this is the engine thread, so the pools are stable
             try:
@@ -2570,13 +2700,17 @@ class InferenceEngine:
                 sb = bucket_for(cur[-1][0] + 1, 1, self.S)
         self._tick_span.set(rows=len(cur), sb=sb)
         with self._span("decode_dispatch", sb=sb, rows=len(cur)) as disp:
-            self._note_walk(disp, max(int(self._pos[s]) for s, _ in cur),
+            self._note_walk(disp, [int(self._pos[s]) for s, _ in cur],
                             1, self.K)
             self._note_select(disp, [(int(self._pos[s]), 1) for s, _ in cur])
             self._note_sample(disp, [
                 (float(self._temps[s]), int(self._topks[s]),
                  float(self._topps[s])) for s, _ in cur])
             rec = self._dispatch_step(prev, cur, sb)
+            if rec is not None:
+                # the host clocks already stand behind the dispatched step
+                self._fold_ended(disp, [(s, int(self._pos[s]))
+                                        for s, _ in cur])
         if rec is None:
             return
         if prev is not None:
@@ -3154,6 +3288,10 @@ class InferenceEngine:
         out["state_bytes"] = self._state_bytes
         out["sparse_blocks_read"] = self._sel_read
         out["sparse_blocks_live"] = self._sel_live
+        out["windows_folded"] = pstats["windows_folded"]
+        out["pages_folded"] = pstats["pages_folded"]
+        out["pages_held"] = self._held
+        out["pages_unfolded"] = self._unfolded
         # bounded prefix-cache advert for the router's affinity
         # scoring: top-N chained-hash roots by refcount (the
         # serve_prefix_advert knob caps N; 0 disables the advert)

@@ -36,6 +36,20 @@ of a pool of fixed-size pages instead:
   a preempted request exactly resumable by re-prefilling
   ``prompt + generated`` (see engine._preempt).
 
+- **Folding.** A model whose cache folds (``cache_fold``: EVA attention
+  keeps a finished window of positions as one page of chunk summaries)
+  hands the pool the arithmetic of its table (``layout``: an
+  ``ops.eva_attention.FoldedPages`` where every other model's is
+  :class:`FlatPages`). A slot's need is then counted in the
+  pages it *holds*, not in its positions: ``max_pages`` is the widest table
+  a ``max_len`` request ever has, ``lease`` covers the dispatch that brings
+  the slot to a depth (the page a window's summaries go to included, where
+  the depth ends a window), and :meth:`PagePool.fold` shrinks the table
+  while the slot lives: the window's entries are replaced by that one page
+  and their pages go back to the pool. Sharing is position by position
+  (a prefix entry is keyed by the tokens of one page), so a folding pool
+  has no prefix cache.
+
 Pure host bookkeeping (numpy + stdlib): device page copies/gathers live
 in the models' paged attention and the engine's executables.
 """
@@ -52,7 +66,7 @@ from .. import metrics as _metrics
 from ..analysis import guards as _guards
 from ..base import MXNetError
 
-__all__ = ["PagePool", "OutOfPages", "pages_for", "prefix_key"]
+__all__ = ["PagePool", "FlatPages", "OutOfPages", "pages_for", "prefix_key"]
 
 
 class OutOfPages(MXNetError):
@@ -63,6 +77,24 @@ class OutOfPages(MXNetError):
 def pages_for(tokens: int, page_size: int) -> int:
     """Pages needed to hold ``tokens`` KV rows (ceil division)."""
     return -(-int(tokens) // int(page_size))
+
+
+class FlatPages:
+    """The arithmetic of a table that never shrinks: an entry for every
+    ``page_size`` positions, each position in its own column. What a pool
+    counts with unless the model's cache folds (module docstring)."""
+    window_pages = 0
+
+    def __init__(self, page_size: int):
+        self.page_size = int(page_size)
+
+    def column(self, pos):
+        return pos
+
+    def entries(self, depth: int) -> int:
+        return pages_for(depth, self.page_size)
+
+    peak = entries
 
 
 def prefix_key(tokens: Sequence[int]) -> int:
@@ -95,15 +127,20 @@ class PagePool:
     num_pages : leasable physical pages (the device pools carry one extra
         sink page at index ``num_pages``)
     page_size : tokens per page
-    max_len : per-request KV capacity; must be a page multiple: the read
-        walks the block table in blocks of whole pages
+    max_len : per-request capacity in positions; must be a page multiple:
+        the read walks the block table in blocks of whole pages
         (models/llama._paged_attention)
     slots : block-table rows (the engine's ``max_batch_size``)
     prefix_cache : publish/match shared prompt prefixes
+    layout : the table's arithmetic (``column``, ``entries``, ``peak``,
+        ``window_pages``): :class:`FlatPages` unless the model's cache folds
+        (module docstring). ``max_pages`` (the table's width, and the least
+        ``num_pages`` may be) is the most pages a ``max_len`` request ever
+        holds: folded, far fewer than ``max_len / page_size``
     """
 
     def __init__(self, num_pages: int, page_size: int, max_len: int,
-                 slots: int, prefix_cache: bool = True):
+                 slots: int, prefix_cache: bool = True, layout=None):
         if page_size < 1:
             raise MXNetError("page_size must be >= 1")
         if num_pages < 1:
@@ -112,13 +149,20 @@ class PagePool:
             raise MXNetError(
                 f"max_len ({max_len}) must be a multiple of page_size "
                 f"({page_size}): the paged read walks whole pages")
-        if num_pages * page_size < max_len:
+        self.layout = layout or FlatPages(page_size)
+        if self.layout.window_pages and prefix_cache:
+            raise MXNetError(
+                "a folding pool shares no prefix: a cached page is keyed by "
+                "the tokens of one page, and a folded page stands for a "
+                "whole window of them; pass prefix_cache=False")
+        self.page_size = int(page_size)
+        self.max_pages = self.layout.peak(max_len)
+        if num_pages < self.max_pages:
             raise MXNetError(
                 f"page pool ({num_pages} pages x {page_size}) cannot hold "
-                f"even one max_len ({max_len}) request")
+                f"even one max_len ({max_len}) request, which holds up to "
+                f"{self.max_pages} pages")
         self.num_pages = int(num_pages)
-        self.page_size = int(page_size)
-        self.max_pages = max_len // page_size
         self.slots = int(slots)
         self.sink = self.num_pages          # physical sink page index
         self._ref = onp.zeros(self.num_pages, onp.int32)
@@ -139,6 +183,8 @@ class PagePool:
         self.leases = 0
         self.frees = 0
         self.cow_forks = 0
+        self.windows_folded = 0
+        self.pages_folded = 0
         self.prefix_hits = 0
         self.prefix_misses = 0
         self.prefix_tokens_saved = 0
@@ -226,11 +272,14 @@ class PagePool:
 
     # ------------------------------------------------------------ leasing
     def lease(self, slot: int, tokens: int) -> int:
-        """Grow ``slot``'s table to cover ``tokens`` KV rows. Returns the
+        """Grow ``slot``'s table for the dispatch that brings it to
+        ``tokens`` positions (``layout.entries``: a page for every
+        ``page_size`` of them or, folded, what the model's arithmetic says,
+        the page a window's summaries go to included). Returns the
         number of pages newly leased; raises :class:`OutOfPages` (after
         evicting reclaimable prefix entries) when the pool is exhausted —
         the table is left unchanged in that case (all-or-nothing)."""
-        need = pages_for(tokens, self.page_size)
+        need = self.layout.entries(tokens)
         if need > self.max_pages:
             raise MXNetError(
                 f"request needs {need} pages but max_len allows only "
@@ -243,6 +292,36 @@ class PagePool:
             self._tables[slot, have:need] = fresh
             self._leased[slot] = need
             return len(fresh)
+
+    def fold(self, slot: int) -> int:
+        """Shrink a leased table whose last window is finished: the window's
+        ``layout.window_pages`` entries are replaced by the one entry behind
+        them (the page the dispatch wrote their summaries to, which
+        ``lease`` brought), the replaced pages are dereferenced and the
+        table ends there. The pool allocates nothing here, so it cannot run
+        out: the call either finds a window's pages and one more leased or
+        raises and leaves the table as it was. Returns the pages that went
+        back to the free list."""
+        count = self.layout.window_pages
+        with self._lock:
+            start = int(self._leased[slot]) - count - 1
+            if not count or start < 0:
+                raise MXNetError(
+                    f"fold needs a window's {count} entries and the page "
+                    f"for their summaries; slot {slot} has "
+                    f"{int(self._leased[slot])} leased")
+            row = self._tables[slot]
+            old = [int(p) for p in row[start:start + count]]
+            row[start] = row[start + count]
+            row[start + 1:] = self.sink
+            self._leased[slot] = start + 1
+            free0 = len(self._free)
+            for p in old:
+                self._decref(p)
+            self.windows_folded += 1
+            self.pages_folded += count
+            _metrics.SERVE_PAGE_FOLDS.inc(count)
+            return len(self._free) - free0
 
     def release(self, slot: int):
         """Return every page the slot references (shared pages survive
@@ -486,6 +565,8 @@ class PagePool:
                 "pages_cached_only": self._cache_only_pages(),
                 "leases": self.leases,
                 "cow_forks": self.cow_forks,
+                "windows_folded": self.windows_folded,
+                "pages_folded": self.pages_folded,
                 "prefix_entries": sum(len(b)
                                       for b in self._prefix.values()),
                 "prefix_hits": self.prefix_hits,

@@ -412,3 +412,65 @@ def test_tail_neither_sorts_nor_relays_a_table(one_chip, tail_engines, width,
                            f"bf16[{VOCAB},{lanes}]": "1,0"}
     else:
         assert layouts == {f"bf16[{VOCAB},{d}]": "1,0"}
+
+
+# ---------------- EvaByte's folding programs at the published widths (PR 34)
+@pytest.fixture(scope="module")
+def evabyte_engine():
+    """One EvaByte layer at the published widths (hidden 4,096, 32 heads of
+    128, MLP 11,008, windows of 2,048 in chunks of 16) behind the
+    conversation cell's engine geometry, but for the pool's size: 32 pages of
+    128 rows, the least that ``max_len`` 32,768 allows, so that the CPU holds
+    70 MB of pools and not 6 GB. Made on the CPU: its builders give the
+    programs that the described chip compiles."""
+    from mxnet_tpu.models.evabyte import EvaByteConfig, EvaByteForCausalLM
+    from mxnet_tpu.serve import InferenceEngine
+    net = EvaByteForCausalLM(EvaByteConfig(num_layers=1))
+    net.initialize()
+    return InferenceEngine(net, max_batch_size=16, max_len=32768,
+                           page_size=128, num_pages=32, prefill_chunk=2048,
+                           min_prompt_bucket=256, prefix_cache=False)
+
+
+@pytest.mark.parametrize("program,bucket",
+                         [("decode", 16), ("chunk", 2048), ("prefill", 256)],
+                         ids=["step_b16", "chunk_c2048", "prefill_b256"])
+def test_evabyte_programs_fold_in_place(one_chip, evabyte_engine, program,
+                                        bucket):
+    """The decode step of 16 rows, a middle chunk of 2,048 positions (a whole
+    window) and a last chunk of 256. Whether a dispatch ends a window is data (``pos +
+    valid`` reaching a multiple of 2,048), so the chunk that ends one and the
+    chunk that does not are ONE program, and so are the two steps: each holds
+    the summarising loop (``mx.eva_summarize``), which makes no trip where no
+    row folds. The chip's compiler takes them at the published widths; the
+    table is 32 entries wide; and the donated pools come out as the buffers
+    that went in: no instruction copies a pool, and the aliased bytes are the
+    pools' bytes. A CPU run cannot see the last fact."""
+    eng = evabyte_engine
+    assert eng.maxp == 15 + 16 + 1
+    build = {"decode": eng._build_step, "chunk": eng._build_chunk,
+             "prefill": eng._build_prefill}[program]
+    # the programs are traced from shapes: the pools get the cell's own 192
+    # pages and the sink here (the compiler treats a pool of 17 MB otherwise:
+    # it prefetches all of it into its nearer memory)
+    args = list(_shapes(eng._example_args(program, bucket), one_chip))
+    args[1] = tuple(_s(one_chip, (193, 128, 2048), jnp.bfloat16)
+                    for _ in args[1])
+    compiled = build(bucket).lower(*args).compile()
+    text = compiled.as_text()
+    assert "mx.eva_summarize" in text
+    # (a middle chunk's logits are not used, so with ONE layer its walk is
+    # dead code: only the writes and the fold are left of its attention)
+    assert "mx.kv_walk" in text or program == "chunk"
+    # 32 heads of 128 in two groups of 16: rows of 2,048 lanes. From rows of
+    # 4,096 the compiler's gather of 16 rows' pages sliced the whole pool in
+    # halves ("mini-gather-slice") in every trip of the walk (PR 34)
+    pool = "bf16[193,128,2048]"
+    assert [tuple(p.shape) for p in eng._pools] == [(33, 128, 2048)] * 4
+    assert "mini-gather" not in text
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if _opcode(line)[0] in ("copy", "copy-start", "copy-done")
+              and pool in _opcode(line)[1]]
+    assert not copies
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == 4 * 193 * 128 * 2048 * 2

@@ -74,6 +74,7 @@ REQUIRED_PAGING_METRICS = (
     "mxnet_serve_page_in_use",
     "mxnet_serve_page_leases_total",
     "mxnet_serve_page_cow_forks_total",
+    "mxnet_serve_page_folds_total",
     "mxnet_serve_page_preemptions_total",
     "mxnet_serve_page_prefix_hits_total",
     "mxnet_serve_page_prefix_misses_total",
