@@ -65,7 +65,8 @@ from ..gluon.block import HybridBlock
 from ..gluon.parameter import Parameter
 from ..ndarray import invoke_jnp
 from ..ops import eva_attention as _eva
-from .llama import LlamaMLP, _decode_positions, _paged_attention, _rope
+from .llama import (LlamaMLP, _decode_positions, _paged_attention, _rope,
+                    walk_form)
 
 __all__ = ["EvaByteConfig", "EvaByteForCausalLM", "EVABYTE_TINY"]
 
@@ -330,6 +331,11 @@ class EvaByteForCausalLM(HybridBlock):
         kv = ((num_pages, page_size, cfg.group_heads * cfg.head_dim),
               cfg.dtype)
         return [kv, kv] * (cfg.num_heads // cfg.group_heads) * cfg.num_layers
+
+    def walk_form(self, T: int) -> str:
+        """The form the shared walk takes over ``T`` new positions, a group
+        of heads a call (``models/llama.walk_form``)."""
+        return walk_form(self.cfg.group_heads, T)
 
     def forward_cached_paged(self, input_ids, pos, block_table, valid,
                              *caches):

@@ -164,6 +164,12 @@ class GPTModel(HybridBlock):
         shp = (num_pages, page_size, cfg.hidden_size)
         return [(shp, cfg.dtype)] * (2 * cfg.num_layers)
 
+    def walk_form(self, T: int) -> str:
+        """The form a layer's paged read takes over ``T`` new positions
+        (``models/llama.walk_form``)."""
+        from .llama import walk_form
+        return walk_form(self.cfg.num_heads, T)
+
     def forward_cached(self, input_ids, pos, *caches):
         hidden, *new_caches = self.forward_cached_hidden(input_ids, pos,
                                                          *caches)

@@ -295,6 +295,25 @@ def kv_block(page_size: int, max_pages: int) -> int:
     return min(max(KV_BLOCK // page_size, 1), max_pages) * page_size
 
 
+# Query columns (heads x new positions) up to which the paged read multiplies
+# a block as the pool stores it: one lane tile. The MXU pads a product's
+# columns to a tile whatever their number, so up to there the zeros of a
+# block-diagonal query cost nothing beside the block's bytes; past it they
+# are real FLOPs (a thousandfold at a chunk), and there the compiler already
+# feeds the head-split products bf16 blocks (PERF.md section 6, PR 35). A
+# constant, not a knob.
+WALK_LANES = 128
+
+
+def walk_form(heads: int, T: int) -> str:
+    """Which form the paged read of ``heads`` query heads over ``T`` new
+    positions takes (:func:`_walk_pages`): ``"lanes"`` for a decode step
+    and a verify of a few tokens, ``"heads"`` for a prefill chunk or
+    bucket. Fixed when a program is traced; the serving engine asks the
+    same (``form`` on its dispatch spans)."""
+    return "lanes" if heads * T <= WALK_LANES else "heads"
+
+
 # jitted by itself so that a program of many layers traces and lowers the
 # walk once: unrolled, the loop's body made a step's lowering a third longer
 # and the engine's warm-up with it (PERF.md section 6, PR 27)
@@ -324,24 +343,43 @@ def _paged_attention(qh, kh, vh, k_pages, v_pages, block_table, pos, rep):
     Writes scatter the T new K/V rows through the table, whole rows
     (page = table[col // ps], offset = col % ps). Reads WALK the table in
     blocks of ``KV_BLOCK`` tokens (whole pages, aligned at column 0) under
-    a running float32 softmax, and the trip count is data: the deepest
-    ACTIVE row's ``pos + T`` (a row whose table starts at the sink is
-    inactive, so a stale ``pos`` there cannot lengthen the walk). Nothing
-    of width ``max_len`` is gathered or converted: a step costs the live
-    pages, not the table's.
+    a running float32 softmax (max, sum and accumulator), and the trip
+    count is data: the deepest ACTIVE row's ``pos + T`` (a row whose table
+    starts at the sink is inactive, so a stale ``pos`` there cannot
+    lengthen the walk). Nothing of width ``max_len`` is gathered or
+    converted: a step costs the live pages, not the table's.
+
+    What multiplies a block has two forms, chosen when the program is
+    traced by :func:`walk_form` from the query columns it brings, ``heads x
+    T`` against ``WALK_LANES``. A decode step, and a verify of a few tokens,
+    multiply the block as the pool stores it, heads on the lanes
+    (:func:`_walk_lanes`): left to the head-split form, the chip converted
+    every block to float32 and relaid it out for the heads in every trip,
+    for a one-row product that stays on its vector unit, and that, not the
+    block's bytes, was what a trip cost (PERF.md section 6, PR 35). A
+    prefill chunk or bucket splits the block into its heads
+    (:func:`_walk_pages`), where the products are large and the compiler
+    feeds them the block as stored already. The scores are products of
+    ``q`` and ``k`` as stored, summed in float32; on the lanes the softmax
+    weights enter the second product in the values' dtype, as they do in
+    the flash kernels and in what the chip makes of a chunk.
 
     What is bitwise: a block that the mask hides entirely is an exact
     no-op on (max, sum, accumulator), and column 0 is valid for every row,
     so a row's output depends neither on the trip count (who else is in
-    the batch) nor on ``T``. T > 1 with per-row ``pos`` is the
-    self-speculative VERIFY step (serve engine ``speculate=K``): column
-    j's logits are bitwise what the sequential decode computes (the
-    chunked-prefill T-invariance contract) -- rejected drafts leave stale
-    K/V rows past the accepted point that the causal mask hides until they
-    are overwritten, exactly like the multi-token loop's speculative rows.
-    Against the CONTIGUOUS layout (:func:`_cached_attention`) the
-    summation order differs, so that parity is token identity of greedy
-    decode (tests/test_serve_paging.py), not bits."""
+    the batch) nor, between programs of one form, on ``T``. T > 1 with
+    per-row ``pos`` is the self-speculative VERIFY step (serve engine
+    ``speculate=K``): column j's logits are bitwise what the sequential
+    decode computes where both take one form (``heads x K <= WALK_LANES``)
+    -- rejected drafts leave stale K/V rows past the accepted point that
+    the causal mask hides until they are overwritten, exactly like the
+    multi-token loop's speculative rows. Between the two forms (a step
+    against the same column of a chunk, a verify wider than that) the
+    outputs agree to the rounding of the weights and of the output to the
+    served dtype (tests/test_paged_walk.py), as they do against the
+    CONTIGUOUS layout (:func:`_cached_attention`), whose summation order
+    differs: that parity is token identity of greedy decode
+    (tests/test_serve_paging.py), not bits."""
     B, H, T, hd = qh.shape
     G, ps = kh.shape[1], k_pages.shape[1]
     maxp = block_table.shape[1]
@@ -374,9 +412,16 @@ def _walk_pages(qh, k_pages, v_pages, block_table, cols, rep):
     """The read side of :func:`_paged_attention`: query row t of batch row
     b (at column ``cols[b, t]``) attends the row's logical columns
     ``j <= cols[b, t]``, one block of pages an iteration. A block is
-    gathered as the pool lies, ``[B, pages, ps, G * hd]``, and split into
-    its heads there: ``[B, block, G, hd]``. The pool itself is only
-    indexed."""
+    gathered as the pool lies, ``[B, pages, ps, G * hd]``; the pool itself
+    is only indexed. What multiplies the block has two forms, and
+    :func:`walk_form` says which a program takes.
+
+    ``"heads"`` (a prefill chunk or bucket): the block is split into its
+    heads, ``[B, block, G, hd]``, and each head's queries meet its keys.
+
+    ``"lanes"`` (:func:`_walk_lanes`: a decode step, a verify of a few
+    tokens): the heads stay on the lanes and the query takes the block's
+    shape instead."""
     B, H, T, hd = qh.shape
     G, ps = H // rep, k_pages.shape[1]
     maxp = block_table.shape[1]
@@ -392,6 +437,8 @@ def _walk_pages(qh, k_pages, v_pages, block_table, cols, rep):
     active = block_table[:, 0] != sink
     live = jnp.max(jnp.where(active, last[:, -1], 0)) + 1
     n = (live + block - 1) // block
+    if walk_form(H, T) == "lanes":
+        return _walk_lanes(qh, k_pages, v_pages, table, bp, last, n, rep)
     q = qh.reshape(B, G, rep, T, hd).astype(jnp.float32) / math.sqrt(hd)
 
     def step(i, carry):
@@ -415,6 +462,58 @@ def _walk_pages(qh, k_pages, v_pages, block_table, cols, rep):
     acc0 = jnp.zeros((B, G, rep, T, hd), jnp.float32)
     _, l, acc = jax.lax.fori_loop(0, n, step, (m0, l0, acc0))
     return (acc / l[..., None]).reshape(B, H, T, hd).astype(qh.dtype)
+
+
+def _walk_lanes(qh, k_pages, v_pages, table, bp, last, n, rep):
+    """The walk of :func:`_walk_pages` for few query columns: ``n`` trips
+    over ``table`` (padded to whole blocks of ``bp`` pages), query row t of
+    batch row b seeing the columns up to ``last[b, t]``.
+
+    A block stays as gathered, ``[B, block, G * hd]`` in the pool's dtype,
+    and the query is laid out to meet it: block-diagonal ``[B, G * hd, N]``
+    whose column ``(g, r, t)`` holds ``qh[b, g * rep + r, t]`` on head g's
+    lanes and zeros on the others'. Scores and weighted values are then two
+    plain matrix products over the block as it lies (the zeros add exact
+    zeros), the accumulator is ``[B, N, G * hd]`` float32, and head g's
+    output is the g-th diagonal block of it, taken once after the loop. No
+    value of a block's size is converted or relaid out inside the loop."""
+    B, H, T, hd = qh.shape
+    G, ps = H // rep, k_pages.shape[1]
+    N, block = H * T, bp * ps
+    q = qh.reshape(B, G, rep * T, hd).astype(k_pages.dtype)
+    heads = jnp.eye(G, dtype=q.dtype)
+    q = jnp.einsum("bgnd,hg->bhdgn", q, heads).reshape(B, G * hd, N)
+    # made once: left alone, the compiler rebuilds it from qh in every trip
+    # (it is G times qh's size, and what inflates is not hoisted)
+    q = jax.lax.optimization_barrier(q)
+    last = jnp.broadcast_to(last[:, None], (B, H, T)).reshape(B, N)
+
+    def step(i, carry):
+        m, l, acc = carry
+        pages = jax.lax.dynamic_slice_in_dim(table, i * bp, bp, axis=1)
+        kb = k_pages[pages].reshape(B, block, G * hd)
+        vb = v_pages[pages].reshape(B, block, G * hd)
+        col = i * block + jnp.arange(block, dtype=jnp.int32)
+        mask = col[None, None, :] <= last[:, :, None]              # [B,N,blk]
+        s = jnp.einsum("bjc,bcn->bnj", kb, q,
+                       preferred_element_type=jnp.float32) / math.sqrt(hd)
+        s = jnp.where(mask, s, -jnp.inf)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        scale = jnp.exp(m - m_new)
+        l = l * scale + p.sum(axis=-1)
+        acc = acc * scale[..., None] + jnp.einsum(
+            "bnj,bjc->bnc", p.astype(vb.dtype), vb,
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    m0 = jnp.full((B, N), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((B, N), jnp.float32)
+    acc0 = jnp.zeros((B, N, G * hd), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, n, step, (m0, l0, acc0))
+    out = jnp.einsum("bgngd->bgnd", acc.reshape(B, G, rep * T, G, hd))
+    out = out / l.reshape(B, G, rep * T, 1)
+    return out.reshape(B, H, T, hd).astype(qh.dtype)
 
 
 class LlamaMLP(HybridBlock):
@@ -892,6 +991,11 @@ class LlamaForCausalLM(HybridBlock):
 
     def cache_spec_paged(self, num_pages: int, page_size: int):
         return self.model.cache_spec_paged(num_pages, page_size)
+
+    def walk_form(self, T: int) -> str:
+        """The form a layer's paged read takes over ``T`` new positions
+        (:func:`walk_form`)."""
+        return walk_form(self.cfg.num_heads, T)
 
     def forward_cached(self, input_ids, pos, *caches):
         h, *new_caches = self.model.forward_cached(input_ids, pos, *caches)

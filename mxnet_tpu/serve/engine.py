@@ -60,8 +60,10 @@ Architecture (vLLM-style continuous batching, TPU-static shapes):
   history (n-gram prompt lookup, serve/speculate.py; no draft model),
   verified in ONE batched forward. The verify recomputes EXACTLY the
   token the non-speculative path would emit at each position (same
-  bitwise logits by the chunked-prefill T-invariance contract, same
-  stateless ``fold_in`` sampling keys), so acceptance is plain equality
+  bitwise logits by the chunked-prefill T-invariance contract, where the
+  verify's paged read takes the step's form: ``heads x K`` within
+  ``models/llama.WALK_LANES``; same stateless ``fold_in`` sampling
+  keys), so acceptance is plain equality
   and output is token-identical to ``speculate=0`` — greedy AND
   sampled. Each round is one host round-trip for 1..K true tokens;
   acceptance/rounds ride ``mxnet_spec_*``. Composes with fused
@@ -85,9 +87,11 @@ few rows it writes in the buffer where they lie and returns that buffer
 loop touches ``self._pools``: an array read from another thread may have
 been donated since, so page exports and imports run at a tick boundary.
 A step reads only the blocks of pages that its deepest row has reached
-(``stats()``: ``kv_walk_blocks`` of ``kv_table_blocks``), and searches for
-a top-k or nucleus threshold only where one of its rows asks for one
-(``sample_rows_filtered`` of ``sample_rows``).
+(``stats()``: ``kv_walk_blocks`` of ``kv_table_blocks``) and multiplies each
+as the pool stores it (``kv_walk_blocks_on_lanes``: the blocks of the
+dispatches whose program took that form, ``models/llama.walk_form``), and
+searches for a top-k or nucleus threshold only where one of its rows asks
+for one (``sample_rows_filtered`` of ``sample_rows``).
 """
 from __future__ import annotations
 
@@ -780,9 +784,14 @@ class InferenceEngine:
         # the paged read walks the table a block at a time, as far as
         # the deepest row reaches: what a dispatch walks and what the
         # table holds are summed here (stats(): kv_walk_blocks /
-        # kv_table_blocks, the share of a max_len read still done)
+        # kv_table_blocks, the share of a max_len read still done), and
+        # of the walked, those a program multiplied as the pool stores them
+        # (kv_walk_blocks_on_lanes: the model's walk_form says which
+        # programs do, where its layers read through the shared walk)
         self._kv_block = _llama.kv_block(self.page_size, self.maxp)
+        self._walk_form = getattr(model, "walk_form", None)
         self._kv_walked = 0
+        self._kv_on_lanes = 0
         self._kv_tabled = 0
         # folding: pages the dispatched rows' tables held, of the pages
         # their depths would hold unfolded (stats(): pages_held /
@@ -2103,7 +2112,8 @@ class InferenceEngine:
         reaches with ``T`` new positions, in blocks, once per substep of the
         multi-token loop) out of how many the table holds (``of``), and add
         both to the sums that ``stats()`` reports. A folded table's columns
-        are the model's (``cache_fold().column``)."""
+        are the model's (``cache_fold().column``). ``form`` is the form the
+        program's paged read was traced in (``models/llama.walk_form``)."""
         blk = self._kv_block
         column = self._pages.layout.column
         walk = sum(-(-max(column(min(p + j + T, self.L) - 1) + 1
@@ -2113,6 +2123,11 @@ class InferenceEngine:
         span.set(walk=walk, of=of)
         self._kv_walked += walk
         self._kv_tabled += of
+        if self._walk_form is not None:
+            form = self._walk_form(T)
+            span.set(form=form)
+            if form == "lanes":
+                self._kv_on_lanes += walk
 
     def _fold_ended(self, span: _profiler.scope, rows):
         """After the dispatch of ``rows``, ``(slot, depth it brought the
@@ -3282,6 +3297,7 @@ class InferenceEngine:
         out["prefilling"] = len(self._prefills)
         out["preemptions"] = self._preempted
         out["kv_walk_blocks"] = self._kv_walked
+        out["kv_walk_blocks_on_lanes"] = self._kv_on_lanes
         out["kv_table_blocks"] = self._kv_tabled
         out["sample_rows"] = self._sample_rows
         out["sample_rows_filtered"] = self._sample_filtered

@@ -150,6 +150,7 @@ CASES = ([("serve_span", "idle"), ("serve_span", "tick")]
          + [("nested_in_tick", c) for c in TICK_CHILDREN]
          + [("idle_outside_tick", "idle")]
          + [("set_inside", a) for a in SET_INSIDE]
+         + [("walk_form", "prefill_dispatch"), ("walk_form", "decode_dispatch")]
          + [("train_span", s) for s in TRAIN_SPANS]
          + [("step_scope", s) for s in STEP_SCOPES]
          + [("train_scope", s) for s in TRAIN_SCOPES]
@@ -186,6 +187,13 @@ def test_program_names(kind, name, request):
         values = [a[attr] for n, _, _, a in line
                   if n == f"mx.serve.{span}" and attr in a]
         assert values and max(values) >= 1
+    elif kind == "walk_form":
+        # the form the dispatched program's paged read was traced in; two
+        # heads over a bucket of 8 tokens are as few columns as a step's
+        line = engine_line(request.getfixturevalue("serve_lines"))
+        forms = [a.get("form") for n, _, _, a in line
+                 if n == f"mx.serve.{name}"]
+        assert forms and set(forms) == {"lanes"}
     elif kind == "train_span":
         lines = request.getfixturevalue("train_lines")
         assert any(n == f"mx.train.{name}" for ln in lines for n, *_ in ln)

@@ -11,6 +11,7 @@ CPU here, so each test steers that predicate itself.
 """
 import dataclasses
 import importlib
+import math
 import os
 
 import jax
@@ -324,6 +325,85 @@ def test_pools_are_written_in_place(one_chip, pool_engines, width, program,
     lanes = -(-d // 128) * 128
     assert compiled.memory_analysis().alias_size_in_bytes \
         == 2 * 321 * 16 * lanes * 2
+
+
+# ------ a decoding row's walk multiplies a block as the pool stores it (PR 35)
+# (rows, heads, head width, T, pool, pages a row): the conversation cell's
+# group of EvaByte heads at 8 rows, the chat cell's GPT-2 XL at 2, and the
+# conversation cell's chunk
+_WALKS = {
+    "evabyte_step_b8": (8, 16, 128, 1, (193, 128, 2048), 32),
+    "xl_step_b2": (2, 25, 64, 1, (321, 16, 1600), 64),
+    "evabyte_chunk_c1024": (1, 16, 128, 1024, (193, 128, 2048), 32),
+}
+# sha1 over "opcode shape" of every instruction of the chunk's loop body, as
+# the compiler gave it before the decode walk changed; a change to the
+# head-split form (models/llama._walk_pages) shows here and has to be meant
+_CHUNK_BODY = "6eb49929274eec89c1470ae811bfc79cfac223a4"
+
+
+def _loop_body(text):
+    """[(opcode, result shapes as text)] of the instructions in the body of
+    the one ``while`` of an optimized HLO text."""
+    import re
+    (name,) = set(re.findall(r" while\(.*?body=%?([\w.\-]+)", text))
+    lines = text.splitlines()
+    start = next(i for i, ln in enumerate(lines)
+                 if re.match(rf"%?{re.escape(name)} \(", ln))
+    body = []
+    for ln in lines[start + 1:]:
+        if ln.startswith("}"):
+            break
+        rest = ln.split(" = ", 1)[1]
+        depth = 0
+        for end, ch in enumerate(rest):       # a tuple's shape has spaces
+            depth += (ch == "(") - (ch == ")")
+            if ch == " " and not depth:
+                break
+        body.append((rest[end + 1:].split("(", 1)[0], rest[:end]))
+    return body
+
+
+@pytest.mark.parametrize("name", list(_WALKS))
+def test_decode_walk_multiplies_blocks_as_stored(one_chip, name):
+    """With few query columns (a step's heads x 1) the loop of the paged
+    read is two gathers of a block as the pool lies and two matrix products
+    over them: no instruction of its body makes a float32 array of a block's
+    size, as the head-split form's ``convert`` and ``copy`` (EvaByte: 8 MiB
+    each, for K and again for V) and its ``reshape f32[2,128,25,64]`` (GPT-2
+    XL) did in every trip. Bfloat16 operands alone do not do it: a one-row
+    product stays on the vector unit, which has no bfloat16 (PERF.md section
+    6, PR 35). A chunk's walk keeps the head-split form, whose products
+    already take the blocks in bfloat16: its loop is what it was. A CPU run
+    cannot see any of this."""
+    import hashlib
+    import re
+    from mxnet_tpu.models.llama import _paged_attention, walk_form
+    rows, heads, hd, T, pool, maxp = _WALKS[name]
+    bf, i32 = jnp.bfloat16, jnp.int32
+    x = _s(one_chip, (rows, heads, T, hd), bf)
+    compiled = jax.jit(
+        lambda q, k, v, kp, vp, bt, pos: _paged_attention(
+            q, k, v, kp, vp, bt, pos, 1), donate_argnums=(3, 4)).lower(
+        x, x, x, _s(one_chip, pool, bf), _s(one_chip, pool, bf),
+        _s(one_chip, (rows, maxp), i32), _s(one_chip, (rows,), i32)).compile()
+    text = compiled.as_text()
+    body = _loop_body(text)
+    if T > 1:
+        assert walk_form(heads, T) == "heads"
+        assert hashlib.sha1("\n".join(
+            f"{op} {shape}" for op, shape in body).encode()).hexdigest() \
+            == _CHUNK_BODY
+        return
+    assert walk_form(heads, T) == "lanes"
+    block = rows * 128 * pool[2]
+    gathered = f"bf16[{block // (pool[1] * pool[2])},{pool[1]},{pool[2]}]"
+    assert sum(shape.startswith(gathered) for _, shape in body) == 2
+    wide = [(op, shape[:80]) for op, shape in body
+            for dims in re.findall(r"f32\[([\d,]+)\]", shape)
+            if math.prod(int(d) for d in dims.split(",")) >= block]
+    assert not wide
+    assert " convolution(" in text
 
 
 # ------------- the tail of a serving program: no sort, no table relaid out
