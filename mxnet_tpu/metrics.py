@@ -987,6 +987,15 @@ SERVE_PAGE_FOLDS = Counter(
     "Pages that went back to the pool while their request lived: a model "
     "whose cache folds finished a window, and the window's pages were "
     "replaced by one page of summaries")
+SERVE_WINDOW_PAGES_RECYCLED = Counter(
+    "mxnet_serve_window_pages_recycled_total",
+    "Pages of a windowed kind that went back to the pool while their "
+    "request lived: they lay wholly behind its sliding window")
+SERVE_MOE_ASSIGNMENTS = Counter(
+    "mxnet_serve_moe_assignments_total",
+    "Token-to-expert assignments the served programs routed (where=all), "
+    "and those among them to experts this engine's model holds "
+    "(where=here)", labels=("where",))
 SERVE_PAGE_PREEMPTIONS = Counter(
     "mxnet_serve_page_preemptions_total",
     "Slots preempted on pool exhaustion (released + requeued; resumed "

@@ -13,6 +13,8 @@ from .t5 import T5Config, T5Model, T5_SMALL, T5_TINY
 from .minicpm_sala import (MiniCPMSALAConfig, MiniCPMSALAForCausalLM,
                            SALA_TINY)
 from .evabyte import EvaByteConfig, EvaByteForCausalLM, EVABYTE_TINY
+from .cohere2_moe import (Cohere2MoEConfig, Cohere2MoEForCausalLM,
+                          COHERE2_MOE_TINY)
 from .generation import generate
 
 # attach the decode loop as a method on the causal-LM families (one
@@ -36,5 +38,6 @@ __all__ = [
     "T5Config", "T5Model", "T5_SMALL", "T5_TINY",
     "MiniCPMSALAConfig", "MiniCPMSALAForCausalLM", "SALA_TINY",
     "EvaByteConfig", "EvaByteForCausalLM", "EVABYTE_TINY",
+    "Cohere2MoEConfig", "Cohere2MoEForCausalLM", "COHERE2_MOE_TINY",
     "generate",
 ]

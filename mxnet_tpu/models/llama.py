@@ -79,7 +79,9 @@ LLAMA_TINY = LlamaConfig(vocab_size=256, hidden_size=64, intermediate_size=128,
 
 
 def _rope(x, positions, theta: float):
-    """Rotary embedding, interleaved-pairs convention; f32 math.
+    """Rotary embedding, rotate-half convention (lane ``i`` turns against
+    lane ``i + D/2``; ``models/cohere2_moe.py`` has the interleaved-pairs
+    one); f32 math.
 
     ``positions`` is [T] (whole batch at the same offsets) or [B, T]
     (per-sequence offsets — the serving engine's continuous batches run
@@ -318,8 +320,9 @@ def walk_form(heads: int, T: int) -> str:
 # walk once: unrolled, the loop's body made a step's lowering a third longer
 # and the engine's warm-up with it (PERF.md section 6, PR 27)
 @jax.named_scope("mx.paged_attention")
-@functools.partial(jax.jit, static_argnames="rep")
-def _paged_attention(qh, kh, vh, k_pages, v_pages, block_table, pos, rep):
+@functools.partial(jax.jit, static_argnames=("rep", "window"))
+def _paged_attention(qh, kh, vh, k_pages, v_pages, block_table, pos, rep,
+                     window=None):
     """Attention for incremental decode over a PAGED cache: the pool
     carries [num_pages + 1, page_size, n_kv * hd] physical pages shared by
     every request, one row a token: the K (or V) vectors of all its heads
@@ -379,7 +382,22 @@ def _paged_attention(qh, kh, vh, k_pages, v_pages, block_table, pos, rep):
     served dtype (tests/test_paged_walk.py), as they do against the
     CONTIGUOUS layout (:func:`_cached_attention`), whose summation order
     differs: that parity is token identity of greedy decode
-    (tests/test_serve_paging.py), not bits."""
+    (tests/test_serve_paging.py), not bits.
+
+    **A sliding window.** With ``window`` (static) a query at column ``i``
+    sees the keys ``i - window < j <= i`` only. The mask gets its lower
+    bound, and the walk its first block: that of the earliest column any
+    ACTIVE row's first query still sees, so a decode step deep in a long
+    request walks ``window / block + 2`` blocks at the most, not its depth.
+    The table is indexed by logical page as ever; the pages behind a row's
+    window may have been given back (``serve/paging.PagePool.slide``: their
+    entries point at the sink), so column 0 says nothing of a row here and a
+    row is active if the page of its first new position is leased. A block
+    the mask hides whole is still an exact no-op, also while a row's window
+    has not begun (its running maximum is still ``-inf``: the walk starts
+    where the shallowest row's window does), and a row that sees no key at
+    all (an inactive one) reads zeros. With ``window=None`` none of this is
+    traced: the program is letter for letter what it was."""
     B, H, T, hd = qh.shape
     G, ps = kh.shape[1], k_pages.shape[1]
     maxp = block_table.shape[1]
@@ -404,11 +422,24 @@ def _paged_attention(qh, kh, vh, k_pages, v_pages, block_table, pos, rep):
             vh.transpose(0, 2, 1, 3).reshape(B, T, G * hd)
             .astype(v_pages.dtype))
     with jax.named_scope("mx.kv_walk"):
-        out = _walk_pages(qh, k_pages, v_pages, block_table, cols, rep)
+        out = _walk_pages(qh, k_pages, v_pages, block_table, cols, rep,
+                          window)
     return out, k_pages, v_pages
 
 
-def _walk_pages(qh, k_pages, v_pages, block_table, cols, rep):
+def _softmax_step(m, l, s, windowed: bool):
+    """One block's scores ``s`` (masked ones ``-inf``) into the running
+    maximum and sum: ``(m_new, l_new, p, scale)``. Under a window a row may
+    not have met a visible key yet; its maximum is then ``-inf`` and must not
+    be subtracted from itself."""
+    m_new = jnp.maximum(m, s.max(axis=-1))
+    ref = jnp.where(jnp.isneginf(m_new), 0.0, m_new) if windowed else m_new
+    p = jnp.exp(s - ref[..., None])
+    scale = jnp.exp(m - ref)
+    return m_new, l * scale + p.sum(axis=-1), p, scale
+
+
+def _walk_pages(qh, k_pages, v_pages, block_table, cols, rep, window=None):
     """The read side of :func:`_paged_attention`: query row t of batch row
     b (at column ``cols[b, t]``) attends the row's logical columns
     ``j <= cols[b, t]``, one block of pages an iteration. A block is
@@ -434,11 +465,22 @@ def _walk_pages(qh, k_pages, v_pages, block_table, cols, rep):
     table = jnp.pad(block_table, ((0, 0), (0, -maxp % bp)),
                     constant_values=sink)
     last = jnp.minimum(cols, L - 1)               # deepest column a query sees
-    active = block_table[:, 0] != sink
+    if window is None:
+        active = block_table[:, 0] != sink
+        first, low = 0, None
+    else:
+        # the pages behind a window are given back, column 0 among them
+        active = jnp.take_along_axis(
+            block_table, jnp.minimum(cols[:, :1] // ps, maxp - 1),
+            axis=1)[:, 0] != sink
+        low = cols - (window - 1)                 # earliest column it sees
+        first = jnp.maximum(
+            jnp.min(jnp.where(active, low[:, 0], L)), 0) // block
     live = jnp.max(jnp.where(active, last[:, -1], 0)) + 1
     n = (live + block - 1) // block
     if walk_form(H, T) == "lanes":
-        return _walk_lanes(qh, k_pages, v_pages, table, bp, last, n, rep)
+        return _walk_lanes(qh, k_pages, v_pages, table, bp, last, n, rep,
+                           first, low)
     q = qh.reshape(B, G, rep, T, hd).astype(jnp.float32) / math.sqrt(hd)
 
     def step(i, carry):
@@ -448,23 +490,25 @@ def _walk_pages(qh, k_pages, v_pages, block_table, cols, rep):
         vb = v_pages[pages].reshape(B, block, G, hd).astype(jnp.float32)
         col = i * block + jnp.arange(block, dtype=jnp.int32)
         mask = col[None, None, :] <= last[:, :, None]              # [B,T,blk]
+        if low is not None:
+            mask = mask & (col[None, None, :] >= low[:, :, None])
         s = jnp.einsum("bgrtd,bjgd->bgrtj", q, kb)
         s = jnp.where(mask[:, None, None], s, -jnp.inf)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        scale = jnp.exp(m - m_new)
-        l = l * scale + p.sum(axis=-1)
+        m_new, l, p, scale = _softmax_step(m, l, s, low is not None)
         acc = acc * scale[..., None] + jnp.einsum("bgrtj,bjgd->bgrtd", p, vb)
         return m_new, l, acc
 
     m0 = jnp.full((B, G, rep, T), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((B, G, rep, T), jnp.float32)
     acc0 = jnp.zeros((B, G, rep, T, hd), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(0, n, step, (m0, l0, acc0))
+    _, l, acc = jax.lax.fori_loop(first, n, step, (m0, l0, acc0))
+    if low is not None:
+        l = jnp.where(l == 0.0, 1.0, l)           # a row that saw no key
     return (acc / l[..., None]).reshape(B, H, T, hd).astype(qh.dtype)
 
 
-def _walk_lanes(qh, k_pages, v_pages, table, bp, last, n, rep):
+def _walk_lanes(qh, k_pages, v_pages, table, bp, last, n, rep, first=0,
+                low=None):
     """The walk of :func:`_walk_pages` for few query columns: ``n`` trips
     over ``table`` (padded to whole blocks of ``bp`` pages), query row t of
     batch row b seeing the columns up to ``last[b, t]``.
@@ -487,6 +531,8 @@ def _walk_lanes(qh, k_pages, v_pages, table, bp, last, n, rep):
     # (it is G times qh's size, and what inflates is not hoisted)
     q = jax.lax.optimization_barrier(q)
     last = jnp.broadcast_to(last[:, None], (B, H, T)).reshape(B, N)
+    if low is not None:
+        low = jnp.broadcast_to(low[:, None], (B, H, T)).reshape(B, N)
 
     def step(i, carry):
         m, l, acc = carry
@@ -495,13 +541,12 @@ def _walk_lanes(qh, k_pages, v_pages, table, bp, last, n, rep):
         vb = v_pages[pages].reshape(B, block, G * hd)
         col = i * block + jnp.arange(block, dtype=jnp.int32)
         mask = col[None, None, :] <= last[:, :, None]              # [B,N,blk]
+        if low is not None:
+            mask = mask & (col[None, None, :] >= low[:, :, None])
         s = jnp.einsum("bjc,bcn->bnj", kb, q,
                        preferred_element_type=jnp.float32) / math.sqrt(hd)
         s = jnp.where(mask, s, -jnp.inf)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        scale = jnp.exp(m - m_new)
-        l = l * scale + p.sum(axis=-1)
+        m_new, l, p, scale = _softmax_step(m, l, s, low is not None)
         acc = acc * scale[..., None] + jnp.einsum(
             "bnj,bjc->bnc", p.astype(vb.dtype), vb,
             preferred_element_type=jnp.float32)
@@ -510,7 +555,9 @@ def _walk_lanes(qh, k_pages, v_pages, table, bp, last, n, rep):
     m0 = jnp.full((B, N), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((B, N), jnp.float32)
     acc0 = jnp.zeros((B, N, G * hd), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(0, n, step, (m0, l0, acc0))
+    _, l, acc = jax.lax.fori_loop(first, n, step, (m0, l0, acc0))
+    if low is not None:
+        l = jnp.where(l == 0.0, 1.0, l)           # a row that saw no key
     out = jnp.einsum("bgngd->bgnd", acc.reshape(B, G, rep * T, G, hd))
     out = out / l.reshape(B, G, rep * T, 1)
     return out.reshape(B, H, T, hd).astype(qh.dtype)

@@ -120,7 +120,8 @@ from ..ndarray import NDArray
 from ..parallel.functional import functionalize
 from . import grammar as _grammar
 from .bucketing import bucket_for, bucket_ladder
-from .paging import FlatPages, OutOfPages, PagePool, pages_for, prefix_key
+from .paging import (FlatPages, OutOfPages, PagePool, WindowedPages,
+                     pages_for, prefix_key)
 
 __all__ = ["InferenceEngine", "RequestHandle", "ServeResult",
            "QueueFullError", "EngineClosedError",
@@ -289,6 +290,9 @@ class _PendingStep:
     # step's token — the lookahead feedback twin of ``nxt`` (the host
     # ledger stays authoritative; it re-advances at the read)
     gstate: Any = None
+    # a model that counts its experts' tokens: device [layers, held] int32,
+    # read where ``nxt`` is
+    counts: Any = None
 
 
 def _jit_named(fn, name: str, donate_argnums=()):
@@ -417,6 +421,48 @@ class InferenceEngine:
     page stands for a window of 2,048; ``speculate`` and ``multi_token > 1``:
     a position written past the accepted or the finished one may have ended
     a window, and a fold cannot be taken back.
+
+    **Pools of two kinds, and experts that count.** A model that mixes
+    layers which read every position with layers which read only the last
+    ``window`` says so with one method more than the paged protocol:
+
+    - ``cache_window()`` returns the window. The full layers' pools keep
+      the page ids, the table and the ledger described above; the windowed
+      layers' pools are a *kind* of their own, with their own page ids, a
+      second :class:`~mxnet_tpu.serve.paging.PagePool` (``layout`` a
+      ``WindowedPages``) and a second table. ``cache_spec_paged`` takes
+      ``(full, windowed)`` page counts, ``cache_kinds()`` says which kind
+      each pool is of, and ``forward_cached_paged(ids, pos, block_table,
+      valid, *pools)`` takes both tables as ``[B, 2, max_pages]`` and, as a
+      folding model does, how many of each row's ``T`` positions are real;
+    - before each dispatch the host gives back every page of the windowed
+      kind that lies wholly behind the row's window (``PagePool.slide``) and
+      leases both kinds at the back, so a request at any depth holds at most
+      ``ceil((window + prefill_chunk) / page_size) + 1`` windowed pages.
+      Need, ``OutOfPages``, preemption and release count both kinds; the
+      second pool holds that bound for every slot, so it is the full kind
+      that runs out first;
+    - a model whose layers route tokens to experts it holds a share of has
+      ``expert_counts() -> (layers, held, experts a token)``, and its
+      ``forward_cached_paged``
+      returns one more small int32 array behind the pools: the tokens each
+      held expert received. The step, chunk and prefill programs hand it on,
+      and the engine reads it where it reads the sampled tokens (no wait of
+      its own): a step's go onto its ``mx.serve.emit`` span (``moe_hit`` of
+      ``moe_held`` experts that received a token, ``moe_here`` of
+      ``moe_routed`` assignments), every program's into ``stats()``.
+
+    ``stats()`` gains ``window_pages_held`` / ``window_pages_unwindowed``
+    (what the dispatched rows held of the windowed kind, of what their
+    depths would hold unwindowed; ``wheld`` / ``wdepth_pages`` on the two
+    dispatch spans, and ``wwalk``: blocks a windowed layer's read visits,
+    beside ``walk``), ``window_pages_recycled``, ``moe_assignments``,
+    ``moe_assignments_here`` and ``moe_expert_tokens_max``. **Refused with a
+    windowed kind, each with an ``MXNetError`` that says why**:
+    ``prefix_cache=True``, COW ``copy`` and page migration (no request keeps
+    the pages of its prefix), ``speculate`` and ``multi_token > 1`` (a
+    position written past the accepted one may lie where the host has
+    already given the page behind the window to another request).
 
     Parameters
     ----------
@@ -652,6 +698,7 @@ class InferenceEngine:
         # slot: class docstring
         self._stateful = hasattr(model, "cache_spec_state")
         if not (self._stateful or hasattr(model, "cache_fold")
+                or hasattr(model, "cache_window")
                 or _gen._can_cache(model)):
             raise MXNetError(
                 "InferenceEngine requires the KV-cache decode protocol "
@@ -772,15 +819,55 @@ class InferenceEngine:
                       if hasattr(model, "cache_fold") else None)
         if self._fold is not None:
             self._refuse_with_fold(prefix_cache, speculate, multi_token)
+        # a model whose pools are of two kinds says so with cache_window
+        # (class docstring); like a folding one it is told how many of a
+        # row's positions are real
+        self._window = (int(model.cache_window())
+                        if hasattr(model, "cache_window") else None)
+        self._takes_valid = not (self._fold is None and self._window is None)
+        if self._window is not None:
+            self._refuse_with_window(prefix_cache, speculate, multi_token)
         #: a request is nothing but its pages, a page nothing but its
         #: positions' rows: what page sharing and migration rest on
-        self._pages_portable = not (self._stateful or self._fold)
+        self._pages_portable = not (self._stateful or self._fold
+                                    or self._window)
         layout = self._fold or FlatPages(self.page_size)
         if num_pages is None:
             num_pages = self.S * layout.peak(self.L)
         self._pages = PagePool(num_pages, self.page_size, self.L, self.S,
                                prefix_cache=prefix_cache, layout=layout)
         self.maxp = self._pages.max_pages
+        if prefill_chunk is None:
+            prefill_chunk = self.page_size
+        self._chunk = min(int(prefill_chunk), self.L)
+        if self._chunk < 1:
+            raise MXNetError("prefill_chunk must be >= 1")
+        # the windowed kind's ledger: its own page ids and table, a slot's
+        # pages dereferenced behind its window and leased in front
+        self._wpages: Optional[PagePool] = None
+        if self._window is not None:
+            wlayout = WindowedPages(self.page_size, self._window, self._chunk)
+            # the most one request holds, for every slot
+            self._wpages = PagePool(self.S * wlayout.need(self.L),
+                                    self.page_size, self.L, self.S,
+                                    prefix_cache=False, layout=wlayout)
+        # what the dispatched rows held of the windowed kind, of what their
+        # depths would hold unwindowed, and the blocks a windowed layer's
+        # read visited (stats(): window_pages_held / _unwindowed,
+        # window_walk_blocks)
+        self._wheld = 0
+        self._wdepth = 0
+        self._wwalked = 0
+        # a model that counts its experts' tokens (class docstring): the
+        # sums of stats(), and the counts of chunks not read yet
+        self._moe = (tuple(model.expert_counts())
+                     if hasattr(model, "expert_counts") else None)
+        # assignments a token makes over all layers
+        self._moe_a_token = self._moe[0] * self._moe[2] if self._moe else 0
+        self._moe_routed = 0
+        self._moe_tokens = (onp.zeros(self._moe[:2], onp.int64)
+                            if self._moe else None)
+        self._moe_unread: List[Any] = []
         # the paged read walks the table a block at a time, as far as
         # the deepest row reaches: what a dispatch walks and what the
         # table holds are summed here (stats(): kv_walk_blocks /
@@ -820,7 +907,9 @@ class InferenceEngine:
         # unleased block-table entries point at it, so pad/empty-row
         # writes land harmlessly and masked reads of unleased
         # territory contribute exact zeros
-        pool_spec = model.cache_spec_paged(num_pages + 1, self.page_size)
+        pool_spec = model.cache_spec_paged(
+            num_pages + 1 if self._wpages is None
+            else (num_pages + 1, self._wpages.num_pages + 1), self.page_size)
         # per-slot recurrent state, a second kind of pool behind the
         # page pools: indexed by slot, no page axis, one slot more than
         # the engine serves (the sink, for rows that serve no request)
@@ -839,13 +928,8 @@ class InferenceEngine:
         self._sel_live = 0
         self._tok_bytes = sum(
             int(onp.prod(s)) * onp.dtype(d).itemsize
-            // ((num_pages + 1) * self.page_size)
-            for s, d in pool_spec)
-        if prefill_chunk is None:
-            prefill_chunk = self.page_size
-        self._chunk = min(int(prefill_chunk), self.L)
-        if self._chunk < 1:
-            raise MXNetError("prefill_chunk must be >= 1")
+            // (s[ax] * self.page_size)
+            for (s, d), ax in zip(pool_spec, self._paxes))
         if self._fold is not None and self._fold.window % self._chunk:
             raise MXNetError(
                 f"prefill_chunk ({self._chunk}) must divide the model's "
@@ -1376,8 +1460,39 @@ class InferenceEngine:
                 "the device loop writes past a finished row's budget and "
                 "would end windows the host has leased no summary page for")
 
+    @staticmethod
+    def _refuse_with_window(prefix_cache, speculate, multi_token):
+        """What a model with a windowed kind of pool cannot be served with,
+        each with its reason (class docstring)."""
+        if prefix_cache:
+            raise MXNetError(
+                "prefix_cache=True cannot serve a model with a windowed "
+                "kind of pool: the pages behind a request's sliding window "
+                "are given back while it lives, so no request keeps the "
+                "pages of its prefix for another to map (and a COW copy "
+                "would have to follow two tables); pass prefix_cache=False")
+        if speculate:
+            raise MXNetError(
+                "speculate cannot serve a model with a windowed kind of "
+                "pool: the pages behind the window are given back by the "
+                "position the host counts, and a rejected draft would have "
+                "moved that position past pages it still needs")
+        if multi_token > 1:
+            raise MXNetError(
+                "multi_token > 1 cannot serve a model with a windowed kind "
+                "of pool: the device loop advances positions the host has "
+                "not slid the window for, and writes past a finished row's "
+                "budget into pages it no longer holds")
+
     # ------------------------------------------------- page migration
     def _refuse_migration(self):
+        if self._window is not None:
+            raise MXNetError(
+                "page migration (copy / extract / inject) cannot move a "
+                "request of a model with a windowed kind of pool: a request "
+                "holds pages of two kinds under two tables, those behind "
+                "its window already given back, and a shipped page is "
+                "verified as one page of one table")
         if self._stateful:
             raise MXNetError(
                 "page migration (copy / extract / inject) cannot move a "
@@ -1646,8 +1761,9 @@ class InferenceEngine:
         them, so from here on these are the engine's."""
         out = fn(*self._example_args(label, bucket))
         if label in self._POOL_WRITERS:
-            self._pools = (out if label in ("chunk", "copy", "inject")
-                           else out[-1])
+            whole = ("copy", "inject") if self._moe else \
+                ("chunk", "copy", "inject")
+            self._pools = out if label in whole else out[-1]
         jax.block_until_ready(out)
 
     def _example_args(self, label: str, bucket: int):
@@ -1673,8 +1789,7 @@ class InferenceEngine:
         if label == "score":
             return (self._values, onp.zeros((1, bucket), onp.int32),
                     onp.int32(2))
-        sink_tbl = lambda rows: onp.full(       # noqa: E731
-            (rows, self.maxp), self._pages.sink, onp.int32)
+        sink_tbl = lambda rows: self._tables([], rows)   # noqa: E731
         if label == "spec":
             return (self._values, self._pools,
                     onp.zeros((bucket, self.spec), onp.int32),
@@ -1872,7 +1987,8 @@ class InferenceEngine:
         counter ``counter0`` so preempted requests resume mid-stream)."""
         fm = self._fm
         grammar = self._grammar
-        stateful, folding = self._stateful, self._fold is not None
+        stateful, counted = self._stateful, self._takes_valid
+        moe = self._moe is not None
 
         def prefill(values, pools, ids, true_len, start, table, *rest):
             rows = None
@@ -1880,8 +1996,8 @@ class InferenceEngine:
                 # the row's slot, and how many of the bucket's positions
                 # are the prompt's: padding must not move a state
                 rows, rest = (rest[0], jnp.reshape(true_len, (1,))), rest[1:]
-            elif folding:
-                # ... nor end a window
+            elif counted:
+                # ... nor end a window, nor be routed to an expert
                 rows = (jnp.reshape(true_len, (1,)),)
             if grammar:
                 (gcls, gnxt, gacc, gstate, geos,
@@ -1898,6 +2014,8 @@ class InferenceEngine:
                     if grammar else None)
             tok0 = _gen.sample_tokens(last, keys, temps, topks, topps,
                                       mask=mask)
+            if moe:
+                return tok0[0], new_pools[-1], new_pools[:-1]
             return tok0[0], new_pools
 
         return _jit_named(prefill, f"prefill_b{pb}", donate_argnums=1)
@@ -1906,14 +2024,16 @@ class InferenceEngine:
         """A middle prefill chunk: KV-page writes only (XLA dead-code-
         eliminates the LM head — the chunk's logits are never used)."""
         fm = self._fm
-        folding = self._fold is not None
+        counted, moe = self._takes_valid, self._moe is not None
 
         def chunk(values, pools, ids, start, table, *slot):
             real = (jnp.full(1, cs, jnp.int32),)
-            rows = slot + real if slot else real if folding else None
+            rows = slot + real if slot else real if counted else None
             _logits, new_pools = _gen.decode_step(fm, values, ids, start,
                                                   pools, block_table=table,
                                                   rows=rows)
+            if moe:
+                return new_pools[-1], new_pools[:-1]
             return new_pools
 
         return _jit_named(chunk, f"chunk_c{cs}", donate_argnums=1)
@@ -1938,13 +2058,14 @@ class InferenceEngine:
             return _jit_named(step, f"step_b{sb}", donate_argnums=1)
 
         grammar = self._grammar
-        stateful, folding = self._stateful, self._fold is not None
+        stateful, counted = self._stateful, self._takes_valid
+        moe = self._moe is not None
 
         def step(values, pools, tokens, pos, tables, *rest):
             rows = None
             if stateful:
                 rows, rest = (rest[0], jnp.ones(sb, jnp.int32)), rest[1:]
-            elif folding:
+            elif counted:
                 rows = (jnp.ones(sb, jnp.int32),)
             if grammar:
                 (gcls, gnxt, gacc, gstate, geos,
@@ -1963,10 +2084,14 @@ class InferenceEngine:
                     if grammar else None)
             nxt = _gen.sample_tokens(logits[:, -1], keys, temps, topks,
                                      topps, mask=mask)
+            if moe:
+                new_pools, counts = new_pools[:-1], new_pools[-1]
             if grammar:
                 ngs = _grammar.grammar_advance(gcls, gnxt, gstate, nxt,
                                                geos)
                 return nxt, ngs, new_pools
+            if moe:
+                return nxt, counts, new_pools
             return nxt, new_pools
 
         return _jit_named(step, f"step_b{sb}", donate_argnums=1)
@@ -2123,6 +2248,15 @@ class InferenceEngine:
         span.set(walk=walk, of=of)
         self._kv_walked += walk
         self._kv_tabled += of
+        if self._window is not None:
+            # a windowed layer's read starts at the block of the earliest
+            # column any of the rows' first queries still sees
+            wwalk = sum(
+                -(-min(max(p + j + T for p in positions), self.L) // blk)
+                - max(min(p + j for p in positions) - self._window + 1, 0)
+                // blk for j in range(substeps))
+            span.set(wwalk=wwalk)
+            self._wwalked += wwalk
         if self._walk_form is not None:
             form = self._walk_form(T)
             span.set(form=form)
@@ -2152,6 +2286,61 @@ class InferenceEngine:
         self._held += held
         self._unfolded += unfolded
         self._tick_folded += folded
+
+    def _note_window(self, span: _profiler.scope, rows):
+        """After the dispatch of ``rows``, ``(slot, depth it brought the
+        slot to)``: tell the span what their tables held of the windowed
+        kind when it ran (``wheld``) of the pages their depths would hold
+        unwindowed (``wdepth_pages``), and add both to the sums of
+        ``stats()``. Nothing, for a model without such a kind."""
+        if self._wpages is None:
+            return
+        held = sum(self._wpages.held(s) for s, _ in rows)
+        depth = sum(pages_for(d, self.page_size) for _, d in rows)
+        span.set(wheld=held, wdepth_pages=depth)
+        self._wheld += held
+        self._wdepth += depth
+
+    def _note_experts(self, counts, routed: int,
+                      span: Optional[_profiler.scope] = None):
+        """Add one program's expert counts (``[layers, held]`` tokens
+        received, read from the device) and its ``routed`` assignments to
+        the sums of ``stats()``; a step's go onto its ``emit`` span too:
+        ``moe_hit`` of ``moe_held`` held experts received a token, ``moe_here``
+        of ``moe_routed`` assignments went to a held expert."""
+        counts = onp.asarray(counts, onp.int64)
+        here = int(counts.sum())
+        self._moe_tokens += counts
+        self._moe_routed += routed
+        _metrics.SERVE_MOE_ASSIGNMENTS.labels(where="all").inc(routed)
+        _metrics.SERVE_MOE_ASSIGNMENTS.labels(where="here").inc(here)
+        if span is not None:
+            span.set(moe_hit=int((counts > 0).sum()), moe_held=counts.size,
+                     moe_here=here, moe_routed=routed)
+
+    def _read_chunk_experts(self):
+        """The counts of the chunks and prefills dispatched before the
+        tokens just read, in their order: what the device has finished is
+        read, and nothing is waited for (a chunk dispatched behind the step
+        just read is still running, and is read with a later step)."""
+        while self._moe_unread and self._moe_unread[0][0].is_ready():
+            self._note_experts(*self._moe_unread.pop(0))
+
+    def _lease(self, s: int, start: int, end: int):
+        """Lease what the dispatch that writes slot ``s``'s positions
+        ``[start, end)`` needs, of every kind: the full kind to ``end``; the
+        windowed kind slid to ``start`` first (what lies wholly behind that
+        position's window goes back to the pool) and then leased to ``end``.
+        Raises :class:`OutOfPages` from either pool."""
+        self._pages.lease(s, end)
+        if self._wpages is not None:
+            self._wpages.slide(s, start)
+            self._wpages.lease(s, end)
+
+    def _release(self, s: int):
+        self._pages.release(s)
+        if self._wpages is not None:
+            self._wpages.release(s)
 
     def _note_sample(self, span: _profiler.scope, rows):
         """Tell a dispatch span how many of the ``(temperature, top_k,
@@ -2325,6 +2514,9 @@ class InferenceEngine:
         resume = getattr(req, "_resume", None) or ()
         tokens = min(len(req.prompt_ids) + len(resume) + self._adv, self.L)
         need = self._pages.layout.peak(tokens)
+        if self._wpages is not None and \
+                self._wpages.free_pages() < self._wpages.layout.need(tokens):
+            return False
         return (self._pages.free_pages()
                 + self._pages.cached_pages()) >= need
 
@@ -2416,7 +2608,11 @@ class InferenceEngine:
         return n
 
     def _table_row(self, s: int) -> onp.ndarray:
-        """[1, max_pages] snapshot of the slot's block table."""
+        """[1, max_pages] snapshot of the slot's block table ([1, 2,
+        max_pages] with a windowed kind: the full kind's and its own)."""
+        if self._wpages is not None:
+            return onp.stack([self._pages.table(s),
+                              self._wpages.table(s)])[None]
         return self._pages.table(s)[None, :].copy()
 
     def _prefill_step(self, s: int, span: _profiler.scope):
@@ -2440,7 +2636,7 @@ class InferenceEngine:
         end = min(pf.cursor + self._chunk, P)
         span.set(start=pf.cursor, end=end, final=end == P)
         try:
-            self._pages.lease(s, end)
+            self._lease(s, pf.cursor, end)
             # the fork can ALSO exhaust the pool (lease satisfied from
             # already-held pages, but a shared prefix tail needs a fresh
             # page to fork into) — same yield-and-requeue path
@@ -2461,8 +2657,13 @@ class InferenceEngine:
                 pools = fn(self._values, self._pools, ids,
                            onp.int32(pf.cursor), self._table_row(s),
                            *self._slot_rows([s]))
+                if self._moe:
+                    counts, pools = pools
+                    self._moe_unread.append(
+                        (counts, (end - pf.cursor) * self._moe_a_token))
                 self._pools = pools
                 self._fold_ended(span, [(s, end)])
+                self._note_window(span, [(s, end)])
                 if req._span_prefill is not None:
                     ch = req._span_prefill.child(
                         "serve.prefill_chunk", t0=t0w,
@@ -2490,7 +2691,7 @@ class InferenceEngine:
                          self._gstate[s:s + 1].copy(),
                          onp.array([-1 if req.eos_token_id is None
                                     else req.eos_token_id], onp.int32))
-            tok0, pools = fn(
+            tok0, *counts, pools = fn(
                 self._values, self._pools, ids, onp.int32(rest),
                 onp.int32(pf.cursor), self._table_row(s),
                 *self._slot_rows([s]), *gargs,
@@ -2500,7 +2701,11 @@ class InferenceEngine:
                 onp.array([req.seed & 0xFFFFFFFF], onp.uint32),
                 onp.array([pf.counter0], onp.int32))
             self._pools = pools
+            if counts:
+                self._moe_unread.append(
+                    (counts[0], rest * self._moe_a_token))
             self._fold_ended(span, [(s, P)])
+            self._note_window(span, [(s, P)])
             if req._span_prefill is not None:
                 ch = req._span_prefill.child(
                     "serve.prefill_chunk", t0=t0w, start=pf.cursor, end=P,
@@ -2541,6 +2746,7 @@ class InferenceEngine:
             self._retire(s, STATUS_ERROR, error=str(e))
             return
         now = sync.t1
+        self._read_chunk_experts()
         _metrics.SERVE_ROUNDTRIPS.labels(path="prefill").inc()
         _metrics.SERVE_PREFILL_SECONDS.observe(now - pf.t0)
         if _metrics.ENABLED:
@@ -2603,7 +2809,7 @@ class InferenceEngine:
         self._slots[s] = None
         self._active[s] = False
         self._prefills.pop(s, None)
-        self._pages.release(s)
+        self._release(s)
         self._reset_slot_state(s)
         self._preempted += 1
         _metrics.SERVE_PAGE_PREEMPTIONS.inc()
@@ -2663,7 +2869,7 @@ class InferenceEngine:
                     if self._active[s]:
                         p = int(self._pos[s])
                         self._fork_range(s, p, p + self._adv)
-                        self._pages.lease(s, min(p + self._adv, self.L))
+                        self._lease(s, p, min(p + self._adv, self.L))
                 return preempted
             except OutOfPages:
                 # youngest by ORIGINAL admission time (req.admit_t survives
@@ -2726,6 +2932,8 @@ class InferenceEngine:
                 # the host clocks already stand behind the dispatched step
                 self._fold_ended(disp, [(s, int(self._pos[s]))
                                         for s, _ in cur])
+                self._note_window(disp, [(s, int(self._pos[s]))
+                                         for s, _ in cur])
         if rec is None:
             return
         if prev is not None:
@@ -2747,7 +2955,13 @@ class InferenceEngine:
         tables = onp.full((sb, self.maxp), self._pages.sink, onp.int32)
         for s, _ in cur:
             tables[s] = self._pages.table(s)
-        return tables
+        if self._wpages is None:
+            return tables
+        # with a windowed kind [sb, 2, max_pages]: each row's two tables
+        wtables = onp.full((sb, self.maxp), self._wpages.sink, onp.int32)
+        for s, _ in cur:
+            wtables[s] = self._wpages.table(s)
+        return onp.stack([tables, wtables], axis=1)
 
     def _dispatch_step(self, prev: Optional[_PendingStep],
                        cur: List[Tuple[int, "_Slot"]], sb: int
@@ -2775,7 +2989,7 @@ class InferenceEngine:
         slot_rows = self._slot_rows(
             [r if self._active[r] else self.S for r in range(sb)])
         try:
-            ngs = None
+            ngs, counts = None, []
             if self.K > 1:
                 toks, nxt, steps, pools = fn(
                     self._values, self._pools,
@@ -2799,7 +3013,7 @@ class InferenceEngine:
                     self._counters[:sb].copy())
             else:
                 toks = steps = None
-                nxt, pools = fn(
+                nxt, *counts, pools = fn(
                     self._values, self._pools,
                     tokens, self._pos[:sb].copy(), tables, *slot_rows,
                     self._temps[:sb].copy(), self._topks[:sb].copy(),
@@ -2816,7 +3030,8 @@ class InferenceEngine:
             self._pools_lost(e)
             return None
         rec = _PendingStep(nxt=nxt, sb=sb, t0=t0, toks=toks, steps=steps,
-                           gstate=ngs, slots=cur)
+                           gstate=ngs, slots=cur,
+                           counts=counts[0] if self._moe and counts else None)
         # the dispatched program owns its snapshot of this tick's
         # pos/counters; advance the host clocks now so the NEXT dispatch
         # — possibly before this one is read — sees post-step values.
@@ -2828,7 +3043,7 @@ class InferenceEngine:
             self._counters[s] += self.K
             self._remaining[s] -= self.K
         try:
-            for dev in (rec.toks, rec.steps, nxt):
+            for dev in (rec.toks, rec.steps, nxt, rec.counts):
                 if dev is not None:
                     dev.copy_to_host_async()   # start the D2H early
         except Exception:
@@ -3009,6 +3224,8 @@ class InferenceEngine:
                 else:
                     toks = onp.asarray(rec.nxt)[:, None]  # [sb, 1]
                     steps = 1
+                counts = (None if rec.counts is None
+                          else onp.asarray(rec.counts))
         except Exception as e:  # pragma: no cover - defensive
             warnings.warn(f"serve: decode step failed: {e!r}")
             for s, slot in rec.slots:
@@ -3016,6 +3233,12 @@ class InferenceEngine:
                     self._retire(s, STATUS_ERROR, error=str(e))
             return True
         with self._span("emit") as emit:
+            if counts is not None:
+                # the step's own counts onto the span; the chunks' that ran
+                # before it into the sums
+                self._read_chunk_experts()
+                self._note_experts(
+                    counts, len(rec.slots) * self._moe_a_token, emit)
             retired, appended = self._apply_step(rec, toks, steps, sync.t1)
             emit.set(tokens=appended, retired=int(retired))
         return retired
@@ -3178,7 +3401,7 @@ class InferenceEngine:
         self._active[s] = False
         self._prefills.pop(s, None)
         # shared pages survive under their prefix-cache/other-slot refs
-        self._pages.release(s)
+        self._release(s)
         self._reset_slot_state(s)
         req = slot.req
         now = time.perf_counter()
@@ -3308,6 +3531,22 @@ class InferenceEngine:
         out["pages_folded"] = pstats["pages_folded"]
         out["pages_held"] = self._held
         out["pages_unfolded"] = self._unfolded
+        if self._wpages is not None:
+            wstats = self._wpages.stats()
+            out["window_pages"] = wstats
+            out["window_pages_held"] = self._wheld
+            out["window_pages_unwindowed"] = self._wdepth
+            out["window_pages_recycled"] = wstats["pages_recycled"]
+            out["window_walk_blocks"] = self._wwalked
+            load = max(load, wstats["pages_in_use"] / wstats["pages"])
+        if self._moe is not None:
+            here = int(self._moe_tokens.sum())
+            out["moe_assignments"] = self._moe_routed
+            out["moe_assignments_here"] = here
+            # the busiest held expert's share of the assignments here
+            out["moe_expert_tokens_max"] = (
+                round(float(self._moe_tokens.max()) / here, 4)
+                if here else None)
         # bounded prefix-cache advert for the router's affinity
         # scoring: top-N chained-hash roots by refcount (the
         # serve_prefix_advert knob caps N; 0 disables the advert)

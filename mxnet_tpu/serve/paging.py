@@ -50,6 +50,22 @@ of a pool of fixed-size pages instead:
   (a prefix entry is keyed by the tokens of one page), so a folding pool
   has no prefix cache.
 
+- **A windowed kind.** A model that mixes layers which read every position
+  with layers which read only the last ``window`` (``cache_window``) has
+  pools of two *kinds*, each with page ids and a ledger of its own: the full
+  layers' :class:`PagePool` as above, and a second one for the windowed
+  layers whose ``layout`` is a :class:`WindowedPages`. Its table is indexed
+  by logical page like any other (the paged read and its writes need no
+  other arithmetic, and the read's blocks stay aligned at column 0: a ring
+  indexed modulo its length would need both changed, for a table of a few
+  hundred int32 a slot), but a slot's pages are *dereferenced at the front
+  and leased at the back*: before the dispatch whose first new position is
+  ``pos``, :meth:`PagePool.slide` gives back every page that lies wholly
+  behind that position's window (its entry points at the sink again), so a
+  request at any depth holds at most ``layout.held_bound`` pages of this
+  kind. Sharing is refused as for a folding pool: a page that one request
+  has slid past is another's to overwrite.
+
 Pure host bookkeeping (numpy + stdlib): device page copies/gathers live
 in the models' paged attention and the engine's executables.
 """
@@ -66,7 +82,8 @@ from .. import metrics as _metrics
 from ..analysis import guards as _guards
 from ..base import MXNetError
 
-__all__ = ["PagePool", "FlatPages", "OutOfPages", "pages_for", "prefix_key"]
+__all__ = ["PagePool", "FlatPages", "WindowedPages", "OutOfPages",
+           "pages_for", "prefix_key"]
 
 
 class OutOfPages(MXNetError):
@@ -95,6 +112,29 @@ class FlatPages:
         return pages_for(depth, self.page_size)
 
     peak = entries
+
+
+class WindowedPages(FlatPages):
+    """The arithmetic of a windowed kind's table (module docstring): an entry
+    for every ``page_size`` positions as :class:`FlatPages` has, of which a
+    slot keeps only those its next dispatch can still read. A dispatch of at
+    most ``chunk`` new positions from ``pos`` reads the columns ``pos -
+    window + 1 .. pos + chunk - 1``: ``held_bound`` pages at the most."""
+
+    def __init__(self, page_size: int, window: int, chunk: int):
+        super().__init__(page_size)
+        self.window = int(window)
+        self.held_bound = pages_for(self.window + int(chunk),
+                                    self.page_size) + 1
+
+    def first_live(self, pos: int) -> int:
+        """The first table entry that a dispatch whose first new position is
+        ``pos`` still reads."""
+        return max(int(pos) - self.window + 1, 0) // self.page_size
+
+    def need(self, tokens: int) -> int:
+        """Pages a request of ``tokens`` positions holds at the most."""
+        return min(self.entries(tokens), self.held_bound)
 
 
 def prefix_key(tokens: Sequence[int]) -> int:
@@ -155,13 +195,22 @@ class PagePool:
                 "a folding pool shares no prefix: a cached page is keyed by "
                 "the tokens of one page, and a folded page stands for a "
                 "whole window of them; pass prefix_cache=False")
+        windowed = isinstance(self.layout, WindowedPages)
+        if windowed and prefix_cache:
+            raise MXNetError(
+                "a windowed pool shares no prefix: the pages behind a "
+                "request's window are given back while it lives, so no "
+                "request keeps a prefix's pages; pass prefix_cache=False")
         self.page_size = int(page_size)
         self.max_pages = self.layout.peak(max_len)
-        if num_pages < self.max_pages:
+        # the most pages one request ever holds: the table's width, unless
+        # the kind is windowed
+        most = self.layout.need(max_len) if windowed else self.max_pages
+        if num_pages < most:
             raise MXNetError(
                 f"page pool ({num_pages} pages x {page_size}) cannot hold "
                 f"even one max_len ({max_len}) request, which holds up to "
-                f"{self.max_pages} pages")
+                f"{most} pages")
         self.num_pages = int(num_pages)
         self.slots = int(slots)
         self.sink = self.num_pages          # physical sink page index
@@ -171,6 +220,8 @@ class PagePool:
         self._tables = onp.full((self.slots, self.max_pages), self.sink,
                                 onp.int32)
         self._leased = onp.zeros(self.slots, onp.int32)   # entries per slot
+        # a windowed kind: entries before this one were given back (slide)
+        self._front = onp.zeros(self.slots, onp.int32)
         self.prefix_cache_enabled = bool(prefix_cache)
         # ledger mutations happen on the engine thread, but stats() (the
         # /healthz load signal) is read from HTTP handler threads — the
@@ -185,6 +236,7 @@ class PagePool:
         self.cow_forks = 0
         self.windows_folded = 0
         self.pages_folded = 0
+        self.pages_recycled = 0
         self.prefix_hits = 0
         self.prefix_misses = 0
         self.prefix_tokens_saved = 0
@@ -323,6 +375,32 @@ class PagePool:
             _metrics.SERVE_PAGE_FOLDS.inc(count)
             return len(self._free) - free0
 
+    def slide(self, slot: int, pos: int) -> int:
+        """A windowed kind, before the dispatch whose first new position is
+        ``pos``: dereference every entry of the slot's table that lies
+        wholly behind that position's window (``layout.first_live``) and
+        point it at the sink. The pool allocates nothing here. Returns the
+        pages that went back to the free list."""
+        with self._lock:
+            lo = int(self._front[slot])
+            hi = min(self.layout.first_live(pos), int(self._leased[slot]))
+            if hi <= lo:
+                return 0
+            row = self._tables[slot]
+            free0 = len(self._free)
+            for i in range(lo, hi):
+                self._decref(int(row[i]))
+            row[lo:hi] = self.sink
+            self._front[slot] = hi
+            n = len(self._free) - free0
+            self.pages_recycled += n
+            _metrics.SERVE_WINDOW_PAGES_RECYCLED.inc(n)
+            return n
+
+    def held(self, slot: int) -> int:
+        """Table entries the slot holds now (those slid past are not)."""
+        return int(self._leased[slot]) - int(self._front[slot])
+
     def release(self, slot: int):
         """Return every page the slot references (shared pages survive
         under their remaining refs)."""
@@ -334,6 +412,7 @@ class PagePool:
             self._decref(int(self._tables[slot, i]))
         self._tables[slot, :] = self.sink
         self._leased[slot] = 0
+        self._front[slot] = 0
 
     def release_all(self):
         with self._lock:
@@ -567,6 +646,7 @@ class PagePool:
                 "cow_forks": self.cow_forks,
                 "windows_folded": self.windows_folded,
                 "pages_folded": self.pages_folded,
+                "pages_recycled": self.pages_recycled,
                 "prefix_entries": sum(len(b)
                                       for b in self._prefix.values()),
                 "prefix_hits": self.prefix_hits,

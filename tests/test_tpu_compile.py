@@ -554,3 +554,70 @@ def test_evabyte_programs_fold_in_place(one_chip, evabyte_engine, program,
     assert not copies
     assert compiled.memory_analysis().alias_size_in_bytes \
         == 4 * 193 * 128 * 2048 * 2
+
+
+# ------- Command A+'s programs at the published widths (PR 36): experts that
+# ------- are told what they hold, pools of two kinds
+@pytest.fixture(scope="module")
+def cohere_engine():
+    """Two layers of Cohere2-MoE at the published widths (hidden 4,096, 128
+    query heads over 8 KV heads of 128, experts of width 4,096, 4 shared, a
+    router over 128, a window of 4,096), one sliding and one full, holding 2
+    of the 128 routed experts and a vocabulary of 2,048 rows, so that the CPU
+    holds 1.8 GB of weights and not 9.5; behind the long-document cell's
+    engine geometry, but for the full kind's pool. Made on the CPU: its builders
+    give the programs that the described chip compiles."""
+    from mxnet_tpu.models.cohere2_moe import (Cohere2MoEConfig,
+                                              Cohere2MoEForCausalLM)
+    from mxnet_tpu.serve import InferenceEngine
+    net = Cohere2MoEForCausalLM(Cohere2MoEConfig(
+        vocab_size=2048, num_layers=2,
+        layer_types=("sliding_attention", "full_attention"),
+        experts_held=(0, 2)))
+    net.initialize()
+    return InferenceEngine(net, max_batch_size=16, max_len=33280,
+                           page_size=128, num_pages=260, prefill_chunk=1024,
+                           min_prompt_bucket=512, prefix_cache=False)
+
+
+@pytest.mark.parametrize("program,bucket",
+                         [("decode", 16), ("chunk", 1024), ("prefill", 512)],
+                         ids=["step_b16", "chunk_c1024", "prefill_b512"])
+def test_cohere2_moe_programs_fit_and_copy_nothing(one_chip, cohere_engine,
+                                                   program, bucket):
+    """The decode step of 16 rows, a middle chunk of 1,024 positions and a
+    last chunk of 512, with the cell's own pools (4,160 pages of the full
+    kind, 656 of the windowed, and their sinks). The chip's compiler takes
+    them; the donated pools of both kinds come out as the buffers that went
+    in; no expert's matrices are copied to be multiplied (the held experts'
+    stacked weights are sliced where they lie, inside the loop whose trip
+    count follows the routing); and what the program needs beside its
+    arguments, added to the whole cut's 9.47 GB of weights and the cell's
+    pools, is inside the chip's 16 GiB. A CPU run cannot see any of it."""
+    eng = cohere_engine
+    assert eng._wpages.layout.held_bound == 41 and eng.maxp == 260
+    build = {"decode": eng._build_step, "chunk": eng._build_chunk,
+             "prefill": eng._build_prefill}[program]
+    args = list(_shapes(eng._example_args(program, bucket), one_chip))
+    pages = {0: 4161, 1: 657}
+    args[1] = tuple(_s(one_chip, (pages[kind], 128, 1024), jnp.bfloat16)
+                    for kind in eng.model.cache_kinds())
+    compiled = build(bucket).lower(*args).compile()
+    text = compiled.as_text()
+    for scope in ("mx.moe_route", "mx.moe_experts", "mx.moe_shared",
+                  "mx.kv_walk", "mx.kv_write"):
+        assert scope in text, scope
+    pools = 2 * (4161 + 657) * 128 * 1024 * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == pools
+    big = [line.strip()[:160] for line in text.splitlines()
+           if _opcode(line)[0] in ("copy", "copy-start", "copy-done")
+           and any(shape in _opcode(line)[1] for shape in (
+               "[4161,128,1024]", "[657,128,1024]", "[2,4096,4096]",
+               "[4096,4096]", "[16384,4096]", "[4096,16384]"))]
+    assert not big, big
+    # the whole cut: 4 layers, 16 held experts, 32,768 rows of vocabulary;
+    # pools: one full layer's and three windowed ones'
+    weights = 2 * 4_733_292_544
+    cell_pools = 2 * (4161 + 3 * 657) * 128 * 1024 * 2
+    assert weights + cell_pools + mem.temp_size_in_bytes < 0.95 * 16 * 2 ** 30

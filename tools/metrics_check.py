@@ -75,6 +75,8 @@ REQUIRED_PAGING_METRICS = (
     "mxnet_serve_page_leases_total",
     "mxnet_serve_page_cow_forks_total",
     "mxnet_serve_page_folds_total",
+    "mxnet_serve_window_pages_recycled_total",
+    "mxnet_serve_moe_assignments_total",
     "mxnet_serve_page_preemptions_total",
     "mxnet_serve_page_prefix_hits_total",
     "mxnet_serve_page_prefix_misses_total",
