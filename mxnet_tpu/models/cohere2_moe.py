@@ -438,7 +438,7 @@ class Cohere2MoEForCausalLM(HybridBlock):
         return (cfg.num_layers, cfg.experts_held[1], cfg.num_experts_per_tok)
 
     def walk_form(self, T: int) -> str:
-        return walk_form(self.cfg.num_heads, T)
+        return walk_form(self.cfg.num_heads, T, self.cfg.head_dim)
 
     def forward_cached_paged(self, input_ids, pos, block_table, valid,
                              *caches):

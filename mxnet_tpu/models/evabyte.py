@@ -335,7 +335,7 @@ class EvaByteForCausalLM(HybridBlock):
     def walk_form(self, T: int) -> str:
         """The form the shared walk takes over ``T`` new positions, a group
         of heads a call (``models/llama.walk_form``)."""
-        return walk_form(self.cfg.group_heads, T)
+        return walk_form(self.cfg.group_heads, T, self.cfg.head_dim)
 
     def forward_cached_paged(self, input_ids, pos, block_table, valid,
                              *caches):

@@ -168,7 +168,8 @@ class GPTModel(HybridBlock):
         """The form a layer's paged read takes over ``T`` new positions
         (``models/llama.walk_form``)."""
         from .llama import walk_form
-        return walk_form(self.cfg.num_heads, T)
+        return walk_form(self.cfg.num_heads, T,
+                         self.cfg.hidden_size // self.cfg.num_heads)
 
     def forward_cached(self, input_ids, pos, *caches):
         hidden, *new_caches = self.forward_cached_hidden(input_ids, pos,

@@ -29,8 +29,10 @@ from ..base import MXNetError
 from ..gluon import nn
 from ..gluon.block import HybridBlock
 from ..gluon.parameter import Parameter
+from ..device import on_tpu
 from ..ndarray import NDArray, asarray, invoke_jnp
 from ..ops.attention import flash_attention as _flash_attention
+from ..ops.paged_prefill import paged_prefill_attention
 
 __all__ = ["LlamaConfig", "LlamaForCausalLM", "LlamaModel", "LlamaStackedDecoder",
            "llama_shardings",
@@ -307,13 +309,17 @@ def kv_block(page_size: int, max_pages: int) -> int:
 WALK_LANES = 128
 
 
-def walk_form(heads: int, T: int) -> str:
-    """Which form the paged read of ``heads`` query heads over ``T`` new
-    positions takes (:func:`_walk_pages`): ``"lanes"`` for a decode step
-    and a verify of a few tokens, ``"heads"`` for a prefill chunk or
-    bucket. Fixed when a program is traced; the serving engine asks the
-    same (``form`` on its dispatch spans)."""
-    return "lanes" if heads * T <= WALK_LANES else "heads"
+def walk_form(heads: int, T: int, head_dim: int) -> str:
+    """Which form the paged read of ``heads`` query heads of ``head_dim``
+    over ``T`` new positions takes (:func:`_walk_pages`): ``"lanes"`` for a
+    decode step and a verify of a few tokens; for a prefill chunk or bucket
+    ``"kernel"`` on a TPU where a head is whole lane tiles of a pool's row
+    (``ops/paged_prefill.py``), else ``"heads"``. Fixed when a program is
+    traced; the serving engine asks the same (``form`` on its dispatch
+    spans)."""
+    if heads * T <= WALK_LANES:
+        return "lanes"
+    return "kernel" if on_tpu() and head_dim % 128 == 0 else "heads"
 
 
 # jitted by itself so that a program of many layers traces and lowers the
@@ -352,20 +358,23 @@ def _paged_attention(qh, kh, vh, k_pages, v_pages, block_table, pos, rep,
     lengthen the walk). Nothing of width ``max_len`` is gathered or
     converted: a step costs the live pages, not the table's.
 
-    What multiplies a block has two forms, chosen when the program is
+    What multiplies a block has three forms, chosen when the program is
     traced by :func:`walk_form` from the query columns it brings, ``heads x
-    T`` against ``WALK_LANES``. A decode step, and a verify of a few tokens,
-    multiply the block as the pool stores it, heads on the lanes
-    (:func:`_walk_lanes`): left to the head-split form, the chip converted
-    every block to float32 and relaid it out for the heads in every trip,
-    for a one-row product that stays on its vector unit, and that, not the
-    block's bytes, was what a trip cost (PERF.md section 6, PR 35). A
-    prefill chunk or bucket splits the block into its heads
-    (:func:`_walk_pages`), where the products are large and the compiler
-    feeds them the block as stored already. The scores are products of
-    ``q`` and ``k`` as stored, summed in float32; on the lanes the softmax
+    T`` against ``WALK_LANES``, and the head's width. A decode step, and a
+    verify of a few tokens, multiply the block as the pool stores it, heads
+    on the lanes (:func:`_walk_lanes`): left to the head-split form, the
+    chip converted every block to float32 and relaid it out for the heads in
+    every trip, for a one-row product that stays on its vector unit, and
+    that, not the block's bytes, was what a trip cost (PERF.md section 6, PR
+    35). A prefill chunk or bucket splits the block into its heads: on a TPU
+    where a head is whole lane tiles in one Pallas kernel
+    (``ops/paged_prefill.py``), whose running softmax stays in VMEM where
+    XLA's loop carried it through HBM in every trip, the size of the whole
+    chunk's queries (PERF.md section 6, PR 37); elsewhere in the XLA loop of
+    :func:`_walk_pages`. The scores are products of ``q`` and ``k`` as
+    stored, summed in float32; on the lanes and in the kernel the softmax
     weights enter the second product in the values' dtype, as they do in
-    the flash kernels and in what the chip makes of a chunk.
+    the flash kernels and in what the chip makes of the XLA loop.
 
     What is bitwise: a block that the mask hides entirely is an exact
     no-op on (max, sum, accumulator), and column 0 is valid for every row,
@@ -444,11 +453,18 @@ def _walk_pages(qh, k_pages, v_pages, block_table, cols, rep, window=None):
     b (at column ``cols[b, t]``) attends the row's logical columns
     ``j <= cols[b, t]``, one block of pages an iteration. A block is
     gathered as the pool lies, ``[B, pages, ps, G * hd]``; the pool itself
-    is only indexed. What multiplies the block has two forms, and
+    is only indexed. What multiplies the block has three forms, and
     :func:`walk_form` says which a program takes.
 
-    ``"heads"`` (a prefill chunk or bucket): the block is split into its
-    heads, ``[B, block, G, hd]``, and each head's queries meet its keys.
+    ``"heads"`` (a prefill chunk or bucket off a TPU, or whose head is not
+    whole lane tiles): the block is split into its heads, ``[B, block, G,
+    hd]``, and each head's queries meet its keys.
+
+    ``"kernel"`` (a prefill chunk or bucket on a TPU, ``hd % 128 == 0``):
+    the same, in one Pallas kernel (``ops/paged_prefill.py``) that reads
+    each head's lanes of a block page by page and keeps the running softmax
+    in VMEM; each query tile walks only the blocks it sees. ``cols`` is
+    ``pos + t`` there, as :func:`_paged_attention` makes it.
 
     ``"lanes"`` (:func:`_walk_lanes`: a decode step, a verify of a few
     tokens): the heads stay on the lanes and the query takes the block's
@@ -478,9 +494,14 @@ def _walk_pages(qh, k_pages, v_pages, block_table, cols, rep, window=None):
             jnp.min(jnp.where(active, low[:, 0], L)), 0) // block
     live = jnp.max(jnp.where(active, last[:, -1], 0)) + 1
     n = (live + block - 1) // block
-    if walk_form(H, T) == "lanes":
+    form = walk_form(H, T, hd)
+    if form == "lanes":
         return _walk_lanes(qh, k_pages, v_pages, table, bp, last, n, rep,
                            first, low)
+    if form == "kernel":
+        return paged_prefill_attention(qh, k_pages, v_pages, table,
+                                       cols[:, 0], L, active, block, rep,
+                                       window)
     q = qh.reshape(B, G, rep, T, hd).astype(jnp.float32) / math.sqrt(hd)
 
     def step(i, carry):
@@ -1042,7 +1063,7 @@ class LlamaForCausalLM(HybridBlock):
     def walk_form(self, T: int) -> str:
         """The form a layer's paged read takes over ``T`` new positions
         (:func:`walk_form`)."""
-        return walk_form(self.cfg.num_heads, T)
+        return walk_form(self.cfg.num_heads, T, self.cfg.hd)
 
     def forward_cached(self, input_ids, pos, *caches):
         h, *new_caches = self.model.forward_cached(input_ids, pos, *caches)

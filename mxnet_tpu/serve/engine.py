@@ -89,7 +89,8 @@ been donated since, so page exports and imports run at a tick boundary.
 A step reads only the blocks of pages that its deepest row has reached
 (``stats()``: ``kv_walk_blocks`` of ``kv_table_blocks``) and multiplies each
 as the pool stores it (``kv_walk_blocks_on_lanes``: the blocks of the
-dispatches whose program took that form, ``models/llama.walk_form``), and
+dispatches whose program took that form, ``models/llama.walk_form``); a
+chunk on a TPU reads them in one kernel (``kv_walk_blocks_in_kernel``); and
 searches for a top-k or nucleus threshold only where one of its rows asks
 for one (``sample_rows_filtered`` of ``sample_rows``).
 """
@@ -873,12 +874,14 @@ class InferenceEngine:
         # table holds are summed here (stats(): kv_walk_blocks /
         # kv_table_blocks, the share of a max_len read still done), and
         # of the walked, those a program multiplied as the pool stores them
-        # (kv_walk_blocks_on_lanes: the model's walk_form says which
-        # programs do, where its layers read through the shared walk)
+        # and those it read in one kernel (kv_walk_blocks_on_lanes /
+        # _in_kernel: the model's walk_form says which programs do, where
+        # its layers read through the shared walk)
         self._kv_block = _llama.kv_block(self.page_size, self.maxp)
         self._walk_form = getattr(model, "walk_form", None)
         self._kv_walked = 0
         self._kv_on_lanes = 0
+        self._kv_in_kernel = 0
         self._kv_tabled = 0
         # folding: pages the dispatched rows' tables held, of the pages
         # their depths would hold unfolded (stats(): pages_held /
@@ -2262,6 +2265,8 @@ class InferenceEngine:
             span.set(form=form)
             if form == "lanes":
                 self._kv_on_lanes += walk
+            elif form == "kernel":
+                self._kv_in_kernel += walk
 
     def _fold_ended(self, span: _profiler.scope, rows):
         """After the dispatch of ``rows``, ``(slot, depth it brought the
@@ -3521,6 +3526,7 @@ class InferenceEngine:
         out["preemptions"] = self._preempted
         out["kv_walk_blocks"] = self._kv_walked
         out["kv_walk_blocks_on_lanes"] = self._kv_on_lanes
+        out["kv_walk_blocks_in_kernel"] = self._kv_in_kernel
         out["kv_table_blocks"] = self._kv_tabled
         out["sample_rows"] = self._sample_rows
         out["sample_rows_filtered"] = self._sample_filtered

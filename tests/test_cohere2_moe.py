@@ -222,7 +222,7 @@ def test_paged_attention_under_a_window(T, form, depths):
     attention, over a table whose pages behind each row's window point at
     the sink as ``PagePool.slide`` leaves them."""
     H, G, hd, ps, maxp, window = 8, 2, 16, 16, 32, 100
-    assert llama.walk_form(H, T) == form
+    assert llama.walk_form(H, T, hd) == form
     assert llama.kv_block(ps, maxp) == 128 < 300
     rng = np.random.RandomState(T + len(depths))
     B, L = len(depths), ps * maxp

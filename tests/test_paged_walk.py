@@ -1,8 +1,10 @@
 """The read side of ``models/llama._paged_attention``: a loop over blocks of
 logical pages whose trip count is the deepest active row's, under a running
-float32 softmax, in two forms (``llama.walk_form``): few query columns meet
+float32 softmax, in three forms (``llama.walk_form``): few query columns meet
 each block as the pool stores it, heads on the lanes; a chunk's meet it split
-into heads.
+into heads, and on a TPU, where a head is whole lane tiles, in one Pallas
+kernel (``ops/paged_prefill.py``, held here in Pallas' TPU interpreter against
+the XLA form).
 
 Held against ``_attend`` over the gathered contiguous view (what the read
 was before the loop) with queries as served, in bfloat16, to a tolerance set
@@ -11,6 +13,8 @@ each other on one input; and the walk against itself bit for bit where the
 contract says so: a row alone and beside a deeper row, ``T = 1`` against
 column j of a ``T = K`` of the same form.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as onp
@@ -22,6 +26,7 @@ from mxnet_tpu.models import llama
 from mxnet_tpu.models.gpt import GPTConfig
 from mxnet_tpu.models.llama import (_attend, _paged_attention, _walk_pages,
                                     kv_block, walk_form)
+from mxnet_tpu.ops import paged_prefill
 from mxnet_tpu.serve import InferenceEngine
 
 G, HD = 3, 16
@@ -158,7 +163,7 @@ SERVED = {
 @pytest.mark.parametrize("name", list(SERVED))
 def test_served_geometries_in_the_form_they_take(name):
     g, hd, rep, T, form = SERVED[name]
-    assert walk_form(g * rep, T) == form
+    assert walk_form(g * rep, T, hd) == form
     held_to_the_reference(
         make(16, 16, [3, 126, 250], T, rep, seed=5, g=g, hd=hd), rep)
 
@@ -176,7 +181,7 @@ def test_the_two_forms_agree_on_one_input(name, monkeypatch):
     # a new function each, so that the second is traced anew
     lanes = jax.jit(lambda *a: _walk_pages(*a, rep))(q, kp, vp, table, cols)
     monkeypatch.setattr(llama, "WALK_LANES", 0)
-    assert walk_form(g * rep, T) == "heads"
+    assert walk_form(g * rep, T, hd) == "heads"
     heads = jax.jit(lambda *a: _walk_pages(*a, rep))(q, kp, vp, table, cols)
     assert not onp.array_equal(*f32(lanes, heads))
     assert (within(lanes, heads.astype(jnp.float32)) <= 1).all()
@@ -215,7 +220,8 @@ def test_decode_is_bitwise_column_j_of_verify(j, rep):
     rejected draft, and the causal mask hides them)."""
     K = 4
     q, k, v, kp, vp, table, pos = make(16, 16, [126, 40], K, rep)
-    assert walk_form(q.shape[1], K) == walk_form(q.shape[1], 1) == "lanes"
+    assert walk_form(q.shape[1], K, HD) == walk_form(q.shape[1], 1, HD) \
+        == "lanes"
     wide, kp, vp = paged(q, k, v, kp, vp, table, pos, rep)
     one, _, _ = paged(q[:, :, j:j + 1], k[:, :, j:j + 1], v[:, :, j:j + 1],
                       kp, vp, table, pos + j, rep)
@@ -327,3 +333,141 @@ def test_stats_sum_the_walk_and_the_table(engine, prompt, deep):
         - before["kv_walk_blocks_on_lanes"]
     assert on_lanes == walked - prefill_walk
     assert on_lanes == (table // 2 - prefills) * (2 if deep else 1)
+
+
+# ------------------------------- a chunk's read in one kernel (PR 37)
+def steer_to_the_kernel(mp):
+    """``walk_form`` answers as on a TPU, and the kernel runs in Pallas' TPU
+    interpreter; traces made before are dropped, so that ``_paged_attention``
+    is traced anew."""
+    mp.setattr(llama, "on_tpu", lambda: True)
+    mp.setattr(llama, "paged_prefill_attention", functools.partial(
+        paged_prefill.paged_prefill_attention, interpret=True))
+    jax.clear_caches()
+
+
+# (page size, pages a row, depths, T, kv heads, query heads a kv head,
+# window, inactive rows): the head width is 128 in all
+KERNEL = {
+    # every active row's window starts past block 0 (first > 0), and the
+    # tile's later rows see nothing of the first block it walks
+    "gqa-rep4-window-first-past-0": (16, 24, [230, 300], 64, 2, 4, 20, []),
+    "gqa-rep4-window-beside-a-shallow-row": (16, 24, [5, 300], 64, 2, 4,
+                                             100, []),
+    "mha-rep1-group-rows-of-two-depths": (16, 24, [100, 260], 48, 4, 1,
+                                          None, []),
+    "inactive-row-with-a-stale-pos": (16, 16, [20, 200], 48, 2, 2, None,
+                                      [1]),
+    "windowed-inactive-row": (16, 16, [150, 200], 48, 2, 2, 64, [0]),
+    "pad-columns-past-L": (16, 16, [240, 3], 40, 2, 2, None, []),
+    "table-no-multiple-of-the-block": (16, 12, [180, 60], 48, 2, 2, None,
+                                       []),
+    "windowed-past-L-table-no-multiple": (16, 12, [170, 20], 48, 2, 2, 50,
+                                          []),
+    # a window of two blocks: a block wholly inside it walks unmasked, the
+    # one its start cuts is masked
+    "window-of-two-blocks": (16, 40, [400, 330], 48, 2, 2, 200, []),
+}
+# a cell's rows and a trip's scores: as budgeted (one tile, trips as long as
+# the small table), and small (tiles of 16 positions, a block a trip)
+BUDGETS = {"as-budgeted": (paged_prefill.ROWS, paged_prefill.SCORES),
+           "tiles-of-16-a-block-a-trip": (64, 0)}
+
+
+@pytest.mark.parametrize("budget", list(BUDGETS))
+@pytest.mark.parametrize("name", list(KERNEL))
+def test_kernel_equals_the_heads_form(name, budget, monkeypatch):
+    """The kernel against the XLA heads form on one input: the outputs of
+    every active row to the roundings of the weights and the output, the
+    pools bit for bit (the write is the same code); an inactive row reads
+    zeros, where the XLA form reads whatever its stale ``pos`` reaches."""
+    ps, maxp, depths, T, g, rep, window, inactive = KERNEL[name]
+    q, k, v, kp, vp, table, pos = make(ps, maxp, depths, T, rep, seed=11,
+                                       g=g, hd=128)
+    table = table.at[jnp.asarray(inactive, jnp.int32)].set(kp.shape[0] - 1)
+    args = (q, k, v, kp, vp, table, pos)
+    attend = lambda *a: _paged_attention(*a, rep, window=window)  # noqa
+    assert walk_form(g * rep, T, 128) == "heads"
+    heads, kp_h, vp_h = jax.jit(attend)(*args)
+    steer_to_the_kernel(monkeypatch)
+    monkeypatch.setattr(paged_prefill, "ROWS", BUDGETS[budget][0])
+    monkeypatch.setattr(paged_prefill, "SCORES", BUDGETS[budget][1])
+    assert walk_form(g * rep, T, 128) == "kernel"
+    kern, kp_k, vp_k = jax.jit(attend)(*args)
+    jax.clear_caches()
+    assert kern.dtype == heads.dtype == jnp.bfloat16
+    kern, heads = (onp.asarray(a, onp.float32) for a in (kern, heads))
+    live = [b for b in range(len(depths)) if b not in inactive]
+    assert (within(kern[live], heads[live]) <= 1).all()
+    assert not kern[inactive].any()
+    assert bool((kp_k == kp_h).all()) and bool((vp_k == vp_h).all())
+
+
+@pytest.mark.parametrize("heads, T, hd, tpu, form", [
+    (32, 4, 128, True, "lanes"),            # heads x T = WALK_LANES
+    (32, 5, 128, True, "kernel"),           # the first past it
+    (32, 5, 64, True, "heads"),             # a head is half a lane tile
+    (32, 5, 128, False, "heads"),           # off the chip
+    (25, 128, 64, True, "heads"),           # GPT-2 XL's chunk
+    (16, 1024, 128, True, "kernel"),        # EvaByte's group's chunk
+    (128, 1, 128, True, "lanes"),           # Command A+'s decode step
+])
+def test_walk_form_three_answers(heads, T, hd, tpu, form, monkeypatch):
+    monkeypatch.setattr(llama, "on_tpu", lambda: tpu)
+    assert walk_form(heads, T, hd) == form
+
+
+@pytest.fixture(scope="module")
+def kernel_engine():
+    """Four heads of 128 over chunks and buckets of 64 positions (256 query
+    columns) take the kernel, a step (4) the lanes form; ``on_tpu`` steered
+    for the engine's life, the kernel in the interpreter."""
+    with pytest.MonkeyPatch.context() as mp:
+        steer_to_the_kernel(mp)
+        mx.random.seed(0)
+        net = GPTModel(GPTConfig(vocab_size=64, hidden_size=512,
+                                 num_layers=2, num_heads=4,
+                                 max_position_embeddings=256, dropout=0.0))
+        net.initialize()
+        eng = InferenceEngine(net, max_batch_size=2, max_len=256,
+                              page_size=16, prefill_chunk=64,
+                              min_prompt_bucket=64)
+        eng.start()
+        yield eng
+        eng.shutdown()
+        jax.clear_caches()
+
+
+class _Span:
+    def __init__(self):
+        self.attrs = {}
+
+    def set(self, **kw):
+        self.attrs.update(kw)
+
+
+@pytest.mark.parametrize("what", ["stats", "span-form"])
+def test_kernel_dispatches_are_counted(kernel_engine, what):
+    """A 150-token prompt: chunks of 64 and 64 and a bucket of 64 that ends
+    at 192 walk 1 + 1 + 2 blocks in the kernel, and its decode steps on the
+    lanes (``stats()``); a chunk's dispatch span carries ``form`` "kernel",
+    a step's "lanes"."""
+    eng = kernel_engine
+    if what == "span-form":
+        forms = []
+        for T in (64, 1):
+            span = _Span()
+            eng._note_walk(span, [0], T)
+            forms.append(span.attrs["form"])
+        assert forms == ["kernel", "lanes"]
+        return
+    before = eng.stats()
+    r = eng.submit(onp.arange(150) % 64, 6).result(300)
+    assert r.ok
+    after = eng.stats()
+    delta = {key: after[key] - before[key] for key in (
+        "kv_walk_blocks", "kv_walk_blocks_on_lanes",
+        "kv_walk_blocks_in_kernel")}
+    assert delta["kv_walk_blocks_in_kernel"] == 4
+    assert delta["kv_walk_blocks_on_lanes"] \
+        == delta["kv_walk_blocks"] - 4 > 0

@@ -328,18 +328,36 @@ def test_pools_are_written_in_place(one_chip, pool_engines, width, program,
 
 
 # ------ a decoding row's walk multiplies a block as the pool stores it (PR 35)
-# (rows, heads, head width, T, pool, pages a row): the conversation cell's
-# group of EvaByte heads at 8 rows, the chat cell's GPT-2 XL at 2, and the
-# conversation cell's chunk
+# ------ and a chunk's is one kernel where a head is whole lane tiles (PR 37)
+# (rows, heads, query heads a KV head, head width, T, pool, pages a row,
+# window): the conversation cell's group of EvaByte heads at 8 rows, the chat
+# cell's GPT-2 XL at 2, the conversation cell's chunk, the document cell's
+# windowed chunk (Command A+: 8 KV heads, rows of 1,024 lanes), the chat
+# cell's chunk
 _WALKS = {
-    "evabyte_step_b8": (8, 16, 128, 1, (193, 128, 2048), 32),
-    "xl_step_b2": (2, 25, 64, 1, (321, 16, 1600), 64),
-    "evabyte_chunk_c1024": (1, 16, 128, 1024, (193, 128, 2048), 32),
+    "evabyte_step_b8": (8, 16, 1, 128, 1, (193, 128, 2048), 32, None),
+    "xl_step_b2": (2, 25, 1, 64, 1, (321, 16, 1600), 64, None),
+    "evabyte_chunk_c1024": (1, 16, 1, 128, 1024, (193, 128, 2048), 32, None),
+    "commandaplus_chunk_c1024": (1, 128, 16, 128, 1024, (4161, 128, 1024),
+                                 260, 4096),
+    "xl_chunk_c128": (1, 25, 1, 64, 128, (321, 16, 1600), 64, None),
 }
-# sha1 over "opcode shape" of every instruction of the chunk's loop body, as
-# the compiler gave it before the decode walk changed; a change to the
-# head-split form (models/llama._walk_pages) shows here and has to be meant
-_CHUNK_BODY = "6eb49929274eec89c1470ae811bfc79cfac223a4"
+# sha1 over "opcode shape" of every instruction of the XL chunk's loop body,
+# as the compiler gave it at the parent of PR 37: a head of 64 lanes keeps the
+# head-split form, and a change to it (models/llama._walk_pages) shows here
+_XL_CHUNK_BODY = "f2d0947de9bf973ce19b20034deab4d5028dd653"
+
+
+@pytest.fixture
+def walk_on_tpu(monkeypatch):
+    """``models/llama.walk_form`` answers as on a TPU (it asks ``on_tpu()``,
+    which sees this process's CPU); traces made before are dropped, so that
+    the jitted ``_paged_attention`` is traced anew."""
+    from mxnet_tpu.models import llama
+    monkeypatch.setattr(llama, "on_tpu", lambda: True)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
 
 
 def _loop_body(text):
@@ -365,7 +383,7 @@ def _loop_body(text):
 
 
 @pytest.mark.parametrize("name", list(_WALKS))
-def test_decode_walk_multiplies_blocks_as_stored(one_chip, name):
+def test_walk_compiles_in_its_form(one_chip, walk_on_tpu, name):
     """With few query columns (a step's heads x 1) the loop of the paged
     read is two gathers of a block as the pool lies and two matrix products
     over them: no instruction of its body makes a float32 array of a block's
@@ -373,29 +391,42 @@ def test_decode_walk_multiplies_blocks_as_stored(one_chip, name):
     each, for K and again for V) and its ``reshape f32[2,128,25,64]`` (GPT-2
     XL) did in every trip. Bfloat16 operands alone do not do it: a one-row
     product stays on the vector unit, which has no bfloat16 (PERF.md section
-    6, PR 35). A chunk's walk keeps the head-split form, whose products
-    already take the blocks in bfloat16: its loop is what it was. A CPU run
-    cannot see any of this."""
+    6, PR 35). A chunk whose head is whole lane tiles is one Pallas kernel
+    (``ops/paged_prefill.py``): Mosaic takes its DMAs of a head's lanes and
+    its VMEM budget, and no XLA loop is left to carry the float32
+    accumulator of ``[G, rep, T, hd]`` through HBM (PERF.md section 6, PR
+    37). GPT-2 XL's chunk (heads of 64 lanes) keeps the head-split form,
+    whose loop is what it was. A CPU run cannot see any of this."""
     import hashlib
     import re
     from mxnet_tpu.models.llama import _paged_attention, walk_form
-    rows, heads, hd, T, pool, maxp = _WALKS[name]
+    rows, heads, rep, hd, T, pool, maxp, window = _WALKS[name]
     bf, i32 = jnp.bfloat16, jnp.int32
     x = _s(one_chip, (rows, heads, T, hd), bf)
+    kv = _s(one_chip, (rows, heads // rep, T, hd), bf)
     compiled = jax.jit(
         lambda q, k, v, kp, vp, bt, pos: _paged_attention(
-            q, k, v, kp, vp, bt, pos, 1), donate_argnums=(3, 4)).lower(
-        x, x, x, _s(one_chip, pool, bf), _s(one_chip, pool, bf),
+            q, k, v, kp, vp, bt, pos, rep, window=window),
+        donate_argnums=(3, 4)).lower(
+        x, kv, kv, _s(one_chip, pool, bf), _s(one_chip, pool, bf),
         _s(one_chip, (rows, maxp), i32), _s(one_chip, (rows,), i32)).compile()
     text = compiled.as_text()
+    form = walk_form(heads, T, hd)
+    if form == "kernel":
+        assert text.count("tpu_custom_call") == 1 and " while(" not in text
+        carry = rows * heads * T * hd
+        wide = [dims for dims in re.findall(r"f32\[([\d,]+)\]", text)
+                if math.prod(int(d) for d in dims.split(",")) >= carry]
+        assert not wide
+        return
     body = _loop_body(text)
     if T > 1:
-        assert walk_form(heads, T) == "heads"
+        assert form == "heads"
         assert hashlib.sha1("\n".join(
             f"{op} {shape}" for op, shape in body).encode()).hexdigest() \
-            == _CHUNK_BODY
+            == _XL_CHUNK_BODY
         return
-    assert walk_form(heads, T) == "lanes"
+    assert form == "lanes"
     block = rows * 128 * pool[2]
     gathered = f"bf16[{block // (pool[1] * pool[2])},{pool[1]},{pool[2]}]"
     assert sum(shape.startswith(gathered) for _, shape in body) == 2
@@ -515,8 +546,8 @@ def evabyte_engine():
 @pytest.mark.parametrize("program,bucket",
                          [("decode", 16), ("chunk", 2048), ("prefill", 256)],
                          ids=["step_b16", "chunk_c2048", "prefill_b256"])
-def test_evabyte_programs_fold_in_place(one_chip, evabyte_engine, program,
-                                        bucket):
+def test_evabyte_programs_fold_in_place(one_chip, evabyte_engine,
+                                        walk_on_tpu, program, bucket):
     """The decode step of 16 rows, a middle chunk of 2,048 positions (a whole
     window) and a last chunk of 256. Whether a dispatch ends a window is data (``pos +
     valid`` reaching a multiple of 2,048), so the chunk that ends one and the
@@ -540,8 +571,10 @@ def test_evabyte_programs_fold_in_place(one_chip, evabyte_engine, program,
     text = compiled.as_text()
     assert "mx.eva_summarize" in text
     # (a middle chunk's logits are not used, so with ONE layer its walk is
-    # dead code: only the writes and the fold are left of its attention)
+    # dead code: only the writes and the fold are left of its attention); a
+    # last chunk's walk is the kernel (PR 37)
     assert "mx.kv_walk" in text or program == "chunk"
+    assert ("tpu_custom_call" in text) == (program == "prefill")
     # 32 heads of 128 in two groups of 16: rows of 2,048 lanes. From rows of
     # 4,096 the compiler's gather of 16 rows' pages sliced the whole pool in
     # halves ("mini-gather-slice") in every trip of the walk (PR 34)
@@ -584,7 +617,8 @@ def cohere_engine():
                          [("decode", 16), ("chunk", 1024), ("prefill", 512)],
                          ids=["step_b16", "chunk_c1024", "prefill_b512"])
 def test_cohere2_moe_programs_fit_and_copy_nothing(one_chip, cohere_engine,
-                                                   program, bucket):
+                                                   walk_on_tpu, program,
+                                                   bucket):
     """The decode step of 16 rows, a middle chunk of 1,024 positions and a
     last chunk of 512, with the cell's own pools (4,160 pages of the full
     kind, 656 of the windowed, and their sinks). The chip's compiler takes
@@ -607,6 +641,11 @@ def test_cohere2_moe_programs_fit_and_copy_nothing(one_chip, cohere_engine,
     for scope in ("mx.moe_route", "mx.moe_experts", "mx.moe_shared",
                   "mx.kv_walk", "mx.kv_write"):
         assert scope in text, scope
+    # a chunk's and a prefill's walk is the kernel in each layer whose output
+    # is used (a middle chunk's last layer feeds no logits), a step's the
+    # lanes form (PR 37)
+    assert text.count("tpu_custom_call") \
+        == {"decode": 0, "chunk": 1, "prefill": 2}[program]
     pools = 2 * (4161 + 657) * 128 * 1024 * 2
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == pools
@@ -621,3 +660,57 @@ def test_cohere2_moe_programs_fit_and_copy_nothing(one_chip, cohere_engine,
     weights = 2 * 4_733_292_544
     cell_pools = 2 * (4161 + 3 * 657) * 128 * 1024 * 2
     assert weights + cell_pools + mem.temp_size_in_bytes < 0.95 * 16 * 2 ** 30
+
+
+# ---------- the programs that bypass the kernel lower as they did (PR 37)
+# sha1 of ``lower().as_text()`` at the parent of PR 37, with ``walk_form``
+# answering as on a TPU: the chat cell's programs (GPT-2 XL's heads of 64
+# lanes keep the head-split form in a chunk and a bucket), and every decode
+# step of the three serving geometries (a step keeps the lanes form)
+_PARENT_TEXT = {
+    ("xl", "decode", 1): "cef46a897cd068f6244af71dd51fa8ce947e3485",
+    ("xl", "decode", 2): "74cdf0a1fd6acf08ee0e043cc4d9f602594a85ff",
+    ("xl", "decode", 4): "f7ae4763b5f885cadeea79a047def080773ae3e4",
+    ("xl", "decode", 8): "c93b1ae4754963011daa242b67e9ad279b0c76f1",
+    ("xl", "decode", 16): "780e6d7eb45ae7d3fcb8bff7dc95b428d1ab545a",
+    ("xl", "prefill", 8): "8036dac223609863ae053b5a1497048370f331ba",
+    ("xl", "prefill", 16): "a2c8f6b0e3e04e9efcaed1f3a7a75e4e08ad5e87",
+    ("xl", "prefill", 32): "3050aeb967880a65c4c02e43be3457297a21570b",
+    ("xl", "prefill", 64): "af97dceea7db13c30d3b22fe7a19da5feabc6164",
+    ("xl", "prefill", 128): "7b52c1ea41ea9d61e08d5e0b4fa19246bccbbfd5",
+    ("xl", "chunk", 128): "828ab2309a7703dfe1bc3a068280c4108b3bd76b",
+    ("evabyte", "decode", 1): "bc3e95a504fc7b803b40fd23850c245a9f9c5b30",
+    ("evabyte", "decode", 2): "c2dbb7ccae2d5713542eda6e986af63575838e24",
+    ("evabyte", "decode", 4): "727106165587f918e4e6c8a880a1ace68a37019a",
+    ("evabyte", "decode", 8): "cc27c7513ece935ada3e32ab1346437ad903865e",
+    ("evabyte", "decode", 16): "93f24da883324c7fbe516f685d98332d4ed8be76",
+    ("cohere", "decode", 1): "3db5a3093b003e8bf0371010696412f5fbd867b5",
+    ("cohere", "decode", 2): "e68d7c721c02a82b4d3a2f01d8244441a2d88535",
+    ("cohere", "decode", 4): "6b9861caa9f5f88f85cb2f3b7170fd0d50f09088",
+    ("cohere", "decode", 8): "9cedf70e2a8c951d12617a2c4eb02c738249f21c",
+    ("cohere", "decode", 16): "20525df2151e07ca06162499e31dc61c44c76848",
+}
+
+
+@pytest.mark.parametrize("geometry, program, bucket", list(_PARENT_TEXT),
+                         ids=[f"{g}-{p}-{b}" for g, p, b in _PARENT_TEXT])
+def test_programs_that_bypass_the_kernel_lower_as_before(
+        request, one_chip, walk_on_tpu, geometry, program, bucket):
+    import hashlib
+    if geometry == "xl":
+        eng = request.getfixturevalue("pool_engines")("xl_1600")
+    else:
+        eng = request.getfixturevalue(f"{geometry}_engine")
+    build = {"decode": eng._build_step, "chunk": eng._build_chunk,
+             "prefill": eng._build_prefill}[program]
+    args = list(_shapes(eng._example_args(program, bucket), one_chip))
+    if geometry == "evabyte":
+        args[1] = tuple(_s(one_chip, (193, 128, 2048), jnp.bfloat16)
+                        for _ in args[1])
+    elif geometry == "cohere":
+        pages = {0: 4161, 1: 657}
+        args[1] = tuple(_s(one_chip, (pages[kind], 128, 1024), jnp.bfloat16)
+                        for kind in eng.model.cache_kinds())
+    text = build(bucket).lower(*args).as_text()
+    assert hashlib.sha1(text.encode()).hexdigest() \
+        == _PARENT_TEXT[(geometry, program, bucket)]
