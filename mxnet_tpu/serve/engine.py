@@ -75,7 +75,8 @@ Architecture (vLLM-style continuous batching, TPU-static shapes):
   ``mxnet_serve_compiles_total`` /
   ``mxnet_recompilations_total{block=serve_*}`` stay zero after warmup —
   the shape-bucketing contract (block tables and chunk shapes are
-  data/static, never novel avals).
+  data/static, never novel avals). A heartbeat thread (``_Heartbeat``)
+  tells a process that stopped from a chip that was slow.
 
 Single-host, single-device engine; params are captured at construction
 (weight updates require a new engine). The pools are rows of
@@ -97,6 +98,7 @@ for one (``sample_rows_filtered`` of ``sample_rows``).
 from __future__ import annotations
 
 import dataclasses
+import gc
 import queue as _qmod
 import threading
 import time
@@ -294,6 +296,8 @@ class _PendingStep:
     # a model that counts its experts' tokens: device [layers, held] int32,
     # read where ``nxt`` is
     counts: Any = None
+    # the dispatched program, as the device trace names it (``_program``)
+    program: str = ""
 
 
 def _jit_named(fn, name: str, donate_argnums=()):
@@ -303,6 +307,13 @@ def _jit_named(fn, name: str, donate_argnums=()):
     that writes them."""
     fn.__name__ = name
     return jax.jit(fn, donate_argnums=donate_argnums)
+
+
+def _program(fn) -> str:
+    """The name of a serving executable as the device trace's modules line
+    shows it (``jit_step_b8``): a jit and the AOT cache's wrapper of one
+    both carry the traced function's ``__name__``."""
+    return f"jit_{fn.__name__}"
 
 
 def _alias_bytes(fn, args) -> int:
@@ -322,26 +333,126 @@ def _alias_bytes(fn, args) -> int:
 #: and the log with its child spans and the compilations since the tick
 #: before: the operator's view of a stall with no trace running
 _SLOW_TICK_S = 1.0
+#: a wait for the device longer than this is listed, with the program it
+#: waited for, in its tick's slow-tick record
+_SLOW_WAIT_S = 0.1
+#: the heartbeat's period, and the lateness of a beat that is a host pause:
+#: a beat that took over twice its period, so that every heartbeat span of
+#: over 100 ms has an event beside it that says what held it
+_HEARTBEAT_S = 0.05
+_PAUSE_S = 0.05
+#: beats the heartbeat keeps for the slow-tick record (12.8 s of them)
+_BEATS_KEPT = 256
 
 
 class _TickSpan(_profiler.scope):
     """A child span of the engine's current tick (``mx.serve.<name>``):
     the span helper, stamped with the tick's number, adding its seconds to
-    the tick's per-child record that a slow tick reports."""
+    the tick's per-child record that a slow tick reports. While it is open
+    the engine's ``_in_span`` names it: what a late heartbeat reports."""
 
-    __slots__ = ("_children",)
+    __slots__ = ("_engine", "_outer")
 
     def __init__(self, engine: "InferenceEngine", name: str, hist=None,
                  **attrs):
         super().__init__(f"mx.serve.{name}", "serve", hist,
                          tick=engine._tick_no, **attrs)
-        self._children = engine._tick_children
+        self._engine = engine
+
+    def __enter__(self):
+        eng = self._engine
+        self._outer, eng._in_span = eng._in_span, self.name
+        return super().__enter__()
 
     def __exit__(self, *exc):
         super().__exit__(*exc)
-        self._children[self.name] = (self._children.get(self.name, 0.0)
-                                     + self.seconds)
+        eng = self._engine
+        eng._in_span = self._outer
+        children = eng._tick_children
+        children[self.name] = children.get(self.name, 0.0) + self.seconds
         return False
+
+
+def _gc_collections() -> int:
+    return sum(s["collections"] for s in gc.get_stats())
+
+
+class _Heartbeat:
+    """The engine's own pulse: a daemon thread that waits ``_HEARTBEAT_S``
+    at a time inside an ``mx.serve.heartbeat`` span. A beat much longer than
+    that is a time in which this thread could not run: another held the
+    interpreter's lock, or the machine did not run the process. A beat late
+    by more than ``_PAUSE_S`` writes a ``serve.host_pause`` event to the
+    flight recorder and a warning:
+    ``late_s``; ``cpu_s``, the process's CPU seconds over the beat (well
+    above 0: a thread ran and held the interpreter; near 0: the process did
+    not run); ``gc``, collections over the beat; the engine's ``tick`` and
+    the child span its thread was in when the beat woke (``span``).
+    ``pauses`` and ``pause_max_s`` count them for ``stats()``. Each beat's
+    reading of the process's CPU clock also serves the slow-tick record,
+    so that no tick reads it (a system call, which cost a tick 18 us on a
+    v5e host)."""
+
+    def __init__(self, engine: "InferenceEngine"):
+        self._engine = engine
+        self._halt = threading.Event()
+        self._thread = threading.Thread(target=self._run,
+                                        name="mxnet-serve-heartbeat",
+                                        daemon=True)
+        # (start, end, CPU seconds at the start) of the last beats, and
+        # (start, CPU seconds) of the one running
+        self._beats: "deque[Tuple[float, float, float]]" = deque(
+            maxlen=_BEATS_KEPT)
+        self._beat = (time.perf_counter(), time.process_time())
+        self.pauses = 0
+        self.pause_max_s = 0.0
+
+    def start(self):
+        self._thread.start()
+
+    def stop(self):
+        self._halt.set()
+
+    def join(self, timeout: Optional[float] = None):
+        if self._thread.is_alive():
+            self._thread.join(timeout)
+
+    def _run(self):
+        while not self._halt.is_set():
+            cpu0, gc0 = time.process_time(), _gc_collections()
+            with _profiler.scope("mx.serve.heartbeat", "serve") as beat:
+                self._beat = (time.perf_counter(), cpu0)
+                self._halt.wait(_HEARTBEAT_S)
+            self._beats.append((self._beat[0], beat.t1, cpu0))
+            late = beat.seconds - _HEARTBEAT_S
+            if late > _PAUSE_S:
+                self._pause(late, time.process_time() - cpu0,
+                            _gc_collections() - gc0)
+
+    def _pause(self, late: float, cpu_s: float, gcs: int):
+        eng = self._engine
+        self.pauses += 1
+        self.pause_max_s = max(self.pause_max_s, late)
+        fields = dict(late_s=round(late, 6), cpu_s=round(cpu_s, 6), gc=gcs,
+                      tick=eng._tick_no, span=eng._in_span)
+        _recorder.RECORDER.record("event", "serve.host_pause", **fields)
+        logger.warning("serve: host pause: %s", fields)
+
+    def tick_record(self, t0: float, t1: float) -> Tuple[float, float]:
+        """For a tick over ``[t0, t1]`` (``perf_counter``): ``(the longest
+        lateness of a beat that overlapped it, the beat still running
+        included; the process's CPU seconds since the last beat that began
+        by t0, so over the tick and at most a beat before it)``."""
+        running = self._beat
+        beats = list(self._beats) + [(running[0], None, running[1])]
+        worst = time.perf_counter() - running[0] - _HEARTBEAT_S
+        cpu0 = beats[0][2]
+        for b0, b1, c0 in beats:
+            if b0 <= t0:
+                cpu0 = c0
+            if b1 is not None and b0 < t1 and t0 < b1:
+                worst = max(worst, b1 - b0 - _HEARTBEAT_S)
+        return max(worst, 0.0), time.process_time() - cpu0
 
 
 class InferenceEngine:
@@ -1028,11 +1139,15 @@ class InferenceEngine:
         self._step_delay = 0.0
         # the engine thread's tick: its sequence number (carried by every
         # span under it), the open mx.serve.tick span, seconds per child
-        # span, and the compile count when the previous tick closed
+        # span, and the compile count when the previous tick closed; the
+        # child span open now and the tick's waits of over _SLOW_WAIT_S
         self._tick_no = 0
         self._tick_span: Optional[_profiler.scope] = None
         self._tick_children: Dict[str, float] = {}
         self._compiles_seen = _metrics.backend_compiles()
+        self._in_span: Optional[str] = None
+        self._tick_waits: List[list] = []
+        self._heartbeat = _Heartbeat(self)
 
         # counters for stats()
         self._submitted = 0
@@ -1052,6 +1167,7 @@ class InferenceEngine:
         self._thread = threading.Thread(target=self._loop,
                                         name="mxnet-serve-engine",
                                         daemon=True)
+        self._heartbeat.start()
         self._thread.start()
         return self
 
@@ -1098,6 +1214,7 @@ class InferenceEngine:
                 self._thread.join(timeout)
                 if self._thread.is_alive():
                     return
+            self._heartbeat.join(timeout)
             self._apply_swaps()  # loop is dead: unblock swap waiters
             self._apply_page_ops()
             return
@@ -1105,6 +1222,7 @@ class InferenceEngine:
             self._thread.join(timeout)
             if self._thread.is_alive():
                 return            # begin_drain: the loop finishes async
+        self._heartbeat.join(timeout)   # the loop stopped it on its way out
         self._apply_swaps()      # loop is dead: unblock swap waiters
         self._apply_page_ops()
 
@@ -2224,6 +2342,8 @@ class InferenceEngine:
                             generated_ids=list(self._slots[s].generated),
                             error=str(e)))
                         self._slots[s] = None
+        finally:
+            self._heartbeat.stop()
 
     def _idle(self) -> bool:
         return (self._running and not self._queue and not any(self._slots)
@@ -2231,6 +2351,36 @@ class InferenceEngine:
 
     def _span(self, name: str, hist=None, **attrs) -> _TickSpan:
         return _TickSpan(self, name, hist, **attrs)
+
+    def _chip_idle(self) -> int:
+        """1 if every program the engine dispatched has finished on the
+        chip, which then idles until the next one arrives; else 0. Every
+        serving program returns the pools, so a pool belongs to the newest
+        one. Never waits."""
+        return int(self._pools[0].is_ready())
+
+    def _sync(self, name: str, program: str, t0: float, out, fetch,
+              **attrs):
+        """Wait for the program ``program`` dispatched at ``t0``
+        (``perf_counter``) and read its outputs home: ``(fetch(), the
+        instant the wait ended)``. The ``mx.serve.<name>`` span
+        (``program``, ``age_us``: how long ago it was dispatched) holds the
+        wait for its output ``out`` to be ready (the outputs of one
+        execution are ready together), which is the chip finishing
+        everything queued up to and including that program, and then
+        ``mx.serve.fetch``, the copy home and the conversion. A wait of over
+        ``_SLOW_WAIT_S`` goes into the tick's slow-tick record."""
+        age = time.perf_counter() - t0
+        with self._span(name, _metrics.SERVE_HOST_SYNC, program=program,
+                        age_us=int(age * 1e6), **attrs) as sync:
+            out.block_until_ready()
+            with self._span("fetch") as home:
+                value = fetch()
+        if sync.seconds > _SLOW_WAIT_S:
+            self._tick_waits.append(
+                [name, program, round(age * 1e3, 3), round(sync.seconds, 6),
+                 round(home.seconds, 6)])
+        return value, sync.t1
 
     def _note_walk(self, span: _profiler.scope, positions, T: int,
                    substeps: int = 1):
@@ -2398,6 +2548,7 @@ class InferenceEngine:
                         self._cond.wait(0.1)
             self._tick_no += 1
             self._tick_children.clear()
+            self._tick_waits.clear()
             self._tick_folded = 0
             with _profiler.scope(
                     "mx.serve.tick", "serve", tick=self._tick_no,
@@ -2410,13 +2561,26 @@ class InferenceEngine:
                 break
 
     def _close_tick(self, tick: _profiler.scope):
+        """A tick of over ``_SLOW_TICK_S`` goes to the flight recorder and
+        the log: its child spans' seconds (``fetch`` is inside the two
+        syncs), ``waits`` (``[span, program, age_ms, seconds, fetch_s]`` of
+        each wait of over ``_SLOW_WAIT_S``: the wait less ``fetch_s`` is the
+        chip finishing up to that program, ``fetch_s`` the copy home),
+        ``cpu_s`` (the process's CPU seconds over the tick, from the
+        heartbeat's reading at most a beat before it) and
+        ``heartbeat_late_s`` (the longest lateness of a heartbeat that
+        overlapped it: the process stopped, not the chip)."""
         compiles = _metrics.backend_compiles()
         if tick.seconds > _SLOW_TICK_S:
+            late, cpu_s = self._heartbeat.tick_record(
+                tick.t1 - tick.seconds, tick.t1)
             fields = dict(
                 tick=self._tick_no, seconds=round(tick.seconds, 6),
                 rows=tick.args.get("rows", 0), sb=tick.args.get("sb", 0),
                 children={k: round(v, 6)
                           for k, v in self._tick_children.items()},
+                waits=list(self._tick_waits),
+                cpu_s=round(cpu_s, 6), heartbeat_late_s=round(late, 6),
                 folded=self._tick_folded,
                 compiles=compiles - self._compiles_seen)
             _recorder.RECORDER.record("event", "serve.slow_tick", **fields)
@@ -2625,8 +2789,11 @@ class InferenceEngine:
         open ``prefill_dispatch``, is told which). A middle chunk only
         writes KV pages (returns None); the final chunk (bucketed
         remainder) also samples token0 — its host sync is DEFERRED: the
-        returned ``(s, pf, req, slot, tok0_dev)`` record is finalized by
-        the caller after every chunk of the tick has dispatched."""
+        returned ``(s, pf, req, slot, tok0_dev, program, t0)`` record is
+        finalized by the caller after every chunk of the tick has
+        dispatched. The span is told the ``program`` dispatched and whether
+        the chip sat ``idle`` when the dispatch began."""
+        idle = self._chip_idle()
         pf = self._prefills[s]
         slot = self._slots[s]
         req = slot.req
@@ -2659,6 +2826,7 @@ class InferenceEngine:
                 ids[0, :] = pf.ids[pf.cursor:end]
                 self._note_walk(span, [pf.cursor], self._chunk)
                 self._note_select(span, [(pf.cursor, self._chunk)])
+                span.set(program=_program(fn), idle=idle)
                 pools = fn(self._values, self._pools, ids,
                            onp.int32(pf.cursor), self._table_row(s),
                            *self._slot_rows([s]))
@@ -2679,6 +2847,7 @@ class InferenceEngine:
                 return
             # final chunk: bucketed remainder + token0 sampling
             t0w = time.time()
+            t0 = time.perf_counter()
             rest = P - pf.cursor
             pb = bucket_for(rest, self.min_prompt_bucket, self._chunk,
                             self._growth)
@@ -2688,6 +2857,8 @@ class InferenceEngine:
             self._note_walk(span, [pf.cursor], pb)
             self._note_select(span, [(pf.cursor, pb)])
             self._note_sample(span, [(req.temperature, req.top_k, req.top_p)])
+            program = _program(fn)
+            span.set(program=program, idle=idle)
             gargs = ()
             if self._grammar:
                 gargs = (self._gcls[s:s + 1].copy(),
@@ -2732,16 +2903,16 @@ class InferenceEngine:
         # round cannot re-step this slot while its token0 is in flight
         self._pages.insert_prefix(s, pf.ids)
         del self._prefills[s]
-        return (s, pf, req, slot, tok0)
+        return (s, pf, req, slot, tok0, program, t0)
 
     def _prefill_finalize(self, s: int, pf: "_Prefill",
-                          req: RequestHandle, slot: "_Slot", tok0_dev):
-        """Host-sync one deferred final-chunk token0 and activate the
-        slot for decode."""
+                          req: RequestHandle, slot: "_Slot", tok0_dev,
+                          program: str, t0: float):
+        """Host-sync one deferred final-chunk token0 (of ``program``,
+        dispatched at ``t0``) and activate the slot for decode."""
         try:
-            with self._span("prefill_sync", _metrics.SERVE_HOST_SYNC,
-                            slot=s) as sync:
-                tok0 = int(tok0_dev)
+            tok0, now = self._sync("prefill_sync", program, t0, tok0_dev,
+                                   lambda: int(tok0_dev), slot=s)
         except Exception as e:  # pragma: no cover - defensive
             warnings.warn(f"serve: prefill failed: {e!r}")
             # the prefix was published at dispatch, before the device
@@ -2750,7 +2921,6 @@ class InferenceEngine:
             self._pages.clear_prefix_cache()
             self._retire(s, STATUS_ERROR, error=str(e))
             return
-        now = sync.t1
         self._read_chunk_experts()
         _metrics.SERVE_ROUNDTRIPS.labels(path="prefill").inc()
         _metrics.SERVE_PREFILL_SECONDS.observe(now - pf.t0)
@@ -2926,6 +3096,7 @@ class InferenceEngine:
                 sb = bucket_for(cur[-1][0] + 1, 1, self.S)
         self._tick_span.set(rows=len(cur), sb=sb)
         with self._span("decode_dispatch", sb=sb, rows=len(cur)) as disp:
+            idle = self._chip_idle()
             self._note_walk(disp, [int(self._pos[s]) for s, _ in cur],
                             1, self.K)
             self._note_select(disp, [(int(self._pos[s]), 1) for s, _ in cur])
@@ -2934,6 +3105,7 @@ class InferenceEngine:
                  float(self._topps[s])) for s, _ in cur])
             rec = self._dispatch_step(prev, cur, sb)
             if rec is not None:
+                disp.set(program=rec.program, idle=idle)
                 # the host clocks already stand behind the dispatched step
                 self._fold_ended(disp, [(s, int(self._pos[s]))
                                         for s, _ in cur])
@@ -3036,7 +3208,8 @@ class InferenceEngine:
             return None
         rec = _PendingStep(nxt=nxt, sb=sb, t0=t0, toks=toks, steps=steps,
                            gstate=ngs, slots=cur,
-                           counts=counts[0] if self._moe and counts else None)
+                           counts=counts[0] if self._moe and counts else None,
+                           program=_program(fn))
         # the dispatched program owns its snapshot of this tick's
         # pos/counters; advance the host clocks now so the NEXT dispatch
         # — possibly before this one is read — sees post-step values.
@@ -3129,10 +3302,10 @@ class InferenceEngine:
                 dev.copy_to_host_async()      # start the D2H early
         except Exception:
             pass
-        self._process_step_spec(cur, toks, acc, t0, sb)
+        self._process_step_spec(cur, toks, acc, t0, sb, _program(fn))
 
     def _process_step_spec(self, cur, toks_dev, acc_dev, t0: float,
-                           sb: int):
+                           sb: int, program: str):
         """Host-read one verify round and apply it: per row, append the
         ``acc`` valid tokens in order (accepted draft prefix + the one
         correction/bonus token), advancing the pos/counter/remaining
@@ -3140,17 +3313,15 @@ class InferenceEngine:
         move at the read, not the dispatch. EOS/budget/deadline scanning
         stops a row early exactly like the multi-token K-vector scan."""
         try:
-            with self._span("decode_sync", _metrics.SERVE_HOST_SYNC,
-                            sb=sb) as sync:
-                toks = onp.asarray(toks_dev)              # [sb, T]
-                acc = onp.asarray(acc_dev)                # [sb]
+            (toks, acc), now = self._sync(            # [sb, T], [sb]
+                "decode_sync", program, t0, toks_dev,
+                lambda: (onp.asarray(toks_dev), onp.asarray(acc_dev)), sb=sb)
         except Exception as e:  # pragma: no cover - defensive
             warnings.warn(f"serve: speculative decode step failed: {e!r}")
             for s, slot in cur:
                 if self._slots[s] is slot:
                     self._retire(s, STATUS_ERROR, error=str(e))
             return
-        now = sync.t1
         now_wall = time.time()
         chunk_t0w = now_wall - (now - t0)
         _metrics.SERVE_ROUNDTRIPS.labels(path="decode").inc()
@@ -3221,16 +3392,9 @@ class InferenceEngine:
         speculative rows the parity contract discards. Returns True when
         any slot retired."""
         try:
-            with self._span("decode_sync", _metrics.SERVE_HOST_SYNC,
-                            sb=rec.sb) as sync:
-                if rec.toks is not None:
-                    toks = onp.asarray(rec.toks)         # [sb, K]
-                    steps = int(rec.steps)
-                else:
-                    toks = onp.asarray(rec.nxt)[:, None]  # [sb, 1]
-                    steps = 1
-                counts = (None if rec.counts is None
-                          else onp.asarray(rec.counts))
+            (toks, steps, counts), now = self._sync(
+                "decode_sync", rec.program, rec.t0, rec.nxt,
+                lambda: self._fetch_step(rec), sb=rec.sb)
         except Exception as e:  # pragma: no cover - defensive
             warnings.warn(f"serve: decode step failed: {e!r}")
             for s, slot in rec.slots:
@@ -3244,9 +3408,22 @@ class InferenceEngine:
                 self._read_chunk_experts()
                 self._note_experts(
                     counts, len(rec.slots) * self._moe_a_token, emit)
-            retired, appended = self._apply_step(rec, toks, steps, sync.t1)
+            retired, appended = self._apply_step(rec, toks, steps, now)
             emit.set(tokens=appended, retired=int(retired))
         return retired
+
+    @staticmethod
+    def _fetch_step(rec: _PendingStep):
+        """A finished step's outputs on the host: ``(tokens [sb, K],
+        substeps run, expert counts or None)``."""
+        if rec.toks is not None:
+            toks = onp.asarray(rec.toks)
+            steps = int(rec.steps)
+        else:
+            toks = onp.asarray(rec.nxt)[:, None]
+            steps = 1
+        counts = None if rec.counts is None else onp.asarray(rec.counts)
+        return toks, steps, counts
 
     def _apply_step(self, rec: _PendingStep, toks, steps: int,
                     now: float) -> Tuple[bool, int]:
@@ -3524,6 +3701,10 @@ class InferenceEngine:
         out["pages"] = pstats
         out["prefilling"] = len(self._prefills)
         out["preemptions"] = self._preempted
+        # heartbeats late by over _PAUSE_S: the interpreter held, or the
+        # process not run
+        out["host_pauses"] = self._heartbeat.pauses
+        out["host_pause_max_s"] = round(self._heartbeat.pause_max_s, 6)
         out["kv_walk_blocks"] = self._kv_walked
         out["kv_walk_blocks_on_lanes"] = self._kv_on_lanes
         out["kv_walk_blocks_in_kernel"] = self._kv_in_kernel

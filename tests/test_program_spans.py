@@ -5,8 +5,10 @@ trace is there, under the names PERF.md section 3 lists.
 One parametrised test, one case per span or scope. The traced runs and the
 lowerings are made once a module, on the CPU: nothing here is a timing."""
 import glob
+import os
 import re
 import logging
+import sys
 import time
 
 import jax
@@ -24,16 +26,36 @@ from mxnet_tpu.observability import recorder
 from mxnet_tpu.serve import InferenceEngine
 from mxnet_tpu.serve import engine as engine_mod
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(ROOT, "bench"))
+import run as harness  # noqa: E402  (sets up the ``mxbench`` alias)
+
 TICK_CHILDREN = ["admit", "prefill_dispatch", "prefill_sync", "lease",
-                 "decode_dispatch", "decode_sync", "emit"]
+                 "decode_dispatch", "decode_sync", "emit", "fetch"]
+SYNCS = ["prefill_sync", "decode_sync"]
 TRAIN_SPANS = ["step", "h2d", "dispatch", "loss_sync"]
-# attributes that are known only inside the span and set on it there
+# attributes that each span carries, most known only inside it and set there
 SET_INSIDE = ["tick.rows", "tick.sb", "prefill_dispatch.start",
               "prefill_dispatch.end", "prefill_dispatch.final",
               "admit.admitted", "emit.tokens",
               "prefill_dispatch.walk", "prefill_dispatch.of",
               "decode_dispatch.walk", "decode_dispatch.of",
-              "prefill_dispatch.filtered", "decode_dispatch.filtered"]
+              "prefill_dispatch.filtered", "decode_dispatch.filtered",
+              "prefill_dispatch.program", "prefill_dispatch.idle",
+              "decode_dispatch.program", "decode_dispatch.idle",
+              "prefill_sync.program", "prefill_sync.age_us",
+              "decode_sync.program", "decode_sync.age_us"]
+SLOW_TICK_FIELDS = ["once", "children", "fetch", "waits", "cpu_s",
+                    "heartbeat_late_s"]
+# a hand-made record for bench/readers/span_attr_share.py: dispatch spans
+# (start, duration, attributes) inside a window of [0, 10) s
+IDLE_SPANS = {
+    "share": [(1, {"idle": 1}), (2, {"idle": 0}), (3, {"idle": 1}),
+              (4, {"idle": 0}), (11, {"idle": 1})],      # the last outside
+    "passed_over": [(1, {"idle": 1}), (2, {"idle": 0}), (3, {"idle": 0}),
+                    (4, {"idle": 0}), (5, {})],
+    "none_left": [(1, {}), (2, {"walk": 3}), (11, {"idle": 1})]}
+IDLE_SHARE = {"share": 50.0, "passed_over": 25.0, "none_left": None}
 STEP_SCOPES = ["mx.embed", "mx.attn", "mx.paged_attention", "mx.kv_write",
                "mx.kv_walk", "mx.mlp", "mx.lm_head", "mx.sample"]
 TRAIN_SCOPES = ["mx.embed", "mx.attn", "mx.mlp", "mx.lm_head", "mx.loss",
@@ -154,7 +176,35 @@ CASES = ([("serve_span", "idle"), ("serve_span", "tick")]
          + [("train_span", s) for s in TRAIN_SPANS]
          + [("step_scope", s) for s in STEP_SCOPES]
          + [("train_scope", s) for s in TRAIN_SCOPES]
-         + [("step_name", "jit_step_b2"), ("train_name", "jit_train_step")])
+         + [("step_name", "jit_step_b2"), ("train_name", "jit_train_step")]
+         + [("fetch_in_sync", s) for s in SYNCS]
+         + [("heartbeat", "own_thread"), ("host_pause", "held_lock")]
+         + [("span_attr_share", c) for c in IDLE_SPANS])
+
+
+def attr_share(case):
+    """``span_attr_share`` over the hand-made spans of ``IDLE_SPANS``."""
+    from mxbench.readers import span_attr_share
+    names = ["mx.serve.prefill_dispatch", "mx.serve.decode_dispatch"]
+    spans = [[names[i % 2], start, 0.5, attrs]
+             for i, (start, attrs) in enumerate(IDLE_SPANS[case])]
+    run = {"trace": {"host": [("bench.window", 0.0, 10.0)]},
+           "_program_trace": {"window": [0.0, 10.0], "threads": [spans],
+                              "programs": {}}}
+    return span_attr_share.read(run, {"spans": names, "attr": "idle"})
+
+
+def hold_interpreter(seconds):
+    """Keep the interpreter's lock on this thread for ``seconds``: no other
+    thread of the process runs meanwhile."""
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1.0)
+    try:
+        end = time.perf_counter() + seconds
+        while time.perf_counter() < end:
+            pass
+    finally:
+        sys.setswitchinterval(old)
 
 
 @pytest.mark.parametrize("kind, name", CASES,
@@ -186,7 +236,51 @@ def test_program_names(kind, name, request):
         span, attr = name.split(".")
         values = [a[attr] for n, _, _, a in line
                   if n == f"mx.serve.{span}" and attr in a]
-        assert values and max(values) >= 1
+        assert values
+        if attr == "program":
+            # the executable, as the device trace's modules line names it
+            assert all(re.fullmatch(r"jit_(chunk_c|prefill_b|step_b)\d+", v)
+                       for v in values)
+        else:
+            assert max(values) >= 1
+        if attr == "idle":
+            assert set(values) <= {0, 1}
+    elif kind == "fetch_in_sync":
+        # the copy home, after the wait for the chip, inside each wait
+        line = engine_line(request.getfixturevalue("serve_lines"))
+        fetches = [(lo, hi) for n, lo, hi, _ in line
+                   if n == "mx.serve.fetch"]
+        syncs = [(lo, hi) for n, lo, hi, _ in line
+                 if n == f"mx.serve.{name}"]
+        assert syncs
+        for lo, hi in syncs:
+            assert sum(lo <= f_lo and f_hi <= hi
+                       for f_lo, f_hi in fetches) == 1
+    elif kind == "heartbeat":
+        # one thread of its own, which carries nothing else
+        lines = request.getfixturevalue("serve_lines")
+        beating = [ln for ln in lines
+                   if any(n == "mx.serve.heartbeat" for n, *_ in ln)]
+        assert len(beating) == 1
+        assert all(n == "mx.serve.heartbeat" for n, *_ in beating[0])
+    elif kind == "host_pause":
+        eng = request.getfixturevalue("engine")
+        before = eng.stats()["host_pauses"]
+        recorder.RECORDER.reset()
+        hold_interpreter(0.4)
+        time.sleep(0.2)                 # the heartbeat writes its event
+        pauses = [e for e in recorder.RECORDER.snapshot()
+                  if e["name"] == "serve.host_pause"]
+        assert pauses
+        ev = max(pauses, key=lambda e: e["late_s"])
+        # a thread ran and held the interpreter: the process spent CPU
+        assert ev["late_s"] > engine_mod._PAUSE_S and ev["cpu_s"] > 0
+        assert ev["gc"] >= 0 and "tick" in ev and "span" in ev
+        stats = eng.stats()
+        assert stats["host_pauses"] >= before + 1
+        assert stats["host_pause_max_s"] >= ev["late_s"]
+    elif kind == "span_attr_share":
+        assert attr_share(name) == IDLE_SHARE[name]
     elif kind == "walk_form":
         # the form the dispatched program's paged read was traced in; two
         # heads over a bucket of 8 tokens are as few columns as a step's
@@ -243,8 +337,20 @@ def test_compile_counter(call, grows, tmp_path):
         assert events and events[-1]["seconds"] > 0
 
 
-def test_slow_tick_is_recorded(engine, monkeypatch, caplog):
-    monkeypatch.setattr(engine_mod, "_SLOW_TICK_S", 0.5)
+class _Messages(logging.Handler):
+    def __init__(self):
+        super().__init__(logging.WARNING)
+        self.messages = []
+
+    def emit(self, record):
+        self.messages.append(record.getMessage())
+
+
+@pytest.fixture(scope="module")
+def slow_tick(engine):
+    """One tick held 0.6 s by a sleep before its decode step, with every
+    wait for the device listed (``_SLOW_WAIT_S`` 0): the ``serve.slow_tick``
+    events written and the warnings logged while its request ran."""
     real, slept = engine._step_tick, []
 
     def slow_once():
@@ -252,19 +358,52 @@ def test_slow_tick_is_recorded(engine, monkeypatch, caplog):
             slept.append(time.sleep(0.6))
         real()
     slept.append(None)                    # armed after the warm request
-    monkeypatch.setattr(engine, "_step_tick", slow_once)
-    # "compilations since the previous tick" counts the idle time before
-    # this one too: a request first, so that the tick before is recent
-    assert engine.submit(onp.arange(6) % 64, 2).result(120).ok
-    slept.clear()
-    recorder.RECORDER.reset()
-    with caplog.at_level(logging.WARNING):
-        assert engine.submit(onp.arange(6) % 64, 4).result(120).ok
+    log = _Messages()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(engine_mod, "_SLOW_TICK_S", 0.5)
+        mp.setattr(engine_mod, "_SLOW_WAIT_S", 0.0)
+        mp.setattr(engine, "_step_tick", slow_once)
+        # "compilations since the previous tick" counts the idle time
+        # before this one too: a request first, so that the tick before is
+        # recent
+        assert engine.submit(onp.arange(6) % 64, 2).result(120).ok
+        slept.clear()
+        recorder.RECORDER.reset()
+        engine_mod.logger.addHandler(log)
+        try:
+            assert engine.submit(onp.arange(6) % 64, 4).result(120).ok
+        finally:
+            engine_mod.logger.removeHandler(log)
     events = [e for e in recorder.RECORDER.snapshot()
               if e["name"] == "serve.slow_tick"]
+    return events, log.messages
+
+
+@pytest.mark.parametrize("field", SLOW_TICK_FIELDS)
+def test_slow_tick_is_recorded(field, slow_tick):
+    events, messages = slow_tick
     assert len(events) == 1
     ev = events[0]
-    assert ev["seconds"] >= 0.6 and ev["tick"] >= 1 and ev["compiles"] == 0
-    assert "mx.serve.admit" in ev["children"]
-    assert sum(ev["children"].values()) < ev["seconds"]
-    assert sum("slow tick" in r.getMessage() for r in caplog.records) == 1
+    if field == "once":
+        assert ev["seconds"] >= 0.6 and ev["tick"] >= 1
+        assert ev["compiles"] == 0
+        assert sum("slow tick" in m for m in messages) == 1
+    elif field == "children":
+        assert "mx.serve.admit" in ev["children"]
+        # fetch lies inside a sync: counted there already
+        assert sum(v for k, v in ev["children"].items()
+                   if k != "mx.serve.fetch") < ev["seconds"]
+    elif field == "fetch":
+        children = ev["children"]
+        assert 0 < children["mx.serve.fetch"] <= sum(
+            children.get(f"mx.serve.{s}", 0.0) for s in SYNCS)
+    elif field == "waits":
+        # [span, program, age_ms, seconds, fetch_s] of each wait
+        assert ev["waits"]
+        for span, program, age_ms, seconds, fetch_s in ev["waits"]:
+            assert span in SYNCS and program.startswith("jit_")
+            assert age_ms >= 0 and seconds >= fetch_s >= 0
+    elif field == "cpu_s":
+        assert ev["cpu_s"] >= 0
+    elif field == "heartbeat_late_s":
+        assert ev["heartbeat_late_s"] >= 0
