@@ -443,6 +443,49 @@ def _execution_devices(example_args):
     return single or [jax.devices()[0]]
 
 
+#: compiles that jax's own persistent compilation cache answered in this
+#: thread (the event is recorded in the compiling thread)
+_JAX_HITS = threading.local()
+_JAX_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_jax_cache_lock = threading.Lock()
+_listening = False
+
+
+def _on_jax_event(event: str, **_kwargs) -> None:
+    if event == _JAX_HIT_EVENT:
+        _JAX_HITS.n = getattr(_JAX_HITS, "n", 0) + 1
+
+
+def _compile_to_store(jitted, example_args, lowered):
+    """``lowered.compile()`` for an entry about to be persisted. Where jax's
+    own persistent compilation cache answers the compile, the executable it
+    loaded serializes without its code on XLA:CPU: the entry restores, then
+    fails at its first call with ``NOT_FOUND: ... Function ... not found``.
+    Such a program is compiled once more with that cache off (the jitted
+    function's own cache cleared, since it keeps the loaded executable)."""
+    global _listening
+    with _jax_cache_lock:
+        if not _listening:
+            jax.monitoring.register_event_listener(_on_jax_event)
+            _listening = True
+    before = getattr(_JAX_HITS, "n", 0)
+    compiled = lowered.compile()
+    if getattr(_JAX_HITS, "n", 0) == before:
+        return compiled
+    from jax.experimental.compilation_cache import compilation_cache as _jcc
+    with _jax_cache_lock:
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        _jcc.reset_cache()
+        try:
+            if hasattr(jitted, "clear_cache"):
+                jitted.clear_cache()
+            return jitted.lower(*example_args).compile()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            _jcc.reset_cache()
+
+
 def compile_cached(jitted, example_args: Sequence, label: str,
                    extra: Any = None):
     """Compile ``jitted`` for ``example_args`` through the persistent
@@ -534,7 +577,7 @@ def compile_cached(jitted, example_args: Sequence, label: str,
 
     _metrics.AOT_MISSES.labels(block=label).inc()
     t0 = time.perf_counter()
-    compiled = lowered.compile()
+    compiled = _compile_to_store(jitted, example_args, lowered)
     _metrics.AOT_COMPILE_SECONDS.observe(time.perf_counter() - t0)
     _ledger(compiled)
     try:

@@ -6,7 +6,7 @@
   sparse_attention.py``): grouped-query heads over few key/value heads, no
   position embedding, RMS-normed queries and keys, a sigmoid output gate. It
   owns paged pools: K and V pages and the compressed keys the selection
-  scores.
+  scores, each a row of ``kv heads * hd`` lanes a position.
 - ``"lightning-attn"``: linear attention with a per-head decay (Lightning
   Attention-2; ``ops/linear_attention.py``): as many key/value heads as query
   heads, rotary positions on the normed queries and keys, an RMS norm over
@@ -186,11 +186,13 @@ class SALASparseMixer(HybridBlock):
 
         def fn(q, k, v, g, qn, kn, posv, bt, validv, kp, vp, kcp):
             q = _norm(_heads(q, H, hd), qn, cfg.rms_eps)
-            k = _norm(_heads(k, G, hd), kn, cfg.rms_eps)
+            # the pools take a position's K and V as one row of G * hd
+            k = _norm(k.reshape(B, T, G, hd), kn, cfg.rms_eps).reshape(
+                B, T, G * hd)
             posv = jnp.broadcast_to(jnp.asarray(posv, jnp.int32), (B,))
             o, kp, vp, kcp = _sparse.sparse_paged_attention(
-                q, k, _heads(v, G, hd), kp, vp, kcp, bt, posv, validv,
-                rep=H // G, sc=cfg.sparse)
+                q, k, v, kp, vp, kcp, bt, posv, validv, rep=H // G,
+                sc=cfg.sparse)
             o = o.transpose(0, 2, 1, 3).reshape(B, T, H * hd)
             o = o * jax.nn.sigmoid(g.astype(jnp.float32)).astype(o.dtype)
             return o, kp, vp, kcp
@@ -279,17 +281,19 @@ class MiniCPMSALAForCausalLM(HybridBlock):
     # ------------------------------------------------------ cache protocol
     def cache_spec_paged(self, num_pages: int, page_size: int):
         """[(shape, dtype)] of the sparse layers' pools, three a layer in
-        layer order: K and V pages ``[num_pages, kv heads, page, hd]`` and
-        the compressed keys ``[num_pages, kv heads, page // stride, hd]``.
-        A page is a selection block, so ``page_size`` is not free."""
+        layer order: K and V pages ``[num_pages, page, kv heads * hd]`` and
+        the compressed keys ``[num_pages, page // stride, kv heads * hd]``:
+        a position is one row of whole lane tiles, which a program writes
+        and reads where it lies (``ops/sparse_attention.py``). A page is a
+        selection block, so ``page_size`` is not free."""
         cfg = self.cfg
         if page_size != cfg.sparse.block:
             raise MXNetError(
                 f"MiniCPM-SALA selects whole pages: page_size must be the "
                 f"sparse block, {cfg.sparse.block}, not {page_size}")
-        G, hd = cfg.num_kv_heads, cfg.head_dim
-        kv = ((num_pages, G, page_size, hd), cfg.dtype)
-        kc = ((num_pages, G, _sparse.compressed_per_page(cfg.sparse), hd),
+        row = cfg.num_kv_heads * cfg.head_dim
+        kv = ((num_pages, page_size, row), cfg.dtype)
+        kc = ((num_pages, _sparse.compressed_per_page(cfg.sparse), row),
               cfg.dtype)
         return [kv, kv, kc] * cfg.mixer_types.count(SPARSE)
 

@@ -25,6 +25,15 @@ every live page once for all its queries and applies the selection as a mask,
 so its cost is the dense walk's; a gathered read per query tile is not built
 (``PERF.md`` section 7).
 
+**The pools are rows.** K and V pages are ``[pages + 1, block, G * hd]``
+and the compressed keys ``[pages + 1, block // stride, G * hd]``: a position
+is one row of whole lane tiles, minor-most, as ``models/llama.py``'s pools
+are. A write scatters whole rows where the pool lies; every read takes the
+pool as stored (a decoding row's ``[block, hd]`` window of one KV head's
+lanes, a chunk's whole pages) and splits the heads on what it gathered. A
+pool of ``[pages + 1, G, block, hd]`` was copied into the scatter's layout
+and back by every program.
+
 ``blocks_read`` is the same arithmetic on the host, for the engine's
 counters.
 """
@@ -45,6 +54,9 @@ _NEG = -1e30
 READ_PAGES = 16
 #: tokens one iteration of a prefill chunk's walk holds
 WALK_TOKENS = 256
+#: rows of one of the chip's tiles of a 16-bit pool: ``_head_windows`` splits
+#: a page's rows by it
+_TILE_ROWS = 8
 #: queries scored against the compressed keys at a time (a [heads, tile,
 #: compressed keys] float32 array is the largest the scoring makes)
 SCORE_TILE = 128
@@ -97,47 +109,47 @@ def blocks_read(sc: SparseConfig, pos: int, T: int):
 
 
 # ------------------------------------------------------------------ writes
-def _write(kh, vh, k_pages, v_pages, block_table, cols):
-    """Scatter the T new K/V rows through the table; columns past the
-    table's reach go to the sink page (the last)."""
-    ps, maxp = k_pages.shape[2], block_table.shape[1]
+def _write(k_rows, v_rows, k_pages, v_pages, block_table, cols):
+    """Scatter the T new K/V rows ``[B, T, G * hd]`` through the table, a
+    whole row a position; columns past the table's reach go to the sink
+    page (the last)."""
+    ps, maxp = k_pages.shape[1], block_table.shape[1]
     sink = k_pages.shape[0] - 1
     pg = jnp.take_along_axis(block_table,
                              jnp.minimum(cols // ps, maxp - 1), axis=1)
     pg = jnp.where(cols < maxp * ps, pg, jnp.int32(sink))
     off = cols % ps
-    k_pages = k_pages.at[pg, :, off, :].set(
-        kh.transpose(0, 2, 1, 3).astype(k_pages.dtype))
-    v_pages = v_pages.at[pg, :, off, :].set(
-        vh.transpose(0, 2, 1, 3).astype(v_pages.dtype))
+    k_pages = k_pages.at[pg, off, :].set(k_rows.astype(k_pages.dtype))
+    v_pages = v_pages.at[pg, off, :].set(v_rows.astype(v_pages.dtype))
     return k_pages, v_pages
 
 
 def _compress(k_pages, kc_pages, block_table, pos, valid, T, sc):
     """Write the compressed keys whose last position lies among the row's
     ``valid`` new positions ``[pos, pos + valid)``: each the float32 mean of
-    its ``kernel`` keys, read back from the pages (the first of them may be
-    positions of an earlier chunk)."""
+    its ``kernel`` keys, read back from the pages as rows (the first of them
+    may be positions of an earlier chunk). One that ends past the table's
+    reach goes to the sink page, as its keys did."""
     ps, maxp = sc.block, block_table.shape[1]
     pb = compressed_per_page(sc)
     sink = k_pages.shape[0] - 1
     n_cand = -(-T // sc.stride)
     j = (pos[:, None] - sc.kernel + sc.stride) // sc.stride \
         + jnp.arange(n_cand, dtype=jnp.int32)[None, :]             # [B, n]
-    done = (j >= 0) & (sc.stride * j + sc.kernel - 1
-                       < (pos + valid)[:, None])
+    end = sc.stride * j + sc.kernel - 1
+    done = (j >= 0) & (end < (pos + valid)[:, None]) & (end < maxp * ps)
     tok = sc.stride * j[:, :, None] + jnp.arange(sc.kernel,
                                                  dtype=jnp.int32)
     tok = jnp.clip(tok, 0, maxp * ps - 1)                          # [B,n,k]
     B = tok.shape[0]
     pg = jnp.take_along_axis(block_table, (tok // ps).reshape(B, -1),
                              axis=1).reshape(tok.shape)
-    rows = k_pages[pg, :, tok % ps, :].astype(jnp.float32)       # [B,n,k,G,hd]
-    mean = rows.mean(axis=2)                                       # [B,n,G,hd]
+    rows = k_pages[pg, tok % ps, :].astype(jnp.float32)            # [B,n,k,GD]
+    mean = rows.mean(axis=2)                                       # [B,n,GD]
     jp = jnp.clip(j // pb, 0, maxp - 1)
     dst = jnp.where(done, jnp.take_along_axis(block_table, jp, axis=1),
                     jnp.int32(sink))
-    return kc_pages.at[dst, :, j % pb, :].set(mean.astype(kc_pages.dtype))
+    return kc_pages.at[dst, j % pb, :].set(mean.astype(kc_pages.dtype))
 
 
 # --------------------------------------------------------------- selection
@@ -231,12 +243,37 @@ def _softmax_step(carry, s, mask, vb, eq):
     return m_new, l, acc
 
 
+def _head_windows(pool, pages, hd):
+    """``pool`` ``[P1, ps, G * hd]``, ``pages`` ``[B, G, n]`` page ids ->
+    ``[B, G, n, ps, hd]``: KV head ``g``'s window of each page, its ``ps``
+    rows by its own ``hd`` lanes, gathered where the pool lies (at ``hd`` of
+    128, one lane tile: no byte of the other heads is read). The pool is
+    viewed as ``[P1, ps / r, r, G, hd]``, ``r`` rows a tile, which is the
+    order the chip's ``(8, 128)`` tiles already hold it in: the view moves
+    nothing, where gathering a window at a lane offset of the plain pool
+    had the compiler relay the whole pool out first."""
+    P1, ps, GD = pool.shape
+    G = GD // hd
+    r = math.gcd(ps, _TILE_ROWS)
+    heads = jnp.broadcast_to(
+        jnp.arange(G, dtype=jnp.int32)[None, :, None], pages.shape)
+    dims = jax.lax.GatherDimensionNumbers(
+        offset_dims=(3, 4, 5), collapsed_slice_dims=(0, 3),
+        start_index_map=(0, 3))
+    out = jax.lax.gather(
+        pool.reshape(P1, ps // r, r, G, hd),
+        jnp.stack([pages, heads], axis=-1), dims, (1, ps // r, r, 1, hd),
+        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+    return out.reshape(pages.shape + (ps, hd))
+
+
 def _read_selected(qh, k_pages, v_pages, block_table, blocks, ok, t, rep):
     """Decode: ``qh`` [B, H, 1, hd] attends the tokens of its selected
     pages, ``READ_PAGES`` pages an iteration, as many iterations as the
     row that selected most needs."""
     B, H, _, hd = qh.shape
-    P1, G, ps, _ = k_pages.shape
+    P1, ps, _ = k_pages.shape
+    G = H // rep
     sink = P1 - 1
     n_max = blocks.shape[-1]
     bp = min(READ_PAGES, n_max)
@@ -248,21 +285,17 @@ def _read_selected(qh, k_pages, v_pages, block_table, blocks, ok, t, rep):
     phys = jnp.take_along_axis(
         jnp.broadcast_to(block_table[:, None, :], (B, G) + block_table.shape[1:]),
         blocks, axis=-1)
-    # [P * G, ps, hd]: a (page, kv head) pair is one gathered slice
-    flat = jnp.where(ok, phys, sink) * G \
-        + jnp.arange(G, dtype=jnp.int32)[None, :, None]
-    kf = k_pages.reshape(P1 * G, ps, hd)
-    vf = v_pages.reshape(P1 * G, ps, hd)
+    phys = jnp.where(ok, phys, sink)                               # [B, G, n]
     n_it = (jnp.max(ok.sum(axis=-1)) + bp - 1) // bp
     q = qh.reshape(B, G, rep, hd).astype(k_pages.dtype)
     scale = 1.0 / math.sqrt(hd)
 
     def step(i, carry):
-        idx = jax.lax.dynamic_slice_in_dim(flat, i * bp, bp, axis=2)
+        idx = jax.lax.dynamic_slice_in_dim(phys, i * bp, bp, axis=2)
         blk = jax.lax.dynamic_slice_in_dim(blocks, i * bp, bp, axis=2)
         good = jax.lax.dynamic_slice_in_dim(ok, i * bp, bp, axis=2)
-        kb = kf[idx].reshape(B, G, bp * ps, hd)
-        vb = vf[idx].reshape(B, G, bp * ps, hd)
+        kb = _head_windows(k_pages, idx, hd).reshape(B, G, bp * ps, hd)
+        vb = _head_windows(v_pages, idx, hd).reshape(B, G, bp * ps, hd)
         col = (blk[..., None] * ps
                + jnp.arange(ps, dtype=jnp.int32)).reshape(B, G, bp * ps)
         mask = jnp.repeat(good, ps, axis=-1) & (col <= t[:, None, None])
@@ -282,10 +315,11 @@ def _read_selected(qh, k_pages, v_pages, block_table, blocks, ok, t, rep):
 def _walk_masked(qh, k_pages, v_pages, block_table, cols, selected, rep):
     """A prefill chunk: query row t of batch row b (at column ``cols[b,
     t]``) attends the columns ``j <= cols[b, t]`` of the blocks
-    ``selected[b, g, t]`` holds, one span of pages an iteration over every
-    live page."""
+    ``selected[b, g, t]`` holds, one span of whole pages an iteration over
+    every live page, the heads split on the gathered span."""
     B, H, T, hd = qh.shape
-    P1, G, ps, _ = k_pages.shape
+    P1, ps, _ = k_pages.shape
+    G = H // rep
     maxp = block_table.shape[1]
     sink = P1 - 1
     bp = min(max(WALK_TOKENS // ps, 1), maxp)
@@ -299,13 +333,11 @@ def _walk_masked(qh, k_pages, v_pages, block_table, cols, selected, rep):
     n_it = (live + span - 1) // span
     q = qh.reshape(B, G, rep, T, hd).astype(k_pages.dtype)
     scale = 1.0 / math.sqrt(hd)
-    kt = k_pages.transpose(0, 2, 1, 3)
-    vt = v_pages.transpose(0, 2, 1, 3)
 
     def step(i, carry):
         pages = jax.lax.dynamic_slice_in_dim(table, i * bp, bp, axis=1)
-        kb = kt[pages].reshape(B, span, G, hd)
-        vb = vt[pages].reshape(B, span, G, hd)
+        kb = k_pages[pages].reshape(B, span, G, hd)
+        vb = v_pages[pages].reshape(B, span, G, hd)
         col = i * span + jnp.arange(span, dtype=jnp.int32)
         causal = col[None, None, :] <= last[:, :, None]           # [B,T,span]
         sel = jnp.repeat(jax.lax.dynamic_slice_in_dim(
@@ -327,26 +359,27 @@ def _walk_masked(qh, k_pages, v_pages, block_table, cols, selected, rep):
 # traces and lowers it once
 @jax.named_scope("mx.sparse_attn")
 @functools.partial(jax.jit, static_argnames=("rep", "sc"))
-def sparse_paged_attention(qh, kh, vh, k_pages, v_pages, kc_pages,
+def sparse_paged_attention(qh, k_rows, v_rows, k_pages, v_pages, kc_pages,
                            block_table, pos, valid, rep, sc: SparseConfig):
-    """``qh`` [B, H, T, hd], ``kh``/``vh`` [B, G, T, hd]: the row's T new
-    positions from ``pos`` [B], ``valid`` [B] of them real. Pools
-    ``[pages + 1, G, block, hd]`` (K, V) and ``[pages + 1, G, block //
-    stride, hd]`` (compressed keys); the last page of each is the sink.
+    """``qh`` [B, H, T, hd], ``k_rows``/``v_rows`` [B, T, G * hd]: the row's
+    T new positions from ``pos`` [B], ``valid`` [B] of them real. Pools
+    ``[pages + 1, block, G * hd]`` (K, V) and ``[pages + 1, block //
+    stride, G * hd]`` (compressed keys); the last page of each is the sink.
     Returns ``(out [B, H, T, hd], k_pages, v_pages, kc_pages)``."""
     B, H, T, hd = qh.shape
-    G = k_pages.shape[1]
+    G = H // rep
     maxp = block_table.shape[1]
     pb = compressed_per_page(sc)
     pos = jnp.asarray(pos, jnp.int32)
     cols = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-    k_pages, v_pages = _write(kh, vh, k_pages, v_pages, block_table, cols)
+    k_pages, v_pages = _write(k_rows, v_rows, k_pages, v_pages, block_table,
+                              cols)
     with jax.named_scope("mx.kv_compress"):
         kc_pages = _compress(k_pages, kc_pages, block_table, pos,
                              jnp.asarray(valid, jnp.int32), T, sc)
     # the row's compressed keys in table order: [B, G, maxp * pb, hd]
-    kc = kc_pages[block_table].transpose(0, 2, 1, 3, 4).reshape(
-        B, G, maxp * pb, hd)
+    kc = kc_pages[block_table].reshape(B, maxp * pb, G, hd).transpose(
+        0, 2, 1, 3)
     t = jnp.minimum(cols, maxp * sc.block - 1)
     q = qh.reshape(B, G, rep, T, hd).astype(kc.dtype)
     if T == 1:

@@ -84,7 +84,9 @@ Single-host, single-device engine; params are captured at construction
 and every program that writes them is given them: prefill, chunk,
 step, verify, copy and inject donate the pools, so a program updates the
 few rows it writes in the buffer where they lie and returns that buffer
-(``stats()["pool_bytes_in_place"]`` against ``kv_bytes``). Only the engine
+(``stats()["pool_bytes_in_place"]`` against ``kv_bytes``, and
+``stats()["pool_copies"]``, 0 where no program copies a pool into another
+layout and back, which an aliased pool can still be). Only the engine
 loop touches ``self._pools``: an array read from another thread may have
 been donated since, so page exports and imports run at a tick boundary.
 A step reads only the blocks of pages that its deepest row has reached
@@ -99,7 +101,9 @@ from __future__ import annotations
 
 import dataclasses
 import gc
+import math
 import queue as _qmod
+import re
 import threading
 import time
 import warnings
@@ -316,17 +320,83 @@ def _program(fn) -> str:
     return f"jit_{fn.__name__}"
 
 
-def _alias_bytes(fn, args) -> int:
-    """Bytes of its arguments that the program ``fn`` compiles to updates
-    where they lie (``memory_analysis().alias_size_in_bytes``; 0 where
-    the backend gives no analysis). The compilation is the one the first
-    call would have made: the jit keeps the executable."""
+def _compiled(fn, args):
+    """The executable that the first call of ``fn`` would have made (the
+    jit keeps it), or None where the backend gives none."""
     try:
-        compiled = (getattr(fn, "_compiled", None)
-                    or fn.lower(*args).compile())
+        return (getattr(fn, "_compiled", None)
+                or fn.lower(*args).compile())
+    except Exception:
+        return None
+
+
+def _alias_bytes(compiled) -> int:
+    """Bytes of its arguments that a program compiles to updates where they
+    lie (``memory_analysis().alias_size_in_bytes``; 0 where the backend
+    gives no analysis)."""
+    try:
         return int(compiled.memory_analysis().alias_size_in_bytes)
     except Exception:
         return 0
+
+
+# one instruction of an optimized HLO text: name, result, opcode, operand
+_INSTRUCTION = re.compile(
+    r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(%?([\w.\-]*)")
+_ARRAY = re.compile(r"(\w+)\[([\d,]*)\](?:\{([^}]*)\})?")
+
+
+def _pool_copies(compiled, pools) -> int:
+    """``count_pool_copies`` over one executable's text (0 where the backend
+    gives none). Reading a program's text back costs 0.1-0.2 s on the v5e
+    host for a 12-layer program, more for deeper ones, so the engine reads
+    the first program of each kind only."""
+    try:
+        text = compiled.as_text()
+    except Exception:
+        return 0
+    return count_pool_copies(text, [p.shape for p in pools])
+
+
+def _laid_out(array):
+    """``(dtype, dims, layout)`` of an HLO array as a copy sees it: the
+    memory space (``S(1)``) left out of the layout."""
+    dtype, dims, layout = array
+    return dtype, dims, re.sub(r"S\(\d+\)", "", layout)
+
+
+def count_pool_copies(hlo_text: str, shapes) -> int:
+    """Instructions of an optimized HLO text that copy a whole pool into
+    another shape or layout: a ``copy`` or ``copy-start`` (a ``copy-done``
+    ends the latter) whose result holds as many elements as a pool of one
+    of ``shapes`` and is laid out otherwise than its operand: the pool
+    relaid out, or viewed flat for a scatter. An aliased pool can still be
+    copied so, written there and copied back, which
+    ``alias_size_in_bytes`` counts as in place. A copy that keeps the
+    layout and changes only the memory space (the compiler staging a small
+    pool nearer for the reads that follow) is not counted."""
+    sizes = {math.prod(s) for s in shapes}
+    copies = []
+    for line in hlo_text.splitlines():
+        if " copy" not in line:
+            continue
+        m = _INSTRUCTION.match(line)
+        if not m or m.group(3) not in ("copy", "copy-start"):
+            continue
+        out = _ARRAY.findall(m.group(2))[:1]
+        if out and math.prod(int(d) for d in out[0][1].split(",") if d) \
+                in sizes:
+            copies.append((_laid_out(out[0]), m.group(4)))
+    operands = {name for _, name in copies}
+    defined = {}
+    for line in hlo_text.splitlines() if operands else ():
+        name = line.split(" = ", 1)[0].strip().replace("ROOT ", "")
+        if name.lstrip("%") in operands:
+            m = _INSTRUCTION.match(line)
+            out = _ARRAY.findall(m.group(2))[:1] if m else []
+            if out:
+                defined[name.lstrip("%")] = _laid_out(out[0])
+    return sum(1 for laid, name in copies if defined.get(name) != laid)
 
 
 #: a tick whose wall time passes this is written to the flight recorder
@@ -1109,6 +1179,11 @@ class InferenceEngine:
         # stats()["pool_bytes_in_place"]: None until a program that
         # writes the pools is built
         self._in_place: Optional[int] = None
+        # stats()["pool_copies"]: instructions that copy a whole pool into
+        # another layout (count_pool_copies), in the first program built
+        # of each kind that writes the pools
+        self._pool_copies = 0
+        self._copies_read: set = set()
         # batched scoring (teacher-forced logprobs): its own bucket
         # ladder over the prompt geometry — warmed by warmup_score()
         self._score_fns: Dict[int, Any] = {}
@@ -1985,9 +2060,14 @@ class InferenceEngine:
                         meta={"bucket": bucket, "slots": self.S,
                               "max_len": self.L, "multi_token": self.K})
                 if label in self._POOL_WRITERS:
-                    alias = _alias_bytes(fn, args)
+                    compiled = _compiled(fn, args)
+                    alias = _alias_bytes(compiled)
                     self._in_place = (alias if self._in_place is None
                                       else min(self._in_place, alias))
+                    if compiled is not None and label not in self._copies_read:
+                        self._copies_read.add(label)
+                        self._pool_copies += _pool_copies(compiled,
+                                                          self._pools)
                 cache[bucket] = fn
             else:
                 _metrics.CACHE_HITS.labels(block=f"serve_{label}").inc()
@@ -3679,9 +3759,15 @@ class InferenceEngine:
             # of those, the bytes that every program built so far that
             # writes the pools updates where they lie: the least
             # memory_analysis().alias_size_in_bytes among them. Equal to
-            # kv_bytes when no program copies a pool; 0 where the backend
-            # gives no analysis, and before the first program is built
+            # kv_bytes when every program aliases every pool; 0 where the
+            # backend gives no analysis, and before the first program is
+            # built
             "pool_bytes_in_place": self._in_place or 0,
+            # what that cannot see: copies of an aliased pool into another
+            # layout and back, in the first program of each kind among them
+            # (count_pool_copies). 0 when those write and read the pools as
+            # they lie
+            "pool_copies": self._pool_copies,
         }
         if self.spec:
             out["spec"] = {

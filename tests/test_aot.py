@@ -167,6 +167,41 @@ def test_unserializable_executable_leaves_signature_stub(
     assert aot_dir.entries()[0]["kind"] == aot.KIND_SIGNATURE
 
 
+def test_entry_stored_after_a_jax_cache_hit_restores_and_runs(tmp_path):
+    """An AOT miss that jax's own persistent compilation cache answers
+    still stores an executable that restores and runs: XLA:CPU serializes
+    an executable that jax's cache loaded without its code."""
+    from jax.experimental.compilation_cache import compilation_cache as jcc
+
+    def jitted():                 # a new function each time: no shared trace
+        def f(p, x):
+            h = jnp.tanh(p)
+            return jnp.where(x[:, None] > 0, h + 1.0, h * 2.0).sum(0)
+        return jax.jit(f)
+
+    args = (jnp.ones((16, 8)), jnp.arange(16) - 3)
+    want = onp.asarray(jitted()(*args))
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    jcc.reset_cache()
+    jax.config.update(keys[0], str(tmp_path / "jax"))
+    jax.config.update(keys[1], 0)
+    try:
+        aot.enable(str(tmp_path / "first"))
+        aot.compile_cached(jitted(), args, label="t")    # fills jax's cache
+        aot.enable(str(tmp_path / "second"))
+        aot.compile_cached(jitted(), args, label="t")    # jax's cache answers
+        fn = aot.compile_cached(jitted(), args, label="t")
+        assert fn.from_cache
+        assert onp.array_equal(onp.asarray(fn(*args)), want)
+    finally:
+        aot.disable()
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        jcc.reset_cache()
+
+
 # ------------------------------------------------------------- integration
 def _dense_net():
     mx.random.seed(0)

@@ -163,6 +163,94 @@ def test_selection_is_the_references():
     assert dropped > 0
 
 
+# (T, the rows' positions, the rows' valid counts): a decoding row under
+# dense_len, one past it, one whose column lies past the table's reach of 64;
+# a chunk at the front, one past dense_len that ends in padding, one that
+# crosses the reach. A fourth row of every batch holds no page (its table is
+# the sink's) and writes only the sink
+_ROW_POOL_CASES = {
+    "decode": (1, (5, 40, 64), (1, 1, 1)),
+    "chunk": (8, (0, 33, 60), (8, 5, 8)),
+}
+
+
+@pytest.mark.parametrize("case", list(_ROW_POOL_CASES))
+def test_row_pools_give_dense_attention_over_the_selection(case):
+    """``sparse_paged_attention`` over pools of rows ``[pages + 1, block,
+    G * hd]``: each real query's output is dense float64 attention over the
+    columns at or before it of the blocks the reference selects, and the
+    pools hold afterwards what they held with each new key, value and
+    completed compressed key in its place: a real page at its column, a
+    column past the table's reach in the sink page, no other page
+    touched."""
+    T, pos, valid = _ROW_POOL_CASES[case]
+    sc, z = sparse_config(), ref.sizes(CFG)
+    H, G, hd, ps = z["H"], z["G"], z["hd"], sc.block
+    rep, pb, maxp = H // G, sa.compressed_per_page(sc), 16
+    reach, B = maxp * ps, 4
+    sink = B * maxp
+    rng = np.random.RandomState(T)
+    table = np.full((B, maxp), sink, np.int32)
+    table[:3] = rng.permutation(3 * maxp).reshape(3, maxp)
+    pos, valid = np.asarray(pos + (0,), np.int32), np.asarray(valid + (T,),
+                                                              np.int32)
+    keys = rng.standard_normal((B, G, pos.max() + T, hd))
+    vals = rng.standard_normal((B, G, pos.max() + T, hd))
+    k_pool, v_pool, kc_pool = (rng.standard_normal((sink + 1, n, G * hd))
+                               for n in (ps, ps, pb))
+    row = lambda x, b, c: x[b, :, c].reshape(-1)                   # [G * hd]
+    kc_of = lambda b, j: keys[b, :, sc.stride * j:sc.stride * j
+                              + sc.kernel].mean(axis=1).reshape(-1)
+    for b in range(3):                     # what earlier positions wrote
+        for c in range(min(pos[b], reach)):
+            k_pool[table[b, c // ps], c % ps] = row(keys, b, c)
+            v_pool[table[b, c // ps], c % ps] = row(vals, b, c)
+        for j in range((pos[b] - sc.kernel) // sc.stride + 1):
+            kc_pool[table[b, j // pb], j % pb] = kc_of(b, j)
+    want_k, want_v, want_kc = k_pool.copy(), v_pool.copy(), kc_pool.copy()
+    for b in range(3):
+        for c in range(pos[b], min(pos[b] + T, reach)):
+            want_k[table[b, c // ps], c % ps] = row(keys, b, c)
+            want_v[table[b, c // ps], c % ps] = row(vals, b, c)
+        for j in range(max(pos[b] - sc.kernel + 1, 0) // sc.stride,
+                       (pos[b] + valid[b] - sc.kernel) // sc.stride + 1):
+            if sc.stride * j + sc.kernel - 1 >= pos[b] and \
+                    sc.stride * j + sc.kernel - 1 < reach:
+                want_kc[table[b, j // pb], j % pb] = kc_of(b, j)
+    q = rng.standard_normal((B, H, T, hd))
+    new = lambda x: np.stack([x[b, :, pos[b]:pos[b] + T].transpose(1, 0, 2)
+                              .reshape(T, G * hd) for b in range(B)])
+    f32 = lambda x: jnp.asarray(x, jnp.float32)
+    out, kp, vp, kcp = sa.sparse_paged_attention(
+        f32(q), f32(new(keys)), f32(new(vals)), f32(k_pool), f32(v_pool),
+        f32(kc_pool), jnp.asarray(table), jnp.asarray(pos),
+        jnp.asarray(valid), rep=rep, sc=sc)
+    for got, want in ((kp, want_k), (vp, want_v), (kcp, want_kc)):
+        np.testing.assert_allclose(np.asarray(got)[:sink], want[:sink],
+                                   rtol=1e-6, atol=1e-6)
+    checked = 0
+    for b in range(3):
+        n_c = (pos[b] + valid[b] - sc.kernel) // sc.stride + 1
+        kc = np.stack([kc_of(b, j).reshape(G, hd) for j in range(n_c)], 1)
+        cols = pos[b] + np.arange(valid[b])
+        cols = cols[cols < reach]
+        sel = np.asarray(ref._selected(
+            f32(q[b, :, :len(cols)]), f32(kc), jnp.asarray(cols), z, maxp))
+        for t, c in enumerate(cols):
+            for h in range(H):
+                g = h // rep
+                read = np.repeat(sel[g, t], ps)[:c + 1]
+                s = keys[b, g, :c + 1] @ q[b, h, t] / np.sqrt(hd)
+                s = np.where(read, s, -np.inf)
+                p = np.exp(s - s.max())
+                want = (p / p.sum()) @ vals[b, g, :c + 1]
+                np.testing.assert_allclose(np.asarray(out)[b, h, t], want,
+                                           rtol=2e-5, atol=2e-5)
+            checked += 1
+    assert checked == sum(min(p + v, reach) - p for p, v in
+                          zip(pos[:3], valid[:3]))
+
+
 # ---------------------------------------------- the model, by hand, on logits
 def by_hand(net, seq, n_prompt, chunk):
     """Prefill ``seq[:n_prompt]`` in chunks of ``chunk`` (the last one padded
@@ -252,6 +340,24 @@ def test_engine_serves_the_references_tokens_and_counts_its_reads(net,
         assert stats["state_bytes"] == 2 * 5 * 4 * 16 * 16 * 4
     finally:
         eng.shutdown()
+
+
+def test_engine_reads_no_pool_copy_in_its_programs(net):
+    """``stats()["pool_copies"]`` is 0 before a program is built and an int
+    after ``warmup()`` has built them all: the first step, prefill and chunk
+    read back, with the page and state pools they were given. The described
+    chip's count is ``tests/test_tpu_compile.py``'s."""
+    eng = InferenceEngine(net, max_batch_size=2, max_len=64, page_size=PS,
+                          num_pages=32, prefill_chunk=8, min_prompt_bucket=2,
+                          prefix_cache=False)
+    assert eng.stats()["pool_copies"] == 0
+    eng.warmup()
+    stats = eng.stats()
+    assert type(stats["pool_copies"]) is int and stats["pool_copies"] == 0
+    row = 2 * 16                             # KV heads x head width
+    assert [tuple(p.shape) for p in eng._pools[:3]] == [
+        (33, PS, row), (33, PS, row),
+        (33, sa.compressed_per_page(sparse_config()), row)]
 
 
 def test_rows_of_one_batch_equal_each_alone_and_a_reused_slot_starts_clean(

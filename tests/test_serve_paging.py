@@ -463,17 +463,60 @@ def test_every_program_updates_the_pools_in_place(gpt_model, name):
     kw.update(IN_PLACE_ENGINES[name])
     eng = InferenceEngine(net, **kw)
     assert eng.stats()["pool_bytes_in_place"] == 0      # nothing built yet
+    assert eng.stats()["pool_copies"] == 0
     given = eng._pools
     eng.warmup()
     stats = eng.stats()
     if given[0].is_deleted():       # the backend donates (the CPU's does)
         assert stats["pool_bytes_in_place"] == stats["kv_bytes"] > 0
+    # the first program of each kind was read back: none relays a pool out
+    assert type(stats["pool_copies"]) is int and stats["pool_copies"] == 0
     assert not any(p.is_deleted() for p in eng._pools)
     # the examples wrote the sink page only
     for p, ax in zip(eng._pools, eng._paxes):
         live = onp.take(onp.asarray(p, onp.float32),
                         range(p.shape[ax] - 1), axis=ax)
         assert not live.any()
+
+
+# optimized HLO as the described v5e gives it, cut to the lines that matter:
+# a pool relaid out for a scatter and back (heads ahead of rows), the same pool
+# staged in another memory space as declared, and an array of another size
+_POOL_HLO = {
+    "relaid": ("%kp.1 = bf16[9,2,4,128]{3,2,1,0:T(8,128)(2,1)} parameter(3)\n"
+               "%copy.1 = bf16[9,2,4,128]{3,1,2,0:T(2,128)(2,1)S(1)} "
+               "copy(%kp.1), sharding={replicated}\n"
+               "%fusion.8 = bf16[9,2,4,128]{3,1,2,0:T(2,128)(2,1)S(1)} "
+               "fusion(%copy.1), kind=kCustom\n"
+               "ROOT %copy.2 = bf16[9,2,4,128]{3,2,1,0:T(8,128)(2,1)} "
+               "copy(%fusion.8)", 2),
+    "flat": ("%p = bf16[9,8,256]{2,1,0:T(8,128)(2,1)} parameter(0)\n"
+             "%copy-start = (bf16[72,2,128]{2,1,0:T(2,128)(2,1)}, "
+             "bf16[9,8,256]{2,1,0:T(8,128)(2,1)}, u32[]{:S(2)}) "
+             "copy-start(%p)", 1),
+    "staged": ("%f = bf16[9,8,256]{2,1,0:T(8,128)(2,1)S(1)} fusion(%p)\n"
+               "%copy-start.1 = (bf16[9,8,256]{2,1,0:T(8,128)(2,1)}, "
+               "bf16[9,8,256]{2,1,0:T(8,128)(2,1)S(1)}, u32[]{:S(2)}) "
+               "copy-start(%f)\n"
+               "%copy-done.1 = bf16[9,8,256]{2,1,0:T(8,128)(2,1)} "
+               "copy-done(%copy-start.1)\n"
+               "%q = bf16[9,8,256]{2,1,0:T(8,128)(2,1)} parameter(1)\n"
+               "%copy.3 = bf16[9,8,256]{2,1,0:T(8,128)(2,1)S(1)} copy(%q)",
+               0),
+    "other_size": ("%a = bf16[9,4,128]{2,1,0} parameter(0)\n"
+                   "%copy.4 = bf16[9,4,128]{1,2,0} copy(%a)", 0),
+}
+
+
+@pytest.mark.parametrize("case", list(_POOL_HLO))
+def test_pool_copies_count_what_moves_a_pool_into_another_layout(case):
+    """``count_pool_copies`` (``stats()["pool_copies"]``) counts a copy whose
+    result holds as many elements as a pool, laid out otherwise than its
+    operand: relaid, or flattened for a scatter; not a copy that only moves
+    the pool to another memory space, nor one of another size."""
+    from mxnet_tpu.serve.engine import count_pool_copies
+    text, want = _POOL_HLO[case]
+    assert count_pool_copies(text, [(9, 2, 4, 128), (9, 8, 256)]) == want
 
 
 @pytest.mark.parametrize("running", [False, True],

@@ -222,18 +222,52 @@ def test_trainstep_body_compiles_data_parallel(topo, one_chip, as_tpu):
 @pytest.mark.parametrize("rows,T", [(16, 1), (1, 1024)],
                          ids=["decode_b16", "chunk_c1024"])
 def test_sparse_paged_attention_compiles(one_chip, rows, T):
+    """A decode step of 16 rows and a chunk of 1,024 positions, the pools
+    donated: the chip keeps the ``[2561, 64, 256]`` K and V pools as
+    declared (a position's two heads as one row of two lane tiles), no
+    instruction copies a pool, nor the ``[163904, 256]`` view the chunk's
+    scatter writes through, and every pool that goes in comes out as the
+    same buffer. With ``[2561, 2, 64, 128]`` pools both programs copied
+    each pool into the scatter's layout and back, aliased all the same, so
+    that ``alias_size_in_bytes`` could not tell: ``count_pool_copies``,
+    which ``stats()["pool_copies"]`` sums, read 6 in each. A CPU run cannot
+    see this."""
+    import re
+    from mxnet_tpu.serve.engine import count_pool_copies
     sa = importlib.import_module("mxnet_tpu.ops.sparse_attention")
     bf, i32 = jnp.bfloat16, jnp.int32
-    pages = lambda last: _s(one_chip, (2561, 2, last, 128), bf)
-    _compile(
-        lambda q, k, v, kp, vp, kc, bt, pos, valid: sa.sparse_paged_attention(
-            q, k, v, kp, vp, kc, bt, pos, valid, rep=16,
+    kv, kc = (2561, 64, 256), (2561, 4, 256)
+    rows_in = _s(one_chip, (rows, T, 256), bf)
+    compiled = jax.jit(
+        lambda q, k, v, kp, vp, kcp, bt, pos, valid: sa.sparse_paged_attention(
+            q, k, v, kp, vp, kcp, bt, pos, valid, rep=16,
             sc=sa.SparseConfig()),
-        _s(one_chip, (rows, 32, T, 128), bf),
-        _s(one_chip, (rows, 2, T, 128), bf),
-        _s(one_chip, (rows, 2, T, 128), bf), pages(64), pages(64), pages(4),
+        donate_argnums=(3, 4, 5)).lower(
+        _s(one_chip, (rows, 32, T, 128), bf), rows_in, rows_in,
+        _s(one_chip, kv, bf), _s(one_chip, kv, bf), _s(one_chip, kc, bf),
         _s(one_chip, (rows, 520), i32), _s(one_chip, (rows,), i32),
-        _s(one_chip, (rows,), i32))
+        _s(one_chip, (rows,), i32)).compile()
+    text = compiled.as_text()
+    entry = re.search(r"entry_computation_layout=\{\((.*?)\)->", text).group(1)
+    assert re.findall(r"bf16\[2561,64,256\]\{([\d,]*)", entry) == ["2,1,0"] * 2
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if _opcode(line)[0] in ("copy", "copy-start", "copy-done")
+              and any(shape in _opcode(line)[1] for shape in (
+                  "[2561,64,256]", "[163904,", "[10244,"))]
+    assert not copies, copies
+    # the compressed keys (5.2 MB) may be staged nearer in the 16-row step,
+    # whose rows gather 17 MB of them: as declared, in another memory space
+    staged = [_opcode(line) for line in text.splitlines()
+              if "[2561,4,256]" in _opcode(line)[1]
+              and _opcode(line)[0] in ("copy", "copy-start", "copy-done")]
+    assert all(op != "copy" and shape.count("{2,1,0:T(4,128)(2,1)") ==
+               shape.count("[2561,4,256]") for op, shape in staged), staged
+    assert count_pool_copies(text, [kv, kv, kc]) == 0
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry_computation",
+                        text).group(1)
+    assert aliased.count("alias") == 3
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == 2 * 2561 * 64 * 256 * 2 + 2561 * 4 * 256 * 2
 
 
 @pytest.mark.parametrize("rows,T", [(16, 1), (1, 1024)],
